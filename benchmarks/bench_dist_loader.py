@@ -158,24 +158,10 @@ def main():
                   help='lax.scan chunk size K for --scan')
   args = ap.parse_args()
 
-  if not args.tpu:
-    # jax 0.4.x has no jax_num_cpu_devices config key — the XLA flag
-    # must be in place before backend init (conftest.py's pattern)
-    import os
-    import re
-    flags = re.sub(r'--xla_force_host_platform_device_count=\d+', '',
-                   os.environ.get('XLA_FLAGS', ''))
-    os.environ['XLA_FLAGS'] = (
-        flags +
-        f' --xla_force_host_platform_device_count={args.cpu_devices}'
-    ).strip()
   import jax
   if not args.tpu:
     jax.config.update('jax_platforms', 'cpu')
-    try:
-      jax.config.update('jax_num_cpu_devices', args.cpu_devices)
-    except AttributeError:
-      pass   # jax 0.4.x: XLA_FLAGS above is the knob
+    jax.config.update('jax_num_cpu_devices', args.cpu_devices)
   from jax.sharding import Mesh
 
   sys.path.insert(0, __file__.rsplit('/', 2)[0])
@@ -271,8 +257,7 @@ def main():
 
 def _scan_ab(args, jax, glt):
   """Per-step collocated training epoch vs DistScanTrainer's scanned
-  epoch, per mesh size: instrumented dispatch counts (the wall-clock
-  story on the remote-dispatch rig — PERF.md) plus CPU-mesh wall as a
+  epoch, per mesh size: instrumented dispatch counts plus CPU-mesh wall as a
   scheduling sanity check. Both arms run the SAME data-parallel update
   (pipeline.DistFusedEpochTrainer), so the A/B isolates epoch
   EXECUTION: ~5 dispatches/step vs ceil(steps/K) + 2 per epoch."""

@@ -9,8 +9,8 @@ mesh story is checkable on a laptop:
 
     python benchmarks/dryrun_multichip.py --devices 8
 
-Pass --tpu to run on the attached accelerator devices instead (the
-device count must then not exceed the real chip count).
+Pass --tpu to run on the attached accelerator devices instead; it fails
+if jax finds no TPU or fewer chips than --devices.
 """
 import argparse
 import os
@@ -22,11 +22,9 @@ def main():
   ap.add_argument('--devices', type=int, default=8,
                   help='mesh size (virtual CPU devices unless --tpu)')
   ap.add_argument('--tpu', action='store_true',
-                  help='use the attached accelerator devices (skips the '
-                       'CPU-platform override)')
+                  help='use the attached accelerator devices; fails if '
+                       'there are fewer than --devices')
   args = ap.parse_args()
-  if not args.tpu:
-    os.environ.setdefault('JAX_PLATFORMS', 'cpu')
   root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
   sys.path.insert(0, root)
   import importlib.util
@@ -34,7 +32,7 @@ def main():
       '_glt_graft_entry', os.path.join(root, '__graft_entry__.py'))
   entry = importlib.util.module_from_spec(spec)
   spec.loader.exec_module(entry)
-  entry.dryrun_multichip(args.devices)
+  entry.dryrun_multichip(args.devices, tpu=args.tpu)
 
 
 if __name__ == '__main__':

@@ -141,8 +141,9 @@ def main():
 
   # in-process CPU collectives can deadlock when several multi-device
   # programs are in flight (docs/get_started/dist_train.md "Testing
-  # without hardware") — serialize steps on the CPU mesh; real TPU
-  # collectives ride ICI and need no barrier
+  # without hardware") — serialize steps on the CPU mesh. TPU
+  # collectives need no barrier: the per-step mesh loop ran unserialized
+  # on the four-chip v5e host (PERF.md "Bring-up (PR 21)")
   serialize = jax.default_backend() == 'cpu'
   losses, accs, epoch_times = [], [], []
   for epoch in range(args.epochs):

@@ -5,7 +5,12 @@ sage_supervised_server.py: the server owns the graph + features, runs
 sampling producers on request, and streams batches to training clients
 over RPC. Start this first; it prints its endpoint for the client.
 
-Run: python examples/distributed/server_client/sage_server.py --port 18777
+A chip belongs to one process: beside a trainer on a one-chip machine the
+server is started on the CPU backend (graph_mode='CPU' keeps the arrays on
+the host but does not pick a backend — the sampler uploads to whatever
+backend this process has).
+
+Run: JAX_PLATFORMS=cpu python examples/distributed/server_client/sage_server.py --port 18777
 """
 import argparse
 import os

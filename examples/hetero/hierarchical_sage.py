@@ -128,8 +128,7 @@ def main():
         accs.append(acc)
       jax.block_until_ready(losses[-1])
       epoch_times.append(time.perf_counter() - t0)
-    # keep device handles; fetching here would degrade the NEXT
-    # variant's dispatch on this rig (PERF.md property 2)
+    # keep device handles; everything is fetched once, after the loop
     report[name] = {
         'first_loss': losses[0], 'final_loss': losses[-1],
         'final_acc': accs[-1],

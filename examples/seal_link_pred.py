@@ -77,9 +77,7 @@ def main():
 
   import jax
   if args.platform == 'cpu':
-    # env-var selection (JAX_PLATFORMS) is not honored by this jax
-    # build; the config key is (tests/conftest.py) — must run before
-    # any backend use
+    # must run before any backend use
     jax.config.update('jax_platforms', 'cpu')
   import jax.numpy as jnp
   import flax.linen as nn

@@ -1,12 +1,9 @@
 """Rule compat-shard-map: shard_map resolves ONLY through utils/compat.
 
-``jax.shard_map`` is a moving target across the jax versions this
-package must run on (top-level export on the TPU rig's jax, the
-``jax.experimental.shard_map`` module on the 0.4.x CI images, and the
-``check_rep``/``check_vma`` keyword rename between them).
-``utils/compat.py`` owns that resolution; a direct import anywhere else
-reintroduces exactly the ~40-collection-failure class of breakage PR 3
-fixed, invisible until the code runs on the other jax.
+``utils/compat.py`` is the package's one ``shard_map`` home: it owns the
+spelling of the replication-check keyword (``check_replication`` ->
+``check_vma``), so a jax rename is a one-file change. A direct import
+anywhere else splits that knowledge across the call sites again.
 """
 import ast
 from typing import List
@@ -17,9 +14,8 @@ from .core import Config, Finding, ParsedModule
 RULE = 'compat-shard-map'
 
 _MSG = ('direct {what} — shard_map must resolve through '
-        'utils/compat.py (version shim: top-level vs experimental home, '
-        'check_rep/check_vma rename); import '
-        '`from ..utils.compat import shard_map` instead')
+        'utils/compat.py (the one home of the replication-check '
+        'keyword); import `from ..utils.compat import shard_map` instead')
 
 
 def check_package(modules: List[ParsedModule], config: Config):

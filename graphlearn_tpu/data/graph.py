@@ -5,7 +5,8 @@ TPU-native re-design of /root/reference/graphlearn_torch/python/data/graph.py.
 ``Topology`` is the host-side CSR/CSC container (numpy) built from COO/CSR/CSC
 input. ``Graph`` owns the device placement: on TPU the CSR arrays live in HBM
 as jax Arrays (mode ``HBM``, the analog of the reference's CUDA/DMA mode), or
-stay in host RAM (mode ``CPU``); the reference's ZERO_COPY (UVA pinned host
+stay in host RAM until a sampler uploads them (mode ``CPU``); the reference's
+ZERO_COPY (UVA pinned host
 memory readable by the GPU) has no TPU equivalent, so ``ZERO_COPY`` is accepted
 and mapped to ``HBM`` with the cold/overflow path handled by the feature store
 instead.
@@ -117,7 +118,11 @@ class Graph:
   """Device-placed graph (reference: data/graph.py:178-297).
 
   Modes:
-    'CPU'  — arrays stay in host numpy; sampling runs via jax on CPU backend.
+    'CPU'  — arrays stay in host numpy here; a sampler uploads them once
+             to ITS process's default jax backend. The mode does not pick
+             a backend: a process that must stay off the chip (a sampling
+             server or worker beside a trainer) is started with
+             JAX_PLATFORMS=cpu — one process per chip.
     'HBM'  — indptr/indices/eids/weights are jax Arrays resident in device
              HBM (reference CUDA 'DMA' mode analog).
     'ZERO_COPY' — accepted for API parity, maps to 'HBM' (no UVA on TPU; cold

@@ -193,15 +193,14 @@ class UnifiedTensor:
   pallas_v2_run_span = 8
 
   def _pallas_ok(self) -> bool:
-    """All-hot gathers use a Pallas row-DMA kernel only when opted in
-    (either generation's flag) AND the table is single-device
-    TPU-resident with a 128-lane-aligned feature dim."""
-    import jax
-    t = self._device_part
-    return ((self.use_pallas or self.use_pallas_v2) and
-            jax.default_backend() == 'tpu' and
-            t is not None and t.shape[1] % 128 == 0 and
-            len(t.sharding.device_set) == 1)
+    """All-hot gathers use a Pallas row-DMA kernel when opted in (either
+    generation's flag). Off-TPU the flag is inert; on TPU a table the
+    kernel cannot serve raises (ops.table_kernel_ok)."""
+    if not (self.use_pallas or self.use_pallas_v2) or \
+        self._device_part is None:
+      return False
+    from ..ops.gather_pallas import table_kernel_ok
+    return table_kernel_ok('UnifiedTensor', self._device_part)
 
   def _small_block_target(self):
     """Placement for per-batch blocks: replicated when the hot table is

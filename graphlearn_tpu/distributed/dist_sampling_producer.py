@@ -43,11 +43,11 @@ def _sampling_worker_loop(rank, dataset_handle, sampling_config, seeds,
   the dead worker would have produced (batch i's key depends only on
   (worker seed, call index), never on history).
   """
+  # one process per chip: the parent holds it, so this worker selects
+  # the CPU backend before its first backend use (a spawned child has
+  # initialised none; a failure here must be loud, never a TPU open)
   import jax
-  try:
-    jax.config.update('jax_platforms', 'cpu')
-  except RuntimeError:
-    pass
+  jax.config.update('jax_platforms', 'cpu')
   import graphlearn_tpu as glt
 
   # rebuild from host-side ipc handles; device state stays on CPU here

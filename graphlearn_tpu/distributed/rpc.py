@@ -29,8 +29,8 @@ start unless ``insecure=True``); loopback binds may omit it for parity
 with local multiprocess use. Residual risk: the handshake authenticates
 peers but does not encrypt or MAC the frames that follow, so an
 attacker who can rewrite established TCP streams (not just connect) can
-still inject pickles — the network boundary (VPC / firewall / TLS
-tunnel) remains the outer wall against that class.
+still inject pickles — the network boundary (VPC / firewall / TLS)
+remains the outer wall against that class.
 """
 import hashlib
 import hmac

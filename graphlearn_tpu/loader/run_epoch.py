@@ -4,9 +4,8 @@ program stream.
 Epoch-as-a-program (scan_epoch.ScanTrainer) collapsed an epoch to
 ``ceil(steps/K) + 2`` dispatches, but a RUN of E epochs still pays that
 per epoch — ``E * (ceil(steps/K) + 2)`` dispatches plus per-epoch host
-Python (seed redraw, counter bookkeeping). On the remote-dispatch
-runtime PERF.md profiles, those per-epoch prologues are pure dispatch
-tax. :class:`RunTrainer` extends the contract one level up: the E-epoch
+Python (seed redraw, counter bookkeeping) — pure host time between
+programs. :class:`RunTrainer` extends the contract one level up: the E-epoch
 run executes as
 
     ``ceil(E * steps / K) + 2`` dispatches

@@ -1,10 +1,10 @@
 """Epoch-as-a-program: scanned K-step sample -> collate -> train execution.
 
-PERF.md establishes that on this rig the per-step DISPATCH is the dominant
-wall-clock tax: device trace and wall clock diverge by 100-1000x once any
-fetch lands, which is why `OverlappedTrainer` already collapsed 3
-dispatches/step to 1. But an epoch is still ~steps dispatches plus
-per-step host numpy (seed padding). The reference hides sampling latency
+The per-step loop pays three program launches per batch plus per-step
+host numpy (seed padding), and the device idles while the host does it
+(~9 % over two traced steps of the products path on a v5e — PERF.md
+"Bring-up (PR 21)"; not yet a benchmark number). `OverlappedTrainer`
+collapsed 3 dispatches/step to 1. The reference hides sampling latency
 with producer processes/streams (dist_sampling_producer.py); on TPU the
 native answer is to put the LOOP ITSELF on device: `ScanTrainer` executes
 an epoch as ~ceil(steps/K) dispatches — a `lax.scan` over a static chunk
@@ -539,8 +539,7 @@ class DistScanTrainer(DistFusedEpochTrainer):
 
   The per-step distributed loop pays >= 2 program dispatches per batch
   (sample program + collate, plus the feature/label gathers and the
-  train step) and a host numpy seed slice each step — on this rig's
-  remote-dispatch runtime the dominant wall-clock tax (PERF.md). Here
+  train step) and a host numpy seed slice each step. Here
   the scanned chunk is ONE jitted shard_map program whose ``lax.scan``
   body composes, per shard and per step:
 

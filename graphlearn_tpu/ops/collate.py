@@ -3,10 +3,9 @@ jitted dispatch.
 
 The reference collates on the host driver (loader/node_loader.py:85-113
 gathers features via UnifiedTensor then builds PyG Data). Here collation
-must be a single device program for a different reason: an eager op whose
-input is a still-pending sampler output serializes the dispatch pipeline
-on remote-dispatch runtimes (PERF.md), so the loader may not touch the
-sampler's outputs eagerly. All arrays enter as arguments (never closures),
+must be a single device program for a different reason: every eager op
+on a sampler output is one more program launch the host pays per batch,
+so the loader does not touch the sampler's outputs eagerly. All arrays enter as arguments (never closures),
 and optional stores are trace-time ``None`` branches.
 """
 import functools

@@ -32,9 +32,12 @@ tiered-storage staging planner, searchsorted slab gathers) pass
 probed by benchmarks/prof_gather2.py; routing stays evidence-gated
 behind ``UnifiedTensor.use_pallas_v2`` exactly like v1's ``use_pallas``.
 
-Falls back to `jnp.take` off-TPU (interpret mode exists but is orders of
-magnitude slower; tests exercise the kernels via interpret=True on small
-shapes). The fallback is bit-identical: same clamped-id contract.
+Calling one of these ops IS asking for the kernel. Off-TPU they fall back
+to the bit-identical `jnp.take` (same clamped-id contract) so CPU tests
+and examples run; tests exercise the kernels via interpret=True on small
+shapes. On TPU there is no fallback: a table Mosaic cannot serve (width
+not 128-lane aligned) raises with the reason instead of quietly running
+XLA's gather under the kernel's name.
 """
 import functools
 import time
@@ -42,6 +45,53 @@ import warnings
 
 import jax
 import jax.numpy as jnp
+
+
+def _require_lane_aligned(owner: str, table):
+  """Mosaic HBM row slices must be 128-lane aligned; on TPU a kernel that
+  was asked for never quietly becomes XLA's gather."""
+  if table.shape[1] % 128 != 0:
+    raise ValueError(
+        f'{owner}: a Pallas gather was asked for, but the table width '
+        f'{table.shape[1]} is not a multiple of 128 lanes — Mosaic cannot '
+        'lower the HBM row DMA. Pad the width, or gather with jnp.take '
+        '(clear the use_pallas* flag); there is no XLA fallback on TPU')
+
+
+def _kernel_route(name: str, table, ids, interpret: bool, force: bool):
+  """True -> run the Pallas kernel, False -> the off-TPU ``jnp.take``
+  fallback. On TPU a request the kernel cannot serve raises."""
+  if ids.shape[0] == 0:
+    return False
+  if interpret:
+    return True        # the interpreter has no lane constraint
+  if jax.default_backend() == 'tpu':
+    _require_lane_aligned(name, table)
+    return True
+  if force and table.shape[1] % 128 != 0:
+    warnings.warn(
+        f'{name}(force=True): table width {table.shape[1]} is not '
+        '128-lane aligned — Mosaic cannot lower the row DMA; falling '
+        'back to jnp.take', stacklevel=3)
+    return False
+  return force
+
+
+def table_kernel_ok(owner: str, table) -> bool:
+  """Whether a store that was ASKED to route its gathers through these
+  kernels (``use_pallas*``) can: False off-TPU (CPU runs keep the XLA
+  gather), True on TPU — and a raise on TPU when the table cannot be
+  served, so a set flag never means a silent XLA gather there."""
+  if jax.default_backend() != 'tpu':
+    return False
+  _require_lane_aligned(owner, table)
+  if len(table.sharding.device_set) != 1:
+    raise ValueError(
+        f'{owner}: a Pallas gather was asked for, but the table is '
+        f'sharded over {len(table.sharding.device_set)} devices — the '
+        'row-DMA kernels serve a single-device table. Clear the '
+        'use_pallas* flag for sharded stores')
+  return True
 
 
 def _gather_kernel(ids_ref, table_ref, out_ref, sems):
@@ -83,26 +133,12 @@ def gather_rows_hbm(table, ids, block_rows: int = 128,
       gather wins on this chip, so callers opt in explicitly
       (UnifiedTensor.use_pallas) — see benchmarks/prof_gather.py.
     interpret: run the Pallas interpreter (CPU tests).
-    force: run the kernel even off-TPU (tests); default falls back to
-      jnp.take when the backend isn't TPU.
+    force: run the kernel even off-TPU (AOT lowering checks); default
+      falls back to jnp.take when the backend isn't TPU.
 
   Returns [B, F] gathered rows.
   """
-  if force and not interpret and table.shape[1] % 128 != 0:
-    # Mosaic HBM row slices must be 128-lane aligned: a forced kernel on
-    # a misaligned table would reach Mosaic and fail to LOWER, not fall
-    # back — so ``force`` yields to the alignment guard (with a warning;
-    # interpret mode has no lane constraint and keeps honoring force)
-    warnings.warn(
-        f'gather_rows_hbm(force=True): table width {table.shape[1]} is '
-        'not 128-lane aligned — Mosaic cannot lower the row DMA; '
-        'falling back to jnp.take', stacklevel=2)
-    force = False
-  if ids.shape[0] == 0 or (
-      not (interpret or force) and (jax.default_backend() != 'tpu' or
-                                    table.shape[1] % 128 != 0)):
-    # Mosaic HBM row slices must be 128-lane aligned — misaligned tables
-    # fall back to XLA's take (UnifiedTensor._pallas_ok routes accordingly)
+  if not _kernel_route('gather_rows_hbm', table, ids, interpret, force):
     return jnp.take(table, jnp.clip(ids, 0, table.shape[0] - 1), axis=0)
   from jax.experimental import pallas as pl
   from jax.experimental.pallas import tpu as pltpu
@@ -298,7 +334,7 @@ def gather_rows_hbm2(table, ids, block_rows: int = 256, run_span: int = 8,
 
   Args:
     table: [N, F] device array (HBM-resident; F must be 128-lane aligned
-      for the kernel path — misaligned widths fall back like v1).
+      for the kernel path — on TPU a misaligned width raises, like v1).
     ids: [B] int32 row indices (clamped to [0, N)).
     block_rows: output rows per grid step (autotune axis 1).
     run_span: rows per multi-row DMA (autotune axis 2; 1 degenerates to
@@ -307,21 +343,13 @@ def gather_rows_hbm2(table, ids, block_rows: int = 256, run_span: int = 8,
       row permutation (the tiered staging planner's slab gathers and
       any searchsorted-driven caller qualify).
     interpret: run the Pallas interpreter (CPU tests).
-    force: run the kernel even off-TPU; still falls back (with a
-      warning) on misaligned widths, like v1.
+    force: run the kernel even off-TPU; off-TPU it still falls back
+      (with a warning) on misaligned widths, like v1.
 
   Returns [B, F] gathered rows.
   """
   from .. import metrics
-  if force and not interpret and table.shape[1] % 128 != 0:
-    warnings.warn(
-        f'gather_rows_hbm2(force=True): table width {table.shape[1]} is '
-        'not 128-lane aligned — Mosaic cannot lower the run DMA; '
-        'falling back to jnp.take', stacklevel=2)
-    force = False
-  if ids.shape[0] == 0 or (
-      not (interpret or force) and (jax.default_backend() != 'tpu' or
-                                    table.shape[1] % 128 != 0)):
+  if not _kernel_route('gather_rows_hbm2', table, ids, interpret, force):
     metrics.inc('ops.gather_fallbacks')
     return jnp.take(table, jnp.clip(ids, 0, table.shape[0] - 1), axis=0)
   metrics.inc('ops.gather_runs')
@@ -330,7 +358,7 @@ def gather_rows_hbm2(table, ids, block_rows: int = 256, run_span: int = 8,
   record_dispatch('gather2')
   out = _gather_rows_hbm2_impl(table, ids, block_rows, run_span,
                                presorted, interpret)
-  # dispatch clock, NOT device time (PERF.md 'wall clocks LIE'): useful
+  # dispatch clock, NOT device time (the call is asynchronous): useful
   # as a liveness/regression signal, never as a throughput claim
   metrics.observe('ops.gather_ms', (time.perf_counter() - t0) * 1e3)
   return out

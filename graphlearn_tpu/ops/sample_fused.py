@@ -17,9 +17,10 @@ HBM-materialized intermediate.
 Bit-matching contract: the draw itself (offsets, validity mask, epos)
 is computed OUTSIDE the kernel with byte-for-byte the same jnp ops as
 ``ops.uniform_sample`` fed by the same counter-addressed fold_in key —
-so the kernel's only job is ``indices[epos]``, and the XLA fallback
-(off-TPU, or routing flag off) IS ``ops.uniform_sample``'s stream:
-identical edges, identical epos, identical mask, on every path.
+so the kernel's only job is ``indices[epos]``, and the off-TPU XLA
+fallback IS ``ops.uniform_sample``'s stream: identical edges, identical
+epos, identical mask, on every path. On TPU there is no fallback: a
+caller that asked for the kernel gets it, or the compiler's refusal.
 
 Layout: the CSR indices ship as a FILL-padded aligned ``[ceil(E/128),
 128]`` block view (``build_indices128`` — the 128-lane cousin of block
@@ -83,77 +84,89 @@ def _draw(start, deg, seed_mask, k: int, key):
   return epos, mask
 
 
-def _hop_kernel_factory(k, nr, nbk):
-  def kernel(plan_ref, blocks_ref, epos_ref, meta_ref, out_ref, win, big,
-             sem_w, sem_b):
+def _use_kernel(name: str, blocks128, interpret: bool, force: bool) -> bool:
+  """True -> run the Pallas kernel, False -> the off-TPU XLA twin. On
+  TPU the kernel was asked for, so there is no XLA route: a missing
+  aligned view raises here, and a kernel Mosaic refuses raises at
+  compile time with Mosaic's message."""
+  if interpret or force:
+    if blocks128 is None:
+      raise ValueError(f'{name}: interpret/force need the '
+                       'build_indices128 view')
+    return True
+  if jax.default_backend() != 'tpu':
+    return False
+  if blocks128 is None:
+    raise ValueError(f'{name}: called on TPU without the '
+                     'build_indices128 aligned view — the fused kernel '
+                     'cannot run and does not fall back to XLA on TPU; '
+                     'call ops.uniform_sample for the XLA hop')
+  return True
+
+
+def _hop_kernel_factory(k, nr, kp):
+  """Per grid step (``bs`` seeds): stage each seed's segment, then pick
+  its k samples. Shaped by what Mosaic lowers on v5e (PERF.md
+  "Bring-up"): scalars the DMAs are addressed by live in SMEM; both DMA
+  paths of a seed share ONE semaphore (a seed takes exactly one path,
+  and DMA semaphore memory holds ~512 of them); staging buffers are
+  [row, seed, lane] so every vector read is a leading-dim index; and the
+  selection is k unrolled [bs, 128] lane-select passes — no 3-D one-hot,
+  no reshape, no unaligned concatenate."""
+  def kernel(plan_s, hub_s, blocks_ref, erow_ref, lane_ref, row0_ref,
+             small_ref, out_ref, win, big, sems):
     from jax.experimental import pallas as pl
-    i = pl.program_id(0)
+    from jax.experimental.pallas import tpu as pltpu
     bs = out_ref.shape[0]
 
-    def dmas(s):
-      from jax.experimental.pallas import tpu as pltpu
-      row0 = plan_ref[i * bs + s, 0]
-      small = plan_ref[i * bs + s, 1]
-      window = pltpu.make_async_copy(blocks_ref.at[pl.ds(row0, nr)],
-                                     win.at[s], sem_w.at[s])
-      return small, window
+    def window(s):
+      return pltpu.make_async_copy(
+          blocks_ref.at[pl.ds(plan_s[s * 8], nr)], win.at[:, s],
+          sems.at[s])
 
-    def row_dma(s, j):
-      from jax.experimental.pallas import tpu as pltpu
-      r = jnp.clip(epos_ref[s, j] // LANES, 0, nbk - 1)
-      return pltpu.make_async_copy(blocks_ref.at[r], big.at[s, j],
-                                   sem_b.at[s, j])
+    def hub_row(s, j):
+      return pltpu.make_async_copy(blocks_ref.at[hub_s[s * kp + j]],
+                                   big.at[j, s], sems.at[s])
 
-    def issue(s, carry):
-      small, window = dmas(s)
+    def each(s, fn):
+      small = plan_s[s * 8 + 1]
 
       @pl.when(small == 1)
       def _():
-        window.start()
+        fn(window(s))
 
       @pl.when(small == 0)
       def _():
-        def issue_j(j, c):
-          row_dma(s, j).start()
-          return c
-        jax.lax.fori_loop(0, k, issue_j, None, unroll=True)
+        for j in range(k):
+          fn(hub_row(s, j))
+
+    def issue(s, carry):
+      each(s, lambda dma: dma.start())
+      return carry
+
+    def drain(s, carry):
+      each(s, lambda dma: dma.wait())
       return carry
 
     jax.lax.fori_loop(0, bs, issue, None)
-
-    def drain(s, carry):
-      small, window = dmas(s)
-
-      @pl.when(small == 1)
-      def _():
-        window.wait()
-
-      @pl.when(small == 0)
-      def _():
-        def drain_j(j, c):
-          row_dma(s, j).wait()
-          return c
-        jax.lax.fori_loop(0, k, drain_j, None, unroll=True)
-      return carry
-
     jax.lax.fori_loop(0, bs, drain, None)
 
-    # dense VPU extraction over the staged windows (one-hot contraction,
-    # NOT take_along_axis — the same rule as ops.uniform_sample_padded)
-    epos = epos_ref[:]                               # [bs, k]
-    row0 = meta_ref[:, 0]                            # [bs]
-    small = meta_ref[:, 1]
-    wflat = win[:].reshape(bs, nr * LANES)
-    pos_l = jnp.clip(epos - row0[:, None] * LANES, 0, nr * LANES - 1)
-    lanes_w = jax.lax.broadcasted_iota(jnp.int32, (1, 1, nr * LANES), 2)
-    small_nbrs = jnp.sum(wflat[:, None, :] * (pos_l[:, :, None] == lanes_w),
-                         axis=-1)
-    lanes_b = jax.lax.broadcasted_iota(jnp.int32, (1, 1, LANES), 2)
-    big_nbrs = jnp.sum(big[:] * ((epos % LANES)[:, :, None] == lanes_b),
-                       axis=-1)
-    sel = jnp.where(small[:, None] == 1, small_nbrs, big_nbrs)  # [bs, k]
-    out_ref[:] = jnp.concatenate(
-        [sel, jnp.zeros((bs, LANES - k), jnp.int32)], axis=1)
+    rsel = erow_ref[:] - row0_ref[:]            # [bs, k] row in window
+    lane = lane_ref[:]                          # [bs, k]
+    small = small_ref[:] == 1                   # [bs, 1]
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (bs, LANES), 1)
+    out = jnp.zeros((bs, LANES), jnp.int32)
+    for j in range(k):
+      rj = rsel[:, j:j + 1]
+      row = win[0]
+      for r in range(1, nr):
+        row = jnp.where(rj == r, win[r], row)
+      # hub seeds staged sample j's own row; their window is untouched
+      row = jnp.where(small, row, big[j])
+      pick = jnp.sum(jnp.where(lanes == lane[:, j:j + 1], row, 0), axis=1,
+                     keepdims=True)
+      out = jnp.where(lanes == j, pick, out)
+    out_ref[:] = out
   return kernel
 
 
@@ -171,42 +184,51 @@ def _gather_epos_pallas(blocks128, start, deg, safe_epos, k: int,
   nbk = blocks128.shape[0]
   assert nbk >= nr, 'build_indices128(min_rows=nr) guarantees this'
   assert 0 < k <= LANES
-  bs = min(block_seeds, b)
+  if interpret:
+    bs = min(block_seeds, b)
+  elif block_seeds % LANES:
+    # the SMEM plan blocks are 1024-word tiles: 8 words per seed
+    raise ValueError(f'block_seeds={block_seeds} must be a multiple of '
+                     f'{LANES} for the compiled kernel')
+  else:
+    bs = block_seeds
   pad = (-b) % bs
+  n = b + pad
   row0 = jnp.clip(start // LANES, 0, nbk - nr).astype(jnp.int32)
   # every sampled position of a 'small' seed lies inside its window:
   # epos < start + deg <= row0*128 + nr*128 (clamped row0 only lowers
   # the base, and the window top then reaches the padded array end)
   small = ((start - row0 * LANES + deg) <= nr * LANES).astype(jnp.int32)
-  plan = jnp.stack([row0, small], axis=1)            # [b, 2]
   epos32 = safe_epos.astype(jnp.int32)
-  if pad:
-    plan = jnp.concatenate(
-        [plan, jnp.tile(jnp.array([[0, 1]], jnp.int32), (pad, 1))])
+  if pad:   # pad seeds stage row 0 as 'small' seeds; sliced off below
+    row0 = jnp.concatenate([row0, jnp.zeros((pad,), jnp.int32)])
+    small = jnp.concatenate([small, jnp.ones((pad,), jnp.int32)])
     epos32 = jnp.concatenate([epos32, jnp.zeros((pad, k), jnp.int32)])
-  grid = (b + pad) // bs
+  erow = jnp.clip(epos32 // LANES, 0, nbk - 1)
+  lane = epos32 % LANES
+  # SMEM copies of what addresses the DMAs, flat and tile-aligned: 8
+  # words per seed (row0, small), kp >= k words per seed (hub rows)
+  kp = -(-k // 8) * 8
+  plan = jnp.pad(jnp.stack([row0, small], axis=1),
+                 ((0, 0), (0, 6))).reshape(-1)
+  hub = jnp.pad(erow, ((0, 0), (0, kp - k))).reshape(-1)
 
+  smem = lambda width: pl.BlockSpec((width,), lambda i: (i,),
+                                    memory_space=pltpu.SMEM)
+  vmem = lambda width: pl.BlockSpec((bs, width), lambda i: (i, 0))
   out = pl.pallas_call(
-      _hop_kernel_factory(k, nr, nbk),
-      grid_spec=pltpu.PrefetchScalarGridSpec(
-          num_scalar_prefetch=1,
-          grid=(grid,),
-          in_specs=[
-              pl.BlockSpec(memory_space=pl.ANY),               # blocks128
-              pl.BlockSpec((bs, k), lambda i, plan_ref: (i, 0)),   # epos
-              pl.BlockSpec((bs, 2), lambda i, plan_ref: (i, 0)),   # meta
-          ],
-          out_specs=pl.BlockSpec((bs, LANES), lambda i, plan_ref: (i, 0)),
-          scratch_shapes=[
-              pltpu.VMEM((bs, nr, LANES), jnp.int32),
-              pltpu.VMEM((bs, k, LANES), jnp.int32),
-              pltpu.SemaphoreType.DMA((bs,)),
-              pltpu.SemaphoreType.DMA((bs, k)),
-          ],
-      ),
-      out_shape=jax.ShapeDtypeStruct((b + pad, LANES), jnp.int32),
+      _hop_kernel_factory(k, nr, kp),
+      grid=(n // bs,),
+      in_specs=[smem(bs * 8), smem(bs * kp),
+                pl.BlockSpec(memory_space=pl.ANY),         # blocks128
+                vmem(k), vmem(k), vmem(1), vmem(1)],
+      out_specs=vmem(LANES),
+      scratch_shapes=[pltpu.VMEM((nr, bs, LANES), jnp.int32),
+                      pltpu.VMEM((k, bs, LANES), jnp.int32),
+                      pltpu.SemaphoreType.DMA((bs,))],
+      out_shape=jax.ShapeDtypeStruct((n, LANES), jnp.int32),
       interpret=interpret,
-  )(plan, blocks128, epos32, plan)
+  )(plan, hub, blocks128, erow, lane, row0[:, None], small[:, None])
   return out[:b, :k]
 
 
@@ -223,16 +245,16 @@ def sample_hop_fused(indptr, indices, blocks128, seeds, seed_mask, k: int,
   Args:
     indptr/indices: the CSR (used by the fallback path and for
       ``meta=None`` row lookup).
-    blocks128: :func:`build_indices128` aligned view (may be None —
-      forces the XLA fallback).
+    blocks128: :func:`build_indices128` aligned view (None is only
+      accepted off-TPU, where the XLA twin runs anyway).
     seeds/seed_mask/k/key/meta: exactly :func:`ops.uniform_sample`.
     window: staged segment span per seed (multiple of 128; autotune axis
       probed by benchmarks/prof_gather2.py). Seeds with deg > window
       take the per-sample row-DMA path — never a whole-batch fallback.
     block_seeds: seeds per grid step.
     interpret: run the Pallas interpreter (CPU parity tests).
-    force: run the kernel off-TPU (tests); default falls back to the
-      XLA hop off-TPU.
+    force: run the kernel off-TPU (AOT lowering checks); default
+      falls back to the XLA hop off-TPU.
 
   Returns (nbrs [B, K], epos [B, K], mask [B, K]) — FILL/0-padded like
   ``uniform_sample``.
@@ -246,9 +268,7 @@ def sample_hop_fused(indptr, indices, blocks128, seeds, seed_mask, k: int,
     deg = indptr[safe_seeds + 1] - start
   epos, mask = _draw(start, deg, seed_mask, k, key)
   safe_epos = jnp.where(mask, epos, 0)
-  use_kernel = blocks128 is not None and (
-      interpret or force or jax.default_backend() == 'tpu')
-  if use_kernel:
+  if _use_kernel('sample_hop_fused', blocks128, interpret, force):
     picked = _gather_epos_pallas(blocks128, start, deg, safe_epos, k,
                                  window, block_seeds, interpret)
   else:
@@ -475,11 +495,12 @@ def _level_pallas(blocks128, start, deg, safe_epos, mask, nodes_prefix,
   pad = (-b) % bs
   s_fill = (b + pad) * k
   s_buf = -(-s_fill // LANES) * LANES
-  assert s_buf <= LEVEL_MAX_CANDIDATES, (
-      f'fused level: {b} seeds x fanout {k} = {s_buf} padded candidates '
-      f'exceeds LEVEL_MAX_CANDIDATES={LEVEL_MAX_CANDIDATES} (the '
-      'in-kernel dedup is O(S^2) compares — route this plan through the '
-      'hop kernel or the XLA merge engine instead)')
+  if s_buf > LEVEL_MAX_CANDIDATES:
+    raise ValueError(
+        f'fused level: {b} seeds x fanout {k} = {s_buf} padded candidates '
+        f'exceeds LEVEL_MAX_CANDIDATES={LEVEL_MAX_CANDIDATES} (the '
+        'in-kernel dedup is O(S^2) compares — route this plan through the '
+        'hop kernel or the XLA merge engine instead)')
   c = nodes_prefix.shape[0]
   c_pad = -(-c // LANES) * LANES
   limit_pad = max(-(-limit // LANES) * LANES, LANES)
@@ -603,9 +624,7 @@ def sample_level_fused(indptr, indices, blocks128, seeds, seed_mask,
   epos, mask = _draw(start, deg, seed_mask, k, key)
   safe_epos = jnp.where(mask, epos, 0)
 
-  use_kernel = blocks128 is not None and (
-      interpret or force or jax.default_backend() == 'tpu')
-  if not use_kernel:
+  if not _use_kernel('sample_level_fused', blocks128, interpret, force):
     picked = indices[safe_epos]
     nbrs = jnp.where(mask, picked, FILL)
     state2, out = induce_next_merge(state, src_idx, nbrs, mask,

@@ -10,8 +10,8 @@ kernels never pay this (dynamic shapes); calibrated static caps are the
 TPU answer.
 
 `estimate_frontier_caps` simulates the sampler's per-hop dedup in plain
-numpy (no device work, no jit, no device->host transfers — safe to run
-in-process on remote-dispatch runtimes) over a few probe batches and
+numpy (no device work, no jit, no device->host transfers) over a few
+probe batches and
 returns per-hop caps with slack, rounded up for XLA-friendly shapes.
 Pass them to ``NeighborSampler(frontier_caps=...)`` /
 ``NeighborLoader(frontier_caps=...)``. Sampling stays EXACT as long as
@@ -81,8 +81,7 @@ def estimate_frontier_caps(graph, fanouts: Sequence[int], batch_size: int,
   ``NeighborSampler(frontier_caps=...)``.
   """
   # prefer the host-side Topology CSR: Graph.indptr is a DEVICE array in
-  # HBM mode, and a device->host fetch would both waste the transfer and
-  # degrade remote-dispatch runtimes (PERF.md)
+  # HBM mode, and a device->host fetch would waste the transfer
   src = getattr(graph, 'topo', graph)
   indptr = np.asarray(src.indptr)
   indices = np.asarray(src.indices)

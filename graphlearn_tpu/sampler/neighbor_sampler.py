@@ -333,9 +333,9 @@ def _fused_homo_fn(fanouts, caps, node_cap, with_edge, weighted, mode,
   train and eval loaders of one run) shares one traced/compiled
   executable instead of paying the ~60s XLA compile per instance.
 
-  All device arrays enter as ARGUMENTS, never closure constants — an
-  executable with captured constants pays a flat ~5ms per call on
-  remote-dispatch runtimes (PERF.md).
+  All device arrays enter as ARGUMENTS, never closure constants — a
+  captured array is baked into the executable as a constant (PERF.md
+  rules).
   """
   import jax
 
@@ -527,10 +527,7 @@ class NeighborSampler(BaseSampler):
     # fused=True (default) compiles the whole multi-hop sample into one
     # XLA program — one dispatch per batch, and in-program op fusion. The
     # chained path (fused=False) dispatches each per-op kernel from the
-    # host; it exists for debugging/bisection. (An earlier version
-    # defaulted to chained because the fused program was slow through the
-    # remote-dispatch runtime; that was the closure-captured-constant
-    # penalty, since fixed — see _build_homo_fn.)
+    # host; it exists for debugging/bisection.
     self.fused = fused
     # dedup strategy: 'map' = direct-address table over node ids (no
     # sorts; 4 bytes/node HBM — the TPU hash-table analog), 'sort' =
@@ -625,8 +622,7 @@ class NeighborSampler(BaseSampler):
   def _next_key(self):
     """Per-call key via fold_in of a HOST counter: unlike split-and-carry,
     consecutive batches share no device-side dependency, so their sampling
-    programs pipeline freely (important under remote-dispatch runtimes
-    where dependent dispatches serialize)."""
+    programs pipeline freely."""
     import jax
     self._call_count += 1
     return jax.random.fold_in(self._key, self._call_count)
@@ -808,8 +804,7 @@ class NeighborSampler(BaseSampler):
   def _block_arrays(self, etype=None):
     """(aligned [E/16, 16] view of the CSR indices, packed [N, 2]
     (start, deg) metadata). Built device-side — a host round-trip here
-    would both copy ~E bytes and flip the remote-dispatch runtime into
-    its degraded mode (PERF.md)."""
+    would copy ~E bytes each way."""
     import jax.numpy as jnp
     g = self._get_graph(etype)
     key = ('blocks', id(g))

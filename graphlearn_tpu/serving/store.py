@@ -58,7 +58,8 @@ class EmbeddingStore:
   #: tuned kernel routing (tune/artifact.py apply_kernel_routing):
   #: route the bucket gather through the run-segmented DMA kernel
   #: (ops.gather_rows_hbm2) at the tuned grid point — the same gate as
-  #: UnifiedTensor: inert off-TPU or on non-128-lane-aligned widths
+  #: UnifiedTensor: inert off-TPU; on TPU a width the kernel cannot
+  #: serve raises
   use_pallas_v2 = False
   pallas_v2_block_rows = 256
   pallas_v2_run_span = 8
@@ -95,11 +96,11 @@ class EmbeddingStore:
     if self._gather is None:
       import jax
       import jax.numpy as jnp
-      self._kernel_routed = (
-          self.use_pallas_v2 and jax.default_backend() == 'tpu' and
-          self._emb.shape[1] % 128 == 0)
+      from ..ops.gather_pallas import (_gather_rows_hbm2_impl,
+                                       table_kernel_ok)
+      self._kernel_routed = self.use_pallas_v2 and table_kernel_ok(
+          'EmbeddingStore', self._emb)
       if self._kernel_routed:
-        from ..ops.gather_pallas import _gather_rows_hbm2_impl
         br, rs = self.pallas_v2_block_rows, self.pallas_v2_run_span
 
         def gather(emb, ids, mask):

@@ -16,8 +16,8 @@ Two routes produce the same plan:
   the [steps, node_cap] storage-row matrix once. ``plan_from_rows``
   turns it into per-chunk sorted miss sets.
 * **Host replay (verification / standalone)** — :func:`replay_seed_matrix`
-  mirrors the seed program's permutation math in eager jax on the host
-  CPU backend (threefry is bit-identical across backends), and
+  mirrors the seed program's permutation math in eager jax (threefry is
+  bit-identical across backends), and
   :func:`plan_epoch_host` walks the sampler's fused program step by
   step. tests/test_storage.py pins host-planned == device-observed
   under shuffle=True and False.
@@ -180,15 +180,16 @@ def replay_seed_matrix(seeds: np.ndarray, perm_key, steps: int,
   (seed_mat, mask_mat) exactly as ``ScanTrainer._build_seed_fn``
   (nparts == 1; [steps, batch], zero-padded ragged tail) or
   ``DistScanTrainer._build_seed_fn`` (nparts > 1; [P, steps, batch],
-  cyclic-padded tail) computes them on device. Runs in eager jax ON THE
-  HOST CPU backend — jax's threefry PRNG is bit-identical across
-  backends, which is the whole reason the plan can be trusted."""
+  cyclic-padded tail) computes them on device. The permutation runs in
+  eager jax on the process's default backend (a process started with
+  JAX_PLATFORMS=tpu has no CPU backend to pin it to): jax's threefry
+  PRNG is bit-identical across backends, which is the whole reason the
+  plan can be trusted."""
   import jax
   seeds = np.asarray(seeds, np.int32)
   n = seeds.shape[0]
-  with jax.default_device(jax.local_devices(backend='cpu')[0]):
-    order = (np.asarray(jax.random.permutation(perm_key, n))
-             if shuffle else np.arange(n, dtype=np.int32))
+  order = (np.asarray(jax.random.permutation(perm_key, n))
+           if shuffle else np.arange(n, dtype=np.int32))
   total = steps * nparts * batch
   if total <= n:
     ext = order[:total]

@@ -2,7 +2,7 @@
 
 Every probe is HOST-side numpy over the CSR topology (the
 sampler/calibrate.py discipline: no device work, no jit, no
-device->host fetches — safe on remote-dispatch runtimes) and returns
+device->host fetches) and returns
 both the chosen value and an evidence record naming what was measured,
 so the artifact can answer "why this cap / split / K" from the record
 alone. The device-measured half of tuning — the observatory-scored

@@ -128,7 +128,11 @@ def kernel_candidates(base: Candidate,
   which is exactly what the observatory A/B measures. Off-TPU the
   kernels fall back to their XLA twins in-program, so a CPU-replica
   tune() scores them honestly (ties break toward ``base``: the
-  stable sort prefers the earlier, kernels-off field entry)."""
+  stable sort prefers the earlier, kernels-off field entry). On TPU
+  there is no fallback: a kernel Mosaic refuses (the fused LEVEL kernel
+  under exact dedup, on v5e) or a table it cannot serve (width not a
+  multiple of 128) raises, and score_candidate records the candidate
+  as rejected with that message."""
   out = []
   for w in fused_hop_windows:
     if w % 128:

@@ -6,6 +6,7 @@ reference ships a pybind11 extension; here the native runtime (csrc/) is a
 plain shared library compiled with g++ on first use and bound via ctypes
 (pybind11 is not available in this image).
 """
+import hashlib
 import os
 import subprocess
 import threading
@@ -19,24 +20,37 @@ _CSRC = os.path.join(_REPO_ROOT, 'csrc')
 _BUILD = os.path.join(_REPO_ROOT, 'build')
 
 
+def _sources():
+  return sorted(os.path.join(_CSRC, f) for f in os.listdir(_CSRC)
+                if f.endswith('.cc'))
+
+
 def native_lib_path() -> str:
-  return os.path.join(_BUILD, 'libglt_c.so')
+  """build/libglt_c-<hash of csrc/*.cc>.so: the name binds the binary to
+  the sources it was built from, so a stale or foreign library (a copied
+  tree keeps no meaningful mtimes) is never loaded."""
+  h = hashlib.sha256()
+  for src in _sources():
+    h.update(os.path.basename(src).encode())
+    with open(src, 'rb') as f:
+      h.update(f.read())
+  return os.path.join(_BUILD, f'libglt_c-{h.hexdigest()[:16]}.so')
 
 
 def build_native(force: bool = False) -> str:
-  """Compile csrc/*.cc into build/libglt_c.so (cached by mtime)."""
-  srcs = sorted(
-      os.path.join(_CSRC, f) for f in os.listdir(_CSRC)
-      if f.endswith('.cc'))
+  """Compile csrc/*.cc into :func:`native_lib_path` unless that exact
+  build already exists."""
   out = native_lib_path()
   if not force and os.path.exists(out):
-    newest = max(os.path.getmtime(s) for s in srcs)
-    if os.path.getmtime(out) >= newest:
-      return out
+    return out
   os.makedirs(_BUILD, exist_ok=True)
+  # compile to a private name, then rename: a concurrent process must
+  # never dlopen a half-written library
+  tmp = f'{out}.{os.getpid()}.tmp'
   cmd = ['g++', '-O2', '-fPIC', '-shared', '-std=c++17', '-pthread',
-         '-o', out] + srcs
+         '-o', tmp] + _sources()
   subprocess.run(cmd, check=True, capture_output=True, text=True)
+  os.replace(tmp, out)
   return out
 
 

@@ -1,47 +1,22 @@
-"""Version shims over moving jax APIs.
+"""The one home of ``shard_map`` for this package.
 
-One place resolves symbols whose home changed across the jax versions this
-package must run on (the TPU rig's pinned jax vs the 0.4.x CI images), so
-call sites never need try/except imports.
-
-``shard_map``: top-level ``jax.shard_map`` exists only on newer jax; on
-0.4.x the implementation lives in ``jax.experimental.shard_map``. Both
-accept the keyword form used throughout this package
-(``shard_map(f, mesh=..., in_specs=..., out_specs=...)``). Resolution is
-deferred to the first call so importing this package never forces jax in
-(the package-wide convention: jax config keys must stay settable before
-first backend use).
+Every call site imports it from here (the ``compat-shard-map`` lint rule
+enforces that), so the spelling of the replication-check keyword lives
+in one place. Resolution is deferred to the call so importing this
+package never forces jax in.
 """
-
-_shard_map_impl = None
-
-
-def _resolve_shard_map():
-  global _shard_map_impl
-  if _shard_map_impl is None:
-    try:
-      from jax import shard_map as sm  # jax >= 0.6 top-level export
-    except ImportError:
-      from jax.experimental.shard_map import shard_map as sm
-    _shard_map_impl = sm
-  return _shard_map_impl
 
 
 def shard_map(*args, check_replication=None, **kwargs):
-  """jax.shard_map on jax versions that export it, else the
-  jax.experimental.shard_map implementation (jax 0.4.x).
+  """``jax.shard_map`` with ``check_replication`` mapped onto its
+  ``check_vma`` keyword.
 
-  ``check_replication`` (optional bool) resolves to the version's
-  replication-check keyword — ``check_vma`` on new jax, ``check_rep``
-  on 0.4.x. Programs whose replicated outputs come from collectives
-  inside ``lax.scan`` (the scanned-epoch trainers) pass False: the
-  static replication checker cannot see through the scan carry, while
-  the values are replicated by construction (every shard computes the
-  same pmean)."""
-  impl = _resolve_shard_map()
+  Programs whose replicated outputs come from collectives inside
+  ``lax.scan`` (the scanned-epoch trainers) pass False: the static
+  replication checker cannot see through the scan carry, while the
+  values are replicated by construction (every shard computes the same
+  pmean)."""
+  import jax
   if check_replication is not None:
-    import inspect
-    params = inspect.signature(impl).parameters
-    key = 'check_vma' if 'check_vma' in params else 'check_rep'
-    kwargs[key] = check_replication
-  return impl(*args, **kwargs)
+    kwargs['check_vma'] = check_replication
+  return jax.shard_map(*args, **kwargs)

@@ -4,6 +4,7 @@ TPU-native port of /root/reference/graphlearn_torch/python/utils/device.py:
 the reference rotates sampling workers across CUDA devices; here devices are
 jax devices and the default policy is round-robin over local chips.
 """
+import os
 from typing import Optional, Sequence
 
 
@@ -44,21 +45,29 @@ def global_device_put(arr, sharding):
                                       lambda idx: arr[idx])
 
 
-def enable_compilation_cache(path: Optional[str] = None,
-                             min_compile_secs: float = 1.0):
-  """Persist XLA executables to disk so repeated process runs warm-start.
+#: the one in-checkout compile-cache directory (git-ignored). The path is
+#: part of the cache key, so it is fixed: never a temp name, pid or time.
+XLA_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), '.xla_cache')
 
-  The fused multi-hop sampler compiles in ~60s on TPU the first time; with
-  this cache a fresh process (bench run, example, driver re-run) loads the
-  binary instead of recompiling. No reference counterpart (CUDA kernels
-  are AOT-built wheels); this is the JIT-world equivalent.
+
+def enable_compilation_cache(min_compile_secs: float = 1.0) -> str:
+  """Persist XLA executables to disk so repeated process runs warm-start
+  (the JIT-world equivalent of the reference's AOT-built CUDA wheels).
+
+  Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax already caches there
+  and this function sets no directory in code — whoever launched the
+  process placed the cache. Otherwise the cache goes to
+  :data:`XLA_CACHE_DIR` inside the checkout. Returns the directory in
+  effect.
   """
-  import os
   import jax
-  path = path or os.environ.get(
-      'GLT_XLA_CACHE', os.path.expanduser('~/.cache/graphlearn_tpu_xla'))
-  os.makedirs(path, exist_ok=True)
-  jax.config.update('jax_compilation_cache_dir', path)
   jax.config.update('jax_persistent_cache_min_compile_time_secs',
                     min_compile_secs)
-  return path
+  placed = os.environ.get('JAX_COMPILATION_CACHE_DIR')
+  if placed:
+    return placed
+  os.makedirs(XLA_CACHE_DIR, exist_ok=True)
+  jax.config.update('jax_compilation_cache_dir', XLA_CACHE_DIR)
+  return XLA_CACHE_DIR

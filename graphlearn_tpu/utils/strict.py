@@ -7,9 +7,8 @@ scanned epoch programs (``loader.ScanTrainer`` /
 
   * ``jax.transfer_guard('disallow')`` — any IMPLICIT device<->host
     transfer inside the epoch region raises instead of silently
-    reintroducing the per-step sync the scan exists to remove
-    (PERF.md: on this rig wall clock scales with dispatches + fetches,
-    not device ms). Explicit ``jax.device_put`` / ``jax.device_get``
+    reintroducing the per-step sync the scan exists to remove.
+    Explicit ``jax.device_put`` / ``jax.device_get``
     still work — the epoch boundary uses them deliberately.
   * ``jax.checking_leaks()`` — a traced value escaping its trace
     (captured by a host closure, stored on ``self``) raises at the

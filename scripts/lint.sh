@@ -57,7 +57,7 @@ python -m graphlearn_tpu.metrics.logcheck || rc=1
 echo "== bench trajectory gate =="
 # >20% round-over-round regression on a declared lower-is-better key
 # (BENCH_LOWER_IS_BETTER) fails the gate; rounds without numbers are
-# skipped, so a relay-down round never masks or fakes a regression
+# skipped, so a round without numbers never masks or fakes a regression
 python bench.py --gate || rc=1
 
 exit "$rc"

@@ -764,13 +764,24 @@ class TestBenchValidate:
            'run_mean_impl_reshape_ms_error': 'vjp assert'}
     assert bench.validate_bench_record(rec) == []
 
-  def test_checked_in_bench_files_validate(self):
-    # the cheap tier-1 gate over the real BENCH_r*.json trajectory
+  def test_saved_bench_files_validate(self, tmp_path):
+    # --validate over saved records: a raw record, the driver's wrapper
+    # form, and a wrapper whose run produced no parseable line
     bench = _bench()
-    import glob
-    paths = sorted(glob.glob(os.path.join(REPO, 'BENCH_*.json')))
-    assert paths, 'no BENCH_*.json checked in?'
+    rec = {'metric': 'sampled_edges_per_sec', 'value': 1.0,
+           'unit': 'M edges/s', 'vs_baseline': 0.5,
+           'map_device_ms_per_batch': 5.0}
+    saved = {'BENCH_r01.json': rec,
+             'BENCH_r02.json': {'parsed': rec, 'rc': 0},
+             'BENCH_r03.json': {'parsed': None, 'rc': 1}}
+    for name, body in saved.items():
+      (tmp_path / name).write_text(json.dumps(body))
+    paths = sorted(str(tmp_path / name) for name in saved)
     assert bench.validate_bench_files(paths) == 0
+    (tmp_path / 'BENCH_r04.json').write_text(
+        json.dumps(dict(rec, map_device_ms_per_batsh=5.0)))
+    assert bench.validate_bench_files(
+        paths + [str(tmp_path / 'BENCH_r04.json')]) == 1
 
   def test_cli_validate_flag(self):
     proc = subprocess.run(

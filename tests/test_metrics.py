@@ -774,7 +774,7 @@ def test_bench_gate_skips_failed_rounds_and_wrappers(tmp_path):
   p2 = tmp_path / 'BENCH_r02.json'
   p2.write_text(json.dumps(wrapper))       # driver wrapper: unwrapped
   p3 = tmp_path / 'BENCH_r03.json'
-  p3.write_text(json.dumps(failed))        # relay-down round: skipped
+  p3.write_text(json.dumps(failed))        # round without numbers: skipped
   assert bench.gate_bench_files([str(p1), str(p2), str(p3)]) == 0
   # a 30 -> 40 regression hidden behind the failed round still catches
   p4 = tmp_path / 'BENCH_r04.json'
@@ -785,12 +785,17 @@ def test_bench_gate_skips_failed_rounds_and_wrappers(tmp_path):
   assert bench.gate_bench_files([str(p3)]) == 0
 
 
-def test_bench_gate_checked_in_trajectory():
-  """The repo's own BENCH_r*.json history passes the gate (wired into
-  scripts/lint.sh — a regression round would fail lint)."""
-  import glob
+def test_bench_gate_saved_trajectory(tmp_path):
+  """A saved multi-round history passes the gate the way scripts/lint.sh
+  runs it, and every gated key is a registered one."""
   bench = _bench()
-  paths = sorted(glob.glob(os.path.join(REPO, 'BENCH_*.json')))
-  assert paths
+  base = {'metric': 'sampled_edges_per_sec', 'unit': 'M edges/s',
+          'vs_baseline': 1.0}
+  paths = _write_rounds(
+      tmp_path,
+      dict(base, value=40.0, map_device_ms_per_batch=18.0),
+      {'parsed': dict(base, value=80.0, map_device_ms_per_batch=5.1,
+                      train_step_ms_bf16=32.8), 'rc': 0},
+      {'parsed': None, 'rc': 1})
   assert bench.gate_bench_files(paths) == 0
   assert bench.BENCH_LOWER_IS_BETTER <= set(bench.BENCH_KEY_REGISTRY)

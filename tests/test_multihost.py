@@ -19,10 +19,8 @@ import os
 import sys
 pid = int(sys.argv[1])
 port = sys.argv[2]
-# jax 0.4.x has no jax_num_cpu_devices config key — XLA_FLAGS (set
-# before backend init) is the device-count knob there. The parent test
-# process's flags may carry ITS 8-device count (conftest), so replace
-# any existing count with this worker's 4.
+# The parent test process's XLA_FLAGS carry ITS 8-device count
+# (conftest), so replace any existing count with this worker's 4.
 import re
 flags = os.environ.get('XLA_FLAGS', '')
 flags = re.sub(r'--xla_force_host_platform_device_count=\d+', '', flags)
@@ -30,16 +28,7 @@ os.environ['XLA_FLAGS'] = (
     flags + ' --xla_force_host_platform_device_count=4').strip()
 import jax
 jax.config.update('jax_platforms', 'cpu')
-try:
-  jax.config.update('jax_num_cpu_devices', 4)
-except AttributeError:
-  pass
-try:
-  # jax 0.4.x: cross-process CPU collectives need the gloo backend
-  # opted in explicitly (newer jax selects it by default)
-  jax.config.update('jax_cpu_collectives_implementation', 'gloo')
-except (AttributeError, ValueError):
-  pass
+jax.config.update('jax_num_cpu_devices', 4)
 import numpy as np
 import graphlearn_tpu as glt
 from graphlearn_tpu.typing import GraphPartitionData

@@ -635,6 +635,11 @@ def test_trace_parsers_shared_loader(tmp_path):
       {'ph': 'X', 'pid': 1, 'name': 'layer1', 'dur': 500, 'ts': 3},
       # non-TPU lane must be ignored
       {'ph': 'X', 'pid': 2, 'name': 'fusion.9', 'dur': 9000, 'ts': 4},
+      # a v5e trace repeats the device time on a 'Steps' lane under
+      # step-number names: named lanes other than 'XLA Ops' are not ops
+      {'ph': 'M', 'name': 'thread_name', 'pid': 1, 'tid': 3,
+       'args': {'name': 'Steps'}},
+      {'ph': 'X', 'pid': 1, 'tid': 3, 'name': '7', 'dur': 7000, 'ts': 0},
   ]
   d = tmp_path / 'plugins' / 'profile' / 'run'
   d.mkdir(parents=True)
@@ -646,6 +651,7 @@ def test_trace_parsers_shared_loader(tmp_path):
   assert ops['fusion'] == (2.0, 2)     # (1+3) ms total / 2 steps
   assert ops['layer1'] == (0.25, 1)    # bare digits NOT stripped
   assert 'fusion.9' not in ops and 'jit_train_step(123)' not in ops
+  assert '7' not in ops
   top = device_op_ms(str(tmp_path), top=1, steps=2)
   assert list(top) == ['fusion']
 

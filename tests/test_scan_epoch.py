@@ -295,8 +295,7 @@ def test_wrap_dispatch_counts_user_calls():
 
 
 def test_conftest_virtual_cpu_mesh():
-  """Both conftest device-count paths (jax_num_cpu_devices on new jax,
-  XLA_FLAGS on 0.4.x) must deliver the 8-device virtual CPU mesh the
+  """conftest must deliver the 8-device virtual CPU mesh the
   sharding/collective tests assume."""
   import jax
   assert jax.default_backend() == 'cpu'
