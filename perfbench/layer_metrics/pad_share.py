@@ -1,0 +1,12 @@
+"""The work static shapes waste: 1 - valid node rows / node-buffer rows,
+mean over the per-batch slice's batches."""
+LAYER = 'capacity'
+UNIT = '%'
+MOVES = 'seeds_per_s'
+
+
+def read(run):
+  c = run['counts']
+  if not c['nodes']:
+    return None
+  return 100.0 * (1.0 - sum(c['nodes']) / c['buffer_rows'])
