@@ -39,6 +39,7 @@ from typing import Optional
 import numpy as np
 
 from .. import ops
+from ..metrics.registry_names import SCOPE_COLLATE
 from ..ops.route import exchange_capacity
 
 INT32_MAX = np.iinfo(np.int32).max
@@ -381,6 +382,7 @@ class DistFeature:
       rows = jax.lax.cond(total_ovf == 0, hier_path, flat_path, None)
       return rows, ovf
 
+    @jax.named_scope(SCOPE_COLLATE)
     def body(feat_ids, feats, pb, cache_ids, cache_feats, stats, ids,
              mask):
       safe = jnp.maximum(ids, 0)

@@ -140,7 +140,7 @@ class DistLoader(OverflowGuardMixin):
                                       None))
 
   def __iter__(self):
-    from ..utils import step_annotation
+    from ..metrics import spans
     # overflow-policy state resolves BEFORE the span/flight bracket: a
     # raise from it must not leak the attached epoch.run span (which
     # would mis-parent every later span on this thread)
@@ -149,7 +149,8 @@ class DistLoader(OverflowGuardMixin):
     steps, completed = 0, False
     try:
       for i, (idx, mask) in enumerate(self._index_blocks()):
-        with step_annotation('glt_dist_batch', i):
+        # closed before the yield: see NodeLoader.__iter__
+        with spans.span('loader.batch', step=i):
           inp = NodeSamplerInput(self.input_seeds[idx], self.input_type)
           if recompute:
             keys = self.sampler._next_keys()
@@ -163,8 +164,9 @@ class DistLoader(OverflowGuardMixin):
             out = self.sampler.sample_from_nodes(inp, seed_mask=mask)
             if guarded:
               self._accumulate_overflow(out)
-          yield self._collate_fn(out)
-          steps += 1
+          batch = self._collate_fn(out)
+        yield batch
+        steps += 1
       completed = True
       if guarded and not recompute:
         self._finish_epoch_overflow()

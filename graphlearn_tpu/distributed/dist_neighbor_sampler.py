@@ -48,9 +48,11 @@ results — the collective analog of the reference's subgraph RPC fan-out.
 """
 from typing import Dict, List, Optional, Union
 
+import jax
 import numpy as np
 
 from .. import ops
+from ..metrics.registry_names import SCOPE_SAMPLE, hop_scope
 from ..sampler import (EdgeSamplerInput, HeteroSamplerOutput,
                        NodeSamplerInput, SamplerOutput)
 from ..typing import reverse_edge_type
@@ -246,6 +248,7 @@ def _exchange_hop(garr, pb, frontier, fmask, k, key, nparts: int,
   return back_n, back_m, back_e
 
 
+@jax.named_scope(SCOPE_SAMPLE)
 def _homo_hop_loop(gdev, pb, seeds, smask, key, fanouts, caps,
                    node_cap: int, nparts: int, with_edge: bool,
                    weighted: bool, dedup: str = 'sort',
@@ -281,13 +284,15 @@ def _homo_hop_loop(gdev, pb, seeds, smask, key, fanouts, caps,
     # merge engine: clamped occupancy bound (see _fused_homo_fn)
     node_offs, _ = merge_layout_from_caps(caps, fanouts)
   for i, k in enumerate(fanouts):
-    nbrs, m, e = _exchange_hop(gdev, pb, frontier, fmask, k,
-                               hop_keys[i], nparts, with_edge, weighted,
-                               bucket_frac=bucket_frac, axes=axes,
-                               axis_sizes=axis_sizes)
-    state, out = induce(state, fidx, nbrs, m, node_offs[i],
-                        final=(i + 1 == len(fanouts)),
-                        max_new=caps[i + 1])
+    with jax.named_scope(hop_scope(i, 'draw')):
+      nbrs, m, e = _exchange_hop(gdev, pb, frontier, fmask, k,
+                                 hop_keys[i], nparts, with_edge, weighted,
+                                 bucket_frac=bucket_frac, axes=axes,
+                                 axis_sizes=axis_sizes)
+    with jax.named_scope(hop_scope(i, 'induce')):
+      state, out = induce(state, fidx, nbrs, m, node_offs[i],
+                          final=(i + 1 == len(fanouts)),
+                          max_new=caps[i + 1])
     rows.append(out['cols'])   # message direction: neighbor -> seed
     cols.append(out['rows'])
     emasks.append(out['edge_mask'])
@@ -835,6 +840,7 @@ class DistNeighborSampler:
 
   # ------------------------------------------------------- hetero engine
 
+  @jax.named_scope(SCOPE_SAMPLE)
   def _hetero_engine(self, garr, pbs, seed_arrays, key, plan):
     """Typed multi-hop engine body (traced inside shard_map): per-hop,
     per-edge-type route -> all_to_all -> local sample -> all_to_all back

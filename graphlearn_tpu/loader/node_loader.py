@@ -264,8 +264,7 @@ class NodeLoader(OverflowGuardMixin):
       self._epochs_started = getattr(self, '_epochs_started', 0) + 1
 
   def __iter__(self):
-    from ..metrics import flight
-    from ..utils import step_annotation
+    from ..metrics import flight, spans
     self._begin_epoch()
     # overflow-policy resolve BEFORE the flight bracket opens: a config
     # error raising here must not leave a permanently-open record
@@ -274,7 +273,10 @@ class NodeLoader(OverflowGuardMixin):
     steps, completed = 0, False
     try:
       for i, idx in enumerate(self._batcher):
-        with step_annotation('glt_batch', i):
+        # the loader's host work for one batch (glt.loader.batch on a
+        # profiler timeline); closed before the yield, so the
+        # consumer's spans never parent under a suspended generator's
+        with spans.span('loader.batch', step=i):
           seeds = self.input_seeds[idx]
           inp = NodeSamplerInput(seeds, self.input_type)
           if recompute:
@@ -290,8 +292,9 @@ class NodeLoader(OverflowGuardMixin):
                 inp, batch_cap=self.batch_size)
             if guarded:
               self._accumulate_overflow(out)
-          yield self._collate_fn(out)
-          steps += 1
+          batch = self._collate_fn(out)
+        yield batch
+        steps += 1
       completed = True
       if guarded and not recompute:
         self._finish_epoch_overflow()

@@ -31,6 +31,8 @@ from typing import Optional
 import numpy as np
 
 from .. import ops
+from ..metrics.registry_names import (SCOPE_FWD_BWD, SCOPE_TRAIN,
+                                      SCOPE_UPDATE)
 from .node_loader import NodeLoader
 
 _RECOMPUTE_MSG = (
@@ -362,15 +364,18 @@ class DistFusedEpochTrainer:
     over EVERY mesh axis — the SPMD analog of the reference's DDP
     allreduce — then one optax update of the replicated state."""
     import jax
-    (loss, acc), grads = jax.value_and_grad(self._loss_fn, has_aux=True)(
-        state.params, batch)
-    grads = jax.lax.pmean(grads, self._axes)
-    loss = jax.lax.pmean(loss, self._axes)
-    acc = jax.lax.pmean(acc, self._axes)
-    updates, opt_state = self.tx.update(grads, state.opt_state,
-                                        state.params)
     import optax
-    params = optax.apply_updates(state.params, updates)
+    with jax.named_scope(SCOPE_TRAIN):
+      with jax.named_scope(SCOPE_FWD_BWD):
+        (loss, acc), grads = jax.value_and_grad(
+            self._loss_fn, has_aux=True)(state.params, batch)
+        grads = jax.lax.pmean(grads, self._axes)
+        loss = jax.lax.pmean(loss, self._axes)
+        acc = jax.lax.pmean(acc, self._axes)
+      with jax.named_scope(SCOPE_UPDATE):
+        updates, opt_state = self.tx.update(grads, state.opt_state,
+                                            state.params)
+        params = optax.apply_updates(state.params, updates)
     return self._train_state_cls(params, opt_state, state.step + 1), \
         loss, acc
 

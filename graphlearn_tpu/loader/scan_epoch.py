@@ -419,16 +419,22 @@ class ScanTrainer(FusedEpochTrainer):
     losses, accs = [], []
     start = start_step
     with strict_guards():
+      # every host phase between two device programs is a span, and an
+      # attached span is also a glt.<name> event on the profiler's
+      # clock (metrics/spans.py): a trace of this loop names each
+      # device-idle gap by what the host was doing in it. The dispatches
+      # are async, so a span's dur is dispatch wall; the layers' device
+      # time is read from their glt.* scopes (docs/observability.md)
       record_dispatch('epoch_seeds')
-      seed_mat, mask_mat = self._seed_fn(self._seeds_dev, perm_key,
-                                         full_steps)
+      with spans.span('epoch.seeds'):
+        seed_mat, mask_mat = self._seed_fn(self._seeds_dev, perm_key,
+                                           full_steps)
       while start < steps:
         k = min(self.chunk_size, steps - start)
         if self.stage_hook is not None:
-          self.stage_hook(start // self.chunk_size, start, k)
+          with spans.span('epoch.hook', hook='stage', start=start):
+            self.stage_hook(start // self.chunk_size, start, k)
         record_dispatch('scan_chunk')
-        # chunk-level span: host clocks only (the dispatch is async, so
-        # dur is dispatch wall, not device compute — PERF.md's point)
         with spans.span('epoch.chunk', start=start, k=k):
           state, ovf, loss_k, acc_k = self._chunk_fn(
               state, ovf, fargs, self._feats, self._id2i, self._labels,
@@ -447,11 +453,13 @@ class ScanTrainer(FusedEpochTrainer):
                                    accs=accs, steps=steps,
                                    full_steps=full_steps,
                                    start_step=start_step)
-          self.ack_hook(start // self.chunk_size, start, k)
+          with spans.span('epoch.hook', hook='ack', start=start):
+            self.ack_hook(start // self.chunk_size, start, k)
         start += k
       if len(losses) > 1:
         record_dispatch('metrics_concat')
-        losses, accs = self._concat_fn(losses, accs)
+        with spans.span('epoch.concat'):
+          losses, accs = self._concat_fn(losses, accs)
       else:
         losses, accs = losses[0], accs[0]
     # keep the host fold_in stream aligned with what the device consumed
@@ -921,12 +929,14 @@ class DistScanTrainer(DistFusedEpochTrainer):
     start = start_step
     try:
       with strict_guards():
-        seed_mat, mask_mat = self._epoch_prologue(
-            perm_key, full_steps, steps, start_step, base_key, count0)
+        with spans.span('epoch.seeds'):
+          seed_mat, mask_mat = self._epoch_prologue(
+              perm_key, full_steps, steps, start_step, base_key, count0)
         while start < steps:
           k = min(self.chunk_size, steps - start)
           if self.stage_hook is not None:
-            self.stage_hook(start // self.chunk_size, start, k)
+            with spans.span('epoch.hook', hook='stage', start=start):
+              self.stage_hook(start // self.chunk_size, start, k)
           with spans.span('epoch.chunk', start=start, k=k):
             params, opt_state, stepc, ovf, stats, loss_k, acc_k = \
                 self._dispatch_chunk(
@@ -946,11 +956,13 @@ class DistScanTrainer(DistFusedEpochTrainer):
                 ovf=ovf, stats=stats, losses=losses, accs=accs,
                 steps=steps, full_steps=full_steps,
                 start_step=start_step)
-            self.ack_hook(start // self.chunk_size, start, k)
+            with spans.span('epoch.hook', hook='ack', start=start):
+              self.ack_hook(start // self.chunk_size, start, k)
           start += k
         if len(losses) > 1:
           record_dispatch('dist_metrics_concat')
-          losses, accs = self._concat_fn(losses, accs)
+          with spans.span('epoch.concat'):
+            losses, accs = self._concat_fn(losses, accs)
         else:
           losses, accs = losses[0], accs[0]
     except BaseException:

@@ -12,8 +12,7 @@ Emitters: ``ScanTrainer``/``DistScanTrainer`` (the scanned epoch
 programs), ``OverlappedTrainer``, and the per-step loader loops
 (``NodeLoader``/``DistLoader``/remote/mp ``__iter__``). Every record
 carries DELTAS over the epoch — metric counters, per-site dispatch
-counts — plus wall time, a config fingerprint, and the staged
-device-trace key (GLT_PROFILE_DIR) when a trace is being captured.
+counts — plus wall time and a config fingerprint.
 
 Hot-path contract: :func:`epoch_begin` and :func:`epoch_end` touch
 ONLY host state (the metric registry, the active DispatchCounter, the
@@ -248,7 +247,6 @@ def epoch_end(token: Optional[dict], emitter: str, epoch: int,
       'counters': {k: v for k, v in cdelta.items() if k not in known},
       'config': _jsonable(config or {}),
       'config_fingerprint': config_fingerprint(config or {}),
-      'trace': {'profile_dir': os.environ.get('GLT_PROFILE_DIR')},
       'time_unix': round(time.time(), 3),
   }
   if extra:

@@ -41,7 +41,6 @@ _FLIGHT_REQUIRED = {
     'counters': (dict,),
     'config': (dict,),
     'config_fingerprint': (str,),
-    'trace': (dict,),
     'time_unix': (int, float),
 }
 _FLIGHT_NULLABLE = {
@@ -53,6 +52,7 @@ _FLIGHT_NULLABLE = {
 # still validate (present -> type-checked, absent -> fine)
 _FLIGHT_OPTIONAL = {
     'storage': (dict,),
+    'trace': (dict,),   # written until PR 26; logs from then still validate
 }
 
 _SPAN_REQUIRED = {
@@ -71,7 +71,6 @@ _SPAN_NULLABLE = {
 }
 _SPAN_OPTIONAL = {
     'attrs': (dict,),
-    'profile_key': (str,),
 }
 
 

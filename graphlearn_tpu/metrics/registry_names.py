@@ -154,6 +154,16 @@ REGISTERED_SPANS = frozenset({
     # epoch drivers (loader/scan_epoch.py, distributed/dist_loader.py)
     'epoch.run',
     'epoch.chunk',
+    # the scan trainers' host phases between two device programs
+    # (loader/scan_epoch.py): the seed-matrix dispatch, the loss/acc
+    # concat dispatch, and each stage_hook / ack_hook call — with
+    # epoch.chunk they name every idle gap inside an epoch.run
+    'epoch.seeds',
+    'epoch.concat',
+    'epoch.hook',
+    # per-batch loaders (loader/node_loader.py, distributed/
+    # dist_loader.py): one span per delivered batch
+    'loader.batch',
     # remote-loader failover (distributed/dist_loader.py): carries the
     # resilience annotations for the degraded epoch's span tree
     'loader.failover',
@@ -201,4 +211,39 @@ REGISTERED_SPANS = frozenset({
     # bounded-backoff throttle wait on the client, parented under the
     # epoch root via the stager's adopted context (docs/multi_tenancy.md)
     'tenant.throttle',
+})
+
+# Everything the program puts on a PROFILER timeline is named
+# ``glt.<...>``: host side, every attached span enters a
+# ``jax.profiler.TraceAnnotation(PROFILER_PREFIX + <span name>)``
+# (metrics/spans.py); device side, the layers wrap their traced bodies
+# in ``jax.named_scope`` under the names below, so every executor that
+# traces a layer — the scanned chunk, the per-batch programs, the
+# overlapped/dist/tiered/remote programs — carries them in each
+# instruction's ``op_name``. Scopes are compile-time metadata: they add
+# no instruction and no option.
+PROFILER_PREFIX = 'glt.'
+SCOPE_SAMPLE = 'glt.sample'      # sampler/neighbor_sampler.py, dist engine
+SCOPE_COLLATE = 'glt.collate'    # ops/collate.py, dist feature/label lookup
+SCOPE_TRAIN = 'glt.train'        # models/train.py, pipeline._dp_step_body
+SCOPE_FWD_BWD = 'fwd_bwd'        # inside glt.train: the value_and_grad
+SCOPE_UPDATE = 'update'          # inside glt.train: the optimizer
+
+
+def hop_scope(hop: int, part: str) -> str:
+  """Sub-scope of ``glt.sample`` for hop ``hop``: ``part`` is 'draw'
+  (the neighbour draw) or 'induce' (dedup + relabel)."""
+  return f'hop{hop}/{part}'
+
+
+# The closed inventory of device scopes, as they read in an op_name
+# (docs/observability.md lists them; tests/test_metrics.py checks it).
+REGISTERED_SCOPES = frozenset({
+    'glt.sample',
+    'glt.sample/hop<h>/draw',
+    'glt.sample/hop<h>/induce',
+    'glt.collate',
+    'glt.train',
+    'glt.train/fwd_bwd',
+    'glt.train/update',
 })

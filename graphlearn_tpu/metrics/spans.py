@@ -1,5 +1,6 @@
 """Correlated spans: host-clock begin/end records joinable across the
-cluster by one id.
+cluster by one id — and, for spans attached to their thread, the same
+interval on the profiler's clock.
 
 The metrics layer answers "how many / how fast"; spans answer "WHICH
 request / WHICH epoch, across WHICH processes". A span is a tiny
@@ -8,6 +9,17 @@ duration, attrs — kept in a bounded in-process ring and (opt-in,
 ``GLT_SPAN_LOG``) appended as JSONL next to the flight recorder. No
 device clocks, no fetches, no dispatches: one perf_counter read at each
 end and a dict append (docs/observability.md documents the schema).
+
+Profiler timeline: an ATTACHED span (``span()``, ``begin(attach=True)``)
+also enters and leaves a ``jax.profiler.TraceAnnotation('glt.' + name)``
+on its thread, so a profiler trace of any trainer shows the program's
+own ``glt.epoch.run`` / ``glt.epoch.chunk`` / ... host events on the
+clock the device lanes use (docs/observability.md 'The glt. convention').
+No flag: with no profiler session open the annotation is a TraceMe
+no-op. Only when ``jax`` is already imported — this module never imports
+it (mp workers, lint fixtures). Cross-thread (``attach=False``) and
+retroactive (``emit``) spans have no single thread interval and stay
+ring-only.
 
 Correlation model:
 
@@ -32,7 +44,8 @@ id alone. Span NAMES are a closed namespace
 (``registry_names.REGISTERED_SPANS``, graftlint rule ``span-registry``)
 exactly like metric names.
 
-Zero-dependency (pure stdlib), thread-safe, process-local.
+Zero-dependency (pure stdlib; jax only if the process already has it),
+thread-safe, process-local.
 """
 import collections
 import contextlib
@@ -43,6 +56,8 @@ import threading
 import time
 import uuid
 from typing import Dict, Iterable, List, Optional, Tuple
+
+from .registry_names import PROFILER_PREFIX
 
 ENV_LOG = 'GLT_SPAN_LOG'
 ENV_RUN = 'GLT_RUN_ID'
@@ -202,16 +217,16 @@ def reset():
   _recorder.reset()
 
 
-def _profile_key() -> Optional[str]:
-  """The active jax-profiler trace key, when a maybe_start_trace
-  session is live — stamps device traces onto host spans so a Perfetto
-  trace and a span tree correlate (sys.modules probe keeps this module
-  zero-dependency and cycle-free)."""
-  tr = sys.modules.get('graphlearn_tpu.utils.trace')
-  if tr is not None and getattr(tr, '_active', False):
-    return (getattr(tr, '_active_dir', None)
-            or os.environ.get('GLT_PROFILE_DIR'))
-  return None
+def _annotate(name: str):
+  """Enter ``glt.<name>`` on this thread's profiler timeline; the entered
+  annotation, or None where the process never imported jax."""
+  jax = sys.modules.get('jax')
+  profiler = getattr(jax, 'profiler', None)   # None mid-import of jax
+  if profiler is None:
+    return None
+  ann = profiler.TraceAnnotation(PROFILER_PREFIX + name)
+  ann.__enter__()
+  return ann
 
 
 # spans emit per-RPC / per-request: the shared appender keeps a
@@ -239,7 +254,7 @@ def _jsonable_attrs(attrs: dict) -> dict:
 
 class _SpanToken:
   __slots__ = ('name', 'span_id', 'parent', 'trace', 't0', 't0_unix',
-               'attrs', 'attached', 'done')
+               'attrs', 'attached', 'done', 'annotation')
 
   def __init__(self, name, span_id, parent, trace, attrs, attached):
     self.name = name
@@ -251,6 +266,7 @@ class _SpanToken:
     self.attrs = attrs
     self.attached = attached
     self.done = False
+    self.annotation = _annotate(name) if attached else None
 
 
 def begin(name: str, parent: Optional[str] = None,
@@ -277,6 +293,8 @@ def end(tok: Optional[_SpanToken], **attrs) -> Optional[dict]:
   if tok is None or tok.done:
     return None
   tok.done = True
+  if tok.annotation is not None:
+    tok.annotation.__exit__(None, None, None)
   if tok.attached:
     st = _stack()
     if (tok.trace, tok.span_id) in st:
@@ -292,9 +310,6 @@ def end(tok: Optional[_SpanToken], **attrs) -> Optional[dict]:
   }
   if tok.attrs:
     rec['attrs'] = _jsonable_attrs(tok.attrs)
-  key = _profile_key()
-  if key:
-    rec['profile_key'] = key
   _recorder.record(rec)
   _write(rec)
   return rec
@@ -331,9 +346,6 @@ def emit(name: str, *, trace: Optional[str] = None,
   }
   if attrs:
     rec['attrs'] = _jsonable_attrs(attrs)
-  key = _profile_key()
-  if key:
-    rec['profile_key'] = key
   _recorder.record(rec)
   _write(rec)
   return rec

@@ -13,6 +13,9 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from ..metrics.registry_names import (SCOPE_FWD_BWD, SCOPE_TRAIN,
+                                      SCOPE_UPDATE)
+
 
 class TrainState(NamedTuple):
   params: Any
@@ -94,6 +97,25 @@ def make_loss_fn(model, num_classes: int):
   return loss_fn
 
 
+def _jit_train_step(loss_fn, tx):
+  """The jitted optimizer step over ``loss_fn`` (``jit_train_step`` on a
+  profiler timeline), its device work under ``glt.train``: ``fwd_bwd``
+  (the value_and_grad) and ``update`` (the optimizer)."""
+
+  @jax.jit
+  @jax.named_scope(SCOPE_TRAIN)
+  def train_step(state: TrainState, batch):
+    with jax.named_scope(SCOPE_FWD_BWD):
+      (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+          state.params, batch)
+    with jax.named_scope(SCOPE_UPDATE):
+      updates, opt_state = tx.update(grads, state.opt_state, state.params)
+      params = optax.apply_updates(state.params, updates)
+    return TrainState(params, opt_state, state.step + 1), loss, acc
+
+  return train_step
+
+
 def make_train_step(model, tx, num_classes: int):
   """Build the jitted supervised step. The batch dict carries padded
   x/edge_index/edge_mask/y plus num_seed_nodes (seed slots lead the node
@@ -101,13 +123,7 @@ def make_train_step(model, tx, num_classes: int):
 
   loss_fn = make_loss_fn(model, num_classes)
 
-  @jax.jit
-  def train_step(state: TrainState, batch):
-    (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-        state.params, batch)
-    updates, opt_state = tx.update(grads, state.opt_state, state.params)
-    params = optax.apply_updates(state.params, updates)
-    return TrainState(params, opt_state, state.step + 1), loss, acc
+  train_step = _jit_train_step(loss_fn, tx)
 
   @jax.jit
   def eval_step(state: TrainState, batch):
@@ -189,13 +205,7 @@ def make_link_train_step(model, tx):
     acc = hit.sum() / jnp.maximum(valid.sum(), 1)
     return loss, acc
 
-  @jax.jit
-  def train_step(state: TrainState, batch):
-    (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-        state.params, batch)
-    updates, opt_state = tx.update(grads, state.opt_state, state.params)
-    params = optax.apply_updates(state.params, updates)
-    return TrainState(params, opt_state, state.step + 1), loss, acc
+  train_step = _jit_train_step(loss_fn, tx)
 
   @jax.jit
   def eval_step(state: TrainState, batch):

@@ -13,8 +13,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..metrics.registry_names import SCOPE_COLLATE
+
 
 @functools.partial(jax.jit, static_argnames=('label_cap',))
+@jax.named_scope(SCOPE_COLLATE)
 def collate_batch(node, num_nodes, row, col, feats, id2index, labels,
                   edge_feats, edge, label_cap=None):
   """Build the derived batch payloads on device.

@@ -18,10 +18,12 @@ source) local index and ``col`` the *seed* (message target) local index, so
 import functools
 from typing import Dict, List, Optional, Union
 
+import jax
 import numpy as np
 
 from .. import ops
 from ..data import Graph
+from ..metrics.registry_names import SCOPE_SAMPLE, hop_scope
 from ..typing import EdgeType, NodeType, reverse_edge_type
 from .base import (BaseSampler, EdgeSamplerInput, HeteroSamplerOutput,
                    NeighborOutput, NodeSamplerInput, SamplerOutput)
@@ -337,10 +339,9 @@ def _fused_homo_fn(fanouts, caps, node_cap, with_edge, weighted, mode,
   captured array is baked into the executable as a constant (PERF.md
   rules).
   """
-  import jax
-
   init_fn, _, induce_fn = _inducer_for(mode, num_graph_nodes)
 
+  @jax.named_scope(SCOPE_SAMPLE)
   def fn(indptr, indices, eids, cum, tab, deg, eptab, seeds, seed_mask,
          key):
     import jax.numpy as jnp
@@ -377,41 +378,43 @@ def _fused_homo_fn(fanouts, caps, node_cap, with_edge, weighted, mode,
             final=(i + 1 == len(fanouts)), window=fused_hop_window,
             interpret=(fused_hop == 'interpret'))
       else:
-        if padded:
-          nbrs, epos, m = ops.uniform_sample_padded(
-              tab, deg, frontier, fmask, k, keys[i], epos_table=eptab)
-        elif block_num_edges:
-          # deg is the metadata row gather; tab = (csr_meta,
-          # indices_blocks)
-          nbrs, epos, m = ops.uniform_sample_block(
-              deg, tab, block_num_edges, frontier, fmask, k, keys[i])
-        elif weighted:
-          nbrs, epos, m = ops.weighted_sample(indptr, indices, cum,
-                                              frontier, fmask, k, keys[i])
-        elif fused_hop:
-          # fused sample+gather Pallas hop (ops/sample_fused.py): same
-          # fold_in stream as uniform_sample bit for bit — tab carries
-          # the [E/128, 128] aligned indices view, deg the csr_meta row
-          # table. Off-TPU the op routes its own XLA fallback, so the
-          # flag is safe to leave on in CPU tests ('interpret' forces
-          # the kernel through the Pallas interpreter for parity
-          # coverage).
-          nbrs, epos, m = ops.sample_hop_fused(
-              indptr, indices, tab, frontier, fmask, k, keys[i], meta=deg,
-              window=fused_hop_window,
-              interpret=(fused_hop == 'interpret'))
-        else:
-          # deg slot carries the [N, 2] csr_meta row table for plain
-          # uniform sampling (see _fused_args / ops.uniform_sample)
-          nbrs, epos, m = ops.uniform_sample(indptr, indices, frontier,
-                                             fmask, k, keys[i], meta=deg)
+        with jax.named_scope(hop_scope(i, 'draw')):
+          if padded:
+            nbrs, epos, m = ops.uniform_sample_padded(
+                tab, deg, frontier, fmask, k, keys[i], epos_table=eptab)
+          elif block_num_edges:
+            # deg is the metadata row gather; tab = (csr_meta,
+            # indices_blocks)
+            nbrs, epos, m = ops.uniform_sample_block(
+                deg, tab, block_num_edges, frontier, fmask, k, keys[i])
+          elif weighted:
+            nbrs, epos, m = ops.weighted_sample(
+                indptr, indices, cum, frontier, fmask, k, keys[i])
+          elif fused_hop:
+            # fused sample+gather Pallas hop (ops/sample_fused.py): same
+            # fold_in stream as uniform_sample bit for bit — tab carries
+            # the [E/128, 128] aligned indices view, deg the csr_meta
+            # row table. Off-TPU the op routes its own XLA fallback, so
+            # the flag is safe to leave on in CPU tests ('interpret'
+            # forces the kernel through the Pallas interpreter for
+            # parity coverage).
+            nbrs, epos, m = ops.sample_hop_fused(
+                indptr, indices, tab, frontier, fmask, k, keys[i],
+                meta=deg, window=fused_hop_window,
+                interpret=(fused_hop == 'interpret'))
+          else:
+            # deg slot carries the [N, 2] csr_meta row table for plain
+            # uniform sampling (see _fused_args / ops.uniform_sample)
+            nbrs, epos, m = ops.uniform_sample(
+                indptr, indices, frontier, fmask, k, keys[i], meta=deg)
         # the frontier feeds the next hop at caps[i+1] width; when
         # nothing truncates it (no node_budget clamp) the map inducer can
         # emit it positionally and skip two S-element compaction scatters
         compact = (i + 1 < len(caps)) and caps[i + 1] < caps[i] * k
-        state, out = induce_fn(state, fidx, nbrs, m, node_offs[i],
-                               compact, final=(i + 1 == len(fanouts)),
-                               max_new=caps[i + 1])
+        with jax.named_scope(hop_scope(i, 'induce')):
+          state, out = induce_fn(state, fidx, nbrs, m, node_offs[i],
+                                 compact, final=(i + 1 == len(fanouts)),
+                                 max_new=caps[i + 1])
       # message direction: neighbor -> seed
       rows.append(out['cols'])
       cols.append(out['rows'])
@@ -899,11 +902,13 @@ class NeighborSampler(BaseSampler):
                 else None))
     return self._garrs[id(g)]
 
+  @jax.named_scope(SCOPE_SAMPLE)
   def _run_homo_chain(self, batch_cap: int, fanouts, seeds, seed_mask,
                       key):
     """Same computation as _build_homo_fn but dispatched as the per-op
-    jitted kernels (default path; see `fused` note in __init__)."""
-    import jax
+    jitted kernels (default path; see `fused` note in __init__). The
+    glt.sample scopes reach a program only where a caller traces this
+    chain into one; dispatched eagerly, each kernel is its own program."""
     import jax.numpy as jnp
     ga = self._graph_arrays()
     indptr, indices, eids = ga['indptr'], ga['indices'], ga['eids']
@@ -924,16 +929,18 @@ class NeighborSampler(BaseSampler):
     keys = jax.random.split(key, len(fanouts))
     offset = caps[0]
     for i, k in enumerate(fanouts):
-      if weighted:
-        nbrs, epos, m = ops.weighted_sample(indptr, indices, cum, frontier,
-                                            fmask, k, keys[i])
-      else:
-        nbrs, epos, m = ops.uniform_sample(indptr, indices, frontier,
-                                           fmask, k, keys[i])
+      with jax.named_scope(hop_scope(i, 'draw')):
+        if weighted:
+          nbrs, epos, m = ops.weighted_sample(indptr, indices, cum,
+                                              frontier, fmask, k, keys[i])
+        else:
+          nbrs, epos, m = ops.uniform_sample(indptr, indices, frontier,
+                                             fmask, k, keys[i])
       compact = caps[i + 1] < caps[i] * k   # see _fused_homo_fn note
-      state, out = induce_fn(state, fidx, nbrs, m, offset, compact,
-                             final=(i + 1 == len(fanouts)),
-                             max_new=caps[i + 1])
+      with jax.named_scope(hop_scope(i, 'induce')):
+        state, out = induce_fn(state, fidx, nbrs, m, offset, compact,
+                               final=(i + 1 == len(fanouts)),
+                               max_new=caps[i + 1])
       # tree consumes slot bases (full hop widths); merge consumes the
       # clamped occupancy bound (merge_layout_from_caps)
       offset += (caps[i] * k if self._dedup_mode() == 'tree'
@@ -1028,6 +1035,7 @@ class NeighborSampler(BaseSampler):
     nn = self.num_neighbors
     return list(nn[etype]) if isinstance(nn, dict) else list(nn)
 
+  @jax.named_scope(SCOPE_SAMPLE)
   def _hetero_sample_from_nodes(self, inputs: NodeSamplerInput,
                                 batch_cap: Optional[int] = None):
     """Per-etype hop loop with per-node-type inducers

@@ -12,10 +12,8 @@ from .strict import strict_enabled, strict_guards
 from .tensor import convert_to_array, id2idx, squeeze_dict
 from .topo import (coo_to_csc, coo_to_csr, csr_to_coo, csr_to_csc, ind2ptr,
                    ptr2ind)
-from .trace import (DispatchCounter, annotate, count_dispatches,
-                    counter_get, counter_inc, counters, device_op_ms,
-                    device_program_ms, dispatch_snapshot,
-                    maybe_start_trace, profile_trace, record_dispatch,
-                    reset_counters, step_annotation, stop_trace,
-                    wrap_dispatch)
+from .trace import (DispatchCounter, count_dispatches, counter_get,
+                    counter_inc, counters, device_op_ms,
+                    device_program_ms, dispatch_snapshot, profile_trace,
+                    record_dispatch, reset_counters, wrap_dispatch)
 from .units import format_size, parse_size

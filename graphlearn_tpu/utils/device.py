@@ -45,11 +45,12 @@ def global_device_put(arr, sharding):
                                       lambda idx: arr[idx])
 
 
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
 #: the one in-checkout compile-cache directory (git-ignored). The path is
 #: part of the cache key, so it is fixed: never a temp name, pid or time.
-XLA_CACHE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))), '.xla_cache')
+XLA_CACHE_DIR = os.path.join(_CHECKOUT, '.xla_cache')
 
 
 def enable_compilation_cache(min_compile_secs: float = 1.0) -> str:
@@ -61,10 +62,25 @@ def enable_compilation_cache(min_compile_secs: float = 1.0) -> str:
   process placed the cache. Otherwise the cache goes to
   :data:`XLA_CACHE_DIR` inside the checkout. Returns the directory in
   effect.
+
+  The cache is keyed WITH instruction metadata. jax's default key strips
+  it, so an executable compiled before a scope was named (or renamed)
+  would be loaded for the program that names it, and a profile would
+  show the old names: the layer clock (the ``glt.*`` scopes,
+  docs/observability.md) lives in exactly that metadata. Source paths
+  in the metadata are taken relative to the checkout, so a checkout
+  that moves still hits; an edit that moves a traced line compiles
+  that program once more.
   """
+  import re
+
   import jax
   jax.config.update('jax_persistent_cache_min_compile_time_secs',
                     min_compile_secs)
+  jax.config.update('jax_compilation_cache_include_metadata_in_key', True)
+  if jax.config.jax_hlo_source_file_canonicalization_regex is None:
+    jax.config.update('jax_hlo_source_file_canonicalization_regex',
+                      '^' + re.escape(_CHECKOUT + os.sep))
   placed = os.environ.get('JAX_COMPILATION_CACHE_DIR')
   if placed:
     return placed

@@ -1,17 +1,17 @@
-"""Profiling/tracing hooks over jax.profiler.
+"""Profiler helpers, the dispatch counter and the counter shims.
 
-The reference leans on torch.profiler + nvtx ranges in its benchmarks; the
-TPU equivalents are XLA's profiler traces (viewable in TensorBoard /
-Perfetto). These helpers are no-ops when no trace is active, so loaders
-annotate unconditionally.
+``profile_trace`` captures a jax.profiler trace of a region (viewable in
+TensorBoard / Perfetto; ``chip_smoke.py`` uses it);
+``device_program_ms`` / ``device_op_ms`` reduce one. What the PROGRAM
+puts on that timeline is not set here: the layers name their device work
+``glt.sample`` / ``glt.collate`` / ``glt.train`` (``jax.named_scope``),
+and every attached span of ``metrics/spans.py`` is a ``glt.<span>`` host
+event — with no flag, and at no cost beyond a TraceMe no-op when no
+session is open (docs/observability.md 'The glt. convention').
 
 Usage:
     with glt.utils.profile_trace('/tmp/glt_trace'):
-      for batch in loader:   # each batch shows up as a named step
-        train_step(batch)
-
-or env-driven: set GLT_PROFILE_DIR and call maybe_start_trace() /
-stop_trace() around the region of interest (bench.py honors it).
+      state, losses, accs = trainer.run_epoch(state)
 """
 import contextlib
 import functools
@@ -28,18 +28,6 @@ def profile_trace(logdir: str) -> Iterator[None]:
     yield
   finally:
     jax.profiler.stop_trace()
-
-
-def annotate(name: str, **kwargs):
-  """Named range inside an active trace (no-op otherwise)."""
-  import jax
-  return jax.profiler.TraceAnnotation(name, **kwargs)
-
-
-def step_annotation(name: str, step: int):
-  """Step-numbered range (loader batches, train steps)."""
-  import jax
-  return jax.profiler.StepTraceAnnotation(name, step_num=step)
 
 
 _trace_cache = {}   # (path, mtime) -> parsed events (newest entry only)
@@ -274,57 +262,3 @@ def reset_counters(prefix: str = ''):
   this clears COUNTERS only, exactly the old dict semantics — gauges
   and histograms are reset through metrics.reset()."""
   _registry().reset_counters(prefix)
-
-
-_active = False
-_active_dir: Optional[str] = None
-
-
-def active_profile_dir() -> Optional[str]:
-  """The live maybe_start_trace() session's log dir, or None. Spans
-  opened while a profiler session is live stamp this key
-  (metrics/spans.py ``profile_key``), so device traces and host span
-  trees correlate — previously the key only reached flight records."""
-  return _active_dir if _active else None
-
-
-def maybe_start_trace(env_var: str = 'GLT_PROFILE_DIR') -> Optional[str]:
-  """Start a trace if ``env_var`` names a directory; returns the dir.
-
-  Exception-safe: a ``start_trace`` that raises (unwritable dir, a
-  profiler session another tool left open) must leave ``_active``
-  False AND best-effort-close any half-opened profiler session —
-  otherwise the next maybe_start_trace either silently no-ops for the
-  rest of the run or trips over the orphaned session."""
-  global _active, _active_dir
-  logdir = os.environ.get(env_var)
-  if logdir and not _active:
-    import jax
-    try:
-      jax.profiler.start_trace(logdir)
-    except BaseException:
-      _active = False
-      _active_dir = None
-      try:       # close a partially-started session so a later start
-        jax.profiler.stop_trace()   # isn't wedged by the orphan
-      except Exception:  # noqa: BLE001 - cleanup of a failed start
-        pass
-      raise
-    _active = True
-    _active_dir = logdir
-    return logdir
-  return None
-
-
-def stop_trace():
-  """Stop the maybe_start_trace() session. Exception-safe: ``_active``
-  is cleared FIRST — a stop_trace that raises (trace-write failure)
-  must not leave the flag stuck True, where every later
-  maybe_start_trace would silently no-op and the run would quietly
-  produce no traces at all."""
-  global _active, _active_dir
-  if _active:
-    import jax
-    _active = False
-    _active_dir = None
-    jax.profiler.stop_trace()

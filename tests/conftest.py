@@ -28,6 +28,12 @@ os.environ.setdefault('JAX_COMPILATION_CACHE_DIR',
                       os.path.join(tempfile.gettempdir(),
                                    'glt_test_xla_cache'))
 
+# pytest-xdist workers inherit that variable and so DO cache there. Key
+# it as the program does (utils.enable_compilation_cache): jax's default
+# key strips instruction metadata, and a test that reads the glt.* scopes
+# out of a compiled program would be served another compile's names.
+jax.config.update('jax_compilation_cache_include_metadata_in_key', True)
+
 import signal
 
 import numpy as np

@@ -95,6 +95,43 @@ def test_compilation_cache_is_placed_from_outside(tmp_path, monkeypatch):
     assert out.stdout.split() == [fixed, fixed]
 
 
+def test_compilation_cache_never_serves_another_scopes_executable(tmp_path):
+  """The layer clock lives in instruction metadata, which jax's default
+  cache key strips: two programs that differ only in a named scope would
+  share one cached executable, and a profile of the second would show
+  the first's names. enable_compilation_cache() keys the cache with
+  metadata, and with source paths relative to the checkout."""
+  code = (
+      'import os, sys\n'
+      'import jax, jax.numpy as jnp\n'
+      'import graphlearn_tpu as glt\n'
+      'assert glt.utils.enable_compilation_cache(0.0) == sys.argv[1]\n'
+      'def make(scope):\n'
+      '  @jax.jit\n'
+      '  def f(x):\n'
+      '    with jax.named_scope(scope):\n'
+      '      return jnp.sort(x) * 2\n'
+      '  return f\n'
+      'for scope in ("glt.a", "glt.b"):\n'
+      '  f = make(scope)\n'
+      '  f(jnp.arange(8.0)).block_until_ready()\n'
+      '  text = f.lower(jnp.arange(8.0)).compile().as_text()\n'
+      '  assert "/" + scope + "/" in text, (scope, text)\n'
+      'print(jax.config.jax_hlo_source_file_canonicalization_regex)\n')
+  placed = str(tmp_path / 'cache')
+  env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=placed,
+             JAX_PLATFORMS='cpu', PYTHONPATH=REPO)
+  out = subprocess.run([sys.executable, '-c', code, placed],
+                       capture_output=True, text=True, timeout=180,
+                       env=env, cwd=str(tmp_path))
+  assert out.returncode == 0, out.stderr[-2000:]
+  import re
+  assert re.sub(out.stdout.strip(), '',
+                os.path.join(REPO, 'graphlearn_tpu', 'ops', 'collate.py')
+                ) == os.path.join('graphlearn_tpu', 'ops', 'collate.py')
+  assert len(os.listdir(placed)) >= 2   # one entry per scope, not one
+
+
 @pytest.mark.slow  # tier-1 budget (PR 19): staged-npz example variant
 # — the sub-second example tests stay tier-1, full run already slow
 def test_products_staged_npz_path(tmp_path):

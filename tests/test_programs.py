@@ -175,15 +175,39 @@ def test_span_log_jsonl_and_schema(tmp_path, monkeypatch):
   assert len(spans.read_log(str(path))) == 2
 
 
-def test_span_profile_key_stamped_when_profiler_live(monkeypatch):
-  from graphlearn_tpu.utils import trace as trace_mod
-  monkeypatch.setattr(trace_mod, '_active', True)
-  monkeypatch.setattr(trace_mod, '_active_dir', '/tmp/trace_key_x')
-  rec = spans.end(spans.begin('epoch.run', emitter='test'))
-  assert rec['profile_key'] == '/tmp/trace_key_x'
-  monkeypatch.setattr(trace_mod, '_active', False)
-  rec2 = spans.end(spans.begin('epoch.run', emitter='test'))
-  assert 'profile_key' not in rec2
+def test_attached_spans_enter_the_profiler_timeline_as_glt_names(
+    monkeypatch):
+  """An attached span enters and leaves ``TraceAnnotation('glt.' +
+  name)`` on its thread; cross-thread (attach=False) and retroactive
+  (emit) spans have no thread interval and stay ring-only."""
+  import jax
+  log = []
+
+  class Recorder:
+
+    def __init__(self, name):
+      self.name = name
+
+    def __enter__(self):
+      log.append(('enter', self.name))
+
+    def __exit__(self, *exc):
+      log.append(('exit', self.name))
+
+  monkeypatch.setattr(jax.profiler, 'TraceAnnotation', Recorder)
+  with spans.span('epoch.run', emitter='test'):
+    with spans.span('epoch.chunk', k=2):
+      pass
+    spans.end(spans.begin('serving.request', attach=False))
+    spans.emit('serving.queue', dur_ms=1.0)
+  assert log == [('enter', 'glt.epoch.run'), ('enter', 'glt.epoch.chunk'),
+                 ('exit', 'glt.epoch.chunk'), ('exit', 'glt.epoch.run')]
+  # a span that dies by exception still leaves the timeline
+  with pytest.raises(KeyError):
+    with spans.span('epoch.hook', hook='ack'):
+      raise KeyError('boom')
+  assert log[-2:] == [('enter', 'glt.epoch.hook'),
+                      ('exit', 'glt.epoch.hook')]
 
 
 def test_build_tree_flags_orphans_and_dedupes():

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 import graphlearn_tpu as glt
-from graphlearn_tpu.models import GraphSAGE, train as train_lib
+from graphlearn_tpu.models import GAT, GraphSAGE, train as train_lib
 
 
 def make_dataset(n=96, f=6, seed=0):
@@ -278,6 +278,75 @@ def test_retrace_budget_catches_chunk_length_perturbation():
   assert programs.compile_count('scan_chunk') - c0 >= 2
   ev = programs.last_compile('scan_chunk')
   assert ev.index >= 1 and 'arg ' in ev.diff
+
+
+def _op_names(compiled_text):
+  import re
+  return re.findall(r'op_name="([^"]*)"', compiled_text)
+
+
+def _abstract(args):
+  import jax
+  return jax.tree.map(
+      lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+      if hasattr(a, 'shape') else a, args)
+
+
+@pytest.mark.parametrize('model_cls', [GraphSAGE, GAT])
+def test_layer_scopes_are_in_the_scanned_body_and_the_per_batch_programs(
+    model_cls):
+  """The layer clock: every instruction of a layer carries its glt.*
+  scope in op_name — inside the while body of the chunk program, and in
+  the per-batch programs, whose names the benchmark reads, unchanged."""
+  import jax
+  import jax.numpy as jnp
+  ds = make_dataset()
+  model = model_cls(hidden_dim=8, out_dim=3, num_layers=2)
+  b0 = next(iter(_make_loader(ds, 24)))
+  first = train_lib.batch_to_dict(b0)
+  state, tx = _fresh_state(model, first)
+  loader = _make_loader(ds, 24)
+  trainer = glt.loader.ScanTrainer(loader, model, tx, 3, chunk_size=3)
+  seen, real = [], trainer._chunk_fn
+
+  def spy(*args):
+    seen.append(_abstract(args))
+    return real(*args)
+
+  trainer._chunk_fn = spy
+  trainer.run_epoch(state)
+  chunk = real.lower(*seen[0]).compile().as_text()
+  assert 'HloModule jit_scan_epoch_chunk' in chunk
+  names = _op_names(chunk)
+  in_body = [n for n in names
+             if n.startswith('jit(scan_epoch_chunk)/while/body/')]
+  wanted = ['glt.sample/hop0/draw', 'glt.sample/hop1/induce',
+            'glt.collate', 'glt.train/fwd_bwd', 'glt.train/update']
+  for scope in wanted:
+    assert any(f'/{scope}/' in n for n in in_body), scope
+  # nothing of a layer sits outside the loop under the layer's name
+  assert not [n for n in names if 'glt.' in n and n not in in_body
+              and n.startswith('jit(scan_epoch_chunk)/')]
+
+  sampler = loader.sampler
+  seeds = jnp.zeros((8,), jnp.int32)
+  sample = trainer._sample_fn.lower(
+      *_abstract(sampler._fused_args()), seeds, seeds > -1,
+      jax.random.PRNGKey(0)).compile().as_text()
+  collate = glt.ops.collate_batch.lower(
+      b0.node, jnp.int32(1), b0.edge_index[0], b0.edge_index[1],
+      trainer._feats, trainer._id2i, trainer._labels, None, None,
+      label_cap=trainer._label_cap).compile().as_text()
+  step, _ = train_lib.make_train_step(model, tx, 3)
+  train = step.lower(_fresh_state(model, first)[0],
+                     first).compile().as_text()
+  for text, module, scopes in [
+      (sample, 'jit_sample_', wanted[:2]),
+      (collate, 'jit_collate_batch', wanted[2:3]),
+      (train, 'jit_train_step', wanted[3:])]:
+    assert f'HloModule {module}' in text
+    for scope in scopes:
+      assert any(f'/{scope}/' in n for n in _op_names(text)), scope
 
 
 def test_wrap_dispatch_counts_user_calls():
