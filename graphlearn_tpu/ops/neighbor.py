@@ -27,6 +27,96 @@ import numpy as np
 from .unique import FILL
 
 
+# A frontier of at least _MIN_TILED_ROWS rows is drawn tile by tile, and only
+# in the tiles that begin below its last valid row: calibrated frontier caps
+# leave a third of every hop padding, and on the v5e a padded row's gathers
+# (all of element 0) cost more than a valid row's (PERF.md, PR 27). Up to
+# DRAW_TILES tiles, of a multiple of _TILE_ALIGN rows: finer tiles leave
+# less padding in the last one run, and below 256 rows a tile no longer
+# pays for its loop iteration. Smaller frontiers (a seed batch, a serving
+# call) take one gather over the whole cap.
+DRAW_TILES = 64
+_TILE_ALIGN = 256
+_MIN_TILED_ROWS = 2048
+
+
+def draw_tile_rows(b: int) -> int:
+  """Rows per tile for a frontier of ``b`` rows (a multiple of
+  ``_TILE_ALIGN``), or 0 where the frontier is too small to tile."""
+  if b < _MIN_TILED_ROWS:
+    return 0
+  per_tile = -(-b // DRAW_TILES)
+  return -(-per_tile // _TILE_ALIGN) * _TILE_ALIGN
+
+
+def draw_offsets(start, deg, seed_mask, u, k: int):
+  """The uniform draw's arithmetic, shared with ``ops/sample_fused.py``
+  (whose kernels promise this stream bit for bit): per-row (start, degree)
+  and the ``[B, K]`` uniforms -> (epos, mask), unmasked ``epos``."""
+  rand_off = jnp.floor(u * deg[:, None].astype(u.dtype)).astype(jnp.int32)
+  rand_off = jnp.minimum(rand_off, jnp.maximum(deg[:, None] - 1, 0))
+  seq_off = jnp.arange(k, dtype=jnp.int32)[None, :]
+  offsets = jnp.where(deg[:, None] > k, rand_off, seq_off)
+  mask = seed_mask[:, None] & (offsets < deg[:, None])
+  return start[:, None] + offsets, mask
+
+
+def _draw_rows(indptr, indices, meta, seeds, seed_mask, u, k: int):
+  """``uniform_sample`` over the rows given (the whole frontier or one
+  tile of it): the row-table gather, the offsets, the element gather."""
+  safe_seeds = jnp.where(seed_mask, seeds, 0)
+  if meta is not None:
+    row = meta[safe_seeds]
+    start, deg = row[:, 0], row[:, 1]
+  else:
+    start = indptr[safe_seeds]
+    deg = indptr[safe_seeds + 1] - start
+  epos, mask = draw_offsets(start, deg, seed_mask, u, k)
+  safe_epos = jnp.where(mask, epos, 0)
+  nbrs = jnp.where(mask, indices[safe_epos], FILL)
+  return nbrs, safe_epos, mask
+
+
+def uniform_sample_tiled(indptr, indices, seeds, seed_mask, k: int, key,
+                         meta=None):
+  """:func:`uniform_sample` in tiles of :func:`draw_tile_rows` frontier
+  rows (one tile where that is 0), run only for the tiles that begin
+  below the last valid row of ``seed_mask`` (any mask: a prefix mask
+  skips its padding, a scattered one runs every tile). Rows of tiles not
+  run read what masked rows read: FILL, 0, False. The last tile is
+  clamped to end at the cap, so it may redraw rows of the one before —
+  to the same values.
+
+  Returns ``(nbrs, epos, mask, tiles)``; ``tiles`` is the int32 number
+  of tiles run, ``ceil(last valid row / tile rows)``.
+  """
+  b = seeds.shape[0]
+  tile_rows = draw_tile_rows(b) or b
+  u = jax.random.uniform(key, (b, k))    # ONE stream over the whole cap
+  last_valid = jnp.max(jnp.where(
+      seed_mask, jnp.arange(1, b + 1, dtype=jnp.int32), 0))
+  tiles = (last_valid + (tile_rows - 1)) // tile_rows
+
+  def draw(seeds, seed_mask, u):
+    return _draw_rows(indptr, indices, meta, seeds, seed_mask, u, k)
+
+  def body(i, out):
+    with jax.named_scope('tile'):
+      lo = jnp.minimum(i * tile_rows, b - tile_rows)
+      part = draw(jax.lax.dynamic_slice(seeds, (lo,), (tile_rows,)),
+                  jax.lax.dynamic_slice(seed_mask, (lo,), (tile_rows,)),
+                  jax.lax.dynamic_slice(u, (lo, 0), (tile_rows, k)))
+      return tuple(jax.lax.dynamic_update_slice(o, p, (lo, 0))
+                   for o, p in zip(out, part))
+
+  nbrs, epos, mask = jax.eval_shape(draw, seeds, seed_mask, u)
+  init = (jnp.full(nbrs.shape, FILL, nbrs.dtype),
+          jnp.zeros(epos.shape, epos.dtype),
+          jnp.zeros(mask.shape, mask.dtype))
+  nbrs, epos, mask = jax.lax.fori_loop(0, tiles, body, init)
+  return nbrs, epos, mask, tiles
+
+
 @functools.partial(jax.jit, static_argnames=('k',))
 def uniform_sample(indptr, indices, seeds, seed_mask, k: int, key,
                    meta=None):
@@ -50,25 +140,17 @@ def uniform_sample(indptr, indices, seeds, seed_mask, k: int, key,
     epos:  [B, K] position into the CSR ``indices`` array of each sampled
            edge (valid where mask; use to gather edge ids/weights).
     mask:  [B, K] bool validity.
+
+  A frontier large enough to tile (:func:`draw_tile_rows`) skips the
+  gathers of its trailing padding (:func:`uniform_sample_tiled`); the
+  outputs are the same in every element either way.
   """
   b = seeds.shape[0]
-  safe_seeds = jnp.where(seed_mask, seeds, 0)
-  if meta is not None:
-    row = meta[safe_seeds]
-    start, deg = row[:, 0], row[:, 1]
-  else:
-    start = indptr[safe_seeds]
-    deg = indptr[safe_seeds + 1] - start
+  if draw_tile_rows(b):
+    return uniform_sample_tiled(indptr, indices, seeds, seed_mask, k, key,
+                                meta)[:3]
   u = jax.random.uniform(key, (b, k))
-  rand_off = jnp.floor(u * deg[:, None].astype(u.dtype)).astype(jnp.int32)
-  rand_off = jnp.minimum(rand_off, jnp.maximum(deg[:, None] - 1, 0))
-  seq_off = jnp.arange(k, dtype=jnp.int32)[None, :]
-  offsets = jnp.where(deg[:, None] > k, rand_off, seq_off)
-  mask = seed_mask[:, None] & (offsets < deg[:, None])
-  epos = start[:, None] + offsets
-  safe_epos = jnp.where(mask, epos, 0)
-  nbrs = jnp.where(mask, indices[safe_epos], FILL)
-  return nbrs, jnp.where(mask, epos, 0), mask
+  return _draw_rows(indptr, indices, meta, seeds, seed_mask, u, k)
 
 
 def build_row_cumsum(indptr, weights):

@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from .induce_merge import MergeInducerState, induce_next_merge
+from .neighbor import draw_offsets
 from .unique import FILL
 
 LANES = 128
@@ -71,17 +72,11 @@ def build_indices128(indices, min_rows: int = 0):
 
 
 def _draw(start, deg, seed_mask, k: int, key):
-  """ops.uniform_sample's offset draw, byte for byte (the bit-matching
-  contract lives or dies on this staying IDENTICAL to neighbor.py)."""
-  b = seed_mask.shape[0]
-  u = jax.random.uniform(key, (b, k))
-  rand_off = jnp.floor(u * deg[:, None].astype(u.dtype)).astype(jnp.int32)
-  rand_off = jnp.minimum(rand_off, jnp.maximum(deg[:, None] - 1, 0))
-  seq_off = jnp.arange(k, dtype=jnp.int32)[None, :]
-  offsets = jnp.where(deg[:, None] > k, rand_off, seq_off)
-  mask = seed_mask[:, None] & (offsets < deg[:, None])
-  epos = start[:, None] + offsets
-  return epos, mask
+  """ops.uniform_sample's offset draw: the same key over the same shape
+  through the same arithmetic (the bit-matching contract lives or dies
+  on this)."""
+  u = jax.random.uniform(key, (seed_mask.shape[0], k))
+  return draw_offsets(start, deg, seed_mask, u, k)
 
 
 def _use_kernel(name: str, blocks128, interpret: bool, force: bool) -> bool:

@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 import graphlearn_tpu as glt
+from graphlearn_tpu.ops.neighbor import draw_tile_rows
 from graphlearn_tpu.sampler import (EdgeSamplerInput, NegativeSampling,
                                     NodeSamplerInput)
 
@@ -410,6 +411,10 @@ def test_padded_window_auto_and_stats():
     # canary; the full grid runs under -m slow)
     ('random', None, 'map'), ('random', None, 'map_capped'),
     ('random', None, 'map_table'),
+    # exact dedup under CALIBRATED caps wide enough that
+    # ops.uniform_sample draws hop 1 tile by tile (PR 27), fused program
+    # against the per-op chain
+    ('random', None, 'map_tiled'),
     ('random', None, 'tree'),
     ('block', None, 'tree'),
     ('random', 8, 'tree'),
@@ -439,12 +444,12 @@ def test_sampler_invariants_random_graphs(dedup, strategy, padded):
   # fixed fanouts/batch so every mode shares ONE compiled program
   # (_fused_homo_fn is module-cached on the static signature); the
   # randomness lives in the graphs and seeds
-  fanouts = [3, 2]
-  b = 8
+  tiled = dedup == 'map_tiled'
+  fanouts, b = ([10, 2], 256) if tiled else ([3, 2], 8)
   assert padded is None or padded >= max(fanouts)
-  for trial in range(3):
-    n = int(rng.integers(30, 200))
-    e = int(rng.integers(2 * n, 8 * n))
+  for trial in range(1 if tiled else 3):
+    n = 6000 if tiled else int(rng.integers(30, 200))
+    e = 12 * n if tiled else int(rng.integers(2 * n, 8 * n))
     rows = rng.integers(0, n, e)
     cols = rng.integers(0, n, e)
     adj = {(int(r), int(c)) for r, c in zip(rows, cols)}
@@ -454,12 +459,24 @@ def test_sampler_invariants_random_graphs(dedup, strategy, padded):
     # truncation may trip (clean by contract), every invariant below
     # must still hold
     caps = [16, 24] if dedup == 'map_capped' else None
-    s = glt.sampler.NeighborSampler(
-        graph, fanouts, seed=trial, fused=True,
-        dedup='map' if dedup == 'map_capped' else dedup,
-        strategy=strategy, padded_window=padded, frontier_caps=caps)
+    if tiled:
+      caps = glt.sampler.estimate_frontier_caps(graph, fanouts, b,
+                                                num_probes=3)
+      assert draw_tile_rows(caps[0]) and caps[0] < b * fanouts[0]
+    kw = dict(seed=trial, strategy=strategy, padded_window=padded,
+              frontier_caps=caps,
+              dedup='map' if dedup in ('map_capped', 'map_tiled') else dedup)
+    s = glt.sampler.NeighborSampler(graph, fanouts, fused=True, **kw)
     seeds = rng.integers(0, n, b)
     out = s.sample_from_nodes(NodeSamplerInput(seeds), batch_cap=b)
+    if tiled:
+      # the per-op chain draws the same stream through the same tiles
+      per_op = glt.sampler.NeighborSampler(
+          graph, fanouts, fused=False, **kw).sample_from_nodes(
+              NodeSamplerInput(seeds), batch_cap=b)
+      for field in ('node', 'row', 'col', 'edge_mask'):
+        np.testing.assert_array_equal(np.asarray(getattr(out, field)),
+                                      np.asarray(getattr(per_op, field)))
     node = np.asarray(out.node)
     r = np.asarray(out.row)
     c = np.asarray(out.col)

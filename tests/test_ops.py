@@ -91,6 +91,134 @@ def test_uniform_sample_masked_seed():
   assert not bool(m[1].any())
 
 
+def _untiled_uniform_sample(indptr, indices, seeds, seed_mask, k, key,
+                            meta=None):
+  """ops.uniform_sample as it read before it was tiled: one gather over
+  the whole cap. The tiled op must equal this in every element."""
+  b = seeds.shape[0]
+  safe_seeds = jnp.where(seed_mask, seeds, 0)
+  if meta is not None:
+    row = meta[safe_seeds]
+    start, deg = row[:, 0], row[:, 1]
+  else:
+    start = indptr[safe_seeds]
+    deg = indptr[safe_seeds + 1] - start
+  u = jax.random.uniform(key, (b, k))
+  rand_off = jnp.floor(u * deg[:, None].astype(u.dtype)).astype(jnp.int32)
+  rand_off = jnp.minimum(rand_off, jnp.maximum(deg[:, None] - 1, 0))
+  seq_off = jnp.arange(k, dtype=jnp.int32)[None, :]
+  offsets = jnp.where(deg[:, None] > k, rand_off, seq_off)
+  mask = seed_mask[:, None] & (offsets < deg[:, None])
+  epos = start[:, None] + offsets
+  safe_epos = jnp.where(mask, epos, 0)
+  nbrs = jnp.where(mask, indices[safe_epos], ops.FILL)
+  return nbrs, jnp.where(mask, epos, 0), mask
+
+
+def _random_csr(rng, n, max_deg):
+  """Degrees 0..max_deg (so both the keep-all and the random branch of a
+  fan-out below max_deg run), row 0 non-empty: masked rows gather it."""
+  deg = rng.integers(0, max_deg + 1, n)
+  deg[0] = max_deg
+  indptr = np.concatenate([[0], np.cumsum(deg)]).astype(np.int32)
+  indices = rng.integers(0, n, indptr[-1]).astype(np.int32)
+  return indptr, indices
+
+
+@pytest.mark.parametrize('b,mask_kind,k,use_meta', [
+    (2560, 'none', 5, True),            # 0 % valid: no tile runs
+    (2560, 'one_row', 10, True),
+    (2560, 'prefix_66', 15, True),
+    (2560, 'prefix_66', 5, False),      # the two indptr gathers
+    (2560, 'full', 10, False),
+    (2560, 'scattered', 5, True),       # last valid row near the cap
+    (3333, 'prefix_66', 10, True),      # b not a multiple of the tile
+    (3333, 'full', 15, False),          # ... and the clamped last tile runs
+    (3333, 'scattered', 10, False),
+    (20000, 'prefix_66', 5, True),      # a cap past 64 x 256: 512-row tiles
+    (1500, 'prefix_66', 5, True),       # below the threshold: one gather
+])
+def test_uniform_sample_tiled_matches_untiled(b, mask_kind, k, use_meta):
+  """The tiled draw is the untiled one, element for element, for any
+  mask; and it runs ceil(last valid row / tile rows) tiles."""
+  from graphlearn_tpu.ops import neighbor
+  rng = np.random.default_rng(b + k)
+  n = 500
+  indptr, indices = _random_csr(rng, n, 24)
+  n_valid = {'none': 0, 'one_row': 1, 'prefix_66': (2 * b) // 3,
+             'full': b, 'scattered': b}[mask_kind]
+  seed_mask = np.arange(b) < n_valid
+  if mask_kind == 'scattered':
+    seed_mask = rng.random(b) < 0.5
+  seeds = jnp.asarray(rng.integers(0, n, b).astype(np.int32))
+  seed_mask = jnp.asarray(seed_mask)
+  indptr, indices = jnp.asarray(indptr), jnp.asarray(indices)
+  meta = (jnp.stack([indptr[:-1], indptr[1:] - indptr[:-1]], 1)
+          if use_meta else None)
+  key = jax.random.PRNGKey(b * 31 + k)
+  want = _untiled_uniform_sample(indptr, indices, seeds, seed_mask, k, key,
+                                 meta=meta)
+  got = ops.uniform_sample(indptr, indices, seeds, seed_mask, k, key,
+                           meta=meta)
+  for g, w, what in zip(got, want, ('nbrs', 'epos', 'mask')):
+    assert g.dtype == w.dtype and g.shape == w.shape, what
+    np.testing.assert_array_equal(np.asarray(g), np.asarray(w), what)
+  assert np.asarray(want[2]).any() == (n_valid > 0)
+
+  tile_rows = neighbor.draw_tile_rows(b)
+  if b < 2048:
+    assert tile_rows == 0
+    return
+  # the smallest multiple of 256 that covers the cap in DRAW_TILES tiles
+  assert tile_rows % 256 == 0
+  assert (tile_rows - 256) * neighbor.DRAW_TILES < b <= \
+      tile_rows * neighbor.DRAW_TILES
+  *tiled, tiles = neighbor.uniform_sample_tiled(
+      indptr, indices, seeds, seed_mask, k, key, meta)
+  for g, w in zip(tiled, want):
+    np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+  where = np.flatnonzero(np.asarray(seed_mask))
+  last_valid = int(where[-1]) + 1 if where.size else 0
+  assert tiles.dtype == jnp.int32
+  assert int(tiles) == -(-last_valid // tile_rows)
+
+
+def test_uniform_sample_tiled_never_reads_for_masked_tiles():
+  """Tiles past the last valid row contribute nothing: with every masked
+  row's gather target poisoned (today a masked row gathers row 0 of the
+  row table and element 0 of ``indices``), the outputs still equal the
+  clean graph's and hold no poison — the poison is only ever read inside
+  the tiles that ran, where the mask throws it away."""
+  from graphlearn_tpu.ops import neighbor
+  rng = np.random.default_rng(3)
+  n, b, k, poison = 400, 4096, 5, -7
+  indptr, indices = _random_csr(rng, n, 12)
+  # node 0 is where masked rows look: nobody valid samples from it, and
+  # its segment (the head of ``indices``) is poison
+  seeds = rng.integers(1, n, b).astype(np.int32)
+  poisoned = indices.copy()
+  poisoned[:indptr[1]] = poison
+  tile_rows = neighbor.draw_tile_rows(b)
+  n_valid = 5 * tile_rows + 7                # 6 of 16 tiles begin below it
+  assert -(-b // tile_rows) == 16
+  seed_mask = jnp.asarray(np.arange(b) < n_valid)
+  seeds[n_valid:] = 0
+  key = jax.random.PRNGKey(8)
+  args = (jnp.asarray(seeds), seed_mask, k, key)
+  clean = neighbor.uniform_sample_tiled(
+      jnp.asarray(indptr), jnp.asarray(indices), *args)
+  got = neighbor.uniform_sample_tiled(
+      jnp.asarray(indptr), jnp.asarray(poisoned), *args)
+  assert int(got[3]) == 6
+  for g, c in zip(got[:3], clean[:3]):
+    np.testing.assert_array_equal(np.asarray(g), np.asarray(c))
+  nbrs, epos, mask = (np.asarray(x) for x in got[:3])
+  assert not (nbrs == poison).any()
+  ran = int(got[3]) * tile_rows
+  assert mask[:n_valid].any() and not mask[n_valid:].any()
+  assert (nbrs[ran:] == ops.FILL).all() and (epos[ran:] == 0).all()
+
+
 def test_weighted_sample_bias():
   # node 0 -> {1 (w=100), 2 (w=1)}: draws should overwhelmingly pick 1.
   row = np.array([0, 0])

@@ -15,9 +15,9 @@ import graphlearn_tpu as glt
 from graphlearn_tpu.models import GAT, GraphSAGE, train as train_lib
 
 
-def make_dataset(n=96, f=6, seed=0):
+def make_dataset(n=96, f=6, seed=0, degree=4):
   rng = np.random.default_rng(seed)
-  rows = np.repeat(np.arange(n), 4)
+  rows = np.repeat(np.arange(n), degree)
   cols = (rows + rng.integers(1, n, rows.shape[0])) % n
   ds = glt.data.Dataset()
   ds.init_graph(np.stack([rows, cols]), graph_mode='CPU', num_nodes=n)
@@ -26,15 +26,16 @@ def make_dataset(n=96, f=6, seed=0):
   return ds
 
 
-def _make_loader(ds, num_seeds, **kw):
+def _make_loader(ds, num_seeds, fanouts=(3, 2), **kw):
   kw.setdefault('batch_size', 8)
   kw.setdefault('shuffle', False)
   kw.setdefault('seed', 0)
   # a NON-arange seed pool: pool[0] != 0 catches any tail padding that
   # differs from the host path's literal node-id-0 padding
-  pool = (np.random.default_rng(9).permutation(96)[:num_seeds]
+  n = ds.get_graph().num_nodes
+  pool = (np.random.default_rng(9).permutation(n)[:num_seeds]
           .astype(np.int64))
-  return glt.loader.NeighborLoader(ds, [3, 2], pool, **kw)
+  return glt.loader.NeighborLoader(ds, list(fanouts), pool, **kw)
 
 
 def _fresh_state(model, tx_template_batch):
@@ -43,22 +44,32 @@ def _fresh_state(model, tx_template_batch):
                                       tx_template_batch)
 
 
-def test_scan_trainer_matches_per_step_loop():
+@pytest.mark.parametrize('size', ['small', 'tiled'])
+def test_scan_trainer_matches_per_step_loop(size):
   """shuffle=False scanned epoch == the plain per-step loader loop:
   identical per-step losses and final params, with a ragged tail batch
   (44 seeds / batch 8 -> 5 full + 1 tail) and a tail CHUNK (6 steps at
-  K=4 -> chunks of 4 and 2)."""
-  ds = make_dataset()
-  num_seeds = 44
+  K=4 -> chunks of 4 and 2). 'tiled': the same identity under calibrated
+  caps wide enough that ops.uniform_sample draws hop 1 tile by tile
+  (1340 seeds / batch 256 -> 5 full + 1 tail)."""
+  if size == 'small':
+    ds, num_seeds, kw = make_dataset(), 44, {}
+  else:
+    ds, num_seeds = make_dataset(n=6000, degree=12), 1340
+    kw = dict(fanouts=(10, 2), batch_size=256, frontier_caps='auto')
+    from graphlearn_tpu.ops.neighbor import draw_tile_rows
+    caps = _make_loader(ds, num_seeds, **kw).sampler.hop_caps(256)
+    assert draw_tile_rows(caps[1]), caps    # hop 1's frontier is tiled
   model = GraphSAGE(hidden_dim=8, out_dim=3, num_layers=2)
+  make = lambda: _make_loader(ds, num_seeds, **kw)
 
   # template batch from a throwaway loader so neither run's key stream
   # is consumed by model init
-  first = train_lib.batch_to_dict(next(iter(_make_loader(ds, num_seeds))))
+  first = train_lib.batch_to_dict(next(iter(make())))
 
   # ---- reference: plain per-step loop
   import jax
-  ref_loader = _make_loader(ds, num_seeds)
+  ref_loader = make()
   state_ref, tx = _fresh_state(model, first)
   step, _ = train_lib.make_train_step(model, tx, 3)
   losses_ref = []
@@ -68,7 +79,7 @@ def test_scan_trainer_matches_per_step_loop():
   assert len(losses_ref) == 6   # 5 full + ragged tail
 
   # ---- scanned epoch over an identical fresh loader
-  scan_loader = _make_loader(ds, num_seeds)
+  scan_loader = make()
   state_scan, _ = train_lib.create_train_state(
       model, jax.random.PRNGKey(0), first, optimizer=tx)
   trainer = glt.loader.ScanTrainer(scan_loader, model, tx, 3,
