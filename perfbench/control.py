@@ -4,8 +4,9 @@ by a benchmark run: ``python3 perfbench/control.py --workload <name>
 process (the dataset is built once; every seed builds its own trainer).
 
 Per seed it drives the cell's own first call (``executors/*.first_call``),
-replays its batches and follows them with the plain reference: the LOWER
-readings are the program's numbers over the seeds. On the first
+replays its batches and follows them with the cell's plain reference
+(``cell.follower``, the call a run makes): the LOWER readings are the
+program's numbers over the seeds. On the first
 ``--control-seeds`` seeds it also reads what has to FAIL:
 
 * the control — the reference put in the program's place, computed in
@@ -79,8 +80,7 @@ def limits_from(readings):
   return limits, table
 
 
-def main(argv=None, require_platform='tpu', bench_file='BENCHMARK.json',
-         traffic_dir=os.path.join('perfbench', 'traffic')):
+def main(argv=None, require_platform='tpu', bench_file='BENCHMARK.json'):
   ap = argparse.ArgumentParser()
   ap.add_argument('--workload', required=True)
   ap.add_argument('--seeds', type=int, default=12)
@@ -93,8 +93,8 @@ def main(argv=None, require_platform='tpu', bench_file='BENCHMARK.json',
   args = ap.parse_args(argv)
   import jax.numpy as jnp
 
-  from perfbench import check, reference, run
-  t = run.open_cell(args.workload, bench_file, traffic_dir, require_platform)
+  from perfbench import check, run
+  t = run.open_cell(args.workload, bench_file, require_platform)
   cfg, traffic, family, executor = (t['cfg'], t['traffic'], t['family'],
                                     t['executor'])
   cell = family.Cell(cfg, traffic, lambda k, v: None)
@@ -113,11 +113,8 @@ def main(argv=None, require_platform='tpu', bench_file='BENCHMARK.json',
     batches = ex.replay(n_ref, n_val)
     params0 = ex.params0
     ex.free()
-    exact = check.validate_batches(cell, batches, n_val)
-    ref_in = [cell.reference_batch(b['node'], b['edge_index'],
-                                   b['edge_mask']) for b in batches]
-    follow = lambda lr=cell.lr, **kw: reference.follow(
-        cell.model_desc, lr, cell.batch, params0, ref_in, **kw)
+    exact = cell.exact_numbers(batches, n_val)
+    follow = cell.follower(params0, batches)
     ref = follow()
     record('program', seed, dict(
         exact, **check.compare_training(first, params0, *ref)))
@@ -154,13 +151,11 @@ def main(argv=None, require_platform='tpu', bench_file='BENCHMARK.json',
       json.dump(dict(workload=args.workload, readings=readings,
                      summary=summary, limits=table), f, indent=1)
   if args.write_limits:
-    exact = dict.fromkeys(('bad_edges', 'fanout_misses', 'dup_nodes',
-                           'bad_rows', 'overflow'), 0)
     with open(args.write_limits, 'w') as f:
       json.dump(dict(
           note='written by perfbench/control.py limits_from() from readings '
                'on the chip; PERF.md section 2 gives the readings',
-          limits=dict(exact, **limits)), f, indent=1)
+          limits=dict(dict.fromkeys(exact, 0), **limits)), f, indent=1)
   return readings
 
 

@@ -18,6 +18,8 @@ trainer's private attributes — there is no public way to ask a scanned
 epoch which subgraphs it trained on (PERF.md, Open questions). What ties
 the replay to the timed path is the comparison itself: a chunk that had
 trained on any other subgraph could not reproduce the reference's losses.
+The replayed chunk also gives the valid-row counts the per-layer metrics
+need (``valid_counts``): the window's own batches, not another pass's.
 """
 import numpy as np
 
@@ -36,7 +38,7 @@ class Executor:
                                    cell.num_classes,
                                    chunk_size=int(traffic['chunk_size']))
     self.steps_per_call = cell.steps_per_call
-    self.first = None
+    self.first = self._replayed = None
 
   # ------------------------------------------------------------ the call
 
@@ -127,8 +129,14 @@ class Executor:
       if g >= with_rows:
         del b['x']
       out.append(jax.device_get(b))
+    self._replayed = out
     return out
+
+  def valid_counts(self):
+    """The cell's counts over the batches :meth:`replay` sampled again:
+    the first chunk of the window's first call."""
+    return self.cell.valid_counts(self._replayed)
 
   def free(self):
     """Drop the program's state so the reference has the chip."""
-    self.state = self.trainer = self.loader = None
+    self.state = self.trainer = self.loader = self._replayed = None

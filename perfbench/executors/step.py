@@ -4,10 +4,11 @@ until a call ends (``chip_smoke.py``'s per_step and trace phases).
 
 A call is ``steps_per_call`` batches of one pass over the loader, ended by
 ``block_until_ready`` on the last loss. It is the executor of the
-``step-exact`` mix, and the scanned cells' traced runs use it for their
-slice (b): the only place where sampling, collate and the model are
-separate device programs today, so ``sample_ms``, ``collate_ms``,
-``train_ms`` and the valid-row counts are read here.
+``step-exact`` mix, which no cell uses yet (PERF.md section 7 row 1):
+the only place where sampling, collate and the model are separate device
+programs (``jit_sample_*``, ``jit_collate_batch``, ``jit_train_step``), so
+the cell that takes it can bring per-program readers over its traced slice
+(sums over the ``XLA Modules`` lane, in files of their own).
 
 Here the batches are in hand, so nothing is replayed: the first
 ``reference_steps`` batches of the first call are kept for the reference,
@@ -72,25 +73,16 @@ class Executor:
       with jax.profiler.TraceAnnotation('perfbench.step'):
         self.state, loss, _ = self.train_step(self.state,
                                               train_lib.batch_to_dict(b))
-      self._slice.append((b.num_sampled_nodes, b.edge_mask))
+      self._slice.append(dict(num_sampled_nodes=list(b.num_sampled_nodes),
+                              edge_mask=b.edge_mask))
     with jax.profiler.TraceAnnotation('perfbench.host_fetch'):
       jax.block_until_ready(loss)
     return n
 
   def valid_counts(self):
-    """Mean valid node rows per hop and valid edges per hop over the
-    traced slice's batches, and the node buffer's rows."""
+    """The cell's counts over the traced slice's batches."""
     import jax
-    eo = (0,) + tuple(self.cell.edge_offsets)
-    nodes, edges = [], []
-    for nsn, em in self._slice:
-      nodes.append([int(c) for c in jax.device_get(list(nsn))])
-      em = np.asarray(em)
-      edges.append([int(em[eo[h]:eo[h + 1]].sum())
-                    for h in range(len(eo) - 1)])
-    return dict(nodes=np.mean(nodes, 0).tolist(),
-                edges=np.mean(edges, 0).tolist(),
-                buffer_rows=int(self.cell.node_offsets[-1]))
+    return self.cell.valid_counts(jax.device_get(self._slice))
 
   def replay(self, n, with_rows):
     import jax

@@ -13,12 +13,17 @@ import time
 
 import numpy as np
 
-from perfbench import datagen, flops
+from perfbench import check, datagen, flops, reference
 
 
 class Cell:
   """What a configuration builds once per process: the reference's own
-  host arrays, the program's dataset, the caps and the model."""
+  host arrays, the program's dataset, the caps and the model.
+
+  ``run.py`` and ``control.py`` call ``shapes``, ``exact_numbers`` and
+  ``follower``; the executors ``make_loader``, ``make_model``,
+  ``make_state``, ``valid_counts`` and read ``batch``, ``num_classes``,
+  ``steps_per_call``; the readers ``step_flops`` (perfbench/README.md)."""
 
   def __init__(self, cfg, traffic, log):
     import graphlearn_tpu as glt
@@ -99,8 +104,6 @@ class Cell:
     import jax.numpy as jnp
     import optax
     from graphlearn_tpu.models import train as train_lib
-
-    from perfbench import reference
     params = reference.init_params(self.model_desc, seed)
     spec = jax.eval_shape(
         model.init, jax.random.PRNGKey(0),
@@ -118,8 +121,42 @@ class Cell:
                                  jnp.zeros((), jnp.int32))
     return state, tx, jax.device_get(params)
 
+  def shapes(self):
+    """The static shapes of a batch, for the set-up line."""
+    return dict(caps=self.caps, node_rows=self.node_offsets[-1],
+                edge_slots=self.edge_offsets[-1])
+
   def step_flops(self, nodes, edges):
     return flops.step_flops(self.model_desc, nodes, edges)
+
+  def valid_counts(self, batches):
+    """Mean valid node rows per hop and valid edges per hop over host
+    batches (``num_sampled_nodes``, ``edge_mask``), and the node buffer's
+    rows: what ``pad_share``, ``step_mfu`` and the rooflines count on."""
+    eo = (0,) + tuple(self.edge_offsets)
+    nodes = [np.asarray(b['num_sampled_nodes']).reshape(-1).tolist()
+             for b in batches]
+    edges = [[int(np.asarray(b['edge_mask'])[eo[h]:eo[h + 1]].sum())
+              for h in range(len(eo) - 1)] for b in batches]
+    return dict(nodes=np.mean(nodes, 0).tolist(),
+                edges=np.mean(edges, 0).tolist(),
+                buffer_rows=int(self.node_offsets[-1]))
+
+  def exact_numbers(self, batches, n):
+    """The limit-0 numbers of the first ``n`` replayed batches, against
+    the generator's own arrays (``check.validate_batches``)."""
+    return check.validate_batches(self, batches, n)
+
+  def follower(self, params0, batches):
+    """``follow(lr=<the configuration's>, compute_dtype=, half_batch=,
+    precision=) -> (losses, first gradient, params, first moment)``: the
+    plain reference (``perfbench/reference.py``) over the replayed
+    batches from ``params0``. The keywords are ``control.py``'s controls
+    and faults; a run calls it bare."""
+    ref_in = [self.reference_batch(b['node'], b['edge_index'],
+                                   b['edge_mask']) for b in batches]
+    return lambda lr=self.lr, **kw: reference.follow(
+        self.model_desc, lr, self.batch, params0, ref_in, **kw)
 
   def reference_batch(self, node, edge_index, edge_mask):
     """A replayed batch as the reference wants it: rows and labels

@@ -1,5 +1,6 @@
 """The work static shapes waste: 1 - valid node rows / node-buffer rows,
-mean over the per-batch slice's batches."""
+mean over the batches the executor counts on (``valid_counts()``: under
+``scan`` the first chunk's replayed batches, the window's own)."""
 LAYER = 'capacity'
 UNIT = '%'
 MOVES = 'seeds_per_s'

@@ -1,7 +1,7 @@
 """The collate gather against the HBM roofline (bound: bytes) INSIDE the
-window's own chunk program: ``collate_roofline``'s bytes — every valid row
+window's own chunk program: the bytes it must move — every valid row
 read once and written once, perfbench/flops.py ``collate_bytes``, counts
-from the per-batch slice's masks — over the peak HBM rate, as a share of
+from the first chunk's replayed batches — over the peak HBM rate, as a share of
 ``scan_collate_ms``. None, never 0, when there is nothing to read."""
 from perfbench import flops, scope_reduce
 

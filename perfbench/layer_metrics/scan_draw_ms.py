@@ -51,7 +51,8 @@ def draws(run):
   a = run['scan']
   out = {'ms': None, 'tiles': None}
   if a['steps']:
-    scopes, _ = scope_reduce.by_scope(a['device'], scope_reduce.CHUNK_STEM)
+    scopes, _ = scope_reduce.by_scope(a['device'], scope_reduce.CHUNK_STEM,
+                                      scope_reduce.timed_of(a))
     ms = {m.group(1): 1e3 * s / a['steps'] for m, s in
           ((_DRAW.match(k), s) for k, s in sorted(scopes.items())) if m}
     tiles = {hop: n / a['steps']

@@ -1,6 +1,7 @@
 """The whole step's share of the chip's peak: operations forward+backward
-REQUIRE on the valid rows and edges of a batch (perfbench/flops.py, counts
-from the per-batch slice's masks), times the steps per second of the
+REQUIRE on the valid rows and edges of a batch (the cell's ``step_flops``
+over the executor's ``valid_counts()``: under ``scan`` the first chunk's
+replayed batches), times the steps per second of the
 traced slice of the cell's own executor, over the bf16 peak of
 perfbench/peaks.json for this device kind."""
 LAYER = 'whole step'

@@ -26,9 +26,14 @@ def say(**kw):
   print('perfbench: ' + json.dumps(kw), flush=True)
 
 
-def load_cell(workload, bench_file, traffic_dir):
+def load_cell(workload, bench_file):
+  """The files of one cell, found by the names in ``bench_file``. A test's
+  fixture file says where its traffic mixes and limits are (``traffic_dir``,
+  ``limits_dir``); ``BENCHMARK.json`` has neither key."""
   with open(os.path.join(ROOT, bench_file)) as f:
     bench = json.load(f)
+  traffic_dir = bench.get('traffic_dir', os.path.join('perfbench', 'traffic'))
+  limits_dir = bench.get('limits_dir', os.path.join('perfbench', 'limits'))
   cells = {w['name']: w for w in bench['workloads']}
   if workload not in cells:
     raise SystemExit(f'perfbench: no workload {workload!r} in {bench_file}; '
@@ -39,19 +44,17 @@ def load_cell(workload, bench_file, traffic_dir):
     cfg = json.load(f)
   with open(os.path.join(ROOT, traffic_dir, entry['traffic'] + '.json')) as f:
     traffic = json.load(f)
-  with open(os.path.join(ROOT, 'perfbench', 'limits',
-                         workload + '.json')) as f:
+  with open(os.path.join(ROOT, limits_dir, workload + '.json')) as f:
     limits = json.load(f)['limits']
   return bench, entry, cfg, traffic, limits
 
 
-def open_cell(workload, bench_file, traffic_dir, require_platform):
+def open_cell(workload, bench_file, require_platform):
   """Everything a process needs before it builds a cell: the cell's files,
   the device (SystemExit without the platform or the chips the cell asks
   for — no fallback), the chip's peaks, the compile cache, and the
   family and executor modules the files name."""
-  bench, entry, cfg, traffic, limits = load_cell(workload, bench_file,
-                                                 traffic_dir)
+  bench, entry, cfg, traffic, limits = load_cell(workload, bench_file)
   import jax
   devs = jax.devices()
   if devs[0].platform != require_platform or len(devs) < entry['chips']:
@@ -91,50 +94,25 @@ def trace_session(jax, logdir):
   jax.profiler.start_trace(logdir, profiler_options=opts)
 
 
-def traced_phase(jax, ex, cell, traffic, seed, peaks, win):
-  """The two traced slices of a ``--trace 1`` run and the per-layer
-  input they give: (a) a slice of the cell's own executor; (b) the
-  per-batch loop at the cell's shapes, where sampling, collate and the
-  model are separate device programs."""
+def traced_slice(jax, ex):
+  """The traced slice of a ``--trace 1`` run: a short slice of the cell's
+  own executor inside one profiler session, as the readers get it
+  (``run['scan']``), with the device's idle ``gaps`` in it."""
   from perfbench import trace_reduce
-  from perfbench.executors import step as step_mod
   tmp = tempfile.mkdtemp(prefix='perfbench_trace_')
   try:
-    trace_session(jax, os.path.join(tmp, 'a'))
+    trace_session(jax, tmp)
     try:
-      steps_a = ex.traced_slice()
+      steps = ex.traced_slice()
     finally:
       jax.profiler.stop_trace()
-    dev_a, host_a = trace_reduce.load(os.path.join(tmp, 'a'))
-    if isinstance(ex, step_mod.Executor):
-      step_ex = ex
-      dev_b, host_b, steps_b = dev_a, host_a, steps_a
-    else:
-      step_ex = step_mod.Executor(cell, traffic, seed)
-      step_ex._call(2)          # compiles the three per-batch programs
-      trace_session(jax, os.path.join(tmp, 'b'))
-      try:
-        steps_b = step_ex.traced_slice()
-      finally:
-        jax.profiler.stop_trace()
-      dev_b, host_b = trace_reduce.load(os.path.join(tmp, 'b'))
+    device, host = trace_reduce.load(tmp)
   finally:
     shutil.rmtree(tmp, ignore_errors=True)
-  busy_s, window_s, gaps = trace_reduce.busy(
-      dev_a, trace_reduce.window_of(host_a))
-  counts = step_ex.valid_counts()
-  if step_ex is not ex:
-    step_ex.free()
-  run = dict(cell=cell, traffic=traffic, peaks=peaks, window=win,
-             counts=counts,
-             scan=dict(device=dev_a, host=host_a, steps=steps_a,
-                       busy_s=busy_s, window_s=window_s),
-             step=dict(device=dev_b, host=host_b, steps=steps_b))
-  breakdown = {
-      'device_ops': [[n, s] for n, s in
-                     list(trace_reduce.op_seconds(dev_a).items())[:10]],
-      'idle_gaps': trace_reduce.label_gaps(gaps, host_a)}
-  return run, breakdown
+  busy_s, window_s, gaps = trace_reduce.busy(device,
+                                             trace_reduce.window_of(host))
+  return dict(device=device, host=host, steps=steps, busy_s=busy_s,
+              window_s=window_s, gaps=gaps)
 
 
 def read_layer_metrics(bench, workload, run):
@@ -149,20 +127,19 @@ def read_layer_metrics(bench, workload, run):
   return out
 
 
-def main(argv=None, require_platform='tpu', bench_file='BENCHMARK.json',
-         traffic_dir=os.path.join('perfbench', 'traffic')):
+def main(argv=None, require_platform='tpu', bench_file='BENCHMARK.json'):
   ap = argparse.ArgumentParser()
   ap.add_argument('--workload', required=True)
   ap.add_argument('--seed', type=int, required=True)
   ap.add_argument('--seconds', type=float, required=True)
   ap.add_argument('--trace', type=int, choices=(0, 1), default=0)
   args = ap.parse_args(argv)
-  o = open_cell(args.workload, bench_file, traffic_dir, require_platform)
+  o = open_cell(args.workload, bench_file, require_platform)
   jax, bench, cfg, traffic, limits = (o['jax'], o['bench'], o['cfg'],
                                       o['traffic'], o['limits'])
   on_tpu, peaks, cache_dir = o['on_tpu'], o['peaks'], o['cache_dir']
   family, executor = o['family'], o['executor']
-  from perfbench import check, reference
+  from perfbench import check
   cache_before = len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
   phases = {'import_s': time.perf_counter() - T0}
 
@@ -174,8 +151,7 @@ def main(argv=None, require_platform='tpu', bench_file='BENCHMARK.json',
   first = ex.first_call()
   phases['first_call_s'] = time.perf_counter() - t
   setup_s = time.perf_counter() - T0
-  say(setup_phases=phases, caps=cell.caps, node_rows=cell.node_offsets[-1],
-      edge_slots=cell.edge_offsets[-1], cache_dir=cache_dir,
+  say(setup_phases=phases, **cell.shapes(), cache_dir=cache_dir,
       cache_entries_before=cache_before,
       peak_bytes_after_data=peak_data,
       peak_bytes_after_setup=device_record(jax)['memory_peak_bytes'],
@@ -193,21 +169,13 @@ def main(argv=None, require_platform='tpu', bench_file='BENCHMARK.json',
       'hbm_peak_gb': {'value': device['memory_peak_bytes'] / 1e9,
                       'unit': 'GB'},
       'setup_s': {'value': setup_s, 'unit': 's'}}
-  breakdown = None
-  if args.trace:
-    run, breakdown = traced_phase(jax, ex, cell, traffic, args.seed, peaks,
-                                  win)
-    device['busy_s'] = run['scan']['busy_s']
-    device['window_s'] = run['scan']['window_s']
-    say(valid_counts=run['counts'])
-    metrics = read_layer_metrics(bench, args.workload, run)
-  if not on_tpu:
-    # a CPU run counts and compares; it never times the device
-    metrics, breakdown = {}, None
-    device.pop('busy_s', None)
-    device.pop('window_s', None)
 
-  # ---- correct: the first call against the plain reference
+  # ---- the traced slice straight after the window (after the replay
+  # the first launch starts 20-30 ms late: PERF.md section 6, PR 28); then
+  # the first call's batches once more: `correct` follows them, and the
+  # traced run counts its valid rows on them
+  if args.trace:
+    slice_ = traced_slice(jax, ex)
   t = time.perf_counter()
   n_ref, n_val = first['steps'], int(traffic['validated_batches'])
   if n_ref != int(traffic['reference_steps']):
@@ -216,15 +184,32 @@ def main(argv=None, require_platform='tpu', bench_file='BENCHMARK.json',
                      f'{traffic["reference_steps"]}')
   batches = ex.replay(n_ref, n_val)
   params0 = ex.params0
+  replay_s = time.perf_counter() - t
+  breakdown = None
+  if args.trace:
+    from perfbench import scope_reduce
+    run = dict(cell=cell, traffic=traffic, peaks=peaks, window=win,
+               counts=ex.valid_counts(), scan=slice_)
+    breakdown = scope_reduce.breakdown(slice_)
+    device['busy_s'] = slice_['busy_s']
+    device['window_s'] = slice_['window_s']
+    say(valid_counts=run['counts'])
+    metrics = read_layer_metrics(bench, args.workload, run)
+  if not on_tpu:
+    # a CPU run counts and compares; it never times the device
+    metrics, breakdown = {}, None
+    device.pop('busy_s', None)
+    device.pop('window_s', None)
   ex.free()
-  numbers = check.validate_batches(cell, batches, n_val)
-  ref_in = [cell.reference_batch(b['node'], b['edge_index'], b['edge_mask'])
-            for b in batches]
+
+  # ---- correct: the first call against the cell's plain reference
+  t = time.perf_counter()
+  numbers = cell.exact_numbers(batches, n_val)
   numbers.update(check.compare_training(
-      first, params0, *reference.follow(cell.model_desc, cell.lr,
-                                        cell.batch, params0, ref_in)))
+      first, params0, *cell.follower(params0, batches)()))
   correct, table = check.verdict(numbers, limits)
-  say(check_s=time.perf_counter() - t, followed_steps=n_ref,
+  say(replay_s=replay_s, check_s=time.perf_counter() - t,
+      followed_steps=n_ref,
       measured_not_compared={k: v for k, v in numbers.items()
                              if k not in limits})
 

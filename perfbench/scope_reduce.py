@@ -16,17 +16,19 @@ anything it hoisted out of the loop — is *unscoped*.
 Self time is an event's ``dur`` less the ``dur`` of the events nested
 directly in it on the same lane, so a ``while`` / ``conditional`` / ``call``
 counts only its own overhead and the self times of a lane add up to the
-union of its busy intervals (``trace_reduce.op_seconds`` sums a wrapper and
-its children, which is why ``breakdown.device_ops`` is led by ``while``).
+union of its busy intervals (a sum of durations counts a wrapper and its
+children: there a ``while`` repeats its whole body).
 
 ``layers(run)`` is what the six ``scan_*`` / ``host_gap_ms`` readers share.
-It reduces slice (a) — the window's own chunk program — once per run, keeps
-the result in ``run``, and prints one ``perfbench:`` line with the sub-scope
-split, ``host_gap_ms`` by innermost span, what tracing cost, and the
-cross-check: the same reduction over slice (b), where each scope *is* one
-program, beside the program-lane ``sample_ms`` / ``collate_ms`` /
-``train_ms``. With a program that has no ``glt.`` scope or span (the parent
-of PR 26) every reader finds nothing and returns ``None``.
+It reduces the traced slice — the window's own chunk program — once per
+run, keeps the result in ``run``, and prints one ``perfbench:`` line with
+the sub-scope split, ``host_gap_ms`` by innermost span and what tracing
+cost. With a program that has no ``glt.`` scope or span (the parent of
+PR 26) every reader finds nothing and returns ``None``.
+
+``device_ops`` and ``idle_gaps`` are the two lists of a traced run's
+``breakdown``: self time by scope and op class, and the longest idle gaps by
+what the host was doing.
 """
 import bisect
 import collections
@@ -89,6 +91,27 @@ def self_times(device):
   return out, max(1, len(chips))
 
 
+def timed_of(slice_):
+  """``self_times`` of a traced slice, reduced once and kept in it: the
+  ``breakdown``, ``layers`` and ``scan_draw_ms.draws`` all read it."""
+  if 'self_times' not in slice_:
+    slice_['self_times'] = self_times(slice_['device'])
+  return slice_['self_times']
+
+
+def by_scope_and_op(timed):
+  """``{(sub-scope or unscoped, op class): seconds}`` of ``self_times``'
+  value, averaged over its chips — the one accumulation ``by_scope`` and
+  ``device_ops`` fold."""
+  timed, chips = timed
+  acc = collections.defaultdict(float)
+  for e, self_us in timed:
+    path = scope_path(e)
+    op = trace_reduce._SUFFIX.sub('', e.get('name', ''))
+    acc[sub_scope(path) if path else UNSCOPED, op] += self_us / 1e6 / chips
+  return acc
+
+
 def by_scope(device, stem=None, timed=None):
   """``({scope: seconds}, {op class: seconds of the unscoped})`` — self
   time keyed by layer sub-scope (``sub_scope``) or ``unscoped``, over the
@@ -108,15 +131,11 @@ def by_scope(device, stem=None, timed=None):
           e['ts'] + e['dur'] <= iv[i][2] + _EPS_US
     timed = [(e, s) for e, s in timed if inside(e)]
   scopes = collections.defaultdict(float)
-  loose = collections.defaultdict(float)
-  for e, self_us in timed:
-    path = scope_path(e)
-    if path:
-      scopes[sub_scope(path)] += self_us / 1e6 / chips
-    else:
-      scopes[UNSCOPED] += self_us / 1e6 / chips
-      loose[trace_reduce._SUFFIX.sub('', e.get('name', ''))] += \
-          self_us / 1e6 / chips
+  loose = {}
+  for (scope, op), s in by_scope_and_op((timed, chips)).items():
+    scopes[scope] += s
+    if scope == UNSCOPED:
+      loose[op] = s
   return dict(scopes), dict(sorted(loose.items(), key=lambda kv: -kv[1]))
 
 
@@ -148,17 +167,47 @@ def host_gaps(device, host):
   return dict(out)
 
 
+def device_ops(device, top=10, timed=None):
+  """``[[<sub-scope or unscoped>:<op class>, seconds]]``, the ``top``
+  largest by self time (averaged over the chips): they add up to no more
+  than the slice's busy time, and a ``while`` counts only its own."""
+  acc = by_scope_and_op(timed or self_times(device))
+  return [[f'{scope}:{op}', s] for (scope, op), s in
+          sorted(acc.items(), key=lambda kv: -kv[1])[:top]]
+
+
+def idle_gaps(gaps, host, top=10):
+  """The ``top`` longest idle gaps as ``[label, seconds]``: the innermost
+  of the program's ``glt.`` spans open at the gap's middle, by its full
+  name; where none is, the harness's own annotation (``run_epoch``,
+  ``host_fetch``, ``between calls``)."""
+  longest = sorted(gaps, key=lambda g: g[0] - g[1])[:top]
+  inner = trace_reduce.label_gaps(longest, host, top, PREFIX)
+  outer = trace_reduce.label_gaps(longest, host, top)
+  return [[outer_label, s] if label == trace_reduce.NO_SPAN
+          else [PREFIX + label, s]
+          for (label, s), (outer_label, _) in zip(inner, outer)]
+
+
+def breakdown(slice_):
+  """The last line's ``breakdown`` of a traced slice (``device`` and
+  ``host`` events, the device's idle ``gaps``)."""
+  return {'device_ops': device_ops(slice_['device'], timed=timed_of(slice_)),
+          'idle_gaps': idle_gaps(slice_['gaps'], slice_['host'])}
+
+
 def layers(run):
-  """Slice (a) by layer, once per run: ``{'ms': {glt.sample, glt.collate,
-  glt.train, unscoped} ms/step or None, 'host_gap_ms': ms/step or None}``.
+  """The traced slice by layer, once per run: ``{'ms': {glt.sample,
+  glt.collate, glt.train, unscoped} ms/step or None, 'host_gap_ms':
+  ms/step or None}``.
   The first call prints the ``perfbench:`` line described above."""
   if 'scope_reduce' in run:
     return run['scope_reduce']
-  a, b = run['scan'], run['step']
+  a = run['scan']
   out = {'ms': None, 'host_gap_ms': None}
   line = {}
   if a['steps']:
-    timed_a = self_times(a['device'])
+    timed_a = timed_of(a)
     scopes, loose = by_scope(a['device'], CHUNK_STEM, timed_a)
     per_step = lambda s: 1e3 * s / a['steps']
     layer_s = by_layer(scopes)
@@ -183,25 +232,6 @@ def layers(run):
       line['tracing_on_steps_per_s'] = a['steps'] / a['window_s']
       line['tracing_off_steps_per_s'] = (run['window']['steps'] /
                                          run['window']['wall_s'])
-  if b['steps'] and b['device'] is not a['device']:
-    timed_b = self_times(b['device'])
-    layer_b = by_layer(by_scope(b['device'], None, timed_b)[0])
-    if layer_b is not None:
-      # scope_ms against program_ms proves the names; all_ops_ms (every
-      # op inside that program, scoped or not) against program_ms proves
-      # the reducer; what separates them is metadata the compiler lost
-      check = {}
-      for layer, stem in (('glt.sample', 'jit_sample_'),
-                          ('glt.collate', 'jit_collate_batch'),
-                          ('glt.train', 'jit_train_step')):
-        program = trace_reduce.program_ms_per_step(b, stem)
-        scope = 1e3 * layer_b[layer] / b['steps']
-        all_ops = 1e3 * sum(
-            by_scope(b['device'], stem, timed_b)[0].values())
-        check[layer] = {'scope_ms': scope, 'program_ms': program,
-                        'all_ops_ms': all_ops / b['steps'],
-                        'ratio': scope / program if program else None}
-      line['cross_check_slice_b'] = check
   if line:
     print('perfbench: ' + json.dumps({'scope_reduce': line}), flush=True)
   run['scope_reduce'] = out

@@ -1,11 +1,13 @@
 """From a profiler trace to numbers: the benchmark's own reduction.
 
-A copy of the reading ``graphlearn_tpu/utils/trace.py`` does
-(``device_program_ms`` / ``device_op_ms``, repaired in PR 21 to read the
-``XLA Modules`` / ``XLA Ops`` lanes of a v5e trace) — kept here because
-later PRs may change the program and not the yardstick — plus what PR 21
-did by hand: the union of the device's busy intervals, the idle share, and
-the idle gaps labelled by what the host was doing.
+The lane-level reading of a v5e trace (``XLA Modules`` holds the programs,
+``XLA Ops`` the operations; ``graphlearn_tpu/utils/trace.py`` reads the same
+lanes, and later PRs may change the program but not the yardstick): the
+loader, the union of the device's busy intervals, the idle share, and the
+idle gaps labelled by what the host was doing. Time per scope and per
+operation is ``scope_reduce.py``'s, as self time; a sum of whole programs'
+or operations' durations has no reader since PR 28 (the per-batch cell that
+wants one brings it with its readers).
 
 Input is the ``*.trace.json.gz`` the JAX profiler writes: ``M`` events name
 processes (``/device:TPU:0``, ``/host:CPU``) and threads (lanes); ``X``
@@ -21,6 +23,7 @@ import re
 PROGRAM_LANE = 'XLA Modules'
 OP_LANE = 'XLA Ops'
 _SUFFIX = re.compile(r'\.\d+$')
+NO_SPAN = 'between calls'       # label of an idle gap no annotation covers
 
 
 def load(path_or_dir):
@@ -53,45 +56,6 @@ def load(path_or_dir):
     elif proc.startswith('/host'):
       host.append(e)
   return device, host
-
-
-def program_ms(device):
-  """{program: (mean ms per call, calls)} from the programs' own lane."""
-  acc = collections.defaultdict(lambda: [0.0, 0])
-  for e in device:
-    if e['lane'] == PROGRAM_LANE and e.get('name', '').startswith('jit_'):
-      acc[e['name']][0] += e['dur']
-      acc[e['name']][1] += 1
-  return {n: (tot / cnt / 1e3, cnt) for n, (tot, cnt) in acc.items()}
-
-
-def program_total_ms(device, stem):
-  """Device ms summed over every call of the programs whose name
-  contains ``stem``; None when the trace holds none."""
-  hit = [ms * cnt for n, (ms, cnt) in program_ms(device).items()
-         if stem in n]
-  return sum(hit) if hit else None
-
-
-def program_ms_per_step(slice_, stem):
-  """Device ms per step of the programs named ``stem`` in a traced slice
-  (``device`` events and the ``steps`` it ran); None with nothing to read."""
-  total = program_total_ms(slice_['device'], stem)
-  return None if total is None or not slice_['steps'] else (
-      total / slice_['steps'])
-
-
-def op_seconds(device, strip_ids=True):
-  """{op: seconds} from the operations' own lane, instance numbers
-  stripped (``fusion.123`` -> ``fusion``) unless asked otherwise; the
-  ``Steps`` lane holds the same time again and is not read."""
-  acc = collections.defaultdict(float)
-  for e in device:
-    n = e.get('name', '')
-    if e['lane'] != OP_LANE or n.startswith('jit_'):
-      continue
-    acc[_SUFFIX.sub('', n) if strip_ids else n] += e['dur'] / 1e6
-  return dict(sorted(acc.items(), key=lambda kv: -kv[1]))
 
 
 def _merged(intervals):
@@ -143,14 +107,14 @@ def busy(device, window=None):
 
 def label_gaps(gaps, host, top=10, prefix='perfbench.'):
   """The ``top`` longest idle gaps as ``[label, seconds]``: the label is
-  the innermost harness annotation open at the gap's middle (``between
-  calls`` when none is)."""
+  the innermost annotation named ``prefix...`` open at the gap's middle
+  (``NO_SPAN`` when none is)."""
   mine = [e for e in host if e.get('name', '').startswith(prefix)]
   out = []
   for lo, hi in sorted(gaps, key=lambda g: g[0] - g[1])[:top]:
     mid = (lo + hi) / 2
     open_ = [e for e in mine if e['ts'] <= mid <= e['ts'] + e['dur']]
     label = (min(open_, key=lambda e: e['dur'])['name'][len(prefix):]
-             if open_ else 'between calls')
+             if open_ else NO_SPAN)
     out.append([label, (hi - lo) / 1e6])
   return out

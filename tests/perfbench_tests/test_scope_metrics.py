@@ -31,7 +31,7 @@ class _Cell:
 
 def _run_from(trace_file, steps):
   """What ``run.traced_phase`` hands the readers, built from one recorded
-  trace standing in for both slices."""
+  trace standing in for the traced slice."""
   device, host = trace_reduce.load(os.path.join(FIX, trace_file))
   busy_s, window_s, _ = trace_reduce.busy(device)
   slice_ = dict(device=device, host=host, steps=steps, busy_s=busy_s,
@@ -40,7 +40,12 @@ def _run_from(trace_file, steps):
               peaks=dict(hbm_bytes_per_s=819e9, bf16_flops_per_s=197e12),
               counts=dict(nodes=[1024.0, 10700.0, 39000.0, 43200.0],
                           edges=[], buffer_rows=139776),
-              scan=slice_, step=slice_)
+              scan=slice_)
+
+
+def _while_dur_seconds(device):
+  return sum(e['dur'] for e in device if e['lane'] == trace_reduce.OP_LANE
+             and e['name'].split('.')[0] == 'while') / 1e6
 
 
 def _read(name, run_):
@@ -59,12 +64,12 @@ def test_self_time_by_scope_on_a_recorded_v5e_scanned_chunk(capsys):
     assert scopes[k] == pytest.approx(s, rel=1e-9), k
   assert list(loose)[:3] == want['top_unscoped_ops']
   # the while wraps every op of the body: summing durations counts the
-  # body twice (trace_reduce.op_seconds does), self time counts it once
-  ops = trace_reduce.op_seconds(device)
-  assert ops['while'] == pytest.approx(want['while_dur_seconds'], rel=1e-9)
+  # body twice, self time counts it once
+  while_dur = _while_dur_seconds(device)
+  assert while_dur == pytest.approx(want['while_dur_seconds'], rel=1e-9)
   assert loose['while'] == pytest.approx(want['while_self_seconds'],
                                          rel=1e-6)
-  assert loose['while'] < 0.02 * ops['while']
+  assert loose['while'] < 0.02 * while_dur
   # scopes + unscoped = the union of the chunk program's busy intervals
   chunk_busy = want['chunk_busy_seconds']
   assert sum(scopes.values()) == pytest.approx(chunk_busy, rel=1e-9)
@@ -93,6 +98,47 @@ def test_a_program_without_scopes_reads_as_nothing_never_zero(name, capsys):
   run_ = _run_from('trace_v5e_cut.json', 2)
   assert _read(name, run_) is None
   assert 'scope_reduce' not in capsys.readouterr().out
+
+
+def test_breakdown_device_ops_are_self_time_by_scope_and_op_class():
+  """``breakdown.device_ops``: what the next issue's writer reads. A
+  ``while`` counts its own overhead, not its body again, so the list adds
+  up to no more than the slice's busy time; each entry names its scope."""
+  with open(os.path.join(FIX, 'trace_v5e_scan_cut.expected.json')) as f:
+    want = json.load(f)
+  device, _ = trace_reduce.load(os.path.join(FIX, 'trace_v5e_scan_cut.json'))
+  ops = scope_reduce.device_ops(device)
+  assert 0 < len(ops) <= 10
+  seconds = [s for _, s in ops]
+  assert seconds == sorted(seconds, reverse=True)
+  busy_s = trace_reduce.busy(device)[0]
+  assert sum(seconds) <= busy_s * (1 + 1e-9)
+  assert sum(seconds) > 0.5 * busy_s          # the top ten say something
+  scopes = {name.split(':')[0] for name, _ in ops}
+  assert scopes <= set(want['scope_seconds']) | {scope_reduce.UNSCOPED}
+  assert any(s.startswith('glt.train') for s in scopes)
+  everything = dict(scope_reduce.device_ops(device, top=10 ** 6))
+  assert sum(everything.values()) == pytest.approx(busy_s, rel=1e-6)
+  assert everything['unscoped:while'] == pytest.approx(
+      want['while_self_seconds'], rel=1e-6)
+  # the sum of durations the ledger's PR 27 lines show repeats the body
+  assert _while_dur_seconds(device) > 0.9 * busy_s
+
+
+def test_breakdown_idle_gaps_are_named_by_the_programs_innermost_span():
+  span = lambda name, ts, dur: dict(name=name, ts=ts, dur=dur)
+  host = [span('perfbench.run_epoch', 0, 1000),
+          span('glt.epoch.run', 10, 900), span('glt.epoch.chunk', 100, 300),
+          span('perfbench.host_fetch', 1000, 500),
+          span('$other.thread', 0, 5000)]
+  gaps = [(150, 160), (500, 560), (1100, 1400), (2000, 2001), (2, 6)]
+  assert scope_reduce.idle_gaps(gaps, host, top=4) == [
+      ['host_fetch', pytest.approx(300e-6)],
+      ['glt.epoch.run', pytest.approx(60e-6)],
+      ['glt.epoch.chunk', pytest.approx(10e-6)],
+      ['run_epoch', pytest.approx(4e-6)]]
+  assert scope_reduce.idle_gaps(gaps, host)[-1] == [
+      trace_reduce.NO_SPAN, pytest.approx(1e-6)]
 
 
 def test_the_programs_spans_reach_the_trace_under_glt_names(tmp_path):
