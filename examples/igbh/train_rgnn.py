@@ -7,6 +7,11 @@ IGBH-shaped synthetic is generated: papers carry community labels, cites
 edges are homophilous, authorship is random — classification requires
 aggregating over the typed neighborhood.
 
+The epoch runs as a program: exact dedup under calibrated typed caps, the
+dense k-run typed aggregation, and ``ScanTrainer`` over the typed
+``NeighborLoader`` (the typed sampler and the per-type collate are traced
+into the scanned chunk).
+
 Run: python examples/igbh/train_rgnn.py --epochs 2
 """
 import argparse
@@ -71,7 +76,6 @@ def main():
   args = ap.parse_args()
 
   import jax
-  import jax.numpy as jnp
   glt.utils.enable_compilation_cache()
   rng = np.random.default_rng(0)
   ncls = 16
@@ -93,16 +97,25 @@ def main():
   # batches under drop_last (and n_paper < 10 would yield zero seeds)
   n_tr = max(1, int(args.n_paper * 0.1))
   args.batch_size = min(args.batch_size, n_tr)
+  train_idx = np.arange(n_tr)
+  # the normal path for this job: exact dedup under CALIBRATED typed caps
+  # (per (hop, edge type) new-node caps probed on this seed pool), the
+  # dense k-run typed aggregation over that plan, and the epoch as a
+  # program — ScanTrainer traces the typed sampler and the per-type
+  # collate into its scanned chunk
+  caps = glt.sampler.estimate_hetero_frontier_caps(
+      ds.graph, fanouts, {'paper': args.batch_size},
+      input_nodes={'paper': train_idx}, seed=0)
   loader = glt.loader.NeighborLoader(
-      ds, fanouts, ('paper', np.arange(n_tr)),
+      ds, fanouts, ('paper', train_idx),
       batch_size=args.batch_size, shuffle=True, drop_last=True, seed=0,
-      dedup='tree')
+      dedup='merge', frontier_caps=caps)
 
-  # typed dense k-run aggregation over the hierarchical tree layout —
-  # the fast hetero path (PERF.md round 4); --model rgat matches the
-  # reference default (4 heads, per-head dim = hidden // heads)
+  # --model rgat matches the reference default (4 heads, per-head dim =
+  # hidden // heads)
   recs, no, eo = glt.sampler.hetero_tree_blocks(
-      {'paper': args.batch_size}, tuple(fanouts), fanouts)
+      {'paper': args.batch_size}, tuple(fanouts), fanouts,
+      etype_caps=caps)
   etypes = [glt.typing.reverse_edge_type(CITES),
             glt.typing.reverse_edge_type(WRITES),
             glt.typing.reverse_edge_type(REV_WRITES)]
@@ -111,49 +124,26 @@ def main():
                conv=('gat' if args.model == 'rgat' else 'sage'),
                heads=(4 if args.model == 'rgat' else 1),
                hop_node_offsets=no, hop_edge_offsets=eo,
-               tree_dense=True, tree_records=recs)
+               merge_dense=True, tree_records=recs)
 
-  def batch_dict(batch):
-    return dict(x=batch.x, ei=batch.edge_index, em=batch.edge_mask,
-                y=batch.y['paper'],
-                num_seed=batch.num_sampled_nodes['paper'][0])
-
-  first = batch_dict(next(iter(loader)))
-  params = model.init(jax.random.PRNGKey(0), first['x'], first['ei'],
-                      first['em'])
-  import optax
-  tx = optax.adam(args.lr)
-  opt_state = tx.init(params)
-
-  def loss_fn(params, b):
-    logits = model.apply(params, b['x'], b['ei'], b['em'])
-    n = logits.shape[0]          # hierarchical emits the seed prefix
-    y = b['y'][:n]
-    seed_mask = jnp.arange(n) < b['num_seed']
-    ce = optax.softmax_cross_entropy(logits, jax.nn.one_hot(y, ncls))
-    loss = jnp.where(seed_mask, ce, 0.0).sum() / jnp.maximum(
-        seed_mask.sum(), 1)
-    acc = (((logits.argmax(-1) == y) & seed_mask).sum() /
-           jnp.maximum(seed_mask.sum(), 1))
-    return loss, acc
-
-  @jax.jit
-  def train_step(params, opt_state, b):
-    (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-        params, b)
-    updates, opt_state = tx.update(grads, opt_state, params)
-    return optax.apply_updates(params, updates), opt_state, loss, acc
+  # template batch from a throwaway loader, so the training loader's key
+  # stream starts at the first trained batch
+  template = train_lib.batch_to_dict(next(iter(glt.loader.NeighborLoader(
+      ds, fanouts, ('paper', train_idx), batch_size=args.batch_size,
+      seed=0, dedup='merge', frontier_caps=caps))))
+  state, tx = train_lib.create_train_state(model, jax.random.PRNGKey(0),
+                                           template, lr=args.lr)
+  trainer = glt.loader.ScanTrainer(loader, model, tx, ncls, chunk_size=8)
 
   losses, accs, epoch_times = [], [], []
   for epoch in range(args.epochs):
     t0 = time.perf_counter()
-    for batch in loader:
-      params, opt_state, loss, acc = train_step(params, opt_state,
-                                                batch_dict(batch))
-      losses.append(loss)
-      accs.append(acc)
-    jax.block_until_ready(params)
+    state, loss_e, acc_e = trainer.run_epoch(state)
+    jax.block_until_ready(loss_e)
     epoch_times.append(time.perf_counter() - t0)
+    losses.append(np.asarray(loss_e))
+    accs.append(np.asarray(acc_e))
+  losses, accs = np.concatenate(losses), np.concatenate(accs)
 
   print(json.dumps({
       'first_loss': round(float(losses[0]), 4),

@@ -56,15 +56,33 @@ _DIST_REMOTE_MSG = (
     'by one (docs/failure_model.md).')
 
 
+def refuse_typed(loader, name: str):
+  """For the executors whose epoch loop keeps the homogeneous key stream
+  (one key a batch, where a typed batch takes ``_key_stride``): called
+  first in their ``__init__``."""
+  if getattr(loader.sampler, 'is_hetero', False):
+    from ..sampler.capacity import CapacityPlanError
+    raise CapacityPlanError(
+        name, 'its epoch loop keeps the homogeneous key stream',
+        'typed graphs scan through loader.ScanTrainer')
+
+
 class FusedEpochTrainer:
   """Shared plumbing for the fused epoch executors (OverlappedTrainer,
   scan_epoch.ScanTrainer): scope validation, the device feature/label
   tables, and the pure sample+collate body both trainers trace into
   their programs.
 
-  Requirements: homogeneous graph, fused sampler, device-resident
-  feature/label tables, no edge features (the fused programs keep the
-  reference fast path's scope: supervised node classification).
+  Requirements: fused sampler, device-resident feature/label tables, no
+  edge features (the fused programs keep the reference fast path's
+  scope: supervised node classification). Where the batches come from
+  is read from the loader's sampler: a homogeneous graph gives the
+  fused multi-hop program + ``ops.collate_batch``; a typed graph with
+  seeds of ONE node type gives the
+  typed hop loop (``NeighborSampler._typed_fn``) +
+  ``ops.collate_typed_batch`` over per-type tables, closed by the
+  sampler's plan (dict-form ``frontier_caps``). Everything after the
+  batch — the train step, the epoch loop, hooks, recovery — is shared.
   """
 
   _NAME = 'FusedEpochTrainer'
@@ -72,12 +90,7 @@ class FusedEpochTrainer:
   def __init__(self, loader: NodeLoader, model, tx, num_classes: int,
                seed_labels_only: Optional[bool] = None):
     sampler = loader.sampler
-    if getattr(sampler, 'is_hetero', False):
-      # the LOCAL fused trainer is the homo degenerate by design —
-      # typed datasets ride the dist/remote/tiered scan trainers whose
-      # CapacityPlans close the per-ntype shapes
-      # graftlint: allow[hetero-gate] local trainer is homo by design
-      raise ValueError(f'{self._NAME} is homogeneous-only')
+    typed = bool(getattr(sampler, 'is_hetero', False))
     if not sampler.fused:
       raise ValueError(f'{self._NAME} needs the fused sampler path')
     if sampler.with_edge:
@@ -91,14 +104,18 @@ class FusedEpochTrainer:
     self.num_classes = num_classes
     self._sampler = sampler
     self._batch_size = loader.batch_size
-    fanouts = tuple(sampler.num_neighbors)
-    self._sample_fn = sampler._homo_fn(self._batch_size, fanouts)
     if seed_labels_only is None:
       seed_labels_only = loader.seed_labels_only
     self._label_cap = self._batch_size if seed_labels_only else None
-
-    self._feats, self._id2i = self._resolve_feature_tables(loader)
-    self._labels = loader._label_table()
+    self._input_type = loader.input_type if typed else None
+    if typed:
+      self._init_typed_source(loader)
+    else:
+      fanouts = tuple(sampler.num_neighbors)
+      self._sample_fn = sampler._homo_fn(self._batch_size, fanouts)
+      self._key_stride = 1
+      self._feats, self._id2i = self._resolve_feature_tables(loader)
+    self._labels = loader._label_table(self._input_type)
     if self._labels is None:
       raise ValueError(f'{self._NAME} needs node labels')
 
@@ -120,25 +137,85 @@ class FusedEpochTrainer:
                        'out-of-core TieredFeature')
     return dt
 
+  def _init_typed_source(self, loader):
+    """The typed batch source: seeds of ONE node type (``loader.
+    input_type``), the typed hop loop as one program, and per-type
+    device tables for every node type the plan gives rows to."""
+    from ..sampler.capacity import CapacityPlan
+    t_in = self._input_type
+    if t_in is None:
+      raise ValueError(f'{self._NAME}: a typed graph needs seeds of one '
+                       "node type — pass input_nodes=('<ntype>', ids)")
+    sampler = self._sampler
+    plan = CapacityPlan.from_sampler(sampler, self._batch_size,
+                                     input_type=t_in)
+    self._sample_fn = sampler._typed_fn(self._batch_size, t_in)
+    # one fold_in count per (hop, edge type) touch, as the per-batch
+    # typed loader draws them: step g's touches are count0 + g*stride + j
+    self._key_stride = plan.key_draws_per_batch
+    stores = loader.data.node_features
+    stores = stores if isinstance(stores, dict) else {}
+    feats, id2i = {}, {}
+    for t in plan.feat_types(available=stores):
+      dt = stores[t].device_table()
+      if dt is None:
+        raise ValueError(f'{self._NAME} needs a device-resident feature '
+                         f'table for node type {t!r} (Feature on HBM)')
+      feats[t], id2i[t] = dt
+    if not feats:
+      raise ValueError(f'{self._NAME} needs device-resident feature '
+                       'tables (Feature on HBM)')
+    self._feats, self._id2i = feats, id2i
+
+  def _step_keys(self, base_key, count):
+    """What the traced body samples step ``count`` with: the sampler's
+    own fold_in stream — one key on a homogeneous graph, the [S, 2]
+    per-touch keys ``fold_in(base_key, count + j)`` on a typed one."""
+    import jax
+    import jax.numpy as jnp
+    if self._input_type is None:
+      return jax.random.fold_in(base_key, count)
+    return jax.vmap(lambda j: jax.random.fold_in(base_key, count + j))(
+        jnp.arange(self._key_stride, dtype=jnp.int32))
+
   def _make_sample_collate_body(self):
     """The pure traced sample+collate body. ``feats`` is whatever
     pytree :meth:`_resolve_feature_tables` produced — here a plain
-    [N, F] table fed straight to the fused collate gather."""
+    [N, F] table fed straight to the fused collate gather. On a typed
+    graph ``fargs`` is ``sampler._typed_args()``, ``feats`` / ``id2i``
+    the per-type table dicts, ``labels`` the seed type's table and
+    ``key`` the step's [S, 2] per-touch keys."""
     sample_fn, label_cap = self._sample_fn, self._label_cap
+    t_in = self._input_type
 
     def _sample_collate(fargs, feats, id2i, labels, seeds, smask, key):
-      res = sample_fn(*fargs, seeds, smask, key)
-      col = ops.collate_batch(res['node'], res['num_nodes'], res['row'],
-                              res['col'], feats, id2i, labels, None, None,
-                              label_cap=label_cap)
-      batch = dict(x=col['x'], edge_index=col['edge_index'],
-                   edge_mask=res['edge_mask'], y=col['y'],
-                   num_seed_nodes=res['num_sampled_nodes'][0])
+      if t_in is None:
+        res = sample_fn(*fargs, seeds, smask, key)
+        col = ops.collate_batch(res['node'], res['num_nodes'], res['row'],
+                                res['col'], feats, id2i, labels, None,
+                                None, label_cap=label_cap)
+        x, edge_index, y = col['x'], col['edge_index'], col['y']
+        num_seed_nodes = res['num_sampled_nodes'][0]
+      else:
+        res = sample_fn(fargs, seeds, smask, key)
+        x, edge_index, y = ops.collate_typed_batch(
+            res['node'], res['row'], res['col'], feats, id2i, labels,
+            t_in, label_cap=label_cap)
+        num_seed_nodes = res['num_sampled_nodes'][t_in][0]
+      batch = dict(x=x, edge_index=edge_index, edge_mask=res['edge_mask'],
+                   y=y, num_seed_nodes=num_seed_nodes)
       # the calibrated-caps truncation flag rides OUTSIDE the batch dict
       # (train_step must not see it; the batch buffers are donated)
       return batch, res['overflow']
 
     return _sample_collate
+
+  def _sample_args(self):
+    """The sampler's graph device arrays for the traced body, re-read
+    each epoch (a padded-table reseed must reach the chunks)."""
+    if self._input_type is not None:
+      return self._sampler._typed_args()
+    return self._sampler._fused_args()
 
 
 class OverlappedTrainer(FusedEpochTrainer):
@@ -149,6 +226,7 @@ class OverlappedTrainer(FusedEpochTrainer):
   def __init__(self, loader: NodeLoader, model, tx, num_classes: int,
                seed_labels_only: Optional[bool] = None):
     import jax
+    refuse_typed(loader, self._NAME)
     super().__init__(loader, model, tx, num_classes, seed_labels_only)
 
     _sample_collate = self._sample_collate

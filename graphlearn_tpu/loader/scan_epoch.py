@@ -134,9 +134,12 @@ class ScanTrainer(FusedEpochTrainer):
   """Executes an epoch as ~ceil(steps/K) scanned-chunk dispatches.
 
   Args:
-    loader: a homogeneous NeighborLoader on the fused sampler path with
-      device-resident features and labels (same scope as
-      OverlappedTrainer).
+    loader: a NeighborLoader on the fused sampler path with
+      device-resident features and labels — homogeneous (the scope of
+      OverlappedTrainer), or over a typed graph with seeds of one node
+      type: the chunk then traces the typed hop loop and the per-type
+      collate (pipeline.FusedEpochTrainer), under the per-batch typed
+      loader's own keys, and everything else here is the same code.
     chunk_size: K, the static number of steps per scanned dispatch. The
       tail chunk (steps % K) compiles once more at its own length; pick
       K to divide the epoch when compile count matters.
@@ -240,18 +243,23 @@ class ScanTrainer(FusedEpochTrainer):
     from jax import lax
     sample_collate = self._sample_collate
     train_step = self._train_step   # jit-of-jit: inlined into the scan
+    step_keys, stride = self._step_keys, self._key_stride
 
     def scan_epoch_chunk(state, ovf, fargs, feats, id2i, labels,
                          seed_mat, mask_mat, base_key, count0, start, k):
       seeds_k = lax.dynamic_slice_in_dim(seed_mat, start, k, axis=0)
       masks_k = lax.dynamic_slice_in_dim(mask_mat, start, k, axis=0)
-      # the sampler's fold_in stream: global step g -> count0 + g
-      counts_k = count0 + start + lax.iota(seed_mat.dtype, k)
+      # the sampler's fold_in stream: global step g -> count0 + g (a
+      # typed step draws `stride` counts, one per (hop, edge type))
+      if stride == 1:
+        counts_k = count0 + start + lax.iota(seed_mat.dtype, k)
+      else:
+        counts_k = count0 + (start + lax.iota(seed_mat.dtype, k)) * stride
 
       def body(carry, xs):
         state, ovf = carry
         seeds, smask, count = xs
-        key = jax.random.fold_in(base_key, count)
+        key = step_keys(base_key, count)
         batch, overflow = sample_collate(fargs, feats, id2i, labels,
                                          seeds, smask, key)
         state, loss, acc = train_step(state, batch)
@@ -405,7 +413,7 @@ class ScanTrainer(FusedEpochTrainer):
 
     # graph arrays re-fetched each epoch: the padded-table reseed in
     # _begin_epoch must reach the chunks (lazy rebuild in _fused_args)
-    fargs = self._sampler._fused_args()
+    fargs = self._sample_args()
     base_key = self._sampler._key
     # chunk-position scalars enter as EXPLICIT device_puts: inside the
     # strict_guards region (GLT_STRICT=1: transfer_guard('disallow') +
@@ -464,7 +472,7 @@ class ScanTrainer(FusedEpochTrainer):
         losses, accs = losses[0], accs[0]
     # keep the host fold_in stream aligned with what the device consumed
     # (checkpoint/resume and any later per-step sampling continue it)
-    self._sampler._call_count += steps
+    self._sampler._call_count += steps * self._key_stride
     self._epochs += 1
     return state, losses, accs, ovf
 
@@ -531,7 +539,7 @@ class ScanTrainer(FusedEpochTrainer):
     uninterrupted multi-epoch stream). No stats restore: a finished
     epoch already published its accumulators before the crash."""
     self._sampler.load_state_dict(meta['sampler'])
-    self._sampler._call_count += int(meta['steps'])
+    self._sampler._call_count += int(meta['steps']) * self._key_stride
     self._epochs = int(meta['epoch']) + 1
     pad = meta.get('padded')
     if pad:

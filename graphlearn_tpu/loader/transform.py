@@ -142,4 +142,5 @@ def to_hetero_data(out: HeteroSamplerOutput, node_feats=None,
       edge_mask=out.edge_mask, x=node_feats, y=node_labels,
       edge_ids=out.edge, batch=out.batch, batch_size=out.batch_size,
       num_sampled_nodes=out.num_sampled_nodes,
-      num_sampled_edges=out.num_sampled_edges, metadata=dict(out.metadata))
+      num_sampled_edges=out.num_sampled_edges,
+      metadata=dict(out.metadata, input_type=out.input_type))

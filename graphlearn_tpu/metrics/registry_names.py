@@ -230,10 +230,21 @@ SCOPE_FWD_BWD = 'fwd_bwd'        # inside glt.train: the value_and_grad
 SCOPE_UPDATE = 'update'          # inside glt.train: the optimizer
 
 
-def hop_scope(hop: int, part: str) -> str:
+def hop_scope(hop: int, part: str, etype=None) -> str:
   """Sub-scope of ``glt.sample`` for hop ``hop``: ``part`` is 'draw'
-  (the neighbour draw) or 'induce' (dedup + relabel)."""
-  return f'hop{hop}/{part}'
+  (the neighbour draw), 'induce' (dedup + relabel) or, on a typed graph,
+  'merge' (the per-node-type compaction of the hop's new frontiers).
+  The typed hop loop names each edge type's part of the hop:
+  ``hop<h>/<src>__<rel>__<dst>/draw``."""
+  if etype is None:
+    return f'hop{hop}/{part}'
+  return f'hop{hop}/{"__".join(etype)}/{part}'
+
+
+def collate_scope(ntype: str) -> str:
+  """``glt.collate/<ntype>``: one node type's row gather of a typed
+  batch."""
+  return f'{SCOPE_COLLATE}/{ntype}'
 
 
 # The closed inventory of device scopes, as they read in an op_name
@@ -242,7 +253,11 @@ REGISTERED_SCOPES = frozenset({
     'glt.sample',
     'glt.sample/hop<h>/draw',
     'glt.sample/hop<h>/induce',
+    'glt.sample/hop<h>/<etype>/draw',
+    'glt.sample/hop<h>/<etype>/induce',
+    'glt.sample/hop<h>/merge',
     'glt.collate',
+    'glt.collate/<ntype>',
     'glt.train',
     'glt.train/fwd_bwd',
     'glt.train/update',

@@ -11,6 +11,7 @@ from typing import Any, Dict, Sequence
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from flax.linen.dtypes import promote_dtype
 
 from ..typing import EdgeType, NodeType
 from .conv import GATConv, GCNConv, SAGEConv
@@ -133,6 +134,48 @@ def _masked_run_softmax(e, mask, out_dtype, negative_slope):
   ex = jnp.where(mask[..., None], jnp.exp(e), 0.0)
   denom = jnp.maximum(ex.sum(axis=1, keepdims=True), 1e-9)
   return (ex / denom).astype(out_dtype)
+
+
+# One record of a typed merge batch can hold a million edge slots; its
+# gathered [slots, H*D] messages, alive until the backward pass, are what
+# kept the IGBH batch off the chip (PERF.md section 6, PR 30). Above this
+# many slots a record's runs go block by block, each block rematerialised
+# in the backward pass, so only one block's messages are ever alive.
+_RUN_BLOCK_SLOTS = 1 << 16
+
+
+def _gat_runs(w_res, a_src_res, a_par, m, src, heads, hd, negative_slope):
+  """Attention-weighted sum over each k-run: children gathered through
+  ``src`` from the projected rows ``w_res`` [n, H*D] and their alphas
+  ``a_src_res`` [n, H], parents' alphas ``a_par`` [f, H], mask ``m``
+  [f, k] -> [f, H*D]."""
+  f, k = m.shape
+  wch = w_res[src]
+  e = a_src_res[src].reshape(f, k, heads) + a_par[:, None, :]
+  attn = _masked_run_softmax(e, m, wch.dtype, negative_slope)
+  msgs = wch.reshape(f, k, heads, hd)
+  return (msgs * attn[..., None]).sum(axis=1).reshape(f, heads * hd)
+
+
+def _gat_runs_blocked(w_res, a_src_res, a_par, m, src, heads, hd,
+                      negative_slope):
+  """``_gat_runs`` over blocks of runs of at most _RUN_BLOCK_SLOTS edge
+  slots (runs are independent, so the values are the same); padding runs
+  are masked and dropped."""
+  f, k = m.shape
+  nb = -(-f * k // _RUN_BLOCK_SLOTS)
+  if nb == 1:
+    return _gat_runs(w_res, a_src_res, a_par, m, src, heads, hd,
+                     negative_slope)
+  fb = -(-f // nb)
+  pad = nb * fb - f
+  blocks = (jnp.pad(a_par, ((0, pad), (0, 0))).reshape(nb, fb, heads),
+            jnp.pad(m, ((0, pad), (0, 0))).reshape(nb, fb, k),
+            jnp.pad(src, (0, pad * k)).reshape(nb, fb * k))
+  body = jax.checkpoint(
+      lambda w, a, blk: _gat_runs(w, a, *blk, heads, hd, negative_slope))
+  vals = jax.lax.map(lambda blk: body(w_res, a_src_res, blk), blocks)
+  return vals.reshape(nb * fb, heads * hd)[:f]
 
 
 def _masked_run_mean(vals, mask):
@@ -781,6 +824,18 @@ def resolve_hetero_parts(parts, feat_shape, dtype):
   return jnp.concatenate(parts) if len(parts) > 1 else parts[0]
 
 
+class _DenseKernel(nn.Module):
+  """The kernel of ``nn.Dense(features, use_bias=False)`` — same name,
+  shape and init — handed out as an array, for a projection that has to
+  sit inside ``jax.checkpoint`` (no module may be built in there)."""
+  features: int
+
+  @nn.compact
+  def __call__(self, in_dim):
+    return self.param('kernel', nn.initializers.lecun_normal(),
+                      (in_dim, self.features))
+
+
 class TreeHeteroConv(nn.Module):
   """One hetero layer over TYPED tree batches with dense k-run
   aggregation — the typed counterpart of TreeSAGEConv/TreeGATConv.
@@ -907,26 +962,38 @@ class TreeHeteroConv(nn.Module):
       return None
     key_t, res_ts = recs[0]['key_t'], {r['res_t'] for r in recs}
     heads, hd = self.heads, self.out_dim
-    w, alpha_src, alpha_dst_key = self._gat_setup(ename, key_t, res_ts,
-                                                  x_dict)
     n_out = rows[key_t]
-    acc = jnp.zeros((n_out, heads * hd), w[key_t].dtype)
-    for r in recs:
-      if r['parent_base'] >= n_out:
-        break
-      f, k = r['fcap'], r['k']
-      m, src, base, ok = self._run_layout(r, edge_mask_dict,
-                                          edge_index_dict, n_out)
-      wch = w[r['res_t']][src]
-      a_ch = alpha_src[r['res_t']][src]
-      # parents are arithmetic from the dynamic base (compacted
-      # frontier), so one dynamic slice reads the run alphas
-      a_par = jax.lax.dynamic_slice_in_dim(alpha_dst_key, base, f)
-      e = a_ch.reshape(f, k, heads) + a_par[:, None, :]
-      attn = _masked_run_softmax(e, m, wch.dtype, self.negative_slope)
-      msgs = wch.reshape(f, k, heads, hd)
-      vals = (msgs * attn[..., None]).sum(axis=1).reshape(f, heads * hd)
-      acc = self._acc_add(acc, jnp.where(ok[:, None], vals, 0), base)
+    recs = [r for r in recs if r['parent_base'] < n_out]
+    kernel, a_src, a_dst = self._gat_params(ename,
+                                            x_dict[key_t].shape[-1])
+    layouts = [self._run_layout(r, edge_mask_dict, edge_index_dict, n_out)
+               for r in recs]
+
+    def relation(kernel, a_src, a_dst, xs, layouts):
+      # targets live in the output prefix, so the dst side projects only
+      # those rows (the whole typed buffer is 3-6x the prefix)
+      w, alpha_src, alpha_dst_key = self._gat_project(
+          kernel, a_src, a_dst, key_t, res_ts, xs, n_key=n_out)
+      acc = jnp.zeros((n_out, heads * hd), w[recs[0]['res_t']].dtype)
+      for r, (m, src, base, ok) in zip(recs, layouts):
+        # parents are arithmetic from the dynamic base (compacted
+        # frontier), so one dynamic slice reads the run alphas
+        a_par = jax.lax.dynamic_slice_in_dim(alpha_dst_key, base,
+                                             r['fcap'])
+        vals = _gat_runs_blocked(w[r['res_t']], alpha_src[r['res_t']],
+                                 a_par, m, src, heads, hd,
+                                 self.negative_slope)
+        acc = self._acc_add(acc, jnp.where(ok[:, None], vals, 0), base)
+      return acc
+
+    if any(r['fcap'] * r['k'] > _RUN_BLOCK_SLOTS for r in recs):
+      # a relation wide enough to go block by block also projects its
+      # rows again in the backward pass: its [rows, H*D] projections are
+      # the other tensors that outlive the layer (PERF.md section 6)
+      relation = jax.checkpoint(relation)
+    acc = relation(kernel, a_src, a_dst,
+                   {t: x_dict[t] for t in sorted(res_ts | {key_t})},
+                   layouts)
     if not self.concat:
       acc = acc.reshape(n_out, heads, hd).mean(axis=1)
     return key_t, acc
@@ -957,29 +1024,44 @@ class TreeHeteroConv(nn.Module):
                                dtype=self.dtype,
                                name=f'lin_nbr_{ename}')(agg)
 
-  def _gat_setup(self, ename, key_t, res_ts, x_dict):
-    """Shared GAT preamble: per-etype attention params, ONE projection
-    per participating type (flat rows: PERF.md layout rule), and
-    SEPARATE src-/dst-alpha maps — a self-relation (e.g.
-    paper-cites-paper) needs BOTH for the same type: children read
-    a_src, parents read a_dst. Tree and merge paths must share this
-    exactly or the segment-equivalence guarantee diverges."""
+  def _gat_params(self, ename, in_dim):
+    """One relation's attention vectors and projection kernel, as
+    arrays (the names and shapes GATConv's ``att_src`` / ``att_dst`` /
+    ``lin`` have under HeteroConv)."""
     heads, hd = self.heads, self.out_dim
     a_src = self.param(f'att_src_{ename}',
                        nn.initializers.glorot_uniform(), (heads, hd))
     a_dst = self.param(f'att_dst_{ename}',
                        nn.initializers.glorot_uniform(), (heads, hd))
-    lin = nn.Dense(heads * hd, use_bias=False, dtype=self.dtype,
-                   name=f'lin_{ename}')
-    w = {t: lin(x_dict[t]) for t in res_ts | {key_t}}
-    alpha_src = {t: jnp.einsum('nhd,hd->nh',
-                               w[t].reshape(-1, heads, hd), a_src,
-                               preferred_element_type=jnp.float32)
-                 for t in res_ts}
-    alpha_dst_key = jnp.einsum('nhd,hd->nh',
-                               w[key_t].reshape(-1, heads, hd), a_dst,
-                               preferred_element_type=jnp.float32)
-    return w, alpha_src, alpha_dst_key
+    kernel = _DenseKernel(heads * hd, name=f'lin_{ename}')(in_dim)
+    return kernel, a_src, a_dst
+
+  def _gat_project(self, kernel, a_src, a_dst, key_t, res_ts, x_dict,
+                   n_key=None):
+    """Shared GAT preamble: ONE projection per participating type (flat
+    rows: PERF.md layout rule), and SEPARATE src-/dst-alpha maps — a
+    self-relation (e.g. paper-cites-paper) needs BOTH for the same
+    type: children read a_src, parents read a_dst. Tree and merge paths
+    must share this exactly or the segment-equivalence guarantee
+    diverges. ``n_key``: the dst side needs its alphas on the first
+    ``n_key`` rows only, so a key type that is no source here is
+    projected on that prefix. Pure in its arrays, so a caller may put
+    it under ``jax.checkpoint``."""
+    heads, hd = self.heads, self.out_dim
+
+    def lin(x):       # nn.Dense(use_bias=False, dtype=self.dtype)
+      x, k = promote_dtype(x, kernel, dtype=self.dtype)
+      return jax.lax.dot_general(x, k, (((x.ndim - 1,), (0,)), ((), ())))
+
+    alpha = lambda wt, a: jnp.einsum(
+        'nhd,hd->nh', wt.reshape(-1, heads, hd), a,
+        preferred_element_type=jnp.float32)
+    # sorted: the order of a set of strings differs between processes,
+    # and with it the traced program and its compile-cache key
+    w = {t: lin(x_dict[t]) for t in sorted(res_ts)}
+    alpha_src = {t: alpha(w[t], a_src) for t in sorted(res_ts)}
+    w_key = w[key_t] if key_t in w else lin(x_dict[key_t][:n_key])
+    return w, alpha_src, alpha(w_key[:n_key], a_dst)
 
   def _sage_et(self, et, x_dict, edge_mask_dict, rows):
     ename = '__'.join(et)
@@ -1004,8 +1086,9 @@ class TreeHeteroConv(nn.Module):
       return None
     key_t, res_ts = recs[0]['key_t'], {r['res_t'] for r in recs}
     heads, hd = self.heads, self.out_dim
-    w, alpha_src, alpha_dst_key = self._gat_setup(ename, key_t, res_ts,
-                                                  x_dict)
+    w, alpha_src, alpha_dst_key = self._gat_project(
+        *self._gat_params(ename, x_dict[key_t].shape[-1]), key_t, res_ts,
+        x_dict)
 
     def per_record(r, m):
       f, k = r['fcap'], r['k']
@@ -1021,7 +1104,7 @@ class TreeHeteroConv(nn.Module):
       return (msgs * attn[..., None]).sum(axis=1).reshape(f, heads * hd)
 
     parts, key_t = self._walk(recs, edge_mask_dict, rows, per_record)
-    outv = self._resolve(parts, heads * hd, w[key_t].dtype)
+    outv = self._resolve(parts, heads * hd, w[recs[0]['res_t']].dtype)
     if not self.concat:
       outv = outv.reshape(rows[key_t], heads, hd).mean(axis=1)
     return key_t, outv

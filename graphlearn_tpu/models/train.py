@@ -223,7 +223,14 @@ def link_batch_to_dict(batch):
 
 
 def batch_to_dict(batch):
-  """`loader.Data` -> the flat dict the jitted step consumes."""
+  """`loader.Data` -> the flat dict the jitted step consumes. A typed
+  batch (`loader.HeteroData` of a node loader) keeps its per-type dicts
+  and supervises the seed type: its labels, its seed count."""
+  if isinstance(batch.x, dict):
+    t = batch.metadata['input_type']
+    return dict(x=batch.x, edge_index=batch.edge_index,
+                edge_mask=batch.edge_mask, y=batch.y[t],
+                num_seed_nodes=batch.num_sampled_nodes[t][0])
   num_seed = (batch.num_sampled_nodes[0]
               if batch.num_sampled_nodes is not None else batch.batch_size)
   return dict(x=batch.x, edge_index=batch.edge_index,

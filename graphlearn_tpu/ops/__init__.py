@@ -1,5 +1,5 @@
-from .collate import (collate_batch, gather_rows, stack2, stack2_batched,
-                      valid_mask)
+from .collate import (collate_batch, collate_typed_batch, gather_rows,
+                      stack2, stack2_batched, valid_mask)
 from .gather_pallas import (decode_gather_plan, gather_rows_hbm,
                             gather_rows_hbm2, plan_gather_runs)
 from .induce import InducerState, induce_next, init_empty, init_node

@@ -13,7 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..metrics.registry_names import SCOPE_COLLATE
+from ..metrics.registry_names import SCOPE_COLLATE, collate_scope
 
 
 @functools.partial(jax.jit, static_argnames=('label_cap',))
@@ -83,3 +83,28 @@ def gather_rows(table, id2index, ids):
   if id2index is not None:
     safe = id2index[safe]
   return table[safe]
+
+
+def collate_typed_batch(node, row, col, feats, id2index, labels,
+                        input_type, label_cap=None):
+  """The typed counterpart of :func:`collate_batch`, as a traced body
+  (the scanned chunk's; not jitted on its own): per node type the row
+  gather from that type's device table under ``glt.collate/<ntype>``,
+  the seed type's labels and the per-edge-type ``edge_index`` under
+  ``glt.collate``. The same clamped gathers as the per-batch typed
+  loader's (:func:`gather_rows`), so the batches are the same bits.
+
+  ``node`` / ``row`` / ``col``: the typed sampler's dicts; ``feats`` /
+  ``id2index``: ``{ntype: table}`` for the types that carry rows
+  (``id2index[t]`` may be None); ``labels``: the seed type's table.
+  Returns ``(x, edge_index, y)``."""
+  x = {}
+  for t, table in feats.items():
+    with jax.named_scope(collate_scope(t)):
+      x[t] = gather_rows(table, id2index[t], node[t])
+  with jax.named_scope(SCOPE_COLLATE):
+    ids = node[input_type]
+    y = gather_rows(labels, None,
+                    ids if label_cap is None else ids[:label_cap])
+    edge_index = {et: jnp.stack([r, col[et]]) for et, r in row.items()}
+  return x, edge_index, y

@@ -191,9 +191,11 @@ def hetero_capacity_plan(etypes, fanouts_of, seed_caps, edge_dir,
   # silently mis-base intra-hop child blocks)
   etypes = sorted(tuple(et) for et in etypes)
   num_hops = max(len(fanouts_of(et)) for et in etypes)
-  ntypes = set()
-  for (u, _, v) in etypes:
-    ntypes.update((u, v))
+  # sorted, not a set: the engines emit ops in this order, and a set of
+  # strings iterates differently in every process (hash randomisation),
+  # which made every process trace a different program — no compile-cache
+  # hit for a typed job, ever (PERF.md section 6, PR 30)
+  ntypes = sorted({t for (u, _, v) in etypes for t in (u, v)})
   frontier_cap = {t: seed_caps.get(t, 0) for t in ntypes}
   node_caps = dict(frontier_cap)
   hop_caps = []
@@ -692,26 +694,10 @@ class NeighborSampler(BaseSampler):
                      etype: Optional[EdgeType] = None) -> NeighborOutput:
     """One fanout hop; [B] seeds -> dense [B, K] + mask
     (reference: neighbor_sampler.py:128-166)."""
-    g = self._get_graph(etype)
     if key is None:
       key = self._next_key()
-    if self.with_weight and g.edge_weights is not None:
-      nbrs, epos, mask = ops.weighted_sample(
-          g.indptr, g.indices, self._cumsum_for(etype), srcs, src_mask, k,
-          key)
-    elif self.strategy == 'block':
-      blocks, meta = self._block_arrays(etype)
-      nbrs, epos, mask = ops.uniform_sample_block(
-          meta, blocks, int(g.indices.shape[0]), srcs, src_mask, k, key)
-    else:
-      nbrs, epos, mask = ops.uniform_sample(g.indptr, g.indices, srcs,
-                                            src_mask, k, key)
-    edges = None
-    if self.with_edge:
-      import jax.numpy as jnp
-      eids = g.edge_ids
-      edges = (jnp.where(mask, eids[epos], -1) if eids is not None
-               else jnp.where(mask, epos, -1))
+    nbrs, edges, mask = self._draw(self._draw_args(etype), srcs, src_mask,
+                                   k, key)
     return NeighborOutput(nbrs=nbrs, mask=mask, edges=edges)
 
   # -------------------------------------------------------------- homo path
@@ -1035,19 +1021,14 @@ class NeighborSampler(BaseSampler):
     nn = self.num_neighbors
     return list(nn[etype]) if isinstance(nn, dict) else list(nn)
 
-  @jax.named_scope(SCOPE_SAMPLE)
   def _hetero_sample_from_nodes(self, inputs: NodeSamplerInput,
                                 batch_cap: Optional[int] = None):
-    """Per-etype hop loop with per-node-type inducers
-    (reference: neighbor_sampler.py:214-299).
-
-    edge_dir='out': etype (u, r, v) stores u's out-edges (CSR by src);
-      sampling expands u-frontier to v neighbors; emitted under
-      reverse_edge_type (v, rev_r, u) so row=v (source), col=u (target).
-    edge_dir='in': etype stores CSC by dst; expands v-frontier to u
-      in-neighbors; emitted under the original etype, row=u, col=v.
-    """
-    import jax
+    """The per-batch (eager) call of the typed hop loop
+    (:meth:`_typed_hops`): seeds padded on the host, one key per
+    (hop, edge type) touch drawn from the sampler's fold_in counter in
+    the loop's own order, every op dispatched as it is reached
+    (reference: neighbor_sampler.py:214-299). The scanned epoch traces
+    the SAME loop with the same keys (:meth:`_typed_fn`)."""
     import jax.numpy as jnp
     if isinstance(inputs, dict):
       # multi-type seeds (link sampling): {ntype: seed array}
@@ -1068,54 +1049,144 @@ class NeighborSampler(BaseSampler):
       buf[:n_t] = s
       padded_d[t] = buf
       smask_d[t] = np.arange(c) < n_t
-    n = seeds_dict[ntype].shape[0]
-    cap = caps_in[ntype]
-    padded, smask = padded_d[ntype], smask_d[ntype]
+    plan = self._typed_plan(caps_in)
+    # one key per (hop, edge type) touch, in the loop's own order
+    # (CapacityPlan.key_draws_per_batch: the scanned stream's stride)
+    keys = [self._next_key() for per_et in plan[1] for _ in per_et]
+    res = self._typed_hops(
+        self._typed_args(), {t: jnp.asarray(v) for t, v in padded_d.items()},
+        {t: jnp.asarray(v) for t, v in smask_d.items()}, keys, plan)
+    return HeteroSamplerOutput(
+        node=res['node'], num_nodes=res['num_nodes'], row=res['row'],
+        col=res['col'], edge=res['edge'], edge_mask=res['edge_mask'],
+        batch={t: jnp.asarray(padded_d[t]) for t in seeds_dict},
+        batch_size=seeds_dict[ntype].shape[0],
+        num_sampled_nodes=res['num_sampled_nodes'],
+        num_sampled_edges=res['num_sampled_edges'], input_type=ntype,
+        metadata={'seed_inverse': res['seed_inverse'][ntype],
+                  'seed_inverse_dict': res['seed_inverse'],
+                  'seed_mask': smask_d[ntype],
+                  'overflow': res['overflow']})
 
-    etypes = list(self.graph.keys())
+  def _typed_plan(self, seed_caps):
+    """``(ntypes, hop_caps, node_caps)`` of this sampler for the given
+    seed widths — shared with hetero_tree_layout so the hierarchical
+    model forward can never disagree with the engine's layout.
+    Calibrated per-(hop, etype) caps (dict-form frontier_caps) clamp it."""
+    return hetero_capacity_plan(list(self.graph.keys()),
+                                self._etype_fanouts, dict(seed_caps),
+                                self.edge_dir,
+                                etype_caps=self.frontier_caps)
 
-    # Static per-hop/per-ntype buffer plan — shared with
-    # hetero_tree_layout so the hierarchical model forward can never
-    # disagree with the engine's positional layout. Calibrated
-    # per-(hop, etype) caps (dict-form frontier_caps) clamp the plan;
-    # 'clamped' gates the max_new threading + overflow flag below.
-    clamped = self.frontier_caps is not None
-    ntypes, hop_caps, node_caps = hetero_capacity_plan(
-        etypes, self._etype_fanouts, caps_in, self.edge_dir,
-        etype_caps=self.frontier_caps if clamped else None)
+  def _typed_args(self):
+    """``{edge type: device arrays}`` passed (never captured) into the
+    typed hop loop: the CSR, and what the strategy draws from — the
+    row CDF (weighted), the aligned blocks + metadata (block), else the
+    packed [N, 2] (start, degree) row table of ``ops.uniform_sample``."""
+    return {et: self._draw_args(et) for et in self.graph}
+
+  def _draw_args(self, etype=None):
+    """One graph's device arrays for :meth:`_draw`."""
+    import jax.numpy as jnp
+    g, ga = self._get_graph(etype), self._graph_arrays(etype)
+    d = dict(indptr=ga['indptr'], indices=ga['indices'])
+    if self.with_edge and ga['eids'] is not None:
+      d['eids'] = ga['eids']
+    if self.with_weight and g.edge_weights is not None:
+      d['cum'] = jnp.asarray(self._cumsum_for(etype))
+    elif self.strategy == 'block':
+      d['blocks'], d['meta'] = self._block_arrays(etype)
+    else:
+      d['meta'] = self._csr_meta(etype)
+    return d
+
+  def _typed_fn(self, batch_cap: int, input_type: NodeType):
+    """The typed hop loop as ONE jitted program for single-type seeds of
+    width ``batch_cap``: ``fn(gargs, seeds, seed_mask, keys) -> dict``
+    with ``gargs`` from :meth:`_typed_args` and ``keys`` the [S, 2]
+    per-touch keys (``S`` = ``CapacityPlan.key_draws_per_batch``). What
+    the scanned epoch traces into its chunk (loader/pipeline.py)."""
+    sig = ('typed', batch_cap, input_type)
+    if sig not in self._fns:
+      from ..metrics import programs
+      plan = self._typed_plan({input_type: batch_cap})
+
+      def sample_typed(gargs, seeds, seed_mask, keys):
+        return self._typed_hops(gargs, {input_type: seeds},
+                                {input_type: seed_mask}, keys, plan)
+
+      self._fns[sig] = programs.instrument(jax.jit(sample_typed), 'sample')
+    return self._fns[sig]
+
+  def _draw(self, ga, f, fmask, k, key):
+    """One graph's draw for a frontier by the sampler's strategy:
+    ``(nbrs, edge ids or None, mask)`` from :meth:`_draw_args`' arrays
+    (the one dispatch under ``sample_one_hop`` and the typed hop loop)."""
+    import jax.numpy as jnp
+    if 'cum' in ga:
+      nbrs, epos, m = ops.weighted_sample(ga['indptr'], ga['indices'],
+                                          ga['cum'], f, fmask, k, key)
+    elif 'blocks' in ga:
+      nbrs, epos, m = ops.uniform_sample_block(
+          ga['meta'], ga['blocks'], int(ga['indices'].shape[0]), f, fmask,
+          k, key)
+    else:
+      nbrs, epos, m = ops.uniform_sample(ga['indptr'], ga['indices'], f,
+                                         fmask, k, key, meta=ga['meta'])
+    edges = None
+    if self.with_edge:
+      edges = jnp.where(m, ga['eids'][epos] if 'eids' in ga else epos, -1)
+    return nbrs, edges, m
+
+  @jax.named_scope(SCOPE_SAMPLE)
+  def _typed_hops(self, gargs, seeds_d, smask_d, keys, plan):
+    """THE typed hop loop: per-etype draws with per-node-type inducers,
+    written once and called two ways — eagerly per batch
+    (:meth:`_hetero_sample_from_nodes`) and traced into the scanned
+    chunk (:meth:`_typed_fn`). Every array it reads is an argument and
+    every key is ``keys[touch]`` (touches in hop-major, ``hop_caps``
+    order), so the two calls sample the same subgraph under the same
+    keys.
+
+    edge_dir='out': etype (u, r, v) stores u's out-edges (CSR by src);
+      sampling expands u-frontier to v neighbors; emitted under
+      reverse_edge_type (v, rev_r, u) so row=v (source), col=u (target).
+    edge_dir='in': etype stores CSC by dst; expands v-frontier to u
+      in-neighbors; emitted under the original etype, row=u, col=v.
+    """
+    import jax.numpy as jnp
+    ntypes, hop_caps, node_caps = plan
     num_hops = len(hop_caps)
-
-    states = {}
-    frontier = {}
+    # 'clamped' gates the max_new threading + overflow flag below
+    clamped = self.frontier_caps is not None
     with_edge = self.with_edge
-    rows: Dict[EdgeType, list] = {}
-    cols: Dict[EdgeType, list] = {}
-    edges: Dict[EdgeType, list] = {}
-    emasks: Dict[EdgeType, list] = {}
-    nodes_per_hop: Dict[NodeType, list] = {t: [] for t in ntypes}
-    edges_per_hop: Dict[EdgeType, list] = {}
-
     mode = self._dedup_mode()
     if mode == 'map_table':
       raise ValueError("dedup='map_table' is homogeneous-only (no lazy "
                        "empty inducer state); use 'map'/'sort'/'merge' "
                        'or tree for hetero graphs')
     init_seed, init_empty, induce = _inducer_for(mode)
+    states, frontier, inv_d = {}, {}, {}
+    rows: Dict[EdgeType, list] = {}
+    cols: Dict[EdgeType, list] = {}
+    edges: Dict[EdgeType, list] = {}
+    emasks: Dict[EdgeType, list] = {}
+    nodes_per_hop: Dict[NodeType, list] = {t: [] for t in ntypes}
+    edges_per_hop: Dict[EdgeType, list] = {}
+    caps_in = {t: int(s.shape[0]) for t, s in seeds_d.items()}
     offsets = {t: caps_in.get(t, 0) for t in ntypes}  # positional layout
-    inv_d = {}
-    for t in seeds_dict:
-      st, uniq, umask, inv_t = init_seed(
-          jnp.asarray(padded_d[t]), jnp.asarray(smask_d[t]),
-          capacity=node_caps[t])
+    for t in seeds_d:
+      st, uniq, umask, inv_t = init_seed(seeds_d[t], smask_d[t],
+                                         capacity=node_caps[t])
       states[t] = st
       frontier[t] = (uniq, jnp.arange(caps_in[t], dtype=jnp.int32), umask)
       inv_d[t] = inv_t
-    inv = inv_d[ntype]
     for t in ntypes:
       nodes_per_hop[t].append(states[t].num_nodes if t in states
                               else jnp.asarray(0, jnp.int32))
 
     overflow = jnp.zeros((), bool)
+    touch = 0
     for hop in range(num_hops):
       new_parts: Dict[NodeType, list] = {t: [] for t in ntypes}
       items = list(hop_caps[hop].items())
@@ -1127,13 +1198,16 @@ class NeighborSampler(BaseSampler):
         out_et = reverse_edge_type(et) if self.edge_dir == 'out' else et
         f, fidx, fmask = frontier[key_t]
         f, fidx, fmask = f[:fcap], fidx[:fcap], fmask[:fcap]
-        hop_out = self.sample_one_hop(f, fmask, k, etype=et)
+        with jax.named_scope(hop_scope(hop, 'draw', et)):
+          nbrs, eids, m = self._draw(gargs[et], f, fmask, k, keys[touch])
+        touch += 1
         if res_t not in states:
           states[res_t] = init_empty(node_caps[res_t])
-        states[res_t], iout = induce(states[res_t], fidx, hop_out.nbrs,
-                                     hop_out.mask, offsets[res_t],
-                                     final=last_touch.get(res_t) == j,
-                                     max_new=ecap if clamped else None)
+        with jax.named_scope(hop_scope(hop, 'induce', et)):
+          states[res_t], iout = induce(states[res_t], fidx, nbrs, m,
+                                       offsets[res_t],
+                                       final=last_touch.get(res_t) == j,
+                                       max_new=ecap if clamped else None)
         # occupancy bound advances by the CLAMPED contribution (== the
         # full fcap*k width on unclamped plans)
         offsets[res_t] += ecap
@@ -1141,9 +1215,7 @@ class NeighborSampler(BaseSampler):
         cols.setdefault(out_et, []).append(iout['rows'])
         emasks.setdefault(out_et, []).append(iout['edge_mask'])
         if with_edge:
-          edges.setdefault(out_et, []).append(
-              hop_out.edges.reshape(-1) if hop_out.edges is not None
-              else jnp.full_like(iout['rows'], -1))
+          edges.setdefault(out_et, []).append(eids.reshape(-1))
         edges_per_hop.setdefault(out_et, []).append(
             iout['edge_mask'].sum())
         if clamped and ecap < fcap * k:
@@ -1153,31 +1225,33 @@ class NeighborSampler(BaseSampler):
                                  iout['frontier_mask'][:ecap]))
       # Merge per-type new frontiers; each part is compact (valid
       # leading, merge engine contract).
-      for t in ntypes:
-        parts = new_parts[t]
-        if not parts:
-          frontier[t] = (jnp.zeros((0,), jnp.int32),
-                         jnp.zeros((0,), jnp.int32), jnp.zeros((0,), bool))
-          nodes_per_hop[t].append(jnp.asarray(0, jnp.int32))
-          continue
-        fr = jnp.concatenate([p[0] for p in parts])
-        fi = jnp.concatenate([p[1] for p in parts])
-        fm = jnp.concatenate([p[2] for p in parts])
-        if mode == 'merge' and len(parts) > 1:
-          # cross-part compaction: each part may end in invalid slots;
-          # a stable valid-first sort restores the arithmetic
-          # frontier_idx prefix the dense (k-run) hetero aggregation
-          # relies on (models.TreeHeteroConv mode='merge' computes run
-          # bases as min(tgt - j)). Unconditional for merge batches so
-          # merge_dense is safe with or without calibrated caps. Tiny
-          # sort (frontier width); the valid fi of consecutive parts
-          # are consecutive appends.
-          order = jnp.argsort(~fm, stable=True)
-          fr, fi, fm = fr[order], fi[order], fm[order]
-        frontier[t] = (fr, fi, fm)
-        nodes_per_hop[t].append(fm.sum().astype(jnp.int32))
+      with jax.named_scope(hop_scope(hop, 'merge')):
+        for t in ntypes:
+          parts = new_parts[t]
+          if not parts:
+            frontier[t] = (jnp.zeros((0,), jnp.int32),
+                           jnp.zeros((0,), jnp.int32),
+                           jnp.zeros((0,), bool))
+            nodes_per_hop[t].append(jnp.asarray(0, jnp.int32))
+            continue
+          fr = jnp.concatenate([p[0] for p in parts])
+          fi = jnp.concatenate([p[1] for p in parts])
+          fm = jnp.concatenate([p[2] for p in parts])
+          if mode == 'merge' and len(parts) > 1:
+            # cross-part compaction: each part may end in invalid slots;
+            # a stable valid-first sort restores the arithmetic
+            # frontier_idx prefix the dense (k-run) hetero aggregation
+            # relies on (models.TreeHeteroConv mode='merge' computes run
+            # bases as min(tgt - j)). Unconditional for merge batches so
+            # merge_dense is safe with or without calibrated caps. Tiny
+            # sort (frontier width); the valid fi of consecutive parts
+            # are consecutive appends.
+            order = jnp.argsort(~fm, stable=True)
+            fr, fi, fm = fr[order], fi[order], fm[order]
+          frontier[t] = (fr, fi, fm)
+          nodes_per_hop[t].append(fm.sum().astype(jnp.int32))
 
-    out = HeteroSamplerOutput(
+    return dict(
         node={t: s.nodes for t, s in states.items()},
         num_nodes={t: s.num_nodes for t, s in states.items()},
         row={et: jnp.concatenate(v) for et, v in rows.items()},
@@ -1185,13 +1259,8 @@ class NeighborSampler(BaseSampler):
         edge=({et: jnp.concatenate(v) for et, v in edges.items()}
               if with_edge else None),
         edge_mask={et: jnp.concatenate(v) for et, v in emasks.items()},
-        batch={t: jnp.asarray(padded_d[t]) for t in seeds_dict},
-        batch_size=n,
         num_sampled_nodes=nodes_per_hop, num_sampled_edges=edges_per_hop,
-        input_type=ntype,
-        metadata={'seed_inverse': inv, 'seed_inverse_dict': inv_d,
-                  'seed_mask': smask, 'overflow': overflow})
-    return out
+        seed_inverse=inv_d, overflow=overflow)
 
   # ------------------------------------------------------------- link path
 

@@ -1,0 +1,216 @@
+"""Compile the typed cell's two big programs for a v5e that is described,
+not attached, and print what the chip's compiler plans for each
+(``memory_analysis()``) — a builder's rehearsal (on-chip-measurement guide,
+section 2.3), no chip minute spent, no time or rate comes out of it:
+
+    TPU_ACCELERATOR_TYPE=v5litepod-4 TPU_WORKER_HOSTNAMES=localhost \
+    TPU_SKIP_MDS_QUERY=true JAX_PLATFORMS=cpu \
+    python scripts/lower_typed_cell.py [reference] [chunk]
+
+``reference``: ``perfbench/reference_hetero_node.py``'s step at the shapes of
+``rgat-igbh-small.typed-scan-exact`` (it runs on an emptied device: arguments
++ temporaries under 9 GB). ``chunk``: ``ScanTrainer``'s
+``jit_scan_epoch_chunk`` over the typed loader with every table an argument
+at the cell's size (``peak`` under 15.75 GiB, the tables counted once; the
+compile itself refuses a program that does not fit).
+
+The batch's static shapes come from the cell's calibrated caps, which need
+the dataset: ``CAPS`` are the ones a chip run of the cell printed on its
+set-up line (PERF.md section 4), ``VALID`` that run's ``typed_counts``.
+"""
+import json
+import math
+import os
+import sys
+
+os.environ.setdefault('TPU_LOG_DIR', 'disabled')
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+CELL = 'rgat-igbh-small.typed-scan-exact'
+CAPS = {
+    'author__affiliated_to__institute': [128, 1920, 7040],
+    'author__rev_written_by__paper': [128, 18304, 69120],
+    'fos__rev_topic__paper': [128, 35712, 93056],
+    'institute__rev_affiliated_to__author': [128, 128, 8704],
+    'paper__cites__paper': [7296, 36480, 158848],
+    'paper__topic__fos': [4224, 24704, 89600],
+    'paper__written_by__author': [3456, 26240, 179968]}
+# valid rows per type and edges per relation of one 512-seed batch (means
+# over a replayed chunk), summed over hops
+VALID = dict(
+    rows={'author': 144812, 'fos': 78678, 'institute': 5752,
+          'paper': 279114},
+    edges={'author__affiliated_to__institute': 16514,
+           'author__rev_written_by__paper': 80303,
+           'fos__rev_topic__paper': 108565,
+           'institute__rev_affiliated_to__author': 5865,
+           'paper__cites__paper': 352744,
+           'paper__topic__fos': 327255,
+           'paper__written_by__author': 266181})
+
+
+def describe():
+  from jax.experimental import topologies
+  from jax.sharding import SingleDeviceSharding
+  topo = topologies.get_topology_desc(platform='tpu',
+                                      topology_name='v5e:2x2')
+  return SingleDeviceSharding(topo.devices[0])
+
+
+def report(name, compiled):
+  ma = compiled.memory_analysis()
+  out = dict(program=name, argument=ma.argument_size_in_bytes,
+             output=ma.output_size_in_bytes, temp=ma.temp_size_in_bytes,
+             alias=ma.alias_size_in_bytes,
+             generated_code=ma.generated_code_size_in_bytes,
+             peak=getattr(ma, 'peak_memory_in_bytes', None))
+  # `temp` adds up the temporaries of inner loops that are never alive
+  # together; `peak` is what the program needs at once, arguments included
+  out['argument_plus_temp_gb'] = (out['argument'] + out['temp']) / 1e9
+  print('lower_typed_cell: ' + json.dumps(out), flush=True)
+  return out
+
+
+def model_desc(cfg):
+  """The cell's model description from CAPS alone (``hetero_node.Cell``
+  builds the same from the calibrated dataset)."""
+  from perfbench.datagen_hetero_node import etype_of
+  from perfbench.families.hetero_node import layer_bounds, name_of
+  d, m = cfg['dataset'], cfg['model']
+  stored = []
+  for name, rel in d['relations'].items():
+    stored.append(etype_of(name))
+    if 'reverse' in rel:
+      stored.append(etype_of(rel['reverse']))
+  stored.sort()
+  caps = {etype_of(k): v for k, v in CAPS.items()}
+  t_in, depth = d['label_type'], len(m['fanout'])
+  rb, eb = layer_bounds(stored, caps, m['fanout'], t_in, m['batch_size'])
+  # hop h samples a relation whose source type has rows after hop h - 1
+  hop_rel = [[name_of(et) for et in stored if rb[et[0]][h] > (
+      rb[et[0]][h - 1] if h else 0)] for h in range(depth)]
+  ntypes = sorted(t for t in rb if rb[t][-1])
+  return dict(kind=m['kind'], in_dim=d['feat_dim'], hidden=m['hidden'],
+              heads=m['heads'], out_dim=d['num_classes'], layers=depth,
+              out_ntype=t_in, ntypes=ntypes,
+              relations={name_of(et): (et[2], et[0]) for et in stored},
+              hop_relations=hop_rel,
+              row_bounds={t: rb[t] for t in ntypes},
+              edge_bounds={name_of(et): eb[et] for et in stored})
+
+
+def lower_reference(cfg, one_chip, compute_dtype='float32'):
+  import jax
+  import jax.numpy as jnp
+
+  from perfbench import reference_hetero_node as reference
+  md = model_desc(cfg)
+  m, d = cfg['model'], cfg['dataset']
+  room = lambda n: reference._padded(int(n * 1.05))
+  sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+  rows = {t: room(VALID['rows'][t]) for t in md['ntypes']}
+  edges = {r: room(VALID['edges'][r]) for r in md['relations']}
+  print('lower_typed_cell: ' + json.dumps(dict(
+      reference_rows=rows, reference_edges=edges,
+      row_bounds=md['row_bounds'], edge_bounds=md['edge_bounds'])),
+      flush=True)
+  batch = dict(
+      x={t: sds((n, d['feat_dim']), jnp.dtype(d['feature_dtype']))
+         for t, n in rows.items()},
+      y=sds((m['batch_size'],), jnp.int32),
+      edges={r: dict(src=sds((n,), jnp.int32), tgt=sds((n,), jnp.int32),
+                     hops=sds((md['layers'] + 1,), jnp.int32))
+             for r, n in edges.items()})
+  params = jax.eval_shape(lambda: reference.init_params(md, 0))
+  params = jax.tree.map(lambda a: sds(a.shape, a.dtype), params)
+  step = reference.make_step(md, m['lr'], m['batch_size'], compute_dtype)
+  with jax.default_matmul_precision('highest'):
+    compiled = step.jitted.lower(params, params, params,
+                                 sds((), jnp.float32), batch).compile()
+  return report(f'reference.step[{compute_dtype}]', compiled)
+
+
+def lower_chunk(cfg, traffic, one_chip):
+  """``ScanTrainer``'s chunk program over the typed loader, traced as a
+  run traces it — the family's own loader, model and state, the
+  ``typed_scan`` executor's trainer — over a SMALL graph of the cell's
+  types and relations under the cell's caps (a batch's shapes come from
+  caps, fan-out and batch alone), then lowered with every table, a
+  program argument, at the cell's size."""
+  import copy
+
+  import jax
+  import jax.numpy as jnp
+
+  import graphlearn_tpu as glt
+  from perfbench.datagen_hetero_node import etype_of
+  from perfbench.families import hetero_node
+  real = cfg['dataset']
+  small = copy.deepcopy(cfg)
+  d = small['dataset']
+  d['node_types'] = {t: max(n // 64, 256) for t, n in d['node_types'].items()}
+  d['num_train'] = 8 * small['model']['batch_size']
+  for rel in d['relations'].values():
+    rel['edges'] //= 64
+  caps = {etype_of(k): v for k, v in CAPS.items()}
+  calibrate = glt.sampler.estimate_hetero_frontier_caps
+  glt.sampler.estimate_hetero_frontier_caps = lambda *a, **kw: caps
+  try:
+    cell = hetero_node.Cell(small, traffic, lambda k, v: None)
+  finally:
+    glt.sampler.estimate_hetero_frontier_caps = calibrate
+  model = cell.make_model(None)
+  state, tx, _ = cell.make_state(model, 0)
+  tr = glt.ScanTrainer(cell.make_loader(0), model, tx, cell.num_classes,
+                       chunk_size=int(traffic['chunk_size']))
+  sds = lambda shape, dt: jax.ShapeDtypeStruct(tuple(shape), dt,
+                                               sharding=one_chip)
+  spec = lambda tree: jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+  n_of = real['node_types']
+  e_of = {}
+  for name, rel in real['relations'].items():
+    e_of[etype_of(name)] = rel['edges']
+    if 'reverse' in rel:
+      e_of[etype_of(rel['reverse'])] = rel['edges']
+  fargs = {}
+  for et, ga in tr._sample_args().items():
+    n, e = n_of[et[0]], e_of[et]
+    lead = dict(indptr=n + 1, indices=e, meta=n)
+    fargs[et] = {k: sds((lead[k],) + a.shape[1:], a.dtype)
+                 for k, a in ga.items()}
+  feats = {t: sds((n_of[t],) + a.shape[1:], a.dtype)
+           for t, a in tr._feats.items()}
+  id2i = {t: None if a is None else sds((n_of[t],), a.dtype)
+          for t, a in tr._id2i.items()}
+  labels = sds((n_of[cell.input_type],), tr._labels.dtype)
+  steps = real['num_train'] // cell.batch
+  tables = sum(jnp.dtype(a.dtype).itemsize * math.prod(a.shape)
+               for a in jax.tree.leaves((fargs, feats, id2i, labels)))
+  chunk = getattr(tr._chunk_fn, '_glt_instrumented', tr._chunk_fn)
+  k = int(traffic['chunk_size'])
+  compiled = jax.jit(lambda *a: chunk(*a, k), donate_argnums=(0, 1)).lower(
+      spec(state), sds((), jnp.bool_), fargs, feats, id2i, labels,
+      sds((steps, cell.batch), jnp.int32),
+      sds((steps, cell.batch), jnp.bool_), spec(tr._sampler._key),
+      sds((), jnp.int32), sds((), jnp.int32)).compile()
+  out = report('jit_scan_epoch_chunk', compiled)
+  print('lower_typed_cell: ' + json.dumps(dict(
+      tables_bytes=tables, peak_gib=(out['peak'] or 0) / 2 ** 30,
+      chip_gib=15.75)), flush=True)
+  return out
+
+
+def main(argv):
+  from perfbench import run
+  which = argv or ['reference']
+  _, _, cfg, traffic, _ = run.load_cell(CELL, 'BENCHMARK.json')
+  one_chip = describe()
+  if 'reference' in which:
+    lower_reference(cfg, one_chip)
+  if 'chunk' in which:
+    lower_chunk(cfg, traffic, one_chip)
+
+
+if __name__ == '__main__':
+  main(sys.argv[1:])
