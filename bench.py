@@ -1458,9 +1458,11 @@ def main():
         key = f'run_mean_impl_{impl}_ms'
         try:
           models_lib.RUN_MEAN_IMPL = impl
+          # the tree variant: the merge (exact) convs gather k-major
+          # and no longer consult the fork (PERF.md section 6, PR 31)
           tot_i, _ = _run_e2e(ds, train_idx, jnp.bfloat16, jax,
                               f'/tmp/glt_bench_copytax_{impl}',
-                              variant='exact', cal_caps=cal_caps)
+                              variant='tree')
           result[key] = round(float(tot_i), 3) if tot_i else None
         except Exception as e:
           result[key] = None
@@ -1476,7 +1478,7 @@ def main():
         result.get('run_mean_impl_window_ms'))
     result['run_mean_impl_decision'] = dec
     result['run_mean_impl_decision_config'] = (
-        f'{why}; basis: exact-variant bf16 e2e step ({E2E_ITERS} traced '
+        f'{why}; basis: tree-variant bf16 e2e step ({E2E_ITERS} traced '
         'iters); apply by editing models.RUN_MEAN_IMPL citing this '
         'record')
   except Exception as e:

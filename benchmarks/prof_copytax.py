@@ -17,7 +17,11 @@ GAT convs' f32 [f, k, H] softmax chain — ISSUE 13's further
 flat-layout rewrite) on a tree_dense GAT train step: same per-op-class
 tables, same decision discipline.
 
-Run on TPU: python benchmarks/prof_copytax.py [--variant exact|tree]
+Only the slice-fed tree convs consult models.RUN_MEAN_IMPL: the merge
+(``exact``) convs gather their children k-major since PR 31 (PERF.md
+section 6), so ``--variant exact`` traces the same program twice.
+
+Run on TPU: python benchmarks/prof_copytax.py [--variant tree|exact]
                                               [--softmax-ab]
 """
 import argparse
@@ -84,7 +88,7 @@ def _gat_softmax_ab(args):
 
 def main():
   ap = argparse.ArgumentParser()
-  ap.add_argument('--variant', default='exact', choices=['exact', 'tree'])
+  ap.add_argument('--variant', default='tree', choices=['exact', 'tree'])
   ap.add_argument('--iters', type=int, default=10)
   ap.add_argument('--softmax-ab', action='store_true',
                   help='also A/B models.RUN_SOFTMAX_IMPL on a '

@@ -178,12 +178,13 @@ def _gat_runs_blocked(w_res, a_src_res, a_par, m, src, heads, hd,
   return vals.reshape(nb * fb, heads * hd)[:f]
 
 
-def _masked_run_mean(vals, mask):
-  """Masked mean over axis 1 of a [runs, k, F] block ([runs, k] mask) —
+def _masked_run_mean(vals, mask, axis=1):
+  """Masked mean over the run axis of a [runs, k, F] block ([runs, k]
+  mask; ``axis=0``: a k-major [k, runs, F] block and [k, runs] mask) —
   the shared aggregation kernel of the dense-run convs (TreeSAGEConv /
   MergeSAGEConv)."""
-  s = jnp.where(mask[..., None], vals, jnp.zeros((), vals.dtype)).sum(1)
-  inv = (1.0 / jnp.maximum(mask.sum(1), 1)).astype(vals.dtype)
+  s = jnp.where(mask[..., None], vals, jnp.zeros((), vals.dtype)).sum(axis)
+  inv = (1.0 / jnp.maximum(mask.sum(axis), 1)).astype(vals.dtype)
   return s * inv[:, None]
 
 
@@ -219,16 +220,20 @@ def run_impl_decision(reshape_ms, window_ms, rel_margin: float = 0.03):
                      f'{window_ms:.3f} ms, margin {rel_margin:.0%})')
 
 
-# Run-aggregation implementation for the dense convs' mean kernels.
-# 'reshape' (default): reduce over axis 1 of a [runs, k, F] view — the
-# 3D reshape forces a relayout copy on TPU when k is not tile-aligned
-# (fanouts 15/10/5 never are), part of the measured ~3.7 ms/step
-# reshape tax (PERF.md 'MFU and the roofline'). 'window': keep the flat
-# [runs*k, F] layout and reduce k-runs with lax.reduce_window
-# (window/stride k on the row axis) — no 3D view materialized.
-# Numerically identical (equivalence tests run under both); A/B traced
-# by benchmarks/prof_copytax.py on the chip and auto-decided by
-# bench.py's ``run_mean_impl_decision`` key (run_impl_decision above).
+# Run-aggregation implementation of the SLICE-fed tree convs' mean
+# (TreeSAGEConv, TreeHeteroConv._sage_et), whose children are a
+# contiguous f-major slice of the node buffer: the tree layout gives the
+# order. 'reshape' (default): reduce over axis 1 of a [runs, k, F] view
+# — k (15/10/5) lands on the sublane axis and is padded to 16/16/8, so
+# on TPU the view is a physical relayout, forward and backward (on the
+# merge path it read glt.train/fwd_bwd:reshape 2.24 ms a step in
+# sage-products.scan-exact; PERF_LEDGER.jsonl, PR 30). 'window': keep
+# the flat [runs*k, F] layout and reduce k-runs with lax.reduce_window —
+# forward only: equal to 'reshape' there (tested), but it has no reverse
+# mode under jax 0.9 as written and trained wrongly on the chip when
+# given one (PERF.md section 6, PR 31). The merge convs reach their
+# children through an index and gather k-major (_gathered_run_mean),
+# consulting no fork; this one waits for a tree cell (ROADMAP.md D4).
 RUN_MEAN_IMPL = _impl_from_env('GLT_RUN_MEAN_IMPL', 'reshape',
                                ('reshape', 'window'))
 
@@ -256,6 +261,23 @@ def _masked_flat_run_mean(x, mask, k):
     inv = (1.0 / jnp.maximum(mask.sum(1), 1)).astype(x.dtype)
     return s * inv[:, None]
   return _masked_run_mean(x.reshape(f, k, -1), mask)
+
+
+def _gathered_run_mean(x, src, mask, k):
+  """Masked mean over the k-runs that a flat f-major index ``src``
+  [f*k] (-1 = padding) names in the rows table ``x`` [n, F], with a
+  [f, k] mask -> [f, F] — the aggregation of the merge-layout convs
+  (MergeSAGEConv, TreeHeteroConv._sage_et_merge).
+
+  Children that come through an index can be gathered in any order at
+  the same cost, so they are gathered k-MAJOR: slot j of every run is a
+  contiguous, tile-aligned [f, F] slab, ``[k, f, F]`` is a free view of
+  the gathered block and the run sum is k - 1 element-wise adds. No
+  [f, k, F] tensor (k on the padded sublane axis: a relayout of every
+  gathered row, forward and backward) exists on this path."""
+  f = mask.shape[0]
+  src_km = jnp.maximum(src, 0).reshape(f, k).T.reshape(-1)
+  return _masked_run_mean(x[src_km].reshape(k, f, -1), mask.T, axis=0)
 
 
 class TreeSAGEConv(nn.Module):
@@ -322,10 +344,12 @@ class MergeSAGEConv(nn.Module):
   The merge engine emits each hop's edges in frontier order — every
   frontier node's ``k`` draws occupy CONSECUTIVE edge slots — so each
   hop's target column is k-CONSTANT runs. Mean aggregation becomes: one
-  source-row gather, a ``[frontier, k]`` masked reshape-mean (dense VPU
-  work), and a dense block write per hop (``dynamic_update_slice`` at
-  the hop's contiguous target base — ZERO scatter transactions,
-  replacing the segment scatter-add over the full edge width). Exact
+  source-row gather in k-major order, a masked sum of its k aligned
+  ``[frontier, F]`` slabs (``_gathered_run_mean``: dense VPU work, no
+  ``[frontier, k, F]`` view), and a dense block write per hop
+  (``dynamic_update_slice`` at the hop's contiguous target base — ZERO
+  scatter transactions, replacing the segment scatter-add over the full
+  edge width). Exact
   for every merge batch, including calibrated frontier caps (targets
   are unique across hops: dedup expands each node at most once).
   Parameter names match ``SAGEConv`` (``lin_self``/``lin_nbr``) —
@@ -365,7 +389,7 @@ class MergeSAGEConv(nn.Module):
       src = jax.lax.dynamic_slice_in_dim(row, e0, width)
       tgt_blk = jax.lax.dynamic_slice_in_dim(col, e0, width).reshape(f, k)
       m = jax.lax.dynamic_slice_in_dim(edge_mask, e0, width).reshape(f, k)
-      mean = _masked_flat_run_mean(x[jnp.maximum(src, 0)], m, k)
+      mean = _gathered_run_mean(x, src, m, k)
       # the k-run's target local idx (masked slots carry -1: take max)
       tgt = tgt_blk.max(1)
       ok = m.any(1) & (tgt >= 0)
@@ -950,7 +974,7 @@ class TreeHeteroConv(nn.Module):
         break
       m, src, base, ok = self._run_layout(r, edge_mask_dict,
                                           edge_index_dict, n_out)
-      mean = _masked_flat_run_mean(x_dict[r['res_t']][src], m, r['k'])
+      mean = _gathered_run_mean(x_dict[r['res_t']], src, m, r['k'])
       agg = self._acc_add(agg, jnp.where(ok[:, None], mean, 0), base)
     return self._sage_out(ename, key_t, x_key, n_out, agg)
 

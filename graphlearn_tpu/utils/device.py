@@ -81,9 +81,30 @@ def enable_compilation_cache(min_compile_secs: float = 1.0) -> str:
   if jax.config.jax_hlo_source_file_canonicalization_regex is None:
     jax.config.update('jax_hlo_source_file_canonicalization_regex',
                       '^' + re.escape(_CHECKOUT + os.sep))
-  placed = os.environ.get('JAX_COMPILATION_CACHE_DIR')
-  if placed:
-    return placed
-  os.makedirs(XLA_CACHE_DIR, exist_ok=True)
-  jax.config.update('jax_compilation_cache_dir', XLA_CACHE_DIR)
-  return XLA_CACHE_DIR
+  cache_dir = os.environ.get('JAX_COMPILATION_CACHE_DIR')
+  if not cache_dir:
+    cache_dir = XLA_CACHE_DIR
+    os.makedirs(cache_dir, exist_ok=True)
+    jax.config.update('jax_compilation_cache_dir', cache_dir)
+  _restore_access_times(cache_dir)
+  return cache_dir
+
+
+def _restore_access_times(cache_dir: str) -> None:
+  """Give every ``<key>-cache`` entry that lost its ``<key>-atime``
+  file a new one. Under a size limit (``jax_compilation_cache_max_size``)
+  jax reads the access time of EVERY entry before it writes one, and a
+  single missing file makes every write fail with a warning: nothing
+  new is cached, and each process compiles its programs again (seen on
+  the chip machine's own cache, PERF.md section 6, PR 31: 86 s of
+  ``first_call_s`` on every run)."""
+  import glob
+  import time
+  try:
+    for entry in glob.glob(os.path.join(cache_dir, '*-cache')):
+      atime = entry[:-len('-cache')] + '-atime'
+      if not os.path.exists(atime):
+        with open(atime, 'wb') as f:
+          f.write(time.time_ns().to_bytes(8, 'little'))
+  except OSError:
+    pass   # a cache that cannot be repaired is a slower start, no error

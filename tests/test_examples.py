@@ -132,6 +132,35 @@ def test_compilation_cache_never_serves_another_scopes_executable(tmp_path):
   assert len(os.listdir(placed)) >= 2   # one entry per scope, not one
 
 
+def test_compilation_cache_survives_an_entry_without_access_time(tmp_path):
+  """Under a size limit jax reads ``<key>-atime`` of every entry before
+  it writes one, so one entry that lost that file stops all caching (the
+  chip machine's cache was found so, PERF.md section 6, PR 31).
+  enable_compilation_cache() gives such an entry a new access time, and
+  the next program is written."""
+  placed = tmp_path / 'cache'
+  placed.mkdir()
+  (placed / 'jit_lost-0123-cache').write_bytes(b'x' * 64)
+  code = ('import sys, warnings\n'
+          'warnings.simplefilter("error")\n'
+          'import jax, jax.numpy as jnp\n'
+          'import graphlearn_tpu as glt\n'
+          'assert glt.utils.enable_compilation_cache(0.0) == sys.argv[1]\n'
+          'jax.jit(lambda x: jnp.sort(x) * 3)(jnp.arange(8.0))'
+          '.block_until_ready()\n')
+  env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(placed),
+             JAX_COMPILATION_CACHE_MAX_SIZE=str(10 ** 9),
+             JAX_PLATFORMS='cpu', PYTHONPATH=REPO)
+  out = subprocess.run([sys.executable, '-c', code, str(placed)],
+                       capture_output=True, text=True, timeout=180,
+                       env=env, cwd=str(tmp_path))
+  assert out.returncode == 0, out.stderr[-2000:]
+  names = sorted(os.listdir(placed))
+  assert 'jit_lost-0123-atime' in names
+  assert [n for n in names if n.endswith('-cache')
+          and not n.startswith('jit_lost')], names
+
+
 @pytest.mark.slow  # tier-1 budget (PR 19): staged-npz example variant
 # — the sub-second example tests stay tier-1, full run already slow
 def test_products_staged_npz_path(tmp_path):

@@ -927,6 +927,98 @@ def test_flat_run_mean_window_impl_matches():
   np.testing.assert_allclose(o_ref, o_win, rtol=1e-5, atol=1e-5)
 
 
+def _run_mean_case(f, k, fd=16, n=53):
+  """Rows table, flat f-major src with -1 padding and a [f, k] mask that
+  holds an all-masked run, a short run (degree < k) and a full run."""
+  rng = np.random.default_rng(31 * f + k)
+  x = rng.standard_normal((n, fd)).astype(np.float32)
+  m = rng.random((f, k)) < 0.7
+  m[0] = False                      # all-masked run (zero-degree parent)
+  m[1] = np.arange(k) < 2           # short run: degree 2 < k
+  m[2] = True                       # full run
+  src = np.where(m, rng.integers(0, n, (f, k)), -1).astype(np.int32)
+  return x, src.reshape(-1), m
+
+
+@pytest.mark.parametrize('f', [37, 128])
+@pytest.mark.parametrize('k', [5, 10, 15])
+def test_gathered_run_mean_matches_reshape(k, f):
+  """The k-major gathered run mean of the merge convs == the [f, k, F]
+  reshape mean it replaces, in value and in the gradient w.r.t. the rows
+  table (f = 37 is no multiple of 8: the [k, f, F] view may cost a pad,
+  never a value)."""
+  import jax
+  import jax.numpy as jnp
+  from graphlearn_tpu.models import models as M
+  x, src, m = _run_mean_case(f, k)
+  x, src, m = jnp.asarray(x), jnp.asarray(src), jnp.asarray(m)
+  w = jnp.asarray(np.random.default_rng(1).standard_normal(
+      (f, x.shape[1])).astype(np.float32))
+
+  def ref(x):
+    return M._masked_run_mean(x[jnp.maximum(src, 0)].reshape(f, k, -1), m)
+
+  def new(x):
+    return M._gathered_run_mean(x, src, m, k)
+
+  out_ref, out_new = np.asarray(ref(x)), np.asarray(new(x))
+  np.testing.assert_allclose(out_new, out_ref, rtol=1e-6, atol=1e-6)
+  assert not out_new[0].any()       # the all-masked run reads 0
+  np.testing.assert_allclose(       # the short run: 2 slots, divisor 2
+      out_new[1], np.asarray(x)[np.asarray(src)[k:k + 2]].mean(0),
+      rtol=1e-6, atol=1e-6)
+  g_ref = jax.grad(lambda x: (ref(x) * w).sum())(x)
+  g_new = jax.grad(lambda x: (new(x) * w).sum())(x)
+  np.testing.assert_allclose(np.asarray(g_new), np.asarray(g_ref),
+                             rtol=1e-5, atol=1e-6)
+
+
+def _jaxpr_shapes(jaxpr):
+  """Shapes of every value a jaxpr computes, sub-jaxprs included."""
+  import jax
+  for eqn in jaxpr.eqns:
+    for v in eqn.outvars:
+      yield tuple(getattr(v.aval, 'shape', ()))
+    for sub in jax.core.jaxprs_in_params(eqn.params):
+      yield from _jaxpr_shapes(sub)
+
+
+def test_merge_sage_conv_holds_no_run_view():
+  """Structural guard: neither MergeSAGEConv's forward nor its backward
+  holds a rank-3 (f, k, F) value — the view that put k on the padded
+  sublane axis and cost a relayout of every gathered row — so it cannot
+  come back unseen. The walker is checked on the old form first."""
+  import jax
+  import jax.numpy as jnp
+  from graphlearn_tpu.models import models as M
+  hops = ((16, 5), (80, 3))         # (f, k): no (f, k) equals a (k, f)
+  n, fd, e = 120, 24, 16 * 5 + 80 * 3
+  x = jnp.ones((n, fd), jnp.float32)
+  ei = jnp.zeros((2, e), jnp.int32)
+  em = jnp.ones((e,), bool)
+  conv = M.MergeSAGEConv(out_dim=8, edge_offsets=(80, e), fanouts=(5, 3))
+  params = conv.init(jax.random.PRNGKey(0), x, ei, em)
+
+  def loss(params, x):
+    return conv.apply(params, x, ei, em).sum()
+
+  def run_views(fn, *args):
+    shapes = set(_jaxpr_shapes(jax.make_jaxpr(fn)(*args).jaxpr))
+    rows = {s for s in shapes if len(s) == 3 and s[2] == fd}  # not masks
+    return ({s for s in rows if s[:2] in hops},
+            {s for s in rows if s[:2] in tuple((k, f) for f, k in hops)})
+
+  def old(x):
+    return M._masked_flat_run_mean(x[ei[0, :80]], em[:80].reshape(16, 5),
+                                   5).sum()
+
+  assert run_views(jax.grad(old), x)[0] == {(16, 5, fd)}
+  for fn in (loss, jax.grad(loss, argnums=(0, 1))):
+    f_major, k_major = run_views(fn, params, x)
+    assert not f_major, f_major
+    assert k_major == {(5, 16, fd), (3, 80, fd)}
+
+
 def test_flat_run_softmax_window_impl_matches():
   """The flat reduce_window run-softmax (RUN_SOFTMAX_IMPL='window' —
   ISSUE 13's further flat-layout rewrite) matches the reshape kernel at
