@@ -39,7 +39,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 PRODUCTS = dict(
     num_nodes=2_449_029, avg_deg=25, feat_dim=100, num_classes=47,
     fanout=(15, 10, 5), batch=1024, hidden=256, steps=8, chunk=4,
-    # kernel probe shapes: the prof_gather table/ids; one products hop
+    # kernel probe shapes: a 1M-row table, 131k random ids; one products hop
     gather_rows=1_000_000, gather_ids=131_072, hop_seeds=1024,
     mesh_parts=4, mesh_steps=4, mesh_chunk=2)
 

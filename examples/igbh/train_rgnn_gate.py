@@ -24,8 +24,7 @@ per-(hop,etype) caps + dense k-run aggregation on exact merge batches
 (sampler.estimate_hetero_frontier_caps). Convs (--conv): sage / gat
 (RGNN) / hgt (HGT) — every conv supports all three modes.
 
-Prints ONE JSON line with test_acc_at per requested budget —
-benchmarks/hetero_accuracy_matrix.py drives the seeded mode matrix.
+Prints ONE JSON line with test_acc_at per requested budget.
 """
 import argparse
 import json

@@ -14,6 +14,6 @@ from . import (channel, data, distributed, loader, metrics, models, ops,
 # one-call autotuner (a CALLABLE subpackage: graphlearn_tpu.tune(ds,
 # cfg) emits the fast-path config artifact — docs/tuning.md); RunTrainer
 # is the whole-run-as-a-program executor (loader/run_epoch.py).
-from .loader import OverlappedTrainer, RunTrainer, ScanTrainer
+from .loader import RunTrainer, ScanTrainer
 
 __version__ = '0.1.0'

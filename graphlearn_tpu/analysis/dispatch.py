@@ -2,8 +2,8 @@
 hot module must be counted.
 
 The dispatch-budget asserts (tests/test_scan_epoch.py,
-tests/test_dist_scan_epoch.py, bench.py ``epoch_dispatches``) are only
-meaningful if EVERY hot-path program launch calls
+tests/test_dist_scan_epoch.py) and the benchmark's
+``dispatches_per_step`` (perfbench/layer_metrics/) are only meaningful if EVERY hot-path program launch calls
 ``utils.trace.record_dispatch`` at its dispatch site (or is wrapped in
 ``wrap_dispatch``). An un-instrumented ``jax.jit`` entrypoint silently
 deflates the counted budget — the budget test keeps passing while the

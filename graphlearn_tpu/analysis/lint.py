@@ -5,7 +5,7 @@ Usage::
     python -m graphlearn_tpu.analysis.lint graphlearn_tpu/
     python -m graphlearn_tpu.analysis.lint --format json graphlearn_tpu/
     python -m graphlearn_tpu.analysis.lint --changed-only graphlearn_tpu/
-    python -m graphlearn_tpu.analysis.lint --profile bench benchmarks/
+    python -m graphlearn_tpu.analysis.lint --profile bench perfbench/ chip_smoke.py
     python -m graphlearn_tpu.analysis.lint --write-baseline graphlearn_tpu/
     python -m graphlearn_tpu.analysis.lint --list-rules
 
@@ -21,8 +21,8 @@ findings in files touched vs ``--base-ref`` (default HEAD, plus
 staged/unstaged/untracked). Use it in pre-commit hooks to see only
 your own debt without weakening the analysis.
 
-``--profile bench`` is the relaxed profile for benchmarks/ and
-bench.py: the registry rules (metric/span/fault-point names), bracket
+``--profile bench`` is the relaxed profile for perfbench/ and
+chip_smoke.py: the registry rules (metric/span/fault-point names), bracket
 discipline and donation safety stay enforced — a benchmark that leaks
 spans or reads donated buffers measures garbage — while the hot-path
 scoping rules (host-sync, dispatch instrumentation, prng discipline,
@@ -169,9 +169,9 @@ def main(argv=None) -> int:
                        '(default: HEAD)')
   ap.add_argument('--profile', choices=('default', 'bench'),
                   default='default',
-                  help="'bench': relaxed scoping for benchmarks/ and "
-                       'bench.py (registries/brackets/donation still '
-                       'enforced)')
+                  help="'bench': relaxed scoping for perfbench/ and "
+                       'chip_smoke.py (registries/brackets/donation '
+                       'still enforced)')
   ap.add_argument('-q', '--quiet', action='store_true',
                   help='summary line only')
   args = ap.parse_args(argv)

@@ -2,7 +2,7 @@
 documented — the exported namespace stays closed.
 
 The metrics layer's value is the CLOSED ``<subsystem>.<event>``
-namespace (docs/observability.md): dashboards, the bench gate and the
+namespace (docs/observability.md): dashboards, the benchmark and the
 flight-record postmortem tooling all key on exact names, so a typo'd
 or ad-hoc name silently orphans its series. This rule cross-checks
 three sources, mirroring the fault-point-coverage rule:

@@ -187,9 +187,9 @@ class UnifiedTensor:
   # the kernel remains available for rigs where the balance differs
   use_pallas_v2 = False   # opt-in: the run-segmented multi-row DMA
   # gather (ops.gather_rows_hbm2) — the same evidence-gated contract:
-  # auto-route only once benchmarks/prof_gather2.py shows a measured
-  # win on the serving rig. When both flags are set, v2 wins.
-  pallas_v2_block_rows = 256   # autotune grid knobs (prof_gather2)
+  # not measured on the chip (ROADMAP.md S5): auto-route only once a
+  # cell shows a win. When both flags are set, v2 wins.
+  pallas_v2_block_rows = 256   # autotune grid knobs (tune/tuner.py)
   pallas_v2_run_span = 8
 
   def _pallas_ok(self) -> bool:

@@ -61,8 +61,8 @@ from .dist_graph import DistGraph, DistHeteroGraph
 
 
 # canonical home is ops.route (shared with the feature-store miss
-# exchange); re-exported here because benchmarks/tests import them from
-# this module
+# exchange); re-exported here because tests import them from this
+# module
 from ..ops.route import exchange_capacity, round8 as _round8  # noqa: E402,F401
 
 

@@ -256,8 +256,8 @@ class NodeLoader(OverflowGuardMixin):
     fresh random window-subset each epoch, de-biasing the truncation
     (ops.build_padded_adjacency; no-op for non-padded samplers). The
     single counter lives here so every epoch driver — __iter__ and
-    OverlappedTrainer.run_epoch — shares one view of how many epochs
-    this loader has run."""
+    the scanned trainers' run_epoch — shares one view of how many
+    epochs this loader has run."""
     if getattr(self.sampler, 'padded_window', None) is not None:
       if getattr(self, '_epochs_started', 0) > 0:
         self.sampler.refresh_padded_table()

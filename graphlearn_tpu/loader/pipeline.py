@@ -1,34 +1,27 @@
-"""Overlapped (double-buffered) sample -> collate -> train pipeline.
+"""The batch sources the fused epoch executors share.
 
-The reference's defining architecture is the asynchronous producer-consumer
-pipeline: sampling runs decoupled from training and its latency hides
-behind the train step
-(/root/reference/graphlearn_torch/python/distributed/dist_sampling_producer.py:53-151;
-docs/get_started/dist_train.md:3-8 "asynchronous producer consumer model",
-via CUDA streams / separate processes).
+The reference decouples sampling from training with an asynchronous
+producer-consumer pipeline
+(/root/reference/graphlearn_torch/python/distributed/dist_sampling_producer.py:53-151).
+A TPU core runs one XLA program at a time, so here the sampler, the
+collate gather and the train step are traced into ONE program and the
+host only dispatches. This module holds the two pieces those programs
+are built from:
 
-A single TPU core has no concurrent streams — XLA programs execute one at
-a time — so the TPU-native equivalent is PROGRAM FUSION with software
-double-buffering: batch n's train step and batch n+1's sample+collate are
-traced into ONE XLA program with no data dependency between the two
-subgraphs. XLA's scheduler is then free to interleave the sampler/collate
-work (DMA-latency/HBM-bound gathers) with the train step's MXU-bound
-matmul pipeline, which is exactly the resource overlap the reference gets
-from its producer streams. Whether the scheduler exploits it is an
-empirical question — bench.py measures the fused step against the serial
-sum with device-trace truth (PERF.md reports the measured overlap).
+* :class:`FusedEpochTrainer` — the in-program batch source of the
+  single-chip scanned trainers (``scan_epoch.ScanTrainer``,
+  ``run_epoch.RunTrainer``, ``storage.TieredScanTrainer``): scope
+  validation, the device feature/label tables and the pure
+  sample+collate body, for a homogeneous or a typed loader.
+* :class:`DistFusedEpochTrainer` — its mesh counterpart and the
+  per-step trainer of the collocated mesh: the data-parallel train
+  step and the traced sample+collate body that
+  ``scan_epoch.DistScanTrainer`` scans.
 
-Usage:
-    loader = NeighborLoader(ds, fanouts, idx, batch_size=B, ...)
-    trainer = OverlappedTrainer(loader, model, tx, num_classes)
-    state, losses = trainer.run_epoch(state)   # losses stay on device
-
-The host loop stays dispatch-only (no device->host fetches, PERF.md
-rules); fetch the returned loss array once per epoch if needed.
+The host loops stay dispatch-only (no device->host fetch in the hot
+loop); losses stay on device and are fetched once an epoch.
 """
 from typing import Optional
-
-import numpy as np
 
 from .. import ops
 from ..metrics.registry_names import (SCOPE_FWD_BWD, SCOPE_TRAIN,
@@ -68,10 +61,10 @@ def refuse_typed(loader, name: str):
 
 
 class FusedEpochTrainer:
-  """Shared plumbing for the fused epoch executors (OverlappedTrainer,
-  scan_epoch.ScanTrainer): scope validation, the device feature/label
-  tables, and the pure sample+collate body both trainers trace into
-  their programs.
+  """Shared plumbing for the fused epoch executors
+  (scan_epoch.ScanTrainer and its subclasses): scope validation, the
+  device feature/label tables, and the pure sample+collate body they
+  trace into their programs.
 
   Requirements: fused sampler, device-resident feature/label tables, no
   edge features (the fused programs keep the reference fast path's
@@ -216,132 +209,6 @@ class FusedEpochTrainer:
     if self._input_type is not None:
       return self._sampler._typed_args()
     return self._sampler._fused_args()
-
-
-class OverlappedTrainer(FusedEpochTrainer):
-  """Fuses batch n's train step with batch n+1's sample+collate."""
-
-  _NAME = 'OverlappedTrainer'
-
-  def __init__(self, loader: NodeLoader, model, tx, num_classes: int,
-               seed_labels_only: Optional[bool] = None):
-    import jax
-    refuse_typed(loader, self._NAME)
-    super().__init__(loader, model, tx, num_classes, seed_labels_only)
-
-    _sample_collate = self._sample_collate
-    train_step = self._train_step
-
-    def _fused(state, batch, ovf, pending, fargs, feats, id2i, labels,
-               seeds, smask, key):
-      # two independent subgraphs in one program: XLA may interleave
-      new_state, loss, acc = train_step(state, batch)
-      next_batch, next_pending = _sample_collate(fargs, feats, id2i,
-                                                 labels, seeds, smask, key)
-      # overflow accumulates on device — zero host syncs in the hot
-      # loop. ``pending`` is the flag of the batch being trained NOW;
-      # next_pending stays out of the accumulator until its batch is
-      # actually consumed (a dropped prefetch must not taint the epoch)
-      return new_state, loss, acc, next_batch, ovf | pending, next_pending
-
-    # donate the consumed batch buffers (state update buffers are small
-    # relative to the 938k-slot batch; donation keeps HBM flat at two
-    # batches in flight)
-    from ..metrics import programs
-    self._prime_fn = programs.instrument(jax.jit(_sample_collate),
-                                         'prime')
-    self._fused_fn = programs.instrument(
-        jax.jit(_fused, donate_argnums=(1,)), 'fused_step')
-
-  # ---------------------------------------------------------------- loop
-
-  def _seed_batches(self):
-    for idx in self.loader._batcher:
-      seeds = self.loader.input_seeds[idx]
-      n = seeds.shape[0]
-      padded = np.zeros((self._batch_size,), np.int32)
-      padded[:n] = seeds
-      yield padded, np.arange(self._batch_size) < n
-
-  def _dispatch_prime(self, padded, mask):
-    import jax.numpy as jnp
-    from ..utils.trace import record_dispatch
-    record_dispatch('prime')
-    return self._prime_fn(self._sampler._fused_args(), self._feats,
-                          self._id2i, self._labels, jnp.asarray(padded),
-                          jnp.asarray(mask), self._sampler._next_key())
-
-  def run_epoch(self, state, max_steps: Optional[int] = None):
-    """One epoch of overlapped steps. Returns (state, losses) with
-    ``losses`` a list of device scalars (one per step) — fetch once,
-    after the epoch, to keep the hot loop pipelined."""
-    import jax.numpy as jnp
-
-    from ..metrics import flight
-    from ..utils.trace import record_dispatch
-    # _seed_batches walks loader._batcher directly (bypassing
-    # NodeLoader.__iter__), so the per-epoch padded-table reseed must be
-    # driven explicitly — same counter as plain iteration
-    # re-evaluate the guard each epoch (a post-construction policy
-    # change must take effect, like the plain loader's epoch start) —
-    # BEFORE _begin_epoch, so a refused epoch doesn't consume a
-    # padded-table reseed and drift later epochs' windows
-    guarded, recompute = self.loader._overflow_epoch_start()
-    if recompute:
-      raise ValueError(_RECOMPUTE_MSG)
-    self.loader._begin_epoch()
-    flight_tok = flight.epoch_begin()
-    losses = []
-    completed = False
-    truncated = False
-    try:
-      batch = None
-      ovf = jnp.zeros((), bool)   # flags of batches actually trained
-      pending = None              # flag of the in-flight (sampled) batch
-      for padded, mask in self._seed_batches():
-        if batch is None:
-          batch, pending = self._dispatch_prime(padded, mask)
-          continue
-        record_dispatch('fused_step')
-        state, loss, _, batch, ovf, pending = self._fused_fn(
-            state, batch, ovf, pending, self._sampler._fused_args(),
-            self._feats, self._id2i, self._labels, jnp.asarray(padded),
-            jnp.asarray(mask), self._sampler._next_key())
-        losses.append(loss)
-        if max_steps is not None and len(losses) >= max_steps:
-          truncated = True
-          break
-      if batch is not None and not truncated:
-        # natural epoch end: flush the last sampled batch with a plain
-        # train step. A max_steps break drops the pending batch instead
-        # — exactly max_steps optimizer updates, step-exact for
-        # benchmarks and LR schedules.
-        record_dispatch('train_step')
-        state, loss, _ = self._train_step(state, batch)
-        losses.append(loss)
-        ovf = jnp.logical_or(ovf, pending)
-      completed = True
-      if guarded:
-        # hand the device-accumulated flag to the loader's guard:
-        # natural epoch end applies overflow_policy ('raise'/'warn'); a
-        # max_steps break leaves it for loader.check_overflow(). Only
-        # trained batches count — a dropped prefetch's flag is
-        # discarded with it.
-        self.loader._ovf_accum = ovf
-        if not truncated:
-          self.loader._finish_epoch_overflow()
-    finally:
-      # per-epoch flight record (metrics/flight.py) — host deltas only;
-      # a mid-epoch failure still records, with completed=False
-      flight.end_for(
-          self, flight_tok, emitter=self._NAME, steps=len(losses),
-          completed=completed,
-          config=dict(trainer=self._NAME, batch_size=self._batch_size,
-                      fanouts=list(self._sampler.num_neighbors),
-                      num_classes=self.num_classes,
-                      seed=self.loader._batcher.seed),
-          extra={'truncated': truncated})
-    return state, losses
 
 
 class DistFusedEpochTrainer:

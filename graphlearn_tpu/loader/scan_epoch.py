@@ -3,12 +3,12 @@
 The per-step loop pays three program launches per batch plus per-step
 host numpy (seed padding), and the device idles while the host does it
 (~9 % over two traced steps of the products path on a v5e — PERF.md
-"Bring-up (PR 21)"; not yet a benchmark number). `OverlappedTrainer`
-collapsed 3 dispatches/step to 1. The reference hides sampling latency
-with producer processes/streams (dist_sampling_producer.py); on TPU the
-native answer is to put the LOOP ITSELF on device: `ScanTrainer` executes
-an epoch as ~ceil(steps/K) dispatches — a `lax.scan` over a static chunk
-of K steps whose body is the existing fused sample+collate+train program
+"Bring-up (PR 21)"; not yet a benchmark number). The reference hides
+sampling latency with producer processes/streams
+(dist_sampling_producer.py); on TPU the native answer is to put the
+LOOP ITSELF on device: `ScanTrainer` executes an epoch as
+~ceil(steps/K) dispatches — a `lax.scan` over a static chunk of K steps
+whose body is the existing fused sample+collate+train program
 (`pipeline.FusedEpochTrainer` plumbing).
 
 Design points:
@@ -26,8 +26,8 @@ Design points:
     sampling continues the same stream.
   * Losses/accuracies come back as [K] scan outputs; the calibrated-caps
     overflow flag accumulates in the carry — zero host syncs inside the
-    epoch. overflow_policy='recompute' is rejected exactly like
-    `OverlappedTrainer` (it needs a per-batch host sync).
+    epoch. overflow_policy='recompute' is rejected (it needs a
+    per-batch host sync).
   * The train state is DONATED across chunk dispatches, so HBM stays
     flat at one state + one in-flight chunk. The state passed INTO
     run_epoch is consumed — use the returned state.
@@ -135,8 +135,8 @@ class ScanTrainer(FusedEpochTrainer):
 
   Args:
     loader: a NeighborLoader on the fused sampler path with
-      device-resident features and labels — homogeneous (the scope of
-      OverlappedTrainer), or over a typed graph with seeds of one node
+      device-resident features and labels — homogeneous, or over a
+      typed graph with seeds of one node
       type: the chunk then traces the typed hop loop and the per-type
       collate (pipeline.FusedEpochTrainer), under the per-batch typed
       loader's own keys, and everything else here is the same code.
@@ -370,8 +370,8 @@ class ScanTrainer(FusedEpochTrainer):
           resume_overflow=resume_overflow)
       completed = True
       if guarded:
-        # same contract as OverlappedTrainer: natural epoch end applies
-        # overflow_policy; a max_steps break leaves the
+        # natural epoch end applies overflow_policy; a max_steps
+        # break leaves the
         # device-accumulated flag to loader.check_overflow()
         self.loader._ovf_accum = ovf
         if not truncated:

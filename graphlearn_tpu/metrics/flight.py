@@ -9,7 +9,7 @@ can be diffed epoch-by-epoch after the fact (docs/observability.md
 documents the schema and a jq cookbook).
 
 Emitters: ``ScanTrainer``/``DistScanTrainer`` (the scanned epoch
-programs), ``OverlappedTrainer``, and the per-step loader loops
+programs) and the per-step loader loops
 (``NodeLoader``/``DistLoader``/remote/mp ``__iter__``). Every record
 carries DELTAS over the epoch — metric counters, per-site dispatch
 counts — plus wall time and a config fingerprint.

@@ -175,7 +175,7 @@ class ProgramRegistry:
 
   Fed by :func:`instrument` wrappers at the package's dispatch sites;
   read by ``retrace_budget``, the flight recorder (per-epoch deltas of
-  :meth:`flight_snapshot`) and bench.py (:meth:`aggregate`)."""
+  :meth:`flight_snapshot`) and the tuner (:meth:`aggregate`)."""
 
   def __init__(self):
     self._lock = threading.Lock()
@@ -284,7 +284,7 @@ class ProgramRegistry:
       return out
 
   def aggregate(self) -> dict:
-    """Whole-process totals — the bench.py keys (compile_count,
+    """Whole-process totals (compile_count,
     compile_time_s_total, retrace_count, program_flops_total,
     program_peak_hbm_mb). Cost totals are None until any executable
     captured cost (GLT_PROGRAM_COST); they accumulate in running

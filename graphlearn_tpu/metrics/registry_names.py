@@ -41,7 +41,7 @@ REGISTERED_METRICS = frozenset({
     # scrape plumbing (metrics/scrape.py)
     'metrics.scrape_error',
     # online serving endpoint (serving/engine.py) — the end-to-end
-    # latency/throughput surface bench.py --gate regression-tracks
+    # latency/throughput surface (no cell reads it yet: ROADMAP.md R7)
     'serving.requests',
     'serving.batches',
     'serving.refreshed',

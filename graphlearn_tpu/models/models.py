@@ -110,7 +110,7 @@ def _masked_run_softmax(e, mask, out_dtype, negative_slope):
   stabilization (clamping at 0 would underflow exp when every valid
   logit is very negative — the same stabilization GATConv's segment
   softmax uses; all-masked runs fall back to 0), exp, denom floor.
-  Dispatches on RUN_SOFTMAX_IMPL (see above): 'window' keeps the whole
+  Dispatches on RUN_SOFTMAX_IMPL (see below): 'window' keeps the whole
   f32 chain on the flat [runs*k, H] layout."""
   if RUN_SOFTMAX_IMPL == 'window':
     f, k, h = e.shape
@@ -189,11 +189,9 @@ def _masked_run_mean(vals, mask, axis=1):
 
 
 def _impl_from_env(var: str, default: str, allowed) -> str:
-  """Flat-layout decision machinery: the measured default below can be
-  overridden per run (GLT_RUN_MEAN_IMPL / GLT_RUN_SOFTMAX_IMPL) — the
-  deployment-side half of bench.py's ``run_mean_impl_decision`` key,
-  which records the A/B winner so the next round can flip the default
-  here with a one-line, evidence-linked change."""
+  """An implementation choice whose default can be overridden per run
+  by the environment variable ``var`` (GLT_RUN_SOFTMAX_IMPL), so an A/B
+  on the chip needs no edit; a value outside ``allowed`` raises."""
   import os
   v = os.environ.get(var, '').strip()
   if not v:
@@ -203,64 +201,27 @@ def _impl_from_env(var: str, default: str, allowed) -> str:
   return v
 
 
-def run_impl_decision(reshape_ms, window_ms, rel_margin: float = 0.03):
-  """The auto-land rule shared by bench.py's RUN_MEAN_IMPL A/B section:
-  'window' wins only on a > ``rel_margin`` relative improvement (a
-  within-noise tie keeps the incumbent 'reshape', the measured round-4
-  configuration). Returns (decision, evidence-string); None inputs
-  (a failed leg) return (None, reason)."""
-  if reshape_ms is None or window_ms is None:
-    return None, 'undecided: missing ' + (
-        'both legs' if reshape_ms is None and window_ms is None else
-        ('reshape leg' if reshape_ms is None else 'window leg'))
-  if window_ms < reshape_ms * (1.0 - rel_margin):
-    return 'window', (f'window {window_ms:.3f} ms beats reshape '
-                      f'{reshape_ms:.3f} ms by >{rel_margin:.0%}')
-  return 'reshape', (f'reshape {reshape_ms:.3f} ms holds (window '
-                     f'{window_ms:.3f} ms, margin {rel_margin:.0%})')
-
-
-# Run-aggregation implementation of the SLICE-fed tree convs' mean
-# (TreeSAGEConv, TreeHeteroConv._sage_et), whose children are a
-# contiguous f-major slice of the node buffer: the tree layout gives the
-# order. 'reshape' (default): reduce over axis 1 of a [runs, k, F] view
-# — k (15/10/5) lands on the sublane axis and is padded to 16/16/8, so
-# on TPU the view is a physical relayout, forward and backward (on the
-# merge path it read glt.train/fwd_bwd:reshape 2.24 ms a step in
-# sage-products.scan-exact; PERF_LEDGER.jsonl, PR 30). 'window': keep
-# the flat [runs*k, F] layout and reduce k-runs with lax.reduce_window —
-# forward only: equal to 'reshape' there (tested), but it has no reverse
-# mode under jax 0.9 as written and trained wrongly on the chip when
-# given one (PERF.md section 6, PR 31). The merge convs reach their
-# children through an index and gather k-major (_gathered_run_mean),
-# consulting no fork; this one waits for a tree cell (ROADMAP.md D4).
-RUN_MEAN_IMPL = _impl_from_env('GLT_RUN_MEAN_IMPL', 'reshape',
-                               ('reshape', 'window'))
-
-# Same fork for the dense GAT convs' run softmax (TreeGATConv /
-# MergeGATConv): the f32 [runs, k, H] softmax chain carries the same
-# never-tile-aligned k as the mean kernels, and the round-4 trace left a
-# ~1 ms/step tail of softmax-backward transposed layouts. 'window' runs
-# the whole chain (leaky_relu -> per-run max -> exp -> per-run sum ->
-# normalize) on the FLAT [runs*k, H] layout with lax.reduce_window
-# reductions — the further flat-layout rewrite of ISSUE 13(c);
-# equivalence-tested under both, A/B'd by prof_copytax --softmax-ab.
+# Run-aggregation implementation of the dense GAT convs' run softmax
+# (TreeGATConv / MergeGATConv): the f32 [runs, k, H] softmax chain
+# carries a k (15/10/5) that is never tile-aligned. 'reshape' (default)
+# reduces over axis 1 of the [runs, k, H] view; 'window' runs the whole
+# chain (leaky_relu -> per-run max -> exp -> per-run sum -> normalize)
+# on the FLAT [runs*k, H] layout with lax.reduce_window reductions.
+# Forward and jitted gradient are equivalence-tested under both
+# (tests/test_models.py); not measured on the chip — the A/B is
+# ROADMAP.md S1's.
 RUN_SOFTMAX_IMPL = _impl_from_env('GLT_RUN_SOFTMAX_IMPL', 'reshape',
                                   ('reshape', 'window'))
 
 
 def _masked_flat_run_mean(x, mask, k):
   """Masked mean over k-runs of a FLAT [f*k, F] block with a [f, k]
-  mask, dispatching on RUN_MEAN_IMPL (see above)."""
-  f = mask.shape[0]
-  if RUN_MEAN_IMPL == 'window':
-    xz = jnp.where(mask.reshape(-1)[:, None], x,
-                   jnp.zeros((), x.dtype))
-    s = jax.lax.reduce_window(xz, jnp.zeros((), x.dtype), jax.lax.add,
-                              (k, 1), (k, 1), 'VALID')
-    inv = (1.0 / jnp.maximum(mask.sum(1), 1)).astype(x.dtype)
-    return s * inv[:, None]
-  return _masked_run_mean(x.reshape(f, k, -1), mask)
+  mask: the slice-fed tree convs' aggregation (TreeSAGEConv,
+  TreeHeteroConv._sage_et), whose children's order is given by the tree
+  layout. What a k-major layout of the tree blocks would buy waits for
+  ``sage-products.scan-tree``; the flat ``reduce_window`` form's reading
+  is in PERF.md section 6, PR 31."""
+  return _masked_run_mean(x.reshape(mask.shape[0], k, -1), mask)
 
 
 def _gathered_run_mean(x, src, mask, k):

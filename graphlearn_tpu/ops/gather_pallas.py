@@ -28,9 +28,9 @@ the v1 single-row copy (random ids degrade to exactly v1 + the sort).
 The unsort back to caller order is one more payload sort + a [B, F]
 row permutation; callers whose ids are ALREADY sorted-unique (the
 tiered-storage staging planner, searchsorted slab gathers) pass
-``presorted=True`` and skip both. Autotune grid (block_rows, run_span)
-probed by benchmarks/prof_gather2.py; routing stays evidence-gated
-behind ``UnifiedTensor.use_pallas_v2`` exactly like v1's ``use_pallas``.
+``presorted=True`` and skip both. The autotune grid (block_rows,
+run_span) is tune/tuner.py's; not measured on the chip (ROADMAP.md
+S5), so routing stays evidence-gated behind ``UnifiedTensor.use_pallas_v2`` exactly like v1's ``use_pallas``.
 
 Calling one of these ops IS asking for the kernel. Off-TPU they fall back
 to the bit-identical `jnp.take` (same clamped-id contract) so CPU tests
@@ -131,7 +131,7 @@ def gather_rows_hbm(table, ids, block_rows: int = 128,
       Device-trace truth on v5e-1 (1M x 128 f32 table, 131k random ids):
       best config 1.41 ms/call at 128/256 vs XLA take's 1.20 ms — XLA's
       gather wins on this chip, so callers opt in explicitly
-      (UnifiedTensor.use_pallas) — see benchmarks/prof_gather.py.
+      (UnifiedTensor.use_pallas) — rounds 2-20, PERF.md section 6.
     interpret: run the Pallas interpreter (CPU tests).
     force: run the kernel even off-TPU (AOT lowering checks); default
       falls back to jnp.take when the backend isn't TPU.

@@ -9,7 +9,7 @@ globally-unique local index; which duplicate "wins" is unspecified (the
 reference takes atomicCAS first-writer; this engine takes the
 first-in-flat-order occurrence).
 
-Why sorts: on TPU (v5e device-trace, benchmarks/prof_dedup.py) random
+Why sorts: on TPU (v5e device trace, rounds 2-20; PERF.md section 6) random
 element scatters/gathers run at ~140-200 M transactions/s regardless of
 table size — HBM-transaction-bound, so the [N]-table engine's 6 random
 ops/hop cost ~30 ms/batch at products scale. A key+payload `lax.sort` of
@@ -72,8 +72,8 @@ def _seg_fill(vals: jax.Array, flags: jax.Array) -> jax.Array:
 
   Implemented as THREE packed cummaxes instead of an associative scan:
   the scan's log-depth slice/concat cascade lowers to ~40 small XLA ops
-  per call (~1 ms/batch of pure op overhead at products scale, measured
-  in the bench trace), while a cummax is one fused op. Packing rides the
+  per call (~1 ms/batch of pure op overhead at products scale, rounds
+  2-20), while a cummax is one fused op. Packing rides the
   group rank in the high bits — cummax then always selects the CURRENT
   group's value — with the payload split into 3 bytes so everything
   fits int32: group rank < 2^23, values in [0, 2^24). Positions before

@@ -243,8 +243,8 @@ def sample_hop_fused(indptr, indices, blocks128, seeds, seed_mask, k: int,
     blocks128: :func:`build_indices128` aligned view (None is only
       accepted off-TPU, where the XLA twin runs anyway).
     seeds/seed_mask/k/key/meta: exactly :func:`ops.uniform_sample`.
-    window: staged segment span per seed (multiple of 128; autotune axis
-      probed by benchmarks/prof_gather2.py). Seeds with deg > window
+    window: staged segment span per seed (multiple of 128; an autotune
+      axis of tune/tuner.py). Seeds with deg > window
       take the per-sample row-DMA path — never a whole-batch fallback.
     block_seeds: seeds per grid step.
     interpret: run the Pallas interpreter (CPU parity tests).

@@ -441,8 +441,8 @@ def _fused_homo_fn(fanouts, caps, node_cap, with_edge, weighted, mode,
         num_sampled_nodes=nodes_per_hop, num_sampled_edges=edges_per_hop,
         seed_inverse=inv, overflow=overflow)
 
-  # distinguishable per-mode trace name (bench.py keys device-trace
-  # events by the jitted program name); '_capped' marks a clamped
+  # distinguishable per-mode trace name (a device trace keys events
+  # by the jitted program name); '_capped' marks a clamped
   # (budget/frontier_caps) capacity plan
   full = True
   for i, k in enumerate(fanouts):

@@ -26,8 +26,8 @@ serving impedance mismatch. ``ServingEngine`` resolves it the TPU way
     the table.
 
 Instrumented end to end through the PR 6 registry: per-request
-``serving.queue_wait_ms`` / ``serving.total_ms`` histograms (the
-p50/p99 the bench gate tracks), per-batch ``serving.batch_fill`` /
+``serving.queue_wait_ms`` / ``serving.total_ms`` histograms,
+per-batch ``serving.batch_fill`` /
 ``serving.compute_ms``, and ``serving.requests`` / ``serving.batches``
 / ``serving.refreshed`` counters. The remote entry point
 (``DistServer.serve``) is read-only and idempotent, so clients retry it

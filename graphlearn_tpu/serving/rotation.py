@@ -23,8 +23,7 @@ Version lifecycle (docs/serving.md):
      a lookup snapshots the shard tuple exactly once, so every request
      is answered from a SINGLE consistent version — no torn reads
      across the swap, ever. The critical section's duration is the
-     ``serving.rotation_swap_ms`` histogram (``rotation_swap_ms_p99``
-     in bench.py).
+     ``serving.rotation_swap_ms`` histogram.
   3. **Degrade.** A failed shard swap (or build) discards the partial
      version and KEEPS the previous version serving — in-flight and
      subsequent requests see v, none fail. Disk retention is ONE

@@ -30,9 +30,8 @@ this trainer runs the SAME epoch-as-a-program over a
   bit-identically with a ``storage.stage`` fault armed.
 
 Sampling runs twice per epoch (once id-only in the plan, once in the
-chunks) — the price of an exact plan with zero extra dispatches; the
-oversubscription gate (bench.py 'oversub' section, ROADMAP item 2)
-bounds the total at ~1.5x the all-HBM epoch wall.
+chunks) — the price of an exact plan with zero extra dispatches. What
+it costs against the all-HBM epoch is not measured on the chip.
 """
 from typing import Optional
 

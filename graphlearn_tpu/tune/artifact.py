@@ -47,7 +47,7 @@ _V1_CHOICE_KEYS = frozenset({
 
 #: the kernel-routing knobs added in schema version 2 (docs/tuning.md
 #: 'Kernel candidates'): which Pallas fast paths the observatory A/Bs
-#: selected, and their grid points (benchmarks/prof_gather2.py space)
+#: selected, and their grid points (tuner.GATHER2_GRID_* space)
 KERNEL_CHOICE_KEYS = frozenset({
     'use_pallas_v2', 'gather2_block_rows', 'gather2_run_span',
     'use_fused_hop', 'fused_hop_window',

@@ -168,14 +168,14 @@ def epoch_steps(num_seeds: int, batch_size: int,
 
 def wire_dtype_choice(exact: bool) -> Tuple[Optional[str], dict]:
   """bf16 wire is certified semantics-free for FEATURE payloads by the
-  accuracy matrix (benchmarks/accuracy_matrix.py: precision delta
-  only, bounded by bf16 rounding of inputs) — chosen unless the caller
+  accuracy matrix (rounds 2-20, CPU: precision delta only, bounded by
+  bf16 rounding of inputs; not measured on the chip) — chosen unless the caller
   pinned the exact set."""
   value = None if exact else 'bf16'
   evidence = dict(
       knob='wire_dtype', probe='accuracy_matrix',
       value=value,
       note=('exact=True pins full-width f32 wire' if exact else
-            'bf16 feature wire: accuracy-matrix-certified relaxation '
-            '(benchmarks/accuracy_matrix.py)'))
+            'bf16 feature wire: accuracy-matrix-certified '
+            'relaxation'))
   return value, evidence

@@ -22,11 +22,11 @@ already trusts:
    ``COST_TIE_MARGIN``) break on XLA cost attribution (flops, then
    peak HBM) — on CPU replicas, where device wall is a weak signal,
    the cost tie-break is the sharper lens.
-3. **Semantics**: the accuracy matrix (benchmarks/accuracy_matrix.py)
-   certifies which relaxations are exact-equivalent. ``exact=True``
-   pins the exact set — calibrated exact dedup, f32 wire — and only
-   A/Bs within it; the default also fields the certified relaxations
-   (tree dedup, bf16 wire).
+3. **Semantics**: the accuracy matrix (rounds 2-20, CPU; PERF.md
+   section 6) certifies which relaxations are exact-equivalent.
+   ``exact=True`` pins the exact set — calibrated exact dedup, f32
+   wire — and only A/Bs within it; the default also fields the
+   certified relaxations (tree dedup, bf16 wire).
 
 The result is a :class:`~graphlearn_tpu.tune.artifact.TuneArtifact`
 (JSON on disk via ``out_path=``) that the trainer / serving
@@ -52,14 +52,13 @@ COST_TIE_MARGIN = 0.05
 #: the population the "one executable per site" acceptance counts
 CANDIDATE_SITES = ('epoch_seeds', 'scan_chunk', 'metrics_concat')
 
-#: the gather-v2 autotune space (benchmarks/prof_gather2.py's full
-#: grid) the kernel candidate field draws its grid points from —
-#: a point outside the profiled space would be an unmeasured claim
+#: the gather-v2 autotune space the kernel candidate field draws its
+#: grid points from — a point outside it would be an unmeasured claim
 GATHER2_GRID_BLOCKS = (64, 128, 256, 512)
 GATHER2_GRID_SPANS = (1, 4, 8, 16, 32)
 
 #: default kernel-routing grid points fielded per base candidate
-#: (docs/tuning.md 'Kernel candidates'): the prof_gather2 default
+#: (docs/tuning.md 'Kernel candidates'): UnifiedTensor's default
 #: (256, 8) plus the small-block point that wins on short runs
 DEFAULT_GATHER2_POINTS = ((256, 8), (128, 4))
 
@@ -122,7 +121,7 @@ def kernel_candidates(base: Candidate,
   """Kernel-routing variants of ``base`` (docs/tuning.md 'Kernel
   candidates'): the fused sample+gather hop kernel at each window, and
   the run-segmented DMA gather v2 at each (block_rows, run_span) grid
-  point from the prof_gather2 autotune space. Every variant is
+  point from the GATHER2_GRID_* autotune space. Every variant is
   bit-identical to ``base`` (the kernels' parity contract), so
   ``exact_semantics`` carries over — only the program route differs,
   which is exactly what the observatory A/B measures. Off-TPU the
@@ -148,8 +147,7 @@ def kernel_candidates(base: Candidate,
     if br not in GATHER2_GRID_BLOCKS or rs not in GATHER2_GRID_SPANS:
       raise ValueError(
           f'gather2 grid point ({br}, {rs}) is outside the profiled '
-          f'autotune space {GATHER2_GRID_BLOCKS} x {GATHER2_GRID_SPANS} '
-          '(benchmarks/prof_gather2.py)')
+          f'autotune space {GATHER2_GRID_BLOCKS} x {GATHER2_GRID_SPANS}')
     out.append(Candidate(
         f'{base.name}+gather2_b{br}r{rs}', base.loader_kwargs,
         chunk_k=base.chunk_k, exact_semantics=base.exact_semantics,

@@ -127,10 +127,11 @@ def device_op_ms(trace_dir: str, top: int = 0, steps: int = 1,
 # ---------------------------------------------------------------- dispatch
 # Dispatch counting: every program launch costs host time the device may
 # idle through, so the loaders/trainers instrument their dispatch sites
-# and tests/bench.py assert & report dispatches/epoch. The counter is a
-# host-side convention — every hot-path program launch in this package
-# calls record_dispatch() right before dispatching — which makes it
-# exact for the instrumented paths and free (one None check) otherwise.
+# and the tests and perfbench/ assert & report dispatches/epoch. The
+# counter is a host-side convention — every hot-path program launch in
+# this package calls record_dispatch() right before dispatching — which
+# makes it exact for the instrumented paths and free (one None check)
+# otherwise.
 
 
 class DispatchCounter:

@@ -22,29 +22,26 @@ echo "== graftlint =="
 python -m graphlearn_tpu.analysis.lint graphlearn_tpu/ || rc=1
 
 echo "== graftlint (bench profile) =="
-# relaxed profile over the benchmark tier: the registry rules, bracket
-# discipline and donation safety stay enforced — a benchmark that
-# leaks spans or reads donated buffers measures garbage — while the
-# hot-path scoping rules (host-sync/dispatch/prng/retrace/lock) are
-# exempt: benchmarks host-sync on purpose and probe shapes off the
-# ladder. The registry modules ride along so the name checks see the
-# REGISTERED_* frozensets.
+# relaxed profile over the benchmark (perfbench/) and the chip
+# bring-up check: the registry rules, bracket discipline and donation
+# safety stay enforced — a benchmark that leaks spans or reads donated
+# buffers measures garbage — while the hot-path scoping rules
+# (host-sync/dispatch/prng/retrace/lock) are exempt: a benchmark
+# host-syncs on purpose. The registry modules ride along so the name
+# checks see the REGISTERED_* frozensets.
 python -m graphlearn_tpu.analysis.lint --profile bench --no-baseline \
-  benchmarks/ bench.py \
+  perfbench/ chip_smoke.py \
   graphlearn_tpu/metrics/registry_names.py \
   graphlearn_tpu/utils/faults.py || rc=1
 
 echo "== ruff =="
 if python -m ruff --version >/dev/null 2>&1; then
-  python -m ruff check graphlearn_tpu/ tests/ bench.py || rc=1
+  python -m ruff check graphlearn_tpu/ tests/ || rc=1
 elif command -v ruff >/dev/null 2>&1; then
-  ruff check graphlearn_tpu/ tests/ bench.py || rc=1
+  ruff check graphlearn_tpu/ tests/ || rc=1
 else
   echo "ruff not installed — skipping (config lives in pyproject.toml)"
 fi
-
-echo "== bench schema =="
-python bench.py --validate || rc=1
 
 echo "== flight/span JSONL schema =="
 # with no args this SELF-CHECKS: one record through each real recorder
@@ -53,11 +50,5 @@ echo "== flight/span JSONL schema =="
 # Pass file paths to validate captured GLT_RUN_LOG / GLT_SPAN_LOG
 # trails from a run.
 python -m graphlearn_tpu.metrics.logcheck || rc=1
-
-echo "== bench trajectory gate =="
-# >20% round-over-round regression on a declared lower-is-better key
-# (BENCH_LOWER_IS_BETTER) fails the gate; rounds without numbers are
-# skipped, so a round without numbers never masks or fakes a regression
-python bench.py --gate || rc=1
 
 exit "$rc"

@@ -4,7 +4,7 @@
 # tests/conftest.py forces the CPU backend with 8 virtual devices.
 set -euo pipefail
 cd "$(dirname "$0")/.."
-# static gate first: graftlint + ruff + bench schema (seconds, no jax) —
+# static gate first: graftlint + ruff + log schema (seconds, no jax) —
 # a hot-path invariant violation fails the run before any test runs
 bash scripts/lint.sh
 python -m pytest tests/ -q "$@"

@@ -12,8 +12,6 @@ Fixture files live in tmp_path (no package __init__), so their
 package-relative path is just the basename; Config module patterns here
 name fixtures by that basename.
 """
-import importlib.util
-import json
 import os
 import subprocess
 import sys
@@ -727,65 +725,3 @@ class TestStrictGuards:
     with strict_guards():
       out = jnp.arange(4.0) + np.arange(4.0)
     assert np.allclose(np.asarray(out), np.arange(4.0) * 2)
-
-
-# ------------------------------------------------------------- bench schema
-
-def _bench():
-  spec = importlib.util.spec_from_file_location(
-      'bench_for_validate', os.path.join(REPO, 'bench.py'))
-  mod = importlib.util.module_from_spec(spec)
-  spec.loader.exec_module(mod)
-  return mod
-
-
-class TestBenchValidate:
-
-  def test_good_record_passes(self):
-    bench = _bench()
-    rec = {'metric': 'sampled_edges_per_sec', 'value': 1.0,
-           'unit': 'M edges/s', 'vs_baseline': 0.5,
-           'epoch_dispatches': 6, 'dist_scan_epoch_wall_s': 2.0}
-    assert bench.validate_bench_record(rec) == []
-
-  def test_unknown_and_missing_keys_flagged(self):
-    bench = _bench()
-    rec = {'metric': 'm', 'value': 1, 'unit': 'u',
-           'epoch_dispatchs': 6}   # typo'd key, missing vs_baseline
-    problems = bench.validate_bench_record(rec)
-    assert any('epoch_dispatchs' in p for p in problems)
-    assert any("missing required key 'vs_baseline'" in p
-               for p in problems)
-
-  def test_error_section_keys_allowed(self):
-    bench = _bench()
-    rec = {'metric': 'm', 'value': None, 'unit': 'u',
-           'vs_baseline': None, 'scan_epoch_error': 'boom',
-           'run_mean_impl_reshape_ms_error': 'vjp assert'}
-    assert bench.validate_bench_record(rec) == []
-
-  def test_saved_bench_files_validate(self, tmp_path):
-    # --validate over saved records: a raw record, the driver's wrapper
-    # form, and a wrapper whose run produced no parseable line
-    bench = _bench()
-    rec = {'metric': 'sampled_edges_per_sec', 'value': 1.0,
-           'unit': 'M edges/s', 'vs_baseline': 0.5,
-           'map_device_ms_per_batch': 5.0}
-    saved = {'BENCH_r01.json': rec,
-             'BENCH_r02.json': {'parsed': rec, 'rc': 0},
-             'BENCH_r03.json': {'parsed': None, 'rc': 1}}
-    for name, body in saved.items():
-      (tmp_path / name).write_text(json.dumps(body))
-    paths = sorted(str(tmp_path / name) for name in saved)
-    assert bench.validate_bench_files(paths) == 0
-    (tmp_path / 'BENCH_r04.json').write_text(
-        json.dumps(dict(rec, map_device_ms_per_batsh=5.0)))
-    assert bench.validate_bench_files(
-        paths + [str(tmp_path / 'BENCH_r04.json')]) == 1
-
-  def test_cli_validate_flag(self):
-    proc = subprocess.run(
-        [sys.executable, 'bench.py', '--validate'],
-        cwd=REPO, capture_output=True, text=True, timeout=120)
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert 'problem(s)' in proc.stdout

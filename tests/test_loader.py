@@ -393,57 +393,6 @@ def test_frontier_caps_auto_link_loader():
   assert steps == len(loader)
 
 
-@pytest.mark.slow  # tier-1 budget (PR 18): overlapped variant of the
-# overflow guard — test_scan_trainer_overflow_guard stays tier-1
-def test_overlapped_trainer_overflow_guard():
-  """OverlappedTrainer enforces the calibrated-caps guard: the flag
-  accumulates on device through the fused program and the loader's
-  overflow_policy fires at epoch end; a max_steps break leaves the
-  verdict to check_overflow(); 'recompute' is refused at construction
-  (it would need a per-batch host sync, defeating the overlap)."""
-  import jax
-  import pytest
-  from graphlearn_tpu.models import train as train_lib
-  rng = np.random.default_rng(5)
-  n = 64
-  rows = np.repeat(np.arange(n), 3)
-  cols = (rows + rng.integers(1, n, rows.shape[0])) % n
-  ds = glt.data.Dataset()
-  ds.init_graph(np.stack([rows, cols]), graph_mode='CPU', num_nodes=n)
-  ds.init_node_features(rng.standard_normal((n, 4)).astype(np.float32))
-  ds.init_node_labels(np.arange(n) % 3)
-  mk = lambda **kw: glt.loader.NeighborLoader(
-      ds, [2, 2], np.arange(16), batch_size=4, shuffle=False, seed=0,
-      dedup='merge', **kw)
-
-  def trainer_for(loader):
-    import optax
-    model = glt.models.GraphSAGE(hidden_dim=8, out_dim=3, num_layers=2)
-    first = train_lib.batch_to_dict(next(iter(mk(overflow_policy='off'))))
-    state, tx = train_lib.create_train_state(model, jax.random.PRNGKey(0),
-                                             first)
-    return glt.loader.OverlappedTrainer(loader, model, tx, 3), state
-
-  # overflowing caps + default 'raise' -> epoch-end error
-  tr, state = trainer_for(mk(frontier_caps=[1, 1]))
-  with pytest.raises(RuntimeError, match='frontier_caps overflowed'):
-    tr.run_epoch(state)
-
-  # max_steps break forfeits the automatic raise; check_overflow stays
-  # honest (mirrors the plain loader's early-exit semantics)
-  tr, state = trainer_for(mk(frontier_caps=[1, 1]))
-  state, _ = tr.run_epoch(state, max_steps=1)
-  assert tr.loader.check_overflow()
-
-  # calibrated caps stay quiet under the default policy; losses flow
-  tr, state = trainer_for(mk(frontier_caps='auto'))
-  state, losses = tr.run_epoch(state)
-  assert len(losses) > 0 and np.isfinite(float(losses[0]))
-
-  with pytest.raises(ValueError, match='recompute'):
-    trainer_for(mk(frontier_caps=[1, 1], overflow_policy='recompute'))
-
-
 def test_frontier_caps_auto_hetero_rejected():
   """frontier_caps='auto' on a hetero dataset fails with the sampler's
   clear homogeneous-only contract, not an AttributeError inside

@@ -8,7 +8,6 @@ epoch's ceil(steps/K)+2 budget holds with recording on, under
 GLT_STRICT); (2) a remote-server + mp-producer run scrapes a merged,
 role-labelled snapshot at the client, retry-safe under the
 fault-injection registry."""
-import importlib.util
 import json
 import os
 import threading
@@ -722,83 +721,3 @@ def test_span_rule_pragma_and_package_clean(tmp_path):
   pkg = os.path.join(REPO, 'graphlearn_tpu')
   findings, *_ = run_lint([pkg], Config())
   assert [f for f in findings if f.rule == 'span-registry'] == []
-
-
-# ------------------------------------------------- bench trajectory gate
-
-
-def _bench():
-  spec = importlib.util.spec_from_file_location(
-      'bench_for_gate', os.path.join(REPO, 'bench.py'))
-  mod = importlib.util.module_from_spec(spec)
-  spec.loader.exec_module(mod)
-  return mod
-
-
-def _write_rounds(tmp_path, *records):
-  paths = []
-  for i, rec in enumerate(records):
-    p = tmp_path / f'BENCH_r{i + 1:02d}.json'
-    p.write_text(json.dumps(rec))
-    paths.append(str(p))
-  return paths
-
-
-def test_bench_gate_passes_and_fails(tmp_path, capsys):
-  bench = _bench()
-  base = {'metric': 'sampled_edges_per_sec', 'value': 80.0,
-          'unit': 'M edges/s', 'vs_baseline': 2.0}
-  # improvement + small wiggle: pass
-  paths = _write_rounds(
-      tmp_path,
-      dict(base, train_step_ms_bf16=30.0, epoch_dispatches=26),
-      dict(base, train_step_ms_bf16=28.0, epoch_dispatches=27))
-  assert bench.gate_bench_files(paths) == 0
-  # >20% regression on a lower-is-better key: fail, named in output
-  paths = _write_rounds(
-      tmp_path,
-      dict(base, train_step_ms_bf16=30.0),
-      dict(base, train_step_ms_bf16=37.0))
-  assert bench.gate_bench_files(paths) == 1
-  out = capsys.readouterr().out
-  assert 'REGRESSION train_step_ms_bf16' in out
-  assert '1.23x' in out
-
-
-def test_bench_gate_skips_failed_rounds_and_wrappers(tmp_path):
-  bench = _bench()
-  base = {'metric': 'sampled_edges_per_sec', 'value': 1.0,
-          'unit': 'M edges/s', 'vs_baseline': 0.1}
-  good_old = dict(base, train_step_ms_bf16=30.0)
-  wrapper = {'parsed': dict(base, train_step_ms_bf16=31.0), 'rc': 0}
-  failed = {'parsed': None, 'rc': 1}
-  p1 = tmp_path / 'BENCH_r01.json'
-  p1.write_text(json.dumps(good_old))
-  p2 = tmp_path / 'BENCH_r02.json'
-  p2.write_text(json.dumps(wrapper))       # driver wrapper: unwrapped
-  p3 = tmp_path / 'BENCH_r03.json'
-  p3.write_text(json.dumps(failed))        # round without numbers: skipped
-  assert bench.gate_bench_files([str(p1), str(p2), str(p3)]) == 0
-  # a 30 -> 40 regression hidden behind the failed round still catches
-  p4 = tmp_path / 'BENCH_r04.json'
-  p4.write_text(json.dumps(dict(base, train_step_ms_bf16=40.0)))
-  assert bench.gate_bench_files([str(p1), str(p2), str(p3),
-                                 str(p4)]) == 1
-  # nothing parseable at all: pass with a notice, never crash
-  assert bench.gate_bench_files([str(p3)]) == 0
-
-
-def test_bench_gate_saved_trajectory(tmp_path):
-  """A saved multi-round history passes the gate the way scripts/lint.sh
-  runs it, and every gated key is a registered one."""
-  bench = _bench()
-  base = {'metric': 'sampled_edges_per_sec', 'unit': 'M edges/s',
-          'vs_baseline': 1.0}
-  paths = _write_rounds(
-      tmp_path,
-      dict(base, value=40.0, map_device_ms_per_batch=18.0),
-      {'parsed': dict(base, value=80.0, map_device_ms_per_batch=5.1,
-                      train_step_ms_bf16=32.8), 'rc': 0},
-      {'parsed': None, 'rc': 1})
-  assert bench.gate_bench_files(paths) == 0
-  assert bench.BENCH_LOWER_IS_BETTER <= set(bench.BENCH_KEY_REGISTRY)

@@ -873,60 +873,6 @@ def test_merge_dense_hetero_matches_segment(use_caps):
                                  rtol=2e-4, atol=2e-4)
 
 
-def test_flat_run_mean_window_impl_matches():
-  """The flat reduce_window run-mean (RUN_MEAN_IMPL='window') is
-  numerically identical to the reshape kernel, at the kernel level and
-  through a full tree_dense forward — so the copy-tax A/B
-  (benchmarks/prof_copytax.py) compares layouts, not semantics."""
-  import jax
-  import jax.numpy as jnp
-  from graphlearn_tpu.models import models as M
-  rng = np.random.default_rng(0)
-  f, k, fd = 37, 5, 16
-  x = rng.standard_normal((f * k, fd)).astype(np.float32)
-  m = rng.random((f, k)) < 0.7
-  ref = np.asarray(M._masked_flat_run_mean(jnp.asarray(x),
-                                           jnp.asarray(m), k))
-  assert M.RUN_MEAN_IMPL == 'reshape'
-  try:
-    M.RUN_MEAN_IMPL = 'window'
-    win = np.asarray(M._masked_flat_run_mean(jnp.asarray(x),
-                                             jnp.asarray(m), k))
-  finally:
-    M.RUN_MEAN_IMPL = 'reshape'
-  np.testing.assert_allclose(ref, win, rtol=1e-6, atol=1e-6)
-
-  # end-to-end: a tree_dense forward under both impls
-  rng = np.random.default_rng(3)
-  n = 150
-  ds = glt.data.Dataset()
-  ds.init_graph(np.stack([rng.integers(0, n, 1200),
-                          rng.integers(0, n, 1200)]),
-                num_nodes=n, graph_mode='CPU')
-  ds.init_node_features(rng.standard_normal((n, 8)).astype(np.float32))
-  ds.init_node_labels(rng.integers(0, 3, n))
-  loader = glt.loader.NeighborLoader(ds, [3, 2], np.arange(16),
-                                     batch_size=8, seed=0, dedup='tree')
-  b = next(iter(loader))
-  from graphlearn_tpu.models import train as train_lib
-  bd = train_lib.batch_to_dict(b)
-  no, eo = train_lib.tree_hop_offsets(8, [3, 2])
-  model = glt.models.GraphSAGE(hidden_dim=8, out_dim=3, num_layers=2,
-                               hop_node_offsets=no, hop_edge_offsets=eo,
-                               tree_dense=True, fanouts=(3, 2))
-  params = model.init(jax.random.PRNGKey(0), bd['x'], bd['edge_index'],
-                      bd['edge_mask'])
-  o_ref = np.asarray(model.apply(params, bd['x'], bd['edge_index'],
-                                 bd['edge_mask']))
-  try:
-    M.RUN_MEAN_IMPL = 'window'
-    o_win = np.asarray(model.apply(params, bd['x'], bd['edge_index'],
-                                   bd['edge_mask']))
-  finally:
-    M.RUN_MEAN_IMPL = 'reshape'
-  np.testing.assert_allclose(o_ref, o_win, rtol=1e-5, atol=1e-5)
-
-
 def _run_mean_case(f, k, fd=16, n=53):
   """Rows table, flat f-major src with -1 padding and a [f, k] mask that
   holds an all-masked run, a short run (degree < k) and a full run."""
@@ -971,6 +917,41 @@ def test_gathered_run_mean_matches_reshape(k, f):
   g_new = jax.grad(lambda x: (new(x) * w).sum())(x)
   np.testing.assert_allclose(np.asarray(g_new), np.asarray(g_ref),
                              rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize('k', [5, 10, 15])
+def test_flat_run_mean_grad_matches_segment(k):
+  """jit(grad) of the slice-fed tree convs' run mean w.r.t. the rows
+  equals a segment mean's, with an all-masked and a short run: the
+  backward of TreeSAGEConv / TreeHeteroConv._sage_et, which a
+  forward-only equivalence test does not see."""
+  import jax
+  import jax.numpy as jnp
+  from graphlearn_tpu.models import models as M
+  f = 37
+  x, src, m = _run_mean_case(f, k)
+  rows = jnp.asarray(x[np.maximum(src, 0)])           # flat [f*k, F]
+  m = jnp.asarray(m)
+  w = jnp.asarray(np.random.default_rng(2).standard_normal(
+      (f, x.shape[1])).astype(np.float32))
+  seg = jnp.repeat(jnp.arange(f), k)
+
+  def ref(rows):
+    mf = m.reshape(-1).astype(rows.dtype)
+    s = jax.ops.segment_sum(rows * mf[:, None], seg, f)
+    cnt = jnp.maximum(jax.ops.segment_sum(mf, seg, f), 1)
+    return (s / cnt[:, None] * w).sum()
+
+  def new(rows):
+    return (M._masked_flat_run_mean(rows, m, k) * w).sum()
+
+  np.testing.assert_allclose(float(jax.jit(new)(rows)),
+                             float(jax.jit(ref)(rows)), rtol=1e-5)
+  g_ref = np.asarray(jax.jit(jax.grad(ref))(rows))
+  g_new = np.asarray(jax.jit(jax.grad(new))(rows))
+  assert not g_new[:k].any()          # the all-masked run
+  assert g_new[k:k + 2].any() and not g_new[k + 2:2 * k].any()  # short
+  np.testing.assert_allclose(g_new, g_ref, rtol=1e-5, atol=1e-6)
 
 
 def _jaxpr_shapes(jaxpr):
@@ -1023,8 +1004,8 @@ def test_flat_run_softmax_window_impl_matches():
   """The flat reduce_window run-softmax (RUN_SOFTMAX_IMPL='window' —
   ISSUE 13's further flat-layout rewrite) matches the reshape kernel at
   the kernel level (all-masked runs and very-negative logits included)
-  and through full TreeGATConv / MergeGATConv forwards, so the
-  prof_copytax --softmax-ab trace compares layouts, not semantics."""
+  and through a full TreeGATConv forward, so an A/B of the two legs
+  compares layouts, not semantics."""
   import jax
   import jax.numpy as jnp
   from graphlearn_tpu.models import models as M
@@ -1076,18 +1057,47 @@ def test_flat_run_softmax_window_impl_matches():
   np.testing.assert_allclose(o_ref, o_win, rtol=1e-5, atol=1e-5)
 
 
-def test_run_impl_decision_rule():
-  """bench.py's auto-land rule (models.run_impl_decision): 'window'
-  needs a > margin win, ties and missing legs keep/record honestly."""
-  from graphlearn_tpu.models.models import run_impl_decision
-  assert run_impl_decision(10.0, 9.0)[0] == 'window'
-  assert run_impl_decision(10.0, 9.9)[0] == 'reshape'     # within noise
-  assert run_impl_decision(10.0, 10.5)[0] == 'reshape'
-  dec, why = run_impl_decision(None, 9.0)
-  assert dec is None and 'reshape leg' in why
-  dec, why = run_impl_decision(10.0, None)
-  assert dec is None and 'window leg' in why
-  assert run_impl_decision(None, None)[0] is None
+@pytest.mark.parametrize('k', [5, 10])
+@pytest.mark.parametrize('impl', ['reshape', 'window'])
+def test_run_softmax_grad_matches_segment(impl, k, monkeypatch):
+  """jit(grad) of the run softmax w.r.t. the logits, under both
+  RUN_SOFTMAX_IMPL legs, equals a segment softmax's (segment max / sum
+  over the valid slots): the all-masked run takes no gradient and the
+  underflow-prone run keeps a finite one — what an A/B of the two legs
+  needs before it reads ``correct`` on the chip."""
+  import jax
+  import jax.numpy as jnp
+  from graphlearn_tpu.models import models as M
+  rng = np.random.default_rng(7 + k)
+  f, h = 23, 2
+  e = rng.standard_normal((f, k, h)).astype(np.float32) * 10
+  e[3] -= 200.0                       # underflow-prone run
+  m = rng.random((f, k)) < 0.6
+  m[3, 0] = m[3, 1] = True
+  m[5] = False                        # all-masked run
+  w = jnp.asarray(rng.standard_normal((f, k, h)).astype(np.float32))
+  e, m = jnp.asarray(e), jnp.asarray(m)
+  seg = jnp.repeat(jnp.arange(f), k)
+
+  def ref(e):
+    le = jax.nn.leaky_relu(e.reshape(f * k, h), 0.2)
+    mf = m.reshape(f * k)[:, None]
+    mx = jax.ops.segment_max(jnp.where(mf, le, -jnp.inf), seg, f)
+    ex = jnp.where(mf, jnp.exp(le - jnp.where(jnp.isfinite(mx), mx,
+                                              0.0)[seg]), 0.0)
+    den = jnp.maximum(jax.ops.segment_sum(ex, seg, f), 1e-9)
+    return ((ex / den[seg]).reshape(f, k, h) * w).sum()
+
+  def new(e):
+    return (M._masked_run_softmax(e, m, jnp.float32, 0.2) * w).sum()
+
+  g_ref = np.asarray(jax.jit(jax.grad(ref))(e))
+  monkeypatch.setattr(M, 'RUN_SOFTMAX_IMPL', impl)
+  g_new = np.asarray(jax.jit(jax.grad(new))(e))
+  assert np.isfinite(g_new).all()
+  assert not g_new[5].any()           # the all-masked run
+  assert g_new[3][np.asarray(m)[3]].any()   # the underflow-prone run
+  np.testing.assert_allclose(g_new, g_ref, rtol=1e-5, atol=1e-6)
 
 
 @pytest.mark.slow  # tier-1 budget (PR 19): HGT parity stays tier-1 via

@@ -241,8 +241,8 @@ def test_remote_scan_vs_collocated_contract():
   within the remote pair (asserted above — their streams are the same
   counter replay); the collocated mesh samples a different (equally
   exact) stream, so its leg pins the epoch CONTRACT: steps, coverage,
-  finite losses. The wall-clock leg (remote within ~1.3x of
-  collocated) is measured in bench.py's remote_scan section."""
+  finite losses. The wall-clock leg (remote against collocated) is not
+  measured on the chip."""
   import jax
   from graphlearn_tpu.typing import GraphPartitionData
   n = 40

@@ -183,7 +183,7 @@ def test_typed_epoch_dispatch_and_retrace_budget():
 
 def test_typed_scan_refusals_keep_their_messages():
   """What the local scanned epoch does not take, it refuses by name:
-  with_edge batches, seeds of no one node type, and the executors whose
+  with_edge batches, seeds of no one node type, and the executor whose
   loop keeps the homogeneous key stream."""
   import optax
 
@@ -196,15 +196,14 @@ def test_typed_scan_refusals_keep_their_messages():
       dedup='merge')
   with pytest.raises(ValueError, match='with_edge batches are not'):
     glt.loader.ScanTrainer(with_edge, model, tx, CLASSES)
-  for cls in (glt.loader.OverlappedTrainer, glt.loader.RunTrainer):
-    with pytest.raises(CapacityPlanError, match='loader.ScanTrainer'):
-      cls(make(), model, tx, CLASSES)
+  with pytest.raises(CapacityPlanError, match='loader.ScanTrainer'):
+    glt.loader.RunTrainer(make(), model, tx, CLASSES)
   # a typed loader is refused by name only where it is typed: the same
-  # trainer classes still build over a homogeneous loader
+  # trainer class still builds over a homogeneous loader
   from test_scan_epoch import _make_loader, make_dataset
   from graphlearn_tpu.models import GraphSAGE
   homo = GraphSAGE(hidden_dim=8, out_dim=3, num_layers=2)
-  glt.loader.OverlappedTrainer(_make_loader(make_dataset(), 16), homo, tx, 3)
+  glt.loader.RunTrainer(_make_loader(make_dataset(), 16), homo, tx, 3)
 
 
 def _typed_chunk_text():
