@@ -103,37 +103,57 @@ def _tree_blocks(node_offsets, fanouts, n_rows):
   return blocks, eo
 
 
-def _masked_run_softmax(e, mask, out_dtype, negative_slope):
-  """Per-run masked attention softmax over axis 1 of [runs, k, H]
-  logits — the shared kernel of the dense-run GAT convs (TreeGATConv /
-  MergeGATConv): leaky_relu, mask to -inf, TRUE per-run max
-  stabilization (clamping at 0 would underflow exp when every valid
-  logit is very negative — the same stabilization GATConv's segment
-  softmax uses; all-masked runs fall back to 0), exp, denom floor.
-  Dispatches on RUN_SOFTMAX_IMPL (see below): 'window' keeps the whole
-  f32 chain on the flat [runs*k, H] layout."""
-  if RUN_SOFTMAX_IMPL == 'window':
-    f, k, h = e.shape
-    ef = nn.leaky_relu(e.reshape(f * k, h), negative_slope)
-    mf = mask.reshape(f * k)
-    ef = jnp.where(mf[:, None], ef, -jnp.inf)
-    mx = jax.lax.reduce_window(ef, -jnp.inf, jax.lax.max, (k, 1), (k, 1),
-                               'VALID')                          # [f, h]
-    mx = jnp.where(jnp.isfinite(mx), mx, 0.0)
-    ex = jnp.where(mf[:, None],
-                   jnp.exp(ef - jnp.repeat(mx, k, axis=0)), 0.0)
-    denom = jnp.maximum(
-        jax.lax.reduce_window(ex, 0.0, jax.lax.add, (k, 1), (k, 1),
-                              'VALID'), 1e-9)
-    return (ex / jnp.repeat(denom, k, axis=0)).reshape(
-        f, k, h).astype(out_dtype)
+def _masked_run_softmax(e, mask, out_dtype, negative_slope, axis=1):
+  """Per-run masked attention softmax over the run axis of [runs, k, H]
+  logits ([runs, k] mask; ``axis=0``: k-major [k, runs, H] logits and a
+  [k, runs] mask, where the run's max, exp and sum are element-wise over
+  k aligned slabs) — the shared kernel of the dense-run GAT convs:
+  leaky_relu, mask to -inf, TRUE per-run max stabilization (clamping at 0
+  would underflow exp when every valid logit is very negative — the same
+  stabilization GATConv's segment softmax uses; all-masked runs fall
+  back to 0), exp, denom floor."""
   e = nn.leaky_relu(e, negative_slope)
   e = jnp.where(mask[..., None], e, -jnp.inf)
-  mx = e.max(axis=1, keepdims=True)
+  mx = e.max(axis=axis, keepdims=True)
   e = e - jnp.where(jnp.isfinite(mx), mx, 0.0)
   ex = jnp.where(mask[..., None], jnp.exp(e), 0.0)
-  denom = jnp.maximum(ex.sum(axis=1, keepdims=True), 1e-9)
+  denom = jnp.maximum(ex.sum(axis=axis, keepdims=True), 1e-9)
   return (ex / denom).astype(out_dtype)
+
+
+def _head_lanes(heads, hd, dtype):
+  """The 0/1 [H, H*D] matrix whose row h is 1 on head h's D lanes of a
+  flat [.., H*D] row. A product with it (or its transpose) widens [.., H]
+  per-head weights to whole rows, or sums a row per head — the flat
+  512-wide lane axis is never split into (H, D), which would put H = 4
+  on the sublane axis of a T(4,128) tile and re-tile every row. Built
+  from iotas: no literal in the traced program."""
+  lane_head = jnp.arange(heads * hd, dtype=jnp.int32) // hd
+  return (lane_head[None, :] ==
+          jnp.arange(heads, dtype=jnp.int32)[:, None]).astype(dtype)
+
+
+def _head_alphas(w, a, heads, hd):
+  """Per-head attention logits' halves ``<w[.., h, :], a[h, :]>`` of flat
+  rows ``w`` [.., H*D] against an attention vector ``a`` [H, D] ->
+  float32 [.., H]: the rows times the flat vector, summed per head by the
+  head matrix (f32 accumulation) — no [.., H, D] view of ``w``."""
+  return jnp.dot(w.astype(jnp.float32) * a.reshape(heads * hd),
+                 _head_lanes(heads, hd, jnp.float32).T,
+                 preferred_element_type=jnp.float32)
+
+
+def _attend_runs(msgs, e, mask, heads, hd, negative_slope):
+  """Attention-weighted sum of k-MAJOR runs: messages ``msgs``
+  [k, f, H*D], logits ``e`` [k, f, H], mask [k, f] -> [f, H*D]. The
+  softmax is element-wise over the k slabs; its [k, f, H] weights are
+  widened to whole rows by the 0/1 head matrix (exact at ``highest``:
+  every product is a weight times 1 or 0), so no [.., H, D] tensor of
+  gathered messages exists, forward or backward."""
+  attn = _masked_run_softmax(e, mask, msgs.dtype, negative_slope, axis=0)
+  wide = jnp.dot(attn, _head_lanes(heads, hd, attn.dtype),
+                 precision=jax.lax.Precision.HIGHEST)
+  return (msgs * wide).sum(axis=0)
 
 
 # One record of a typed merge batch can hold a million edge slots; its
@@ -146,15 +166,19 @@ _RUN_BLOCK_SLOTS = 1 << 16
 
 def _gat_runs(w_res, a_src_res, a_par, m, src, heads, hd, negative_slope):
   """Attention-weighted sum over each k-run: children gathered through
-  ``src`` from the projected rows ``w_res`` [n, H*D] and their alphas
-  ``a_src_res`` [n, H], parents' alphas ``a_par`` [f, H], mask ``m``
-  [f, k] -> [f, H*D]."""
+  the flat f-major index ``src`` [f*k] from the projected rows ``w_res``
+  [n, H*D] and their alphas ``a_src_res`` [n, H], parents' alphas
+  ``a_par`` [f, H], mask ``m`` [f, k] -> [f, H*D].
+
+  Children that come through an index are gathered k-MAJOR (as
+  ``_gathered_run_mean`` does): slot j of every run is a contiguous
+  [f, H*D] slab, ``[k, f, H*D]`` and ``[k, f, H]`` are free views of the
+  two gathers, and ``_attend_runs`` never leaves whole rows."""
   f, k = m.shape
-  wch = w_res[src]
-  e = a_src_res[src].reshape(f, k, heads) + a_par[:, None, :]
-  attn = _masked_run_softmax(e, m, wch.dtype, negative_slope)
-  msgs = wch.reshape(f, k, heads, hd)
-  return (msgs * attn[..., None]).sum(axis=1).reshape(f, heads * hd)
+  src_km = src.reshape(f, k).T.reshape(-1)
+  e = a_src_res[src_km].reshape(k, f, heads) + a_par[None]
+  return _attend_runs(w_res[src_km].reshape(k, f, heads * hd), e, m.T,
+                      heads, hd, negative_slope)
 
 
 def _gat_runs_blocked(w_res, a_src_res, a_par, m, src, heads, hd,
@@ -186,32 +210,6 @@ def _masked_run_mean(vals, mask, axis=1):
   s = jnp.where(mask[..., None], vals, jnp.zeros((), vals.dtype)).sum(axis)
   inv = (1.0 / jnp.maximum(mask.sum(axis), 1)).astype(vals.dtype)
   return s * inv[:, None]
-
-
-def _impl_from_env(var: str, default: str, allowed) -> str:
-  """An implementation choice whose default can be overridden per run
-  by the environment variable ``var`` (GLT_RUN_SOFTMAX_IMPL), so an A/B
-  on the chip needs no edit; a value outside ``allowed`` raises."""
-  import os
-  v = os.environ.get(var, '').strip()
-  if not v:
-    return default
-  if v not in allowed:
-    raise ValueError(f'{var}={v!r}: expected one of {sorted(allowed)}')
-  return v
-
-
-# Run-aggregation implementation of the dense GAT convs' run softmax
-# (TreeGATConv / MergeGATConv): the f32 [runs, k, H] softmax chain
-# carries a k (15/10/5) that is never tile-aligned. 'reshape' (default)
-# reduces over axis 1 of the [runs, k, H] view; 'window' runs the whole
-# chain (leaky_relu -> per-run max -> exp -> per-run sum -> normalize)
-# on the FLAT [runs*k, H] layout with lax.reduce_window reductions.
-# Forward and jitted gradient are equivalence-tested under both
-# (tests/test_models.py); not measured on the chip — the A/B is
-# ROADMAP.md S1's.
-RUN_SOFTMAX_IMPL = _impl_from_env('GLT_RUN_SOFTMAX_IMPL', 'reshape',
-                                  ('reshape', 'window'))
 
 
 def _masked_flat_run_mean(x, mask, k):
@@ -428,10 +426,10 @@ class MergeGATConv(nn.Module):
   set is exactly its contiguous k-run in the hop that expanded it —
   GAT's segment softmax (scatter-max + scatter-sum per layer, the most
   scatter-bound op in the model zoo, PERF.md) becomes a masked softmax
-  over the ``[frontier, k]`` reshape plus one frontier-sized row
-  scatter per hop. Numerically matches ``GATConv`` on merge batches
-  (same param names: ``lin``/``att_src``/``att_dst``), calibrated caps
-  included.
+  over the k aligned ``[frontier, H*D]`` slabs of a k-major gather
+  (``_attend_runs``) plus one frontier-sized block write per hop.
+  Numerically matches ``GATConv`` on merge batches (same param names:
+  ``lin``/``att_src``/``att_dst``), calibrated caps included.
   """
   out_dim: int
   edge_offsets: Any
@@ -446,23 +444,24 @@ class MergeGATConv(nn.Module):
     if self.dtype is not None:
       x = x.astype(self.dtype)
     n, heads, hd = x.shape[0], self.heads, self.out_dim
-    # w stays FLAT [n, heads*hd]: gathering (and the backward's
-    # scatter-add) on 2D rows keeps XLA's standard T(8,128) layout —
-    # gathering the [n, H, D] reshape instead puts the whole
-    # grad-accumulation on a T(2,128)-tiled 3D layout that costs ~4x
-    # (device-trace: 29 of a 42 ms backward, round 4)
+    # w stays FLAT [n, heads*hd], and so does everything gathered from
+    # it: a hop's children are gathered k-MAJOR, [k, f, H*D] is a free
+    # view of the gathered block, and the per-head sums and weights go
+    # through the 0/1 head matrix (_head_lanes) — no [.., H, D] view of
+    # gathered rows exists (H = 4 on the sublane axis of a T(4,128) tile
+    # re-tiled every row forward and backward: PERF.md section 6, PR 34;
+    # gathering the [n, H, D] reshape itself cost ~4x, round 4)
     w = nn.Dense(heads * hd, use_bias=False, dtype=self.dtype,
                  name='lin')(x)
     a_src = self.param('att_src', nn.initializers.glorot_uniform(),
                        (heads, hd))
     a_dst = self.param('att_dst', nn.initializers.glorot_uniform(),
                        (heads, hd))
-    # dst-alphas over the node buffer (f32 accumulation on the MXU);
+    # dst-alphas over the node buffer (f32 accumulation);
     # src-alphas are computed from the GATHERED messages below — random
     # HBM gathers are transaction-bound (~150M rows/s, PERF.md), so one
     # [width]-row gather per hop is the whole random-access budget
-    alpha_dst = jnp.einsum('nhd,hd->nh', w.reshape(n, heads, hd), a_dst,
-                           preferred_element_type=jnp.float32)
+    alpha_dst = _head_alphas(w, a_dst, heads, hd)
     row, col = edge_index[0], edge_index[1]
     # merge-layout structure: hop i's valid runs target the CONTIGUOUS
     # block the inducer appended for them (frontier_idx = count +
@@ -487,12 +486,11 @@ class MergeGATConv(nn.Module):
                                                                  ).max(1)
       m = jax.lax.dynamic_slice_in_dim(edge_mask, e0, width
                                        ).reshape(f, k)
-      msgs = w[src]                                # the one gather, 2D
-      msgs4 = msgs.reshape(f, k, heads, hd)
-      e = (jnp.einsum('fkhd,hd->fkh', msgs4.astype(jnp.float32), a_src) +
-           alpha_dst[jnp.maximum(tgt, 0)][:, None, :])
-      attn = _masked_run_softmax(e, m, w.dtype, self.negative_slope)
-      outv = (msgs4 * attn[..., None]).sum(axis=1)  # [f, H, D]
+      # the one gather, 2D and k-major: slot j of every run is a slab
+      msgs = w[src.reshape(f, k).T.reshape(-1)].reshape(k, f, heads * hd)
+      e = (_head_alphas(msgs, a_src, heads, hd) +
+           alpha_dst[jnp.maximum(tgt, 0)][None])            # [k, f, H]
+      outv = _attend_runs(msgs, e, m.T, heads, hd, self.negative_slope)
       ok = m.any(1) & (tgt >= 0)
       # block base from tgt[j] - j (invariant across valid runs): a
       # zero-degree frontier node's run has ALL edges masked, so its
@@ -500,7 +498,7 @@ class MergeGATConv(nn.Module):
       # when such runs lead the block
       base = jnp.min(jnp.where(
           ok, tgt - jnp.arange(f, dtype=tgt.dtype), n)).astype(jnp.int32)
-      vals = jnp.where(ok[:, None], outv.reshape(f, heads * hd), 0)
+      vals = jnp.where(ok[:, None], outv, 0)
       acc = jax.lax.dynamic_update_slice(acc, vals, (base, 0))
       e0 = e1
     if self.concat:
@@ -1038,15 +1036,13 @@ class TreeHeteroConv(nn.Module):
       x, k = promote_dtype(x, kernel, dtype=self.dtype)
       return jax.lax.dot_general(x, k, (((x.ndim - 1,), (0,)), ((), ())))
 
-    alpha = lambda wt, a: jnp.einsum(
-        'nhd,hd->nh', wt.reshape(-1, heads, hd), a,
-        preferred_element_type=jnp.float32)
     # sorted: the order of a set of strings differs between processes,
     # and with it the traced program and its compile-cache key
     w = {t: lin(x_dict[t]) for t in sorted(res_ts)}
-    alpha_src = {t: alpha(w[t], a_src) for t in sorted(res_ts)}
+    alpha_src = {t: _head_alphas(w[t], a_src, heads, hd)
+                 for t in sorted(res_ts)}
     w_key = w[key_t] if key_t in w else lin(x_dict[key_t][:n_key])
-    return w, alpha_src, alpha(w_key[:n_key], a_dst)
+    return w, alpha_src, _head_alphas(w_key[:n_key], a_dst, heads, hd)
 
   def _sage_et(self, et, x_dict, edge_mask_dict, rows):
     ename = '__'.join(et)

@@ -5,14 +5,21 @@ section 2.3), no chip minute spent, no time or rate comes out of it:
 
     TPU_ACCELERATOR_TYPE=v5litepod-4 TPU_WORKER_HOSTNAMES=localhost \
     TPU_SKIP_MDS_QUERY=true JAX_PLATFORMS=cpu \
-    python scripts/lower_typed_cell.py [reference] [chunk]
+    python scripts/lower_typed_cell.py [reference] [chunk] [gat]
 
 ``reference``: ``perfbench/reference_hetero_node.py``'s step at the shapes of
 ``rgat-igbh-small.typed-scan-exact`` (it runs on an emptied device: arguments
 + temporaries under 9 GB). ``chunk``: ``ScanTrainer``'s
 ``jit_scan_epoch_chunk`` over the typed loader with every table an argument
 at the cell's size (``peak`` under 15.75 GiB, the tables counted once; the
-compile itself refuses a program that does not fit).
+compile itself refuses a program that does not fit). ``gat``: the same chunk
+program of ``gat-products.scan-exact``. Both chunk modes also print what a
+start-up pays for the program before its first step: seconds to trace and to
+lower it here, the characters, lines and constants of its StableHLO text, the
+compiler's ``generated_code`` bytes and, where this compile-only client can
+serialise an executable, the bytes a warm start reads from the compile cache
+(PERF.md section 6, PR 34: the gate a change to the model passes before its
+first chip call).
 
 The batch's static shapes come from the cell's calibrated caps, which need
 the dataset: ``CAPS`` are the ones a chip run of the cell printed on its
@@ -22,12 +29,15 @@ import json
 import math
 import os
 import sys
+import time
 
 os.environ.setdefault('TPU_LOG_DIR', 'disabled')
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 CELL = 'rgat-igbh-small.typed-scan-exact'
+GAT_CELL = 'gat-products.scan-exact'
+GAT_CAPS = [6528, 33664, 84736]   # that cell's set-up line (PERF.md section 4)
 CAPS = {
     'author__affiliated_to__institute': [128, 1920, 7040],
     'author__rev_written_by__paper': [128, 18304, 69120],
@@ -56,6 +66,57 @@ def describe():
   topo = topologies.get_topology_desc(platform='tpu',
                                       topology_name='v5e:2x2')
   return SingleDeviceSharding(topo.devices[0])
+
+
+def serialized_bytes(compiled):
+  """Bytes of the executable as the compile cache stores it, or why this
+  client cannot say."""
+  try:
+    from jax.experimental import serialize_executable
+    return len(serialize_executable.serialize(compiled)[0])
+  except Exception as e:   # a compile-only client may refuse: say so
+    return f'{type(e).__name__}: {str(e)[:120]}'
+
+
+def compile_timed(name, jitted, args):
+  """Trace, lower and compile ``jitted`` apart, and print what start-up
+  pays for the program (seconds here are this sandbox's CPU: counts to
+  compare parent and change by, not device numbers)."""
+  t0 = time.perf_counter()
+  traced = jitted.trace(*args)
+  t1 = time.perf_counter()
+  lowered = traced.lower()
+  t2 = time.perf_counter()
+  compiled = lowered.compile()
+  t3 = time.perf_counter()
+  text = lowered.as_text()
+  out = report(name, compiled)
+  cost = compiled.cost_analysis() or {}
+  size = dict(program=name, trace_s=round(t1 - t0, 2),
+              lower_s=round(t2 - t1, 2), compile_s=round(t3 - t2, 2),
+              stablehlo_chars=len(text), stablehlo_lines=text.count('\n'),
+              stablehlo_constants=text.count('stablehlo.constant'),
+              generated_code=out['generated_code'],
+              serialized_bytes=serialized_bytes(compiled),
+              bytes_accessed=cost.get('bytes accessed'))
+  print('lower_typed_cell: ' + json.dumps(size), flush=True)
+  return out
+
+
+def compile_chunk(name, tr, state, tables, steps, batch, k, one_chip):
+  """Compile ``tr``'s ``k``-step chunk program as an epoch of ``steps``
+  batches calls it, with ``tables`` = (sample args, feature tables,
+  id-to-index maps, labels) as shapes at the cell's size."""
+  import jax
+  import jax.numpy as jnp
+  sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+  spec = lambda tree: jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+  chunk = getattr(tr._chunk_fn, '_glt_instrumented', tr._chunk_fn)
+  return compile_timed(
+      name, jax.jit(lambda *a: chunk(*a, k), donate_argnums=(0, 1)),
+      (spec(state), sds((), jnp.bool_), *tables,
+       sds((steps, batch), jnp.int32), sds((steps, batch), jnp.bool_),
+       spec(tr._sampler._key), sds((), jnp.int32), sds((), jnp.int32)))
 
 
 def report(name, compiled):
@@ -166,7 +227,6 @@ def lower_chunk(cfg, traffic, one_chip):
                        chunk_size=int(traffic['chunk_size']))
   sds = lambda shape, dt: jax.ShapeDtypeStruct(tuple(shape), dt,
                                                sharding=one_chip)
-  spec = lambda tree: jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
   n_of = real['node_types']
   e_of = {}
   for name, rel in real['relations'].items():
@@ -187,29 +247,68 @@ def lower_chunk(cfg, traffic, one_chip):
   steps = real['num_train'] // cell.batch
   tables = sum(jnp.dtype(a.dtype).itemsize * math.prod(a.shape)
                for a in jax.tree.leaves((fargs, feats, id2i, labels)))
-  chunk = getattr(tr._chunk_fn, '_glt_instrumented', tr._chunk_fn)
-  k = int(traffic['chunk_size'])
-  compiled = jax.jit(lambda *a: chunk(*a, k), donate_argnums=(0, 1)).lower(
-      spec(state), sds((), jnp.bool_), fargs, feats, id2i, labels,
-      sds((steps, cell.batch), jnp.int32),
-      sds((steps, cell.batch), jnp.bool_), spec(tr._sampler._key),
-      sds((), jnp.int32), sds((), jnp.int32)).compile()
-  out = report('jit_scan_epoch_chunk', compiled)
+  out = compile_chunk('jit_scan_epoch_chunk', tr, state,
+                      (fargs, feats, id2i, labels), steps, cell.batch,
+                      int(traffic['chunk_size']), one_chip)
   print('lower_typed_cell: ' + json.dumps(dict(
       tables_bytes=tables, peak_gib=(out['peak'] or 0) / 2 ** 30,
       chip_gib=15.75)), flush=True)
   return out
 
 
+def lower_gat_chunk(cfg, traffic, one_chip):
+  """``gat-products.scan-exact``'s chunk program, by ``lower_chunk``'s
+  method: traced over a graph 1/64 the size under the cell's caps, lowered
+  with every table at the cell's size."""
+  import copy
+
+  import jax
+
+  import graphlearn_tpu as glt
+  from perfbench.families import homo_node
+  real = cfg['dataset']
+  small = copy.deepcopy(cfg)
+  d = small['dataset']
+  d['num_nodes'] //= 64
+  d['num_directed_edges'] //= 64
+  d['num_train'] = 8 * small['model']['batch_size']
+  calibrate = glt.sampler.estimate_frontier_caps
+  glt.sampler.estimate_frontier_caps = lambda *a, **kw: GAT_CAPS
+  try:
+    cell = homo_node.Cell(small, traffic, lambda k, v: None)
+  finally:
+    glt.sampler.estimate_frontier_caps = calibrate
+  model = cell.make_model(None)
+  state, tx, _ = cell.make_state(model, 0)
+  tr = glt.ScanTrainer(cell.make_loader(0), model, tx, cell.num_classes,
+                       chunk_size=int(traffic['chunk_size']))
+  # a table's leading axis is the graph's nodes (+ 1) or its edges
+  lead = {d['num_nodes']: real['num_nodes'],
+          d['num_nodes'] + 1: real['num_nodes'] + 1,
+          d['num_directed_edges']: real['num_directed_edges']}
+  table = lambda tree: jax.tree.map(
+      lambda a: jax.ShapeDtypeStruct((lead[a.shape[0]],) + a.shape[1:],
+                                     a.dtype, sharding=one_chip), tree)
+  return compile_chunk(
+      'jit_scan_epoch_chunk[gat-products]', tr, state,
+      table((tr._sample_args(), tr._feats, tr._id2i, tr._labels)),
+      real['num_train'] // cell.batch, cell.batch,
+      int(traffic['chunk_size']), one_chip)
+
+
 def main(argv):
   from perfbench import run
   which = argv or ['reference']
-  _, _, cfg, traffic, _ = run.load_cell(CELL, 'BENCHMARK.json')
   one_chip = describe()
-  if 'reference' in which:
-    lower_reference(cfg, one_chip)
-  if 'chunk' in which:
-    lower_chunk(cfg, traffic, one_chip)
+  if 'reference' in which or 'chunk' in which:
+    _, _, cfg, traffic, _ = run.load_cell(CELL, 'BENCHMARK.json')
+    if 'reference' in which:
+      lower_reference(cfg, one_chip)
+    if 'chunk' in which:
+      lower_chunk(cfg, traffic, one_chip)
+  if 'gat' in which:
+    _, _, cfg, traffic, _ = run.load_cell(GAT_CELL, 'BENCHMARK.json')
+    lower_gat_chunk(cfg, traffic, one_chip)
 
 
 if __name__ == '__main__':

@@ -1000,104 +1000,177 @@ def test_merge_sage_conv_holds_no_run_view():
     assert k_major == {(5, 16, fd), (3, 80, fd)}
 
 
-def test_flat_run_softmax_window_impl_matches():
-  """The flat reduce_window run-softmax (RUN_SOFTMAX_IMPL='window' —
-  ISSUE 13's further flat-layout rewrite) matches the reshape kernel at
-  the kernel level (all-masked runs and very-negative logits included)
-  and through a full TreeGATConv forward, so an A/B of the two legs
-  compares layouts, not semantics."""
-  import jax
-  import jax.numpy as jnp
-  from graphlearn_tpu.models import models as M
-  rng = np.random.default_rng(0)
-  f, k, h = 23, 5, 2
-  e = rng.standard_normal((f, k, h)).astype(np.float32) * 10
-  e[3] -= 200.0                       # underflow-prone run
-  m = rng.random((f, k)) < 0.6
-  m[5] = False                        # all-masked run
-  ref = np.asarray(M._masked_run_softmax(jnp.asarray(e), jnp.asarray(m),
-                                         jnp.float32, 0.2))
-  assert M.RUN_SOFTMAX_IMPL == 'reshape'
-  try:
-    M.RUN_SOFTMAX_IMPL = 'window'
-    win = np.asarray(M._masked_run_softmax(jnp.asarray(e),
-                                           jnp.asarray(m),
-                                           jnp.float32, 0.2))
-  finally:
-    M.RUN_SOFTMAX_IMPL = 'reshape'
-  np.testing.assert_allclose(ref, win, rtol=1e-6, atol=1e-6)
-
-  # end-to-end: tree GAT forward under both impls, same params
-  rng = np.random.default_rng(4)
-  n = 150
-  ds = glt.data.Dataset()
-  ds.init_graph(np.stack([rng.integers(0, n, 1200),
-                          rng.integers(0, n, 1200)]),
-                num_nodes=n, graph_mode='CPU')
-  ds.init_node_features(rng.standard_normal((n, 8)).astype(np.float32))
-  ds.init_node_labels(rng.integers(0, 3, n))
-  loader = glt.loader.NeighborLoader(ds, [3, 2], np.arange(16),
-                                     batch_size=8, seed=0, dedup='tree')
-  from graphlearn_tpu.models import train as train_lib
-  bd = train_lib.batch_to_dict(next(iter(loader)))
-  no, eo = train_lib.tree_hop_offsets(8, [3, 2])
-  model = glt.models.GAT(hidden_dim=8, out_dim=3, num_layers=2, heads=2,
-                         hop_node_offsets=no, hop_edge_offsets=eo,
-                         tree_dense=True, fanouts=(3, 2))
-  params = model.init(jax.random.PRNGKey(0), bd['x'], bd['edge_index'],
-                      bd['edge_mask'])
-  o_ref = np.asarray(model.apply(params, bd['x'], bd['edge_index'],
-                                 bd['edge_mask']))
-  try:
-    M.RUN_SOFTMAX_IMPL = 'window'
-    o_win = np.asarray(model.apply(params, bd['x'], bd['edge_index'],
-                                   bd['edge_mask']))
-  finally:
-    M.RUN_SOFTMAX_IMPL = 'reshape'
-  np.testing.assert_allclose(o_ref, o_win, rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.parametrize('k', [5, 10])
-@pytest.mark.parametrize('impl', ['reshape', 'window'])
-def test_run_softmax_grad_matches_segment(impl, k, monkeypatch):
-  """jit(grad) of the run softmax w.r.t. the logits, under both
-  RUN_SOFTMAX_IMPL legs, equals a segment softmax's (segment max / sum
-  over the valid slots): the all-masked run takes no gradient and the
-  underflow-prone run keeps a finite one — what an A/B of the two legs
-  needs before it reads ``correct`` on the chip."""
-  import jax
-  import jax.numpy as jnp
-  from graphlearn_tpu.models import models as M
-  rng = np.random.default_rng(7 + k)
-  f, h = 23, 2
+def _run_softmax_case(f, k, h, seed):
+  """Logits [f, k, h] and a mask [f, k] with an underflow-prone run (3:
+  every valid logit near -2000 after scaling), an all-masked run (5) and
+  a run of degree 2 < k (7)."""
+  rng = np.random.default_rng(seed)
   e = rng.standard_normal((f, k, h)).astype(np.float32) * 10
   e[3] -= 200.0                       # underflow-prone run
   m = rng.random((f, k)) < 0.6
   m[3, 0] = m[3, 1] = True
   m[5] = False                        # all-masked run
-  w = jnp.asarray(rng.standard_normal((f, k, h)).astype(np.float32))
+  m[7] = np.arange(k) < 2             # short run: degree 2 < k
+  return e, m
+
+
+def _segment_softmax(e, m, seg, f):
+  """Segment softmax over the valid slots of flat [f*k, h] logits: the
+  reference the run kernels replace (GATConv's stabilization)."""
+  import jax
+  import jax.numpy as jnp
+  le = jax.nn.leaky_relu(e, 0.2)
+  mf = m[:, None]
+  mx = jax.ops.segment_max(jnp.where(mf, le, -jnp.inf), seg, f)
+  ex = jnp.where(mf, jnp.exp(le - jnp.where(jnp.isfinite(mx), mx,
+                                            0.0)[seg]), 0.0)
+  den = jnp.maximum(jax.ops.segment_sum(ex, seg, f), 1e-9)
+  return ex / den[seg]
+
+
+@pytest.mark.parametrize('k', [5, 10, 15])
+@pytest.mark.parametrize('axis', [1, 0])
+def test_run_softmax_grad_matches_segment(axis, k):
+  """jit(grad) of the run softmax w.r.t. the logits — runs on axis 1
+  (the slice-fed tree convs) and k-major on axis 0 (the merge convs) —
+  equals a segment softmax's (segment max / sum over the valid slots):
+  the all-masked run takes no gradient and the underflow-prone run keeps
+  a finite one."""
+  import jax
+  import jax.numpy as jnp
+  from graphlearn_tpu.models import models as M
+  f, h = 23, 2
+  e, m = _run_softmax_case(f, k, h, 7 + k)
+  w = jnp.asarray(np.random.default_rng(k).standard_normal(
+      (f, k, h)).astype(np.float32))
   e, m = jnp.asarray(e), jnp.asarray(m)
   seg = jnp.repeat(jnp.arange(f), k)
 
   def ref(e):
-    le = jax.nn.leaky_relu(e.reshape(f * k, h), 0.2)
-    mf = m.reshape(f * k)[:, None]
-    mx = jax.ops.segment_max(jnp.where(mf, le, -jnp.inf), seg, f)
-    ex = jnp.where(mf, jnp.exp(le - jnp.where(jnp.isfinite(mx), mx,
-                                              0.0)[seg]), 0.0)
-    den = jnp.maximum(jax.ops.segment_sum(ex, seg, f), 1e-9)
-    return ((ex / den[seg]).reshape(f, k, h) * w).sum()
+    return (_segment_softmax(e.reshape(f * k, h), m.reshape(f * k), seg,
+                             f).reshape(f, k, h) * w).sum()
 
   def new(e):
-    return (M._masked_run_softmax(e, m, jnp.float32, 0.2) * w).sum()
+    if axis == 0:   # the same runs, k-major
+      a = M._masked_run_softmax(e.transpose(1, 0, 2), m.T, jnp.float32,
+                                0.2, axis=0).transpose(1, 0, 2)
+    else:
+      a = M._masked_run_softmax(e, m, jnp.float32, 0.2)
+    return (a * w).sum()
 
+  np.testing.assert_allclose(float(jax.jit(new)(e)), float(jax.jit(ref)(e)),
+                             rtol=1e-5)
   g_ref = np.asarray(jax.jit(jax.grad(ref))(e))
-  monkeypatch.setattr(M, 'RUN_SOFTMAX_IMPL', impl)
   g_new = np.asarray(jax.jit(jax.grad(new))(e))
   assert np.isfinite(g_new).all()
   assert not g_new[5].any()           # the all-masked run
   assert g_new[3][np.asarray(m)[3]].any()   # the underflow-prone run
+  assert not g_new[7, 2:].any()       # the short run's masked slots
   np.testing.assert_allclose(g_new, g_ref, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize('k', [5, 10, 15])
+def test_run_softmax_axis0_equals_axis1(k):
+  """The k-major run softmax is the axis-1 one on transposed operands, to
+  the bit: max, exp and sum run over the same k values in the same
+  order, whichever axis holds them."""
+  import jax.numpy as jnp
+  from graphlearn_tpu.models import models as M
+  e, m = _run_softmax_case(23, k, 4, 11 + k)
+  e, m = jnp.asarray(e), jnp.asarray(m)
+  a1 = np.asarray(M._masked_run_softmax(e, m, jnp.float32, 0.2))
+  a0 = np.asarray(M._masked_run_softmax(e.transpose(1, 0, 2), m.T,
+                                        jnp.float32, 0.2, axis=0))
+  np.testing.assert_array_equal(a0.transpose(1, 0, 2), a1)
+  assert not a1[5].any()                        # all-masked: weights 0
+  np.testing.assert_allclose(a1[3].sum(0), 1.0, rtol=1e-6)   # underflow
+  np.testing.assert_allclose(a1[7, :2].sum(0), 1.0, rtol=1e-6)  # short
+
+
+@pytest.mark.parametrize('f', [37, 128])
+@pytest.mark.parametrize('k', [5, 10, 15])
+def test_gat_runs_k_major_matches_segment(k, f):
+  """``_gat_runs`` (children gathered k-major, weights widened by the
+  0/1 head matrix) against a segment-softmax GAT aggregation over the
+  same edges: forward and jit(grad) w.r.t. the projected rows, the alpha
+  table and the parents' alphas, with an underflow-prone, an all-masked
+  and a short run (f = 37 is no multiple of 8)."""
+  import jax
+  import jax.numpy as jnp
+  from graphlearn_tpu.models import models as M
+  heads, hd, n = 4, 8, 61
+  rng = np.random.default_rng(100 + k + f)
+  _, m = _run_softmax_case(f, k, heads, 3 + k)
+  src = rng.integers(0, n, (f, k)).astype(np.int32)
+  w = rng.standard_normal((n, heads * hd)).astype(np.float32)
+  a_src = rng.standard_normal((n, heads)).astype(np.float32) * 10
+  a_par = rng.standard_normal((f, heads)).astype(np.float32) * 10
+  a_par[3] -= 2000.0                  # underflow-prone run
+  cot = jnp.asarray(rng.standard_normal((f, heads * hd)).astype(np.float32))
+  w, a_src, a_par = jnp.asarray(w), jnp.asarray(a_src), jnp.asarray(a_par)
+  src_f, mj = jnp.asarray(src.reshape(-1)), jnp.asarray(m)
+  seg = jnp.repeat(jnp.arange(f), k)
+
+  def ref(w, a_src, a_par):
+    e = a_src[src_f] + a_par[seg]                          # [f*k, H]
+    attn = _segment_softmax(e, mj.reshape(-1), seg, f)
+    msgs = w[src_f].reshape(f * k, heads, hd) * attn[:, :, None]
+    return jax.ops.segment_sum(msgs, seg, f).reshape(f, heads * hd)
+
+  def new(w, a_src, a_par):
+    return M._gat_runs(w, a_src, a_par, mj, src_f, heads, hd, 0.2)
+
+  out_ref = np.asarray(jax.jit(ref)(w, a_src, a_par))
+  out_new = np.asarray(jax.jit(new)(w, a_src, a_par))
+  np.testing.assert_allclose(out_new, out_ref, rtol=1e-5, atol=1e-6)
+  assert not out_new[5].any()         # the all-masked run reads 0
+  assert np.isfinite(out_new).all() and out_new[3].any()   # underflow
+  g_ref = jax.jit(jax.grad(lambda *a: (ref(*a) * cot).sum(), (0, 1, 2)))(
+      w, a_src, a_par)
+  g_new = jax.jit(jax.grad(lambda *a: (new(*a) * cot).sum(), (0, 1, 2)))(
+      w, a_src, a_par)
+  for gn, gr in zip(g_new, g_ref):
+    assert np.isfinite(np.asarray(gn)).all()
+    np.testing.assert_allclose(np.asarray(gn), np.asarray(gr), rtol=1e-4,
+                               atol=1e-5)
+  assert not np.asarray(g_new[2])[5].any()   # all-masked: no gradient
+
+
+def test_merge_gat_conv_holds_no_head_view():
+  """Structural guard: MergeGATConv's forward and backward hold no
+  rank-4 value and no f-major (f, k, .) run view — the [f, k, H, D] view
+  of the gathered messages (H = 4 on the sublane axis of every row) and
+  the [f, k, H] alphas cannot come back unseen. The walker is checked on
+  the old form first."""
+  import jax
+  import jax.numpy as jnp
+  from graphlearn_tpu.models import models as M
+  hops = ((16, 5), (80, 3))         # (f, k): no (f, k) equals a (k, f)
+  n, fd, e, heads, hd = 120, 24, 16 * 5 + 80 * 3, 4, 8
+  x = jnp.ones((n, fd), jnp.float32)
+  ei = jnp.zeros((2, e), jnp.int32)
+  em = jnp.ones((e,), bool)
+  conv = M.MergeGATConv(out_dim=hd, heads=heads, edge_offsets=(80, e),
+                        fanouts=(5, 3))
+  params = conv.init(jax.random.PRNGKey(0), x, ei, em)
+
+  def loss(params, x):
+    return conv.apply(params, x, ei, em).sum()
+
+  def views(fn, *args):
+    shapes = set(_jaxpr_shapes(jax.make_jaxpr(fn)(*args).jaxpr))
+    return ({s for s in shapes if len(s) >= 4},
+            {s for s in shapes if len(s) == 3 and s[:2] in hops})
+
+  def old(w):
+    msgs = w[ei[0, :80]].reshape(16, 5, heads, hd)
+    return (msgs * jnp.ones((16, 5, heads))[..., None]).sum()
+
+  assert views(jax.grad(old), jnp.ones((n, heads * hd)))[0]
+  for fn in (loss, jax.grad(loss, argnums=(0, 1))):
+    rank4, f_major = views(fn, params, x)
+    assert not rank4, rank4
+    assert not f_major, f_major
 
 
 @pytest.mark.slow  # tier-1 budget (PR 19): HGT parity stays tier-1 via
