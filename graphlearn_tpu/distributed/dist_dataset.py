@@ -35,6 +35,46 @@ class DistDataset:
     self.node_feat_pb = node_feat_pb
     self.edge_dir = edge_dir
 
+  @classmethod
+  def from_device_shards(cls, mesh, node_pb, graph: dict, features: dict,
+                         labels=None, edge_dir: str = 'out',
+                         split_ratio: float = 0.0, cache_rows=None,
+                         hotness=None, wire_dtype=None, bucket_frac=2.0):
+    """A homogeneous dataset over shards that already live on their
+    devices (``DistGraph.from_device_shards`` /
+    ``DistFeature.from_device_shards``): a partitioned graph too large
+    to stack in host memory is generated, or loaded shard by shard,
+    straight onto the mesh, and nothing of size N x F or E passes
+    through the host.
+
+    ``graph``: ``row_ids``, ``indptr``, ``indices`` (and optionally
+    ``eids``, ``weights``), each ``[P, ...]`` sharded on its leading
+    axis. ``features``: ``feat_ids`` ``[P, n_max]`` and ``feats``
+    ``[P, n_max, F]``. ``labels``: an ``[P, n_max]`` array in the order
+    of ``feat_ids`` (kept on the devices as a one-column store that
+    shares the feature store's id table and book), or a host ``[N]``
+    array as the constructor takes. ``node_pb`` is the one host array:
+    it routes the features too. ``hotness`` ranks the rows for the hot
+    cache (``split_ratio`` / ``cache_rows``)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from ..utils import global_device_put
+    # one book on the devices for the graph and both stores, not a copy
+    # each (N int32 replicated on every chip)
+    pb_dev = global_device_put(np.asarray(node_pb).astype(np.int32),
+                               NamedSharding(mesh, P()))
+    dg = DistGraph.from_device_shards(mesh, node_pb, edge_dir=edge_dir,
+                                      pb_dev=pb_dev, **graph)
+    df = DistFeature.from_device_shards(
+        mesh, node_pb, features['feat_ids'], features['feats'],
+        split_ratio=split_ratio, cache_rows=cache_rows, hotness=hotness,
+        wire_dtype=wire_dtype, bucket_frac=bucket_frac, pb_dev=pb_dev)
+    if labels is not None and getattr(labels, 'ndim', 1) == 2:
+      labels = DistFeature.from_device_shards(
+          mesh, node_pb, features['feat_ids'], labels[..., None],
+          pb_dev=pb_dev)
+    return cls(dg.num_partitions, 0, dg, df, node_labels=labels,
+               node_feat_pb=np.asarray(node_pb), edge_dir=edge_dir)
+
   def load(self, root_dir: str, mesh=None, node_labels=None,
            edge_dir: str = 'out', feature_dtype=None,
            feature_with_cache: bool = True, split_ratio: float = 0.0,

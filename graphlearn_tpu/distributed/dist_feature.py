@@ -39,7 +39,8 @@ from typing import Optional
 import numpy as np
 
 from .. import ops
-from ..metrics.registry_names import SCOPE_COLLATE
+from ..metrics.registry_names import (SCOPE_CACHE, SCOPE_COLLATE,
+                                      SCOPE_EXCHANGE)
 from ..ops.route import exchange_capacity
 
 INT32_MAX = np.iinfo(np.int32).max
@@ -71,6 +72,70 @@ def feature_exchange_mb(request_width: int, nparts: int, feat_dim: int,
   volumes so byte regressions are visible without a trace."""
   cap = miss_capacity(request_width, nparts, bucket_frac, hit_rate)
   return nparts * cap * (id_bytes + feat_dim * wire_bytes) / 1e6
+
+
+def _hot_ids_fn(h: int):
+  """``DistFeature._hot_ids`` as a program over a device score vector
+  (int32 or float32, no NaN): the ``h`` hottest ids, ascending, ties to
+  the lower id. No sort: the ``h``-th largest score is found by 32
+  halvings over the scores' order-preserving bit patterns, then every id
+  above it and the lowest ids that tie with it are taken in id order (a
+  sort of N keys costs the TPU's compiler half a minute at N = 37 M)."""
+  import jax
+  import jax.numpy as jnp
+  from jax import lax
+
+  def pick(hot):
+    hot = hot.reshape(-1)
+    if jnp.issubdtype(hot.dtype, jnp.floating):
+      # -0.0 ties with 0.0, as it does for the host's sort
+      hot = jnp.where(hot == 0, 0, hot).astype(jnp.float32)
+      bits = lax.bitcast_convert_type(hot, jnp.uint32)
+      key = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+    else:
+      key = lax.bitcast_convert_type(hot.astype(jnp.int32),
+                                     jnp.uint32) ^ jnp.uint32(1 << 31)
+
+    def halve(i, t):
+      # keep the bit where at least h keys still reach the threshold
+      up = t | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
+      return jnp.where(jnp.sum(key >= up) >= h, up, t)
+
+    t = lax.fori_loop(0, 32, halve, jnp.uint32(0))   # h-th largest key
+    above = key > t
+    ties = key == t
+    room = h - jnp.sum(above)
+    take = above | (ties & (jnp.cumsum(ties, dtype=jnp.int32) <= room))
+    return jnp.nonzero(take, size=h)[0].astype(jnp.int32)
+
+  return jax.jit(pick)
+
+
+def _gather_replicated_fn(mesh, dtype):
+  """The program ``(feat_ids [P, n], feats [P, n, F], ids [m]) -> rows
+  [m, F]`` replicated on every device of the mesh, out of row shards on
+  the mesh: each shard looks the ids up in its own sorted id table, and
+  one ``psum`` of the rows' BIT PATTERNS (every shard but the owner adds
+  zero bits, so the sum is the owner's row to the bit, a negative zero
+  included) replicates them."""
+  import jax
+  import jax.numpy as jnp
+  from jax.sharding import PartitionSpec as P
+
+  from ..utils.compat import shard_map
+  ax = tuple(mesh.axis_names)
+  bits = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32}[
+      jnp.dtype(dtype).itemsize]
+
+  def body(fid, f, ids):
+    pos = jnp.clip(jnp.searchsorted(fid[0], ids), 0, fid.shape[1] - 1)
+    found = fid[0][pos] == ids
+    rows = jax.lax.bitcast_convert_type(f[0, pos], bits)
+    rows = jnp.where(found[:, None], rows, jnp.zeros((), bits))
+    return jax.lax.bitcast_convert_type(jax.lax.psum(rows, ax), f.dtype)
+
+  return jax.jit(shard_map(body, mesh=mesh, in_specs=(P(ax), P(ax), P()),
+                           out_specs=P(), check_replication=False))
 
 
 class DistFeature:
@@ -111,6 +176,20 @@ class DistFeature:
     self.feature_pb = np.asarray(feature_pb)
     self.mesh = mesh
     self._init_storage(feat_parts, dtype)
+    self._init_lookup(split_ratio, cache_rows, wire_dtype, bucket_frac,
+                      dedup)
+    h = self.cache_rows
+    if h > 0:
+      self.cache_ids = self._hot_ids(hotness, h)
+      self.cache_feats = self.cpu_get(self.cache_ids)
+    else:
+      self.cache_ids = None
+      self.cache_feats = None
+
+  def _init_lookup(self, split_ratio, cache_rows, wire_dtype, bucket_frac,
+                   dedup):
+    """The lookup's configuration, shared by both constructors: the
+    cache's size and what sizes the miss buckets."""
     self.split_ratio = float(split_ratio)
     self.wire_dtype = wire_dtype
     self.bucket_frac = bucket_frac
@@ -118,29 +197,100 @@ class DistFeature:
     n_total = int(self.feature_pb.shape[0])
     h = int(cache_rows) if cache_rows is not None \
         else int(n_total * self.split_ratio)
-    h = max(0, min(h, n_total))
-    self.cache_rows = h
+    self.cache_rows = max(0, min(h, n_total))
     # hit-rate floor used to size the miss buckets: uniform requests hit
     # at exactly H/N; skewed-to-hot requests (the point of the cache)
     # hit more, so capacities sized on (1 - H/N) only gain slack
-    self._cache_frac = h / n_total if n_total else 0.0
-    if h > 0:
-      if hotness is None:
-        hot_ids = np.arange(h, dtype=np.int64)
-      else:
-        hotness = np.asarray(hotness).reshape(-1)
-        assert hotness.shape[0] == n_total, (
-            f'hotness covers {hotness.shape[0]} ids, feature_pb has '
-            f'{n_total}')
-        hot_ids = np.argsort(-hotness, kind='stable')[:h]
-      self.cache_ids = np.sort(hot_ids).astype(np.int32)
-      self.cache_feats = self.cpu_get(self.cache_ids)
-    else:
-      self.cache_ids = None
-      self.cache_feats = None
+    self._cache_frac = self.cache_rows / n_total if n_total else 0.0
     self._dev = None
     self._stats = None
     self._fns = {}
+
+  def _hot_ids(self, hotness, h: int) -> np.ndarray:
+    """The ``h`` hottest ids, ascending: the first ``h`` of a stable
+    descending sort of ``hotness`` (ties to the lower id); the lowest
+    ids where there is no score."""
+    n_total = int(self.feature_pb.shape[0])
+    if hotness is None:
+      return np.arange(h, dtype=np.int32)
+    hotness = np.asarray(hotness).reshape(-1)
+    assert hotness.shape[0] == n_total, (
+        f'hotness covers {hotness.shape[0]} ids, feature_pb has '
+        f'{n_total}')
+    return np.sort(np.argsort(-hotness, kind='stable')[:h]).astype(np.int32)
+
+  @classmethod
+  def from_device_shards(cls, mesh, feature_pb, feat_ids, feats,
+                         split_ratio: float = 0.0,
+                         cache_rows: Optional[int] = None, hotness=None,
+                         wire_dtype=None, bucket_frac=2.0,
+                         dedup: bool = True, pb_dev=None):
+    """A store over row shards that ALREADY live on their devices:
+    ``feat_ids`` ``[P, n_max]`` (each shard's owned ids ascending,
+    INT32_MAX-padded) and ``feats`` ``[P, n_max, F]`` (its rows in that
+    order), both sharded on their leading axis over ``mesh`` — the
+    arrays :meth:`device_arrays` of a host-built store uploads, bit for
+    bit. No ``[N, F]`` array is ever in host memory: a row store too
+    large to pack on the host (``_init_storage`` holds it once, the
+    caller's parts a second time) is generated or loaded shard by shard
+    straight onto the mesh.
+
+    The hot cache is filled ON the devices: ``hotness`` is an ``[N]``
+    score vector (in-degree; a host or a device array), the hottest
+    ``cache_rows`` ids are selected as the host constructor selects them
+    (stable descending sort, ties to the lower id), and every shard
+    contributes the cached rows it owns to a replicated ``[H, F]`` table
+    through one ``psum`` of the rows' bit patterns (exact: all shards
+    but the owner add zero). ``feature_pb`` stays a host array (the
+    routing book; ``pb_dev`` is its replicated placement where the
+    caller already made it); :meth:`cpu_get` fetches from the devices."""
+    import jax
+    self = cls.__new__(cls)
+    self.num_partitions = int(feat_ids.shape[0])
+    self.feature_pb = np.asarray(feature_pb)
+    self.mesh = mesh
+    self.n_max = int(feats.shape[1])
+    self._fdim = int(feats.shape[2])
+    self.storage_dtype = np.dtype(feats.dtype)
+    self.feat_ids = self.feats = None      # no host copy of the shards
+    self._init_lookup(split_ratio, cache_rows, wire_dtype, bucket_frac,
+                      dedup)
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from ..utils import global_device_put
+    ax = tuple(mesh.axis_names)
+    shard = NamedSharding(mesh, P(ax))
+    repl = NamedSharding(mesh, P())
+    for name, a in (('feat_ids', feat_ids), ('feats', feats)):
+      if not a.sharding.is_equivalent_to(shard, a.ndim):
+        raise ValueError(
+            f'DistFeature.from_device_shards: {name} is placed '
+            f'{a.sharding}, not sharded on its leading axis over the '
+            f'mesh ({shard})')
+    h = self.cache_rows
+    if h > 0:
+      from ..utils.trace import record_dispatch
+      if hotness is None or not isinstance(hotness, jax.Array):
+        cache_ids = global_device_put(self._hot_ids(hotness, h), repl)
+      else:
+        record_dispatch('dist_feature.fill_cache')
+        cache_ids = _hot_ids_fn(h)(jax.device_put(hotness, repl))
+      record_dispatch('dist_feature.fill_cache')
+      cache_feats = _gather_replicated_fn(mesh, feats.dtype)(
+          feat_ids, feats, cache_ids)
+      self.cache_ids = np.asarray(cache_ids)
+    else:
+      cache_ids = global_device_put(np.full((1,), INT32_MAX, np.int32),
+                                    repl)
+      cache_feats = global_device_put(
+          np.zeros((1, self._fdim), self.storage_dtype), repl)
+      self.cache_ids = None
+    self.cache_feats = None                # on the devices only
+    self._dev = dict(
+        feat_ids=feat_ids, feats=feats,
+        feature_pb=pb_dev if pb_dev is not None else global_device_put(
+            self.feature_pb.astype(np.int32), repl),
+        cache_ids=cache_ids, cache_feats=cache_feats)
+    return self
 
   def _init_storage(self, feat_parts, dtype):
     """Pack the per-partition (ids, rows) blocks into the sorted
@@ -244,7 +394,7 @@ class DistFeature:
     cache-split -> miss-dedup -> bucketed-exchange -> merge computation
     and thread the [4] stats row through their own carry.
 
-    Returns ``body(feat_ids [n], feats [n, F], pb, cache_ids,
+    Returns ``body(feat_ids [n], feats [n, F] or [1, n, F], pb, cache_ids,
     cache_feats, stats_row [4], ids [b], mask [b]) ->
     (rows [b, F], new_stats_row [4])``. Must be traced on this store's
     mesh (the exchange collectives run over every mesh axis).
@@ -298,11 +448,16 @@ class DistFeature:
     else:
       def lookup_local(feat_ids, feats, flat):
         """Rows for a flat request vector over this shard's sorted owned
-        ids (zeros where absent/padded)."""
+        ids (zeros where absent/padded). ``feats`` may keep shard_map's
+        leading ``[1, n, F]`` axis: on a TPU dropping it (``feats[0]``)
+        is a physical copy of the whole table in front of every program
+        that gathers from it — 4.7 GB at 9 M rows — where indexing
+        through it is free."""
         pos = jnp.clip(jnp.searchsorted(feat_ids, flat), 0,
                        feat_ids.shape[0] - 1)
         found = feat_ids[pos] == flat
-        return jnp.where(found[:, None], feats[pos], 0)
+        rows = feats[0, pos] if feats.ndim == 3 else feats[pos]
+        return jnp.where(found[:, None], rows, 0)
 
     def exchange_flat(feat_ids, feats, pb, req, rmask):
       """Fractional bucketed all_to_all with replicated full-width
@@ -386,28 +541,30 @@ class DistFeature:
     def body(feat_ids, feats, pb, cache_ids, cache_feats, stats, ids,
              mask):
       safe = jnp.maximum(ids, 0)
-      if h > 0:
-        cpos = jnp.clip(jnp.searchsorted(cache_ids, safe), 0,
-                        cache_ids.shape[0] - 1)
-        is_hit = mask & (cache_ids[cpos] == safe)
-        out_hit = jnp.where(is_hit[:, None], cache_feats[cpos], 0)
-        miss = mask & ~is_hit
-      else:
-        is_hit = jnp.zeros_like(mask)
-        out_hit = jnp.zeros((b, fdim), fdtype)
-        miss = mask
-      if dedup:
-        # one request per unique missed id; `inverse` fans the response
-        # row back to every batch slot that asked for it
-        req, ucnt, inverse = ops.masked_unique(ids, miss, size=b)
-        rmask = req != ops.FILL
-      else:
-        req, rmask = ids, miss
-        inverse = jnp.where(miss, jnp.arange(b, dtype=jnp.int32), -1)
-        ucnt = jnp.sum(miss)
-      exchange = exchange_hier if hier else exchange_flat
-      rows, ovf = exchange(feat_ids, feats, pb, req, rmask)
-      out_miss = rows[jnp.maximum(inverse, 0)]
+      with jax.named_scope(SCOPE_CACHE):
+        if h > 0:
+          cpos = jnp.clip(jnp.searchsorted(cache_ids, safe), 0,
+                          cache_ids.shape[0] - 1)
+          is_hit = mask & (cache_ids[cpos] == safe)
+          out_hit = jnp.where(is_hit[:, None], cache_feats[cpos], 0)
+          miss = mask & ~is_hit
+        else:
+          is_hit = jnp.zeros_like(mask)
+          out_hit = jnp.zeros((b, fdim), fdtype)
+          miss = mask
+      with jax.named_scope(SCOPE_EXCHANGE):
+        if dedup:
+          # one request per unique missed id; `inverse` fans the
+          # response row back to every batch slot that asked for it
+          req, ucnt, inverse = ops.masked_unique(ids, miss, size=b)
+          rmask = req != ops.FILL
+        else:
+          req, rmask = ids, miss
+          inverse = jnp.where(miss, jnp.arange(b, dtype=jnp.int32), -1)
+          ucnt = jnp.sum(miss)
+        exchange = exchange_hier if hier else exchange_flat
+        rows, ovf = exchange(feat_ids, feats, pb, req, rmask)
+        out_miss = rows[jnp.maximum(inverse, 0)]
       out = jnp.where(is_hit[:, None], out_hit.astype(fdtype),
                       jnp.where(miss[:, None], out_miss, 0))
       batch_stats = jnp.stack([
@@ -432,7 +589,8 @@ class DistFeature:
     def body(feat_ids, feats, pb, cache_ids, cache_feats, stats, ids,
              mask):
       # per-shard views: feat_ids [1, n], feats [1, n, F], ids [1, b]
-      out, new_stats = core(feat_ids[0], feats[0], pb, cache_ids,
+      # feats keeps its [1, n, F] axis (see lookup_local)
+      out, new_stats = core(feat_ids[0], feats, pb, cache_ids,
                             cache_feats, stats[0], ids[0], mask[0])
       return out[None], new_stats[None]
 
@@ -474,12 +632,24 @@ class DistFeature:
     return self._fns[b](ids, mask)
 
   def cpu_get(self, ids) -> np.ndarray:
-    """Host-side exact gather (server-side remote serving path)."""
+    """Host-side exact gather (server-side remote serving path). A
+    store built by :meth:`from_device_shards` has no host rows: each
+    partition's rows are gathered on its device and fetched."""
     ids = np.asarray(ids)
     out = np.zeros((ids.shape[0], self.feature_dim), self.storage_dtype)
+    if self.feats is None:
+      by_part = lambda a: {s.index[0].start or 0: s.data[0]
+                           for s in a.addressable_shards}
+      fid, rows = by_part(self._dev['feat_ids']), by_part(self._dev['feats'])
     for p in range(self.num_partitions):
       m = self.feature_pb[np.clip(ids, 0, None)] == p
       if not m.any():
+        continue
+      if self.feats is None:
+        table = np.asarray(fid[p])
+        pos = np.clip(np.searchsorted(table, ids[m]), 0,
+                      table.shape[0] - 1)
+        out[m] = np.asarray(rows[p][pos])
         continue
       pos = np.searchsorted(self.feat_ids[p], ids[m])
       pos = np.clip(pos, 0, self.feat_ids.shape[1] - 1)

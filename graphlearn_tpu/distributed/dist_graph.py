@@ -85,6 +85,75 @@ class DistGraph:
       if has_w:
         self.weights[i, :e] = w
 
+  @classmethod
+  def from_device_shards(cls, mesh, node_pb, row_ids, indptr, indices,
+                         eids=None, weights=None, edge_dir: str = 'out',
+                         pb_dev=None):
+    """A DistGraph over shards that ALREADY live on their devices: the
+    stacked ``[P, ...]`` arrays of the module docstring, each sharded on
+    its leading axis over ``mesh`` (shard p on device p), as
+    :meth:`device_arrays` of a host-built graph would have placed them.
+    Nothing of size E ever passes through host memory: a graph too large
+    to stack on the host (the packed ``[P, E]`` arrays, twice with the
+    caller's parts) is generated, or loaded shard by shard, straight onto
+    the mesh and handed over here.
+
+    ``node_pb`` ([N] global id -> partition) stays a host array — it is
+    what the host-side book lookups read — and is placed replicated
+    (``pb_dev``: that placement where the caller already made it, so a
+    dataset keeps ONE book on the devices for its graph and stores).
+    ``eids`` may be None when no consumer asks for edge ids
+    (``with_edge=False`` samplers): a one-column placeholder stands in
+    for the program argument. The host-side tables of a host-built graph
+    (``sorted_local_indices``, ``row_cumsum_stacked``) have nothing to
+    read here and raise."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from ..utils import global_device_put
+    self = cls.__new__(cls)
+    shard = NamedSharding(mesh, P(tuple(mesh.axis_names)))
+    p = int(row_ids.shape[0])
+    self.num_partitions = p
+    self.partition_idx = 0
+    self.node_pb = np.asarray(node_pb)
+    self.edge_pb = None
+    self.edge_dir = edge_dir
+    self.row_ids = self.indptr = self.indices = self.eids = None
+    self.weights = None
+    for name, a in (('row_ids', row_ids), ('indptr', indptr),
+                    ('indices', indices), ('eids', eids),
+                    ('weights', weights)):
+      if a is not None and not a.sharding.is_equivalent_to(shard, a.ndim):
+        raise ValueError(
+            f'DistGraph.from_device_shards: {name} is placed '
+            f'{a.sharding}, not sharded on its leading axis over the '
+            f'mesh ({shard})')
+    if eids is None:
+      eids = jax.jit(lambda: jnp.full((p, 1), -1, jnp.int32),
+                     out_shardings=shard)()
+    self._dev = dict(
+        row_ids=row_ids, indptr=indptr, indices=indices, eids=eids,
+        node_pb=pb_dev if pb_dev is not None else global_device_put(
+            self.node_pb.astype(np.int32), NamedSharding(mesh, P())))
+    if weights is not None:
+      self._dev['weights'] = weights
+    self._dev_mesh = mesh
+    return self
+
+  @property
+  def on_device(self) -> bool:
+    """True for a graph built by :meth:`from_device_shards`: its
+    stacked arrays exist on the mesh only."""
+    return getattr(self, '_dev', None) is not None
+
+  def _host_only(self, what: str):
+    if self.on_device:
+      raise ValueError(
+          f'DistGraph.{what} reads the host copy of the stacked CSR; a '
+          'graph built by from_device_shards keeps none (weighted '
+          'sampling and strict negatives need a host-built DistGraph)')
+
   @property
   def is_hetero(self) -> bool:
     return False
@@ -97,6 +166,7 @@ class DistGraph:
     """[P, E] per-shard segment-sorted neighbor ids — the binary-search
     membership table for shard-local negative sampling
     (ops.random_negative_sample_local). Computed once, host-side."""
+    self._host_only('sorted_local_indices')
     if not hasattr(self, '_sorted_loc'):
       out = np.full_like(self.indices, -1)
       for p in range(self.indices.shape[0]):
@@ -112,6 +182,7 @@ class DistGraph:
     """[P, E] per-shard row-restarting cumulative edge weights — the
     inverse-CDF table for distributed weighted sampling
     (ops.weighted_sample_local)."""
+    self._host_only('row_cumsum_stacked')
     assert self.weights is not None, 'graph has no edge weights'
     if not hasattr(self, '_wcum'):
       out = np.zeros_like(self.weights)
@@ -141,6 +212,11 @@ class DistGraph:
     every mesh axis (flat 'g' or 2-axis ('slice', 'chip')), partition
     book replicated. Works on multi-host meshes (only this process's
     shards are placed — utils.global_device_put)."""
+    if self.on_device:
+      if mesh != self._dev_mesh:
+        raise ValueError('DistGraph.device_arrays: the shards were '
+                         'handed over on another mesh')
+      return dict(self._dev)
     from jax.sharding import NamedSharding, PartitionSpec as P
     from ..utils import global_device_put
     shard = NamedSharding(mesh, P(tuple(mesh.axis_names)))

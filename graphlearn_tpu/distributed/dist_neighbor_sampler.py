@@ -52,7 +52,8 @@ import jax
 import numpy as np
 
 from .. import ops
-from ..metrics.registry_names import SCOPE_SAMPLE, hop_scope
+from ..metrics.registry_names import (SCOPE_EXCHANGE, SCOPE_SAMPLE,
+                                      hop_scope)
 from ..sampler import (EdgeSamplerInput, HeteroSamplerOutput,
                        NodeSamplerInput, SamplerOutput)
 from ..typing import reverse_edge_type
@@ -171,7 +172,8 @@ def _exchange_hop_hier(garr, pb, frontier, fmask, k, key, sizes,
 
 def _exchange_hop(garr, pb, frontier, fmask, k, key, nparts: int,
                   with_edge: bool, weighted: bool = False,
-                  bucket_frac=2.0, axes=('g',), axis_sizes=None):
+                  bucket_frac=2.0, axes=('g',), axis_sizes=None,
+                  hop_scopes=None):
   """One cross-shard hop, shared by the homo and hetero engines:
   route frontier ids by partition book -> all_to_all request ->
   local fanout sample over this shard's CSR -> all_to_all response ->
@@ -192,9 +194,21 @@ def _exchange_hop(garr, pb, frontier, fmask, k, key, nparts: int,
   loss-free on every input, sub-linear volume growth in nparts on
   typical ones (reference parity: exact split, never drops,
   dist_neighbor_sampler.py:585-648).
+
+  ``hop_scopes=(exchange, draw)`` names the two halves of the hop on the
+  profiler's timeline (the homogeneous hop loop passes
+  ``hop<h>/exchange`` and ``hop<h>/draw``): routing, bucketing and both
+  ``all_to_all`` legs under the first, the shard-local fanout sample
+  under the second. None (the typed engine, which names the whole hop
+  per edge type) opens no scope here.
   """
+  import contextlib
   import jax
   import jax.numpy as jnp
+  x_scope, draw_scope = (
+      (lambda: jax.named_scope(hop_scopes[0]),
+       lambda: jax.named_scope(hop_scopes[1])) if hop_scopes else
+      (contextlib.nullcontext, contextlib.nullcontext))
   if len(axes) == 2:
     assert axis_sizes is not None and len(axis_sizes) == 2
     return _exchange_hop_hier(garr, pb, frontier, fmask, k, key,
@@ -202,34 +216,35 @@ def _exchange_hop(garr, pb, frontier, fmask, k, key, nparts: int,
                               bucket_frac, axes)
   bf = frontier.shape[0]
   safe = jnp.maximum(frontier, 0)
-  dest = jnp.where(fmask, pb[safe], nparts)
-  slot, ok = ops.route_slots(dest, fmask, capacity=bf)
+  with x_scope():
+    dest = jnp.where(fmask, pb[safe], nparts)
+    slot, ok = ops.route_slots(dest, fmask, capacity=bf)
 
   def _do(cap: int):
-    okc = ok & (slot < cap)
-    send = ops.scatter_to_buckets(frontier, dest, slot, okc, nparts, cap)
-    req = jax.lax.all_to_all(send, axes, 0, 0)
-    flat = req.reshape(-1)
-    fm = flat >= 0
-    if weighted:
-      nbrs, epos, m = ops.weighted_sample_local(
-          garr['row_ids'], garr['indptr'], garr['indices'], garr['wcum'],
-          flat, fm, k, key)
-    else:
-      nbrs, epos, m = ops.uniform_sample_local(
-          garr['row_ids'], garr['indptr'], garr['indices'], flat, fm, k,
-          key)
-    resp_n = jax.lax.all_to_all(nbrs.reshape(nparts, cap, k), axes, 0, 0)
-    resp_m = jax.lax.all_to_all(m.reshape(nparts, cap, k), axes, 0, 0)
-    back_n = ops.gather_from_buckets(resp_n, dest, slot, okc)
-    back_m = ops.gather_from_buckets(resp_m, dest, slot, okc,
-                                     fill=False) & okc[:, None]
-    if with_edge:
-      e = jnp.where(m, garr['eids'][jnp.where(m, epos, 0)], -1)
-      resp_e = jax.lax.all_to_all(e.reshape(nparts, cap, k), axes, 0, 0)
-      back_e = ops.gather_from_buckets(resp_e, dest, slot, okc)
-    else:
-      back_e = jnp.zeros((bf, k), jnp.int32)   # uniform cond signature
+    with x_scope():
+      okc = ok & (slot < cap)
+      send = ops.scatter_to_buckets(frontier, dest, slot, okc, nparts,
+                                    cap)
+      req = jax.lax.all_to_all(send, axes, 0, 0)
+      flat = req.reshape(-1)
+      fm = flat >= 0
+    with draw_scope():
+      nbrs, epos, m = _local_sample(garr, flat, fm, k, key, weighted)
+      if with_edge:
+        e = jnp.where(m, garr['eids'][jnp.where(m, epos, 0)], -1)
+    with x_scope():
+      resp_n = jax.lax.all_to_all(nbrs.reshape(nparts, cap, k), axes, 0,
+                                  0)
+      resp_m = jax.lax.all_to_all(m.reshape(nparts, cap, k), axes, 0, 0)
+      back_n = ops.gather_from_buckets(resp_n, dest, slot, okc)
+      back_m = ops.gather_from_buckets(resp_m, dest, slot, okc,
+                                       fill=False) & okc[:, None]
+      if with_edge:
+        resp_e = jax.lax.all_to_all(e.reshape(nparts, cap, k), axes, 0,
+                                    0)
+        back_e = ops.gather_from_buckets(resp_e, dest, slot, okc)
+      else:
+        back_e = jnp.zeros((bf, k), jnp.int32)   # uniform cond signature
     return back_n, back_m, back_e
 
   cap_small = exchange_capacity(bf, nparts, bucket_frac)
@@ -238,8 +253,9 @@ def _exchange_hop(garr, pb, frontier, fmask, k, key, nparts: int,
   else:
     # replicated decision: every shard sees the SAME total overflow, so
     # the collectives inside each branch stay uniform across the mesh
-    ovf = jnp.sum(fmask & (slot >= cap_small)).astype(jnp.int32)
-    total_ovf = jax.lax.psum(ovf, axes)
+    with x_scope():
+      ovf = jnp.sum(fmask & (slot >= cap_small)).astype(jnp.int32)
+      total_ovf = jax.lax.psum(ovf, axes)
     back_n, back_m, back_e = jax.lax.cond(
         total_ovf == 0, lambda _: _do(cap_small), lambda _: _do(bf),
         None)
@@ -257,11 +273,15 @@ def _homo_hop_loop(gdev, pb, seeds, smask, key, fanouts, caps,
   expand hop by hop via _exchange_hop + the chosen inducer. Returns the
   per-shard result dict (no leading axis).
 
+  ``res['exchange_rows']`` ([hops] int32) counts, per hop, the valid
+  frontier ids this shard sent to ANOTHER shard for expansion.
+
   ``dedup='tree'`` uses the positional computation-tree inducer
   (ops/induce_tree.py) — zero random access, ~4x device speedup over the
   exact-dedup inducers at products scale (PERF.md); 'sort' keeps exact
   dedup (the shard-local analog of the reference's inducer).
   """
+  import contextlib
   import jax
   import jax.numpy as jnp
   b = seeds.shape[0]
@@ -283,12 +303,27 @@ def _homo_hop_loop(gdev, pb, seeds, smask, key, fanouts, caps,
   else:
     # merge engine: clamped occupancy bound (see _fused_homo_fn)
     node_offs, _ = merge_layout_from_caps(caps, fanouts)
+  # this shard's linear partition index, row-major over the axis order
+  my = jnp.int32(0)
+  for a, size in zip(axes, axis_sizes or (nparts,)):
+    my = my * size + jax.lax.axis_index(a)
+  sent_per_hop = []
   for i, k in enumerate(fanouts):
-    with jax.named_scope(hop_scope(i, 'draw')):
+    x_scope = hop_scope(i, SCOPE_EXCHANGE)
+    # the hierarchical (2-axis) exchange interleaves its two stages
+    # with the local sample: there the whole hop reads as the draw
+    scopes = (x_scope, hop_scope(i, 'draw')) if len(axes) == 1 else None
+    with jax.named_scope(hop_scope(i, 'draw')) if scopes is None \
+        else contextlib.nullcontext():
       nbrs, m, e = _exchange_hop(gdev, pb, frontier, fmask, k,
                                  hop_keys[i], nparts, with_edge, weighted,
                                  bucket_frac=bucket_frac, axes=axes,
-                                 axis_sizes=axis_sizes)
+                                 axis_sizes=axis_sizes, hop_scopes=scopes)
+    with jax.named_scope(x_scope):
+      # frontier ids another shard expands: what the hop's all_to_all
+      # carries off this chip (the rest rides its own bucket)
+      sent_per_hop.append(jnp.sum(
+          fmask & (pb[jnp.maximum(frontier, 0)] != my)).astype(jnp.int32))
     with jax.named_scope(hop_scope(i, 'induce')):
       state, out = induce(state, fidx, nbrs, m, node_offs[i],
                           final=(i + 1 == len(fanouts)),
@@ -325,7 +360,9 @@ def _homo_hop_loop(gdev, pb, seeds, smask, key, fanouts, caps,
       seed_inverse=inv,
       num_sampled_nodes=jnp.stack(nodes_per_hop),
       num_sampled_edges=jnp.stack(edges_per_hop),
-      overflow=overflow)
+      overflow=overflow,
+      exchange_rows=(jnp.stack(sent_per_hop) if sent_per_hop
+                     else jnp.zeros((0,), jnp.int32)))
   if with_edge:
     res['edge'] = jnp.concatenate(edges)
   return res
@@ -445,6 +482,11 @@ class DistNeighborSampler:
     self._axes = tuple(mesh.axis_names)
     self._axis_sizes = tuple(mesh.shape[a] for a in self._axes)
     self._dev = dist_graph.device_arrays(mesh)
+    if with_edge and getattr(dist_graph, 'on_device', False) and \
+        self._dev['eids'].shape[1] < self._dev['indices'].shape[1]:
+      raise ValueError('with_edge=True needs the graph\'s edge ids; this '
+                       'DistGraph was built by from_device_shards '
+                       'without eids')
     if with_weight:
       self._attach_wcum()
     self._fns = {}
@@ -632,7 +674,7 @@ class DistNeighborSampler:
     out_specs = dict(node=P(ax), num_nodes=P(ax), row=P(ax),
                      col=P(ax), edge_mask=P(ax), seed_inverse=P(ax),
                      num_sampled_nodes=P(ax), num_sampled_edges=P(ax),
-                     overflow=P(ax))
+                     overflow=P(ax), exchange_rows=P(ax))
     if with_edge:
       out_specs['edge'] = P(ax)
     fn = shard_map(
@@ -725,7 +767,7 @@ class DistNeighborSampler:
 
     out_keys = ['node', 'num_nodes', 'row', 'col', 'edge_mask',
                 'seed_inverse', 'num_sampled_nodes', 'num_sampled_edges',
-                'overflow']
+                'overflow', 'exchange_rows']
     if with_edge:
       out_keys.append('edge')
     if mode in ('none', 'binary'):
@@ -1442,6 +1484,11 @@ class DistNeighborSampler:
     if not hasattr(self, '_labels_cache'):
       self._labels_cache = {}  # key -> (id(labels), DistFeature)
     hit = self._labels_cache.get(key)
+    if isinstance(labels, DistFeature) and (hit is None or
+                                            hit[1] is not labels):
+      # a one-column store already on the mesh
+      # (DistDataset.from_device_shards): nothing to shard
+      hit = self._labels_cache[key] = (id(labels), labels)
     if hit is None or hit[0] != id(labels):
       lab = np.asarray(labels).reshape(-1)
       if lab.dtype == np.int64:     # TPU-native widths

@@ -24,8 +24,8 @@ loop); losses stay on device and are fetched once an epoch.
 from typing import Optional
 
 from .. import ops
-from ..metrics.registry_names import (SCOPE_FWD_BWD, SCOPE_TRAIN,
-                                      SCOPE_UPDATE)
+from ..metrics.registry_names import (SCOPE_ALLREDUCE, SCOPE_FWD_BWD,
+                                      SCOPE_TRAIN, SCOPE_UPDATE)
 from .node_loader import NodeLoader
 
 _RECOMPUTE_MSG = (
@@ -314,6 +314,7 @@ class DistFusedEpochTrainer:
       with jax.named_scope(SCOPE_FWD_BWD):
         (loss, acc), grads = jax.value_and_grad(
             self._loss_fn, has_aux=True)(state.params, batch)
+      with jax.named_scope(SCOPE_ALLREDUCE):
         grads = jax.lax.pmean(grads, self._axes)
         loss = jax.lax.pmean(loss, self._axes)
         acc = jax.lax.pmean(acc, self._axes)
@@ -333,7 +334,9 @@ class DistFusedEpochTrainer:
 
     Returns ``(shard_tree, repl_tree, body)`` where ``body(views, repl,
     stats_rows, seeds, smask, key) -> (batch, overflow,
-    new_stats_rows)``; ``views`` is the per-shard ([0]-indexed) view of
+    new_stats_rows, exchange_rows)`` (``exchange_rows``: the [hops]
+    frontier ids this shard sent to other shards, empty on a typed
+    graph); ``views`` is the per-shard ([0]-indexed) view of
     ``shard_tree`` and the trees are the device arrays to feed the
     enclosing shard_map (every ``shard_tree`` leaf takes spec P(axes),
     every ``repl_tree`` leaf P())."""
@@ -391,7 +394,7 @@ class DistFusedEpochTrainer:
                    edge_index=jnp.stack([res['row'], res['col']]),
                    edge_mask=res['edge_mask'], y=y[:, 0],
                    num_seed_nodes=res['num_sampled_nodes'][0])
-      return batch, res['overflow'], srow
+      return batch, res['overflow'], srow, res['exchange_rows']
 
     return shard_tree, repl_tree, body
 
@@ -461,7 +464,8 @@ class DistFusedEpochTrainer:
       batch = dict(x=x, edge_index=ei, edge_mask=res['edge_mask'],
                    y=y[:, 0],
                    num_seed_nodes=res['num_sampled_nodes'][t_in][0])
-      return batch, res['overflow'], new_rows
+      # the typed engine counts no exchange rows: an empty [0] row
+      return batch, res['overflow'], new_rows, jnp.zeros((0,), jnp.int32)
 
     return shard_tree, repl_tree, body
 

@@ -609,6 +609,10 @@ class DistScanTrainer(DistFusedEpochTrainer):
                perm_seed: Optional[int] = None, config=None):
     import jax
     super().__init__(loader, model, tx, num_classes, seed_labels_only)
+    # per chunk, the [P, k, hops] counts of frontier ids each shard sent
+    # to other shards: a scan output, summed on the host once an epoch
+    # (_publish_exchange_rows empties it)
+    self._sent = []
     # config= takes a tune artifact (docs/tuning.md): topology-checked
     # ('dist' or a generic local artifact) and validated against the
     # DistGraph's stacked-partition fingerprint (tune/artifact.py)
@@ -699,7 +703,11 @@ class DistScanTrainer(DistFusedEpochTrainer):
 
     def body(shard_tree, repl_tree, stats, params, opt_state, stepc,
              ovf, seed_mat, mask_mat, base_key, count0, start):
-      views = jax.tree.map(lambda a: a[0], shard_tree)
+      # row tables keep their [1, n, F] axis: dropping it copies the
+      # table in front of the loop (DistFeature._shard_body indexes
+      # through it)
+      views = jax.tree.map(lambda a: a if a.ndim == 3 else a[0],
+                           shard_tree)
       stats_rows = jax.tree.map(lambda a: a[0], stats)
       seeds_k = lax.dynamic_slice_in_dim(seed_mat[0], start, k, 0)
       masks_k = lax.dynamic_slice_in_dim(mask_mat[0], start, k, 0)
@@ -717,18 +725,19 @@ class DistScanTrainer(DistFusedEpochTrainer):
         seeds, smask, count = xs
         keys = jax.random.split(jax.random.fold_in(base_key, count),
                                 nparts)
-        batch, overflow, srows = sc_body(views, repl_tree, srows, seeds,
-                                         smask, keys[my])
+        batch, overflow, srows, sent = sc_body(
+            views, repl_tree, srows, seeds, smask, keys[my])
         state, loss, acc = dp(
             self._train_state_cls(params, opt_state, stepc), batch)
         return (state.params, state.opt_state, state.step,
-                ovf | overflow, srows), (loss, acc)
+                ovf | overflow, srows), (loss, acc, sent)
 
-      (params, opt_state, stepc, ovf, srows), (losses, accs) = lax.scan(
-          step, (params, opt_state, stepc, ovf, stats_rows),
-          (seeds_k, masks_k, counts_k))
+      (params, opt_state, stepc, ovf, srows), (losses, accs, sent) = \
+          lax.scan(step, (params, opt_state, stepc, ovf, stats_rows),
+                   (seeds_k, masks_k, counts_k))
       return (params, opt_state, stepc, ovf,
-              jax.tree.map(lambda a: a[None], srows), losses, accs)
+              jax.tree.map(lambda a: a[None], srows), losses, accs,
+              sent[None])
 
     sh = jax.tree.map(lambda _: P(ax), self._shard_tree)
     rp = jax.tree.map(lambda _: P(), self._repl_tree)
@@ -738,7 +747,7 @@ class DistScanTrainer(DistFusedEpochTrainer):
         body, mesh=mesh,
         in_specs=(sh, rp, stats_spec, P(), P(), P(), P(), P(ax), P(ax),
                   P(), P(), P()),
-        out_specs=(P(), P(), P(), P(), stats_spec, P(), P()),
+        out_specs=(P(), P(), P(), P(), stats_spec, P(), P(), P(ax)),
         check_replication=False)
     # donate the train state + the overflow/stats carries (args 3-6 +
     # 2); the graph/feature tables and seed matrix are reused across
@@ -866,6 +875,7 @@ class DistScanTrainer(DistFusedEpochTrainer):
       # close, so they sit in an inner finally
       try:
         self.loader._publish_feature_stats()
+        self._publish_exchange_rows()
       finally:
         spans.end(epoch_span,
                   steps=(steps if completed else
@@ -946,14 +956,15 @@ class DistScanTrainer(DistFusedEpochTrainer):
             with spans.span('epoch.hook', hook='stage', start=start):
               self.stage_hook(start // self.chunk_size, start, k)
           with spans.span('epoch.chunk', start=start, k=k):
-            params, opt_state, stepc, ovf, stats, loss_k, acc_k = \
-                self._dispatch_chunk(
+            (params, opt_state, stepc, ovf, stats, loss_k, acc_k,
+             sent_k) = self._dispatch_chunk(
                     start // self.chunk_size, k, stats, params,
                     opt_state, stepc, ovf, seed_mat, mask_mat, base_key,
                     count0, jax.device_put(np.int32(start), repl))
           stats_back(stats)
           losses.append(loss_k)
           accs.append(acc_k)
+          self._sent.append(sent_k)
           self._steps_dispatched = start + k
           if self.ack_hook is not None:
             # boundary carry for the recovery seam — valid only inside
@@ -985,6 +996,25 @@ class DistScanTrainer(DistFusedEpochTrainer):
     self._epochs += 1
     return (self._train_state_cls(params, opt_state, stepc),
             losses, accs, ovf)
+
+  # ------------------------------------------------------ exchange rows
+
+  def _publish_exchange_rows(self):
+    """The epoch's per-hop counts of frontier ids shards sent to OTHER
+    shards for expansion, into ``dist_exchange.rows.hop<h>``: the
+    chunks' small scan outputs fetched once an epoch, beside the feature
+    stats (no per-batch host sync). A failed or resumed epoch publishes
+    the chunks it ran. Typed epochs count none (an empty row)."""
+    from ..utils import trace
+    sent, self._sent = self._sent, []
+    fetch = lambda x: (
+        np.asarray(x) if getattr(x, 'is_fully_addressable', True)
+        else np.concatenate([np.asarray(s.data)
+                             for s in x.addressable_shards]))
+    rows = [fetch(x).sum(axis=(0, 1), dtype=np.int64) for x in sent]
+    for h, n in enumerate(np.sum(rows, axis=0).tolist() if rows else ()):
+      # graftlint: allow[metric-registry] one counter per hop of the registered dist_exchange.* family
+      trace.counter_inc(f'dist_exchange.rows.hop{h}', int(n))
 
   # ---------------------------------------------- exchange-aware seams
   # The two points where the epoch program touches the feature-storage

@@ -31,6 +31,13 @@ REGISTERED_METRICS = frozenset({
     # dist_label so the headline dist_feature parity stays clean)
     'dist_feature.*',
     'dist_label.*',
+    # what a scanned mesh epoch's hops sent through their collectives
+    # (loader/scan_epoch.py DistScanTrainer, published once per epoch
+    # beside dist_feature.*): dist_exchange.rows.hop<h> — frontier ids a
+    # shard asked OTHER shards to expand, summed over shards and steps
+    # (the rows the miss-only row exchange asked for are
+    # dist_feature.unique_misses)
+    'dist_exchange.*',
     # mp sampling workers (distributed/dist_sampling_producer.py)
     'producer.batches',
     'producer.sample_ms',
@@ -228,6 +235,11 @@ SCOPE_COLLATE = 'glt.collate'    # ops/collate.py, dist feature/label lookup
 SCOPE_TRAIN = 'glt.train'        # models/train.py, pipeline._dp_step_body
 SCOPE_FWD_BWD = 'fwd_bwd'        # inside glt.train: the value_and_grad
 SCOPE_UPDATE = 'update'          # inside glt.train: the optimizer
+# the mesh's collectives, apart from the local work beside them
+SCOPE_ALLREDUCE = 'allreduce'    # inside glt.train: the pmean (DDP)
+SCOPE_CACHE = 'cache'            # inside glt.collate: the hot-cache hit path
+SCOPE_EXCHANGE = 'exchange'      # inside glt.collate and glt.sample/hop<h>:
+                                 # the all_to_all round trip and its routing
 
 
 def hop_scope(hop: int, part: str, etype=None) -> str:
@@ -235,7 +247,9 @@ def hop_scope(hop: int, part: str, etype=None) -> str:
   (the neighbour draw), 'induce' (dedup + relabel) or, on a typed graph,
   'merge' (the per-node-type compaction of the hop's new frontiers).
   The typed hop loop names each edge type's part of the hop:
-  ``hop<h>/<src>__<rel>__<dst>/draw``."""
+  ``hop<h>/<src>__<rel>__<dst>/draw``. On the mesh a homogeneous hop
+  also has 'exchange': the request and response ``all_to_all`` with
+  their routing, apart from the shard-local 'draw'."""
   if etype is None:
     return f'hop{hop}/{part}'
   return f'hop{hop}/{"__".join(etype)}/{part}'
@@ -253,12 +267,16 @@ REGISTERED_SCOPES = frozenset({
     'glt.sample',
     'glt.sample/hop<h>/draw',
     'glt.sample/hop<h>/induce',
+    'glt.sample/hop<h>/exchange',
     'glt.sample/hop<h>/<etype>/draw',
     'glt.sample/hop<h>/<etype>/induce',
     'glt.sample/hop<h>/merge',
     'glt.collate',
     'glt.collate/<ntype>',
+    'glt.collate/cache',
+    'glt.collate/exchange',
     'glt.train',
     'glt.train/fwd_bwd',
     'glt.train/update',
+    'glt.train/allreduce',
 })

@@ -109,6 +109,43 @@ def estimate_frontier_caps(graph, fanouts: Sequence[int], batch_size: int,
   return [_round_up(int(m * slack), multiple) for m in maxima]
 
 
+def estimate_dist_frontier_caps(dist_graph, mesh, fanouts: Sequence[int],
+                                batch_size: int, input_nodes=None,
+                                num_probes: int = 8, slack: float = 1.5,
+                                seed: int = 0,
+                                multiple: int = 128) -> List[int]:
+  """:func:`estimate_frontier_caps` for the mesh, without a host copy of
+  the graph: the probes run through the distributed sampler itself,
+  uncapped, over the shards where they live — every shard expands its own
+  ``batch_size`` probe seeds over the GLOBAL graph, as a step does — and
+  a hop's cap is ``slack`` times the largest frontier any shard of any
+  probe deduplicated, rounded up to ``multiple``. One device->host fetch
+  of ``[P, hops + 1]`` counts per probe.
+
+  ``batch_size`` is the PER-SHARD seed width (``DistNeighborLoader``'s
+  ``batch_size``). The probes draw what the sampler draws (``k`` with
+  replacement above degree ``k``, every neighbour below), from a stream
+  of their own (``seed``), so they neither depend on nor advance any
+  loader's keys. Pass the caps to ``DistNeighborLoader(frontier_caps=,
+  dedup='merge')``."""
+  from ..distributed.dist_neighbor_sampler import DistNeighborSampler
+  fanouts = list(fanouts)
+  p = dist_graph.num_partitions
+  pool = (np.asarray(input_nodes).reshape(-1) if input_nodes is not None
+          else None)
+  rng = np.random.default_rng(seed)
+  sampler = DistNeighborSampler(dist_graph, fanouts, mesh, dedup='merge',
+                                seed=seed)
+  maxima = np.zeros(len(fanouts), np.int64)
+  for _ in range(num_probes):
+    seeds = (rng.choice(pool, (p, batch_size)) if pool is not None
+             else rng.integers(0, dist_graph.num_nodes, (p, batch_size)))
+    out = sampler.sample_from_nodes(seeds.astype(np.int32))
+    counts = np.asarray(out.num_sampled_nodes).reshape(p, -1)
+    maxima = np.maximum(maxima, counts[:, 1:].max(axis=0))
+  return [_round_up(int(m * slack), multiple) for m in maxima]
+
+
 def estimate_hetero_frontier_caps(graph, num_neighbors, seed_caps,
                                   edge_dir: str = 'out', input_nodes=None,
                                   num_probes: int = 8, slack: float = 1.5,

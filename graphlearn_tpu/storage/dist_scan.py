@@ -275,7 +275,7 @@ class TieredDistScanTrainer(DistScanTrainer):
                    edge_index=jnp.stack([res['row'], res['col']]),
                    edge_mask=res['edge_mask'], y=y[:, 0],
                    num_seed_nodes=res['num_sampled_nodes'][0])
-      return batch, res['overflow'], srow
+      return batch, res['overflow'], srow, res['exchange_rows']
 
     return shard_tree, repl_tree, body
 
@@ -349,7 +349,7 @@ class TieredDistScanTrainer(DistScanTrainer):
       batch = dict(x=x, edge_index=ei, edge_mask=res['edge_mask'],
                    y=y[:, 0],
                    num_seed_nodes=res['num_sampled_nodes'][t_in][0])
-      return batch, res['overflow'], new_rows
+      return batch, res['overflow'], new_rows, jnp.zeros((0,), jnp.int32)
 
     return shard_tree, repl_tree, body
 
@@ -545,18 +545,19 @@ class TieredDistScanTrainer(DistScanTrainer):
         seeds, smask, count = xs
         keys = jax.random.split(jax.random.fold_in(base_key, count),
                                 nparts)
-        batch, overflow, srows = sc_body(views, repl_tree, srows, seeds,
-                                         smask, keys[my], sp_v, sr_v)
+        batch, overflow, srows, sent = sc_body(
+            views, repl_tree, srows, seeds, smask, keys[my], sp_v, sr_v)
         state, loss, acc = dp(
             self._train_state_cls(params, opt_state, stepc), batch)
         return (state.params, state.opt_state, state.step,
-                ovf | overflow, srows), (loss, acc)
+                ovf | overflow, srows), (loss, acc, sent)
 
-      (params, opt_state, stepc, ovf, srows), (losses, accs) = lax.scan(
-          step, (params, opt_state, stepc, ovf, stats_rows),
-          (seeds_k, masks_k, counts_k))
+      (params, opt_state, stepc, ovf, srows), (losses, accs, sent) = \
+          lax.scan(step, (params, opt_state, stepc, ovf, stats_rows),
+                   (seeds_k, masks_k, counts_k))
       return (params, opt_state, stepc, ovf,
-              jax.tree.map(lambda a: a[None], srows), losses, accs)
+              jax.tree.map(lambda a: a[None], srows), losses, accs,
+              sent[None])
 
     sh = jax.tree.map(lambda _: P(ax), self._shard_tree)
     rp = jax.tree.map(lambda _: P(), self._repl_tree)
@@ -568,7 +569,7 @@ class TieredDistScanTrainer(DistScanTrainer):
         body, mesh=mesh,
         in_specs=(sh, rp, stats_spec, P(), P(), P(), P(), P(ax), P(ax),
                   P(), P(), P(), slab_spec, slab_spec),
-        out_specs=(P(), P(), P(), P(), stats_spec, P(), P()),
+        out_specs=(P(), P(), P(), P(), stats_spec, P(), P(), P(ax)),
         check_replication=False)
     jfn = programs.instrument(
         jax.jit(fn, donate_argnums=(2, 3, 4, 5, 6)), 'dist_scan_chunk')

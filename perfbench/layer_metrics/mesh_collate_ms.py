@@ -1,0 +1,14 @@
+"""Device ms per step of the collate — the hot-cache hit path, the miss-only row exchange, the label lookup — INSIDE the mesh cell's own chunk
+program: self time of the ``XLA Ops`` events under ``glt.collate``, per
+chip, mean over the chips of the traced slice (perfbench/mesh_reduce.py;
+the maximum over chips is on its ``mesh_reduce`` line). None with a
+program that has no such scope."""
+from perfbench import mesh_reduce
+
+LAYER = 'collate'
+UNIT = 'ms/step'
+MOVES = 'seeds_per_s'
+
+
+def read(run):
+  return mesh_reduce.layer_ms(run, 'glt.collate')

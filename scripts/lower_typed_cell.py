@@ -1,11 +1,12 @@
-"""Compile the typed cell's two big programs for a v5e that is described,
-not attached, and print what the chip's compiler plans for each
+"""Compile a cell's big programs for a v5e that is described, not
+attached, and print what the chip's compiler plans for each
 (``memory_analysis()``) — a builder's rehearsal (on-chip-measurement guide,
 section 2.3), no chip minute spent, no time or rate comes out of it:
 
     TPU_ACCELERATOR_TYPE=v5litepod-4 TPU_WORKER_HOSTNAMES=localhost \
     TPU_SKIP_MDS_QUERY=true JAX_PLATFORMS=cpu \
-    python scripts/lower_typed_cell.py [reference] [chunk] [gat]
+    python scripts/lower_typed_cell.py [reference] [chunk] [gat] \
+        [mesh-generator] [mesh-cache] [mesh-chunk] [mesh-reference]
 
 ``reference``: ``perfbench/reference_hetero_node.py``'s step at the shapes of
 ``rgat-igbh-small.typed-scan-exact`` (it runs on an emptied device: arguments
@@ -21,6 +22,17 @@ serialise an executable, the bytes a warm start reads from the compile cache
 (PERF.md section 6, PR 34: the gate a change to the model passes before its
 first chip call).
 
+The ``mesh-*`` modes compile the programs of ``sage-papers.mesh-exact`` for
+the described 2x2, PER CHIP (add ``XLA_FLAGS=
+--xla_force_host_platform_device_count=4``: the family's small cell is built
+on four CPU devices): ``mesh-generator`` the programs of
+``perfbench/datagen_mesh_node.py`` at the configuration's size;
+``mesh-cache`` ``DistFeature.from_device_shards``' hot-row selection and
+replicated gather; ``mesh-chunk`` ``DistScanTrainer``'s 16-step chunk over
+the four chips with every table an argument at the cell's size, its
+collectives counted in the compiled text; ``mesh-reference`` the plain
+reference's per-shard gradient on one chip.
+
 The batch's static shapes come from the cell's calibrated caps, which need
 the dataset: ``CAPS`` are the ones a chip run of the cell printed on its
 set-up line (PERF.md section 4), ``VALID`` that run's ``typed_counts``.
@@ -28,6 +40,7 @@ set-up line (PERF.md section 4), ``VALID`` that run's ``typed_counts``.
 import json
 import math
 import os
+import re
 import sys
 import time
 
@@ -38,6 +51,8 @@ sys.path.insert(0, ROOT)
 CELL = 'rgat-igbh-small.typed-scan-exact'
 GAT_CELL = 'gat-products.scan-exact'
 GAT_CAPS = [6528, 33664, 84736]   # that cell's set-up line (PERF.md section 4)
+MESH_CELL = 'sage-papers.mesh-exact'
+MESH_CAPS = [8960, 62080, 192768]  # that cell's set-up line (PERF.md section 4)
 CAPS = {
     'author__affiliated_to__institute': [128, 1920, 7040],
     'author__rev_written_by__paper': [128, 18304, 69120],
@@ -78,7 +93,7 @@ def serialized_bytes(compiled):
     return f'{type(e).__name__}: {str(e)[:120]}'
 
 
-def compile_timed(name, jitted, args):
+def compile_timed(name, jitted, args, collectives=False):
   """Trace, lower and compile ``jitted`` apart, and print what start-up
   pays for the program (seconds here are this sandbox's CPU: counts to
   compare parent and change by, not device numbers)."""
@@ -90,7 +105,7 @@ def compile_timed(name, jitted, args):
   compiled = lowered.compile()
   t3 = time.perf_counter()
   text = lowered.as_text()
-  out = report(name, compiled)
+  out = report(name, compiled, collectives)
   cost = compiled.cost_analysis() or {}
   size = dict(program=name, trace_s=round(t1 - t0, 2),
               lower_s=round(t2 - t1, 2), compile_s=round(t3 - t2, 2),
@@ -119,13 +134,21 @@ def compile_chunk(name, tr, state, tables, steps, batch, k, one_chip):
        spec(tr._sampler._key), sds((), jnp.int32), sds((), jnp.int32)))
 
 
-def report(name, compiled):
+def report(name, compiled, collectives=False):
   ma = compiled.memory_analysis()
   out = dict(program=name, argument=ma.argument_size_in_bytes,
              output=ma.output_size_in_bytes, temp=ma.temp_size_in_bytes,
              alias=ma.alias_size_in_bytes,
              generated_code=ma.generated_code_size_in_bytes,
              peak=getattr(ma, 'peak_memory_in_bytes', None))
+  if collectives:
+    text = compiled.as_text()
+    out['collectives'] = {
+        op: len(re.findall(rf' {op}(?:-start)?\(', text))
+        for op in ('all-to-all', 'all-reduce', 'all-gather')}
+    out['mesh_scopes'] = sorted(set(re.findall(
+        r'glt\.(?:sample/hop\d+/exchange|collate/(?:cache|exchange)|'
+        r'train/allreduce)', text)))
   # `temp` adds up the temporaries of inner loops that are never alive
   # together; `peak` is what the program needs at once, arguments included
   out['argument_plus_temp_gb'] = (out['argument'] + out['temp']) / 1e9
@@ -296,9 +319,186 @@ def lower_gat_chunk(cfg, traffic, one_chip):
       int(traffic['chunk_size']), one_chip)
 
 
+# ------------------------------------------------- the mesh cell, per chip
+
+def mesh_2x2(parts):
+  import numpy as np
+  from jax.experimental import topologies
+  from jax.sharding import Mesh
+  topo = topologies.get_topology_desc(platform='tpu',
+                                      topology_name='v5e:2x2')
+  return Mesh(np.array(topo.devices[:parts]), ('g',))
+
+
+def mesh_generator_programs(cfg, mesh):
+  from perfbench import datagen_mesh_node as datagen
+  d = cfg['dataset']
+  return datagen.programs(
+      mesh, d['num_nodes'], d['num_directed_edges'], d['num_classes'],
+      d['feat_dim'], d['p_intra'], d['feat_snr'], d['num_train'],
+      cfg['graph_seed'], d['powerlaw_dmax'])
+
+
+def lower_mesh_generator(cfg, mesh):
+  import jax
+  import jax.numpy as jnp
+  from jax.sharding import NamedSharding, PartitionSpec as P
+  g = mesh_generator_programs(cfg, mesh)
+  repl, shard = NamedSharding(mesh, P()), NamedSharding(mesh, P('g'))
+  sds = jax.ShapeDtypeStruct
+  cdf = (sds(g['cdf'].shape, jnp.float32, sharding=repl),)
+  compile_timed('datagen.nodes', g['nodes'], cdf)
+  tables = [sds(a.shape, a.dtype, sharding=repl)
+            for a in g['nodes'].lower(*cdf).out_info]
+  compile_timed('datagen.edges', g['edges'], tables, collectives=True)
+  compile_timed('datagen.rows', g['rows'], (
+      sds((g['parts'], g['n_max']), jnp.int32, sharding=shard),
+      sds(g['centres'].shape, jnp.int32, sharding=repl)))
+
+
+def lower_mesh_cache(cfg, mesh):
+  import jax
+  import jax.numpy as jnp
+  from jax.sharding import NamedSharding, PartitionSpec as P
+
+  from graphlearn_tpu.distributed import dist_feature
+  from perfbench import datagen_mesh_node as datagen
+  d = cfg['dataset']
+  n, parts = d['num_nodes'], cfg['partitions']
+  n_max, _ = datagen.shard_sizes(n, d['num_directed_edges'], parts)
+  h = int(n * cfg['feature_store']['split_ratio'])
+  repl, shard = NamedSharding(mesh, P()), NamedSharding(mesh, P('g'))
+  sds = jax.ShapeDtypeStruct
+  compile_timed('cache.hot_ids', dist_feature._hot_ids_fn(h),
+                (sds((n,), jnp.int32, sharding=repl),))
+  compile_timed(
+      'cache.gather_replicated',
+      dist_feature._gather_replicated_fn(mesh, jnp.float32),
+      (sds((parts, n_max), jnp.int32, sharding=shard),
+       sds((parts, n_max, d['feat_dim']), jnp.float32, sharding=shard),
+       sds((h,), jnp.int32, sharding=repl)), collectives=True)
+
+
+def lower_mesh_chunk(cfg, traffic, mesh):
+  """``DistScanTrainer``'s chunk program traced over the family's Cell on
+  a SMALL graph of the cell's widths on four CPU devices under
+  ``MESH_CAPS`` (a batch's shapes come from caps, fan-out and batch alone),
+  lowered for the described chips with every table at the cell's size."""
+  import copy
+
+  import jax
+  import jax.numpy as jnp
+  from jax.sharding import NamedSharding, PartitionSpec as P
+
+  from perfbench.executors import mesh_scan
+  from perfbench.families import mesh_node
+  small = copy.deepcopy(cfg)
+  d = small['dataset']
+  d['num_nodes'] //= 2000
+  d['num_directed_edges'] //= 2000
+  d['num_train'] = 8 * 4 * small['model']['batch_size']
+  d['powerlaw_dmax'] = 200
+  calibrate = mesh_node.estimate_dist_frontier_caps
+  mesh_node.estimate_dist_frontier_caps = lambda *a, **kw: MESH_CAPS
+  try:
+    cell = mesh_node.Cell(small, traffic, lambda k, v: None)
+  finally:
+    mesh_node.estimate_dist_frontier_caps = calibrate
+  ex = mesh_scan.Executor(cell, traffic, 0)
+  tr = ex.trainer
+  real = cfg['dataset']
+  n, parts = real['num_nodes'], cfg['partitions']
+  g = mesh_generator_programs(cfg, cell.mesh)
+  store = cell.dataset.node_features
+  # a table's leading (per-shard) axis: rows, rows + 1, edges; the
+  # replicated ones: nodes, cached rows
+  swap = {store.n_max: g['n_max'], store.n_max + 1: g['n_max'] + 1,
+          tr._shard_tree['g']['indices'].shape[1]: g['e_max'],
+          cell.num_nodes: n, store.cache_rows: int(
+              n * cfg['feature_store']['split_ratio'])}
+  repl, shard = NamedSharding(mesh, P()), NamedSharding(mesh, P('g'))
+  sds = jax.ShapeDtypeStruct
+
+  def at_size(a, sharding, axis):
+    shape = list(a.shape)
+    if len(shape) > axis:
+      shape[axis] = swap.get(shape[axis], shape[axis])
+    return sds(tuple(shape), a.dtype, sharding=sharding)
+
+  sh = jax.tree.map(lambda a: at_size(a, shard, 1), tr._shard_tree)
+  rp = jax.tree.map(lambda a: at_size(a, repl, 0), tr._repl_tree)
+  rep = lambda tree: jax.tree.map(
+      lambda a: sds(a.shape, a.dtype, sharding=repl), tree)
+  steps = real['num_train'] // (parts * cell.batch)
+  tr.mesh = mesh                      # the described chips, same axis
+  chunk = tr._chunk_fn_for(int(traffic['chunk_size']))
+  out = compile_timed(
+      'dist_scan_chunk', getattr(chunk, '_glt_instrumented', chunk),
+      (sh, rp, sds((parts, 4), jnp.int32, sharding=shard),
+       rep(ex.state.params), rep(ex.state.opt_state),
+       sds((), jnp.int32, sharding=repl), sds((), jnp.bool_, sharding=repl),
+       sds((parts, steps, cell.batch), jnp.int32, sharding=shard),
+       sds((parts, steps, cell.batch), jnp.bool_, sharding=shard),
+       rep(tr._sampler._key), sds((), jnp.int32, sharding=repl),
+       sds((), jnp.int32, sharding=repl)), collectives=True)
+  leaves = jax.tree.leaves
+  tables = sum(a.dtype.itemsize * math.prod(a.shape[1:]) for a in leaves(sh))
+  tables += sum(a.dtype.itemsize * math.prod(a.shape) for a in leaves(rp))
+  print('lower_typed_cell: ' + json.dumps(dict(
+      tables_bytes_a_chip=tables, caps=MESH_CAPS,
+      node_rows=cell.node_offsets[-1], edge_slots=cell.edge_offsets[-1],
+      steps_per_epoch=steps, peak_gib=(out['peak'] or 0) / 2 ** 30,
+      chip_gib=15.75)), flush=True)
+
+
+def lower_mesh_reference(cfg, mesh):
+  import jax
+  import jax.numpy as jnp
+  from jax.sharding import SingleDeviceSharding
+
+  from graphlearn_tpu.models import train as train_lib
+  from perfbench import datagen_mesh_node as datagen
+  from perfbench import reference_mesh_node as reference
+  d, m = cfg['dataset'], cfg['model']
+  one = SingleDeviceSharding(mesh.devices.flat[0])
+  no, eo = train_lib.merge_hop_offsets(m['batch_size'], m['fanout'], None,
+                                       MESH_CAPS)
+  desc = dict(kind='sage', in_dim=d['feat_dim'], hidden=m['hidden'],
+              out_dim=d['num_classes'], layers=len(m['fanout']))
+  centre = datagen.centres(cfg['graph_seed'], d['num_classes'],
+                           d['feat_dim'], d['feat_snr'])
+  sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+  rows = lambda ids: datagen.rows_of(jnp, ids, cfg['graph_seed'],
+                                     d['num_classes'], jnp.asarray(centre))
+  shard_grad = jax.jit(jax.value_and_grad(reference.shard_loss(
+      desc, m['batch_size'], rows, jnp.float32)))
+  params = jax.eval_shape(lambda: reference.init_params(desc, 0))
+  params = jax.tree.map(lambda a: sds(a.shape, a.dtype), params)
+  batch = dict(ids=sds((no[-1],), jnp.int32), live=sds((no[-1],), jnp.bool_),
+               y=sds((m['batch_size'],), jnp.int32),
+               src=sds((eo[-1],), jnp.int32), tgt=sds((eo[-1],), jnp.int32),
+               emask=sds((eo[-1],), jnp.bool_))
+  with jax.default_matmul_precision('highest'):
+    compile_timed('reference.shard_grad', shard_grad, (params, batch))
+
+
 def main(argv):
   from perfbench import run
   which = argv or ['reference']
+  mesh_modes = [m for m in which if m.startswith('mesh-')]
+  if mesh_modes:
+    _, _, cfg, traffic, _ = run.load_cell(MESH_CELL, 'BENCHMARK.json')
+    mesh = mesh_2x2(cfg['partitions'])
+    if 'mesh-generator' in which:
+      lower_mesh_generator(cfg, mesh)
+    if 'mesh-cache' in which:
+      lower_mesh_cache(cfg, mesh)
+    if 'mesh-reference' in which:
+      lower_mesh_reference(cfg, mesh)
+    if 'mesh-chunk' in which:
+      lower_mesh_chunk(cfg, traffic, mesh)
+    if len(mesh_modes) == len(which):
+      return
   one_chip = describe()
   if 'reference' in which or 'chunk' in which:
     _, _, cfg, traffic, _ = run.load_cell(CELL, 'BENCHMARK.json')
