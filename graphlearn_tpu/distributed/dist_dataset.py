@@ -52,8 +52,9 @@ class DistDataset:
     axis. ``features``: ``feat_ids`` ``[P, n_max]`` and ``feats``
     ``[P, n_max, F]``. ``labels``: an ``[P, n_max]`` array in the order
     of ``feat_ids`` (kept on the devices as a one-column store that
-    shares the feature store's id table and book), or a host ``[N]``
-    array as the constructor takes. ``node_pb`` is the one host array:
+    shares the feature store's id table, its index and the book), or a
+    host ``[N]`` array as the constructor takes. ``node_pb`` is the one
+    host array:
     it routes the features too. ``hotness`` ranks the rows for the hot
     cache (``split_ratio`` / ``cache_rows``)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -71,7 +72,7 @@ class DistDataset:
     if labels is not None and getattr(labels, 'ndim', 1) == 2:
       labels = DistFeature.from_device_shards(
           mesh, node_pb, features['feat_ids'], labels[..., None],
-          pb_dev=pb_dev)
+          pb_dev=pb_dev, row_index=df._row_index)
     return cls(dg.num_partitions, 0, dg, df, node_labels=labels,
                node_feat_pb=np.asarray(node_pb), edge_dir=edge_dir)
 

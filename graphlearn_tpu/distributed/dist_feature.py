@@ -14,8 +14,8 @@ system — feature rows are ~F x wider than sampler id traffic, PERF.md
      §UnifiedTensor; reference data/feature.py split_ratio + hotness
      reorder): the globally hottest ``cache_rows`` rows live replicated on
      every shard next to its owned partition. Requested ids are split
-     hit/miss INSIDE the program by a searchsorted over the sorted cached
-     id set; hits gather locally and never touch the interconnect.
+     hit/miss INSIDE the program by a lookup in the sorted cached id
+     set; hits gather locally and never touch the interconnect.
   2. **Miss-only bucketed exchange**: only cache misses — deduped within
      the batch (one request per unique id, response scattered back to all
      its slots) — enter the all_to_all, packed into per-destination
@@ -29,6 +29,13 @@ system — feature rows are ~F x wider than sampler id traffic, PERF.md
      half width and upcasts to the storage dtype after
      ``gather_from_buckets`` — independent of hit rate.
 
+Every position in a sorted id table — the owners' lookup of the miss
+buckets in ``feat_ids``, the cache split in ``cache_ids``, set-up's fill
+of the cache — is found through the table's two-level index
+(ops/sorted_index.py): built once when the store is, on the host for
+host tables and on the devices for device shards, and handed to the
+programs beside the rows it leads to.
+
 On-device hit/miss/overflow counters ride the same program (a [P, 4]
 accumulator threaded through every ``get``), so hit rates are observable
 with ZERO per-batch host syncs: fetch with :meth:`stats` /
@@ -39,6 +46,7 @@ from typing import Optional
 import numpy as np
 
 from .. import ops
+from ..ops import sorted_index
 from ..metrics.registry_names import (SCOPE_CACHE, SCOPE_COLLATE,
                                       SCOPE_EXCHANGE)
 from ..ops.route import exchange_capacity
@@ -111,10 +119,31 @@ def _hot_ids_fn(h: int):
   return jax.jit(pick)
 
 
-def _gather_replicated_fn(mesh, dtype):
-  """The program ``(feat_ids [P, n], feats [P, n, F], ids [m]) -> rows
-  [m, F]`` replicated on every device of the mesh, out of row shards on
-  the mesh: each shard looks the ids up in its own sorted id table, and
+def _index_shards_fn(mesh, id_space: int, shift: int):
+  """The program ``feat_ids [P, n] -> (starts [P, S], largest bucket)``:
+  every shard's two-level index built on the device its table lives on,
+  one ``shift`` for all (the shards run one lookup program), the largest
+  bucket of any shard replicated for the host to size ``depth`` from."""
+  import jax
+  from jax.sharding import PartitionSpec as P
+
+  from ..utils.compat import shard_map
+  ax = tuple(mesh.axis_names)
+
+  def body(fid):
+    starts, big = sorted_index.bucket_starts(fid[0], id_space, shift)
+    return starts[None], jax.lax.pmax(big, ax)
+
+  return jax.jit(shard_map(body, mesh=mesh, in_specs=P(ax),
+                           out_specs=(P(ax), P()),
+                           check_replication=False))
+
+
+def _gather_replicated_fn(mesh, dtype, shift: int, depth: int):
+  """The program ``(feat_ids [P, n], feat_starts [P, S], feats
+  [P, n, F], ids [m]) -> rows [m, F]`` replicated on every device of the
+  mesh, out of row shards on the mesh: each shard looks the ids up in its
+  own sorted id table (through its index, ``shift`` / ``depth``), and
   one ``psum`` of the rows' BIT PATTERNS (every shard but the owner adds
   zero bits, so the sum is the owner's row to the bit, a negative zero
   included) replicates them."""
@@ -127,14 +156,15 @@ def _gather_replicated_fn(mesh, dtype):
   bits = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32}[
       jnp.dtype(dtype).itemsize]
 
-  def body(fid, f, ids):
-    pos = jnp.clip(jnp.searchsorted(fid[0], ids), 0, fid.shape[1] - 1)
-    found = fid[0][pos] == ids
+  def body(fid, starts, f, ids):
+    found, pos = sorted_index.indexed_membership(fid[0], starts[0], ids,
+                                                 shift, depth)
     rows = jax.lax.bitcast_convert_type(f[0, pos], bits)
     rows = jnp.where(found[:, None], rows, jnp.zeros((), bits))
     return jax.lax.bitcast_convert_type(jax.lax.psum(rows, ax), f.dtype)
 
-  return jax.jit(shard_map(body, mesh=mesh, in_specs=(P(ax), P(ax), P()),
+  return jax.jit(shard_map(body, mesh=mesh,
+                           in_specs=(P(ax), P(ax), P(ax), P()),
                            out_specs=P(), check_replication=False))
 
 
@@ -185,6 +215,11 @@ class DistFeature:
     else:
       self.cache_ids = None
       self.cache_feats = None
+    n_total = int(self.feature_pb.shape[0])
+    self._set_indexes(
+        sorted_index.build_sorted_index_host(self.feat_ids, n_total),
+        sorted_index.build_sorted_index_host(self._cache_tables()[0],
+                                             n_total))
 
   def _init_lookup(self, split_ratio, cache_rows, wire_dtype, bucket_frac,
                    dedup):
@@ -206,6 +241,33 @@ class DistFeature:
     self._stats = None
     self._fns = {}
 
+  def _cache_tables(self):
+    """The host cache ``(ids [H], rows [H, F])``; a store with no cache
+    feeds its programs a one-entry pad table that matches no id."""
+    if self.cache_rows:
+      return self.cache_ids, self.cache_feats
+    return (np.full((1,), INT32_MAX, np.int32),
+            np.zeros((1, self.feature_dim), self.storage_dtype))
+
+  def _set_indexes(self, row_index, cache_index, shared_rows=False):
+    """Keep the two-level indexes of ``feat_ids`` and ``cache_ids``
+    (``ops.sorted_index.SortedIndex``: the lookup programs are traced
+    with their ``shift`` and ``depth``) and publish what THIS store
+    built over a real table — the halvings a lookup runs and the bytes
+    a chip holds for them. Set once here, never per batch."""
+    from .. import metrics
+    self._row_index, self._cache_index = row_index, cache_index
+    nbytes = 0
+    if not shared_rows:
+      metrics.set_gauge('dist_feature.index_depth.rows', row_index.depth)
+      nbytes += 4 * int(row_index.starts.shape[-1])
+    if self.cache_rows:
+      metrics.set_gauge('dist_feature.index_depth.cache',
+                        cache_index.depth)
+      nbytes += 4 * int(cache_index.starts.shape[-1])
+    if nbytes:
+      metrics.set_gauge('dist_feature.index_bytes', nbytes)
+
   def _hot_ids(self, hotness, h: int) -> np.ndarray:
     """The ``h`` hottest ids, ascending: the first ``h`` of a stable
     descending sort of ``hotness`` (ties to the lower id); the lowest
@@ -224,7 +286,8 @@ class DistFeature:
                          split_ratio: float = 0.0,
                          cache_rows: Optional[int] = None, hotness=None,
                          wire_dtype=None, bucket_frac=2.0,
-                         dedup: bool = True, pb_dev=None):
+                         dedup: bool = True, pb_dev=None,
+                         row_index=None):
     """A store over row shards that ALREADY live on their devices:
     ``feat_ids`` ``[P, n_max]`` (each shard's owned ids ascending,
     INT32_MAX-padded) and ``feats`` ``[P, n_max, F]`` (its rows in that
@@ -243,7 +306,11 @@ class DistFeature:
     through one ``psum`` of the rows' bit patterns (exact: all shards
     but the owner add zero). ``feature_pb`` stays a host array (the
     routing book; ``pb_dev`` is its replicated placement where the
-    caller already made it); :meth:`cpu_get` fetches from the devices."""
+    caller already made it); :meth:`cpu_get` fetches from the devices.
+    The two-level indexes of ``feat_ids`` and of the cached ids are
+    built on the devices too; ``row_index`` is another store's
+    ``_row_index`` over the SAME ``feat_ids`` (a label store shares the
+    feature store's, as it shares the book)."""
     import jax
     self = cls.__new__(cls)
     self.num_partitions = int(feat_ids.shape[0])
@@ -266,17 +333,33 @@ class DistFeature:
             f'DistFeature.from_device_shards: {name} is placed '
             f'{a.sharding}, not sharded on its leading axis over the '
             f'mesh ({shard})')
+    from ..utils.trace import record_dispatch
+    n_total = int(self.feature_pb.shape[0])
+    shared_rows = row_index is not None
+
+    def built(program, table, shift):
+      # one program over the table where it lives; its largest bucket
+      # is the one scalar set-up fetches
+      record_dispatch('dist_feature.build_index')
+      starts, big = program(table)
+      return sorted_index.SortedIndex(
+          starts, shift, sorted_index.index_depth(jax.device_get(big)))
+
+    if not shared_rows:
+      shift = sorted_index.index_shift(self.n_max, n_total)
+      row_index = built(_index_shards_fn(mesh, n_total, shift), feat_ids,
+                        shift)
     h = self.cache_rows
     if h > 0:
-      from ..utils.trace import record_dispatch
       if hotness is None or not isinstance(hotness, jax.Array):
         cache_ids = global_device_put(self._hot_ids(hotness, h), repl)
       else:
         record_dispatch('dist_feature.fill_cache')
         cache_ids = _hot_ids_fn(h)(jax.device_put(hotness, repl))
       record_dispatch('dist_feature.fill_cache')
-      cache_feats = _gather_replicated_fn(mesh, feats.dtype)(
-          feat_ids, feats, cache_ids)
+      cache_feats = _gather_replicated_fn(
+          mesh, feats.dtype, row_index.shift, row_index.depth)(
+              feat_ids, row_index.starts, feats, cache_ids)
       self.cache_ids = np.asarray(cache_ids)
     else:
       cache_ids = global_device_put(np.full((1,), INT32_MAX, np.int32),
@@ -285,11 +368,17 @@ class DistFeature:
           np.zeros((1, self._fdim), self.storage_dtype), repl)
       self.cache_ids = None
     self.cache_feats = None                # on the devices only
+    shift = sorted_index.index_shift(cache_ids.shape[0], n_total)
+    cache_index = built(
+        jax.jit(lambda t: sorted_index.bucket_starts(t, n_total, shift)),
+        cache_ids, shift)
+    self._set_indexes(row_index, cache_index, shared_rows)
     self._dev = dict(
-        feat_ids=feat_ids, feats=feats,
+        feat_ids=feat_ids, feat_starts=row_index.starts, feats=feats,
         feature_pb=pb_dev if pb_dev is not None else global_device_put(
             self.feature_pb.astype(np.int32), repl),
-        cache_ids=cache_ids, cache_feats=cache_feats)
+        cache_ids=cache_ids, cache_starts=cache_index.starts,
+        cache_feats=cache_feats)
     return self
 
   def _init_storage(self, feat_parts, dtype):
@@ -322,20 +411,43 @@ class DistFeature:
       from jax.sharding import NamedSharding, PartitionSpec as P
       from ..utils import global_device_put
       shard = NamedSharding(self.mesh, P(tuple(self.mesh.axis_names)))
-      repl = NamedSharding(self.mesh, P())
-      h = self.cache_rows
-      cache_ids = (self.cache_ids if h else
-                   np.full((1,), INT32_MAX, np.int32))
-      cache_feats = (self.cache_feats if h else
-                     np.zeros((1, self.feature_dim), self.storage_dtype))
-      self._dev = dict(
-          feat_ids=global_device_put(self.feat_ids, shard),
-          feats=global_device_put(self.feats, shard),
-          feature_pb=global_device_put(self.feature_pb.astype(np.int32),
-                                       repl),
-          cache_ids=global_device_put(cache_ids, repl),
-          cache_feats=global_device_put(cache_feats, repl))
+      self._dev = dict(feats=global_device_put(self.feats, shard),
+                       **self._routing_arrays())
     return self._dev
+
+  def _routing_arrays(self) -> dict:
+    """A host-built store's routing structures placed on the mesh: the
+    sorted id tables and their indexes, the book, the hot cache —
+    everything a lookup program takes but the row payload."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from ..utils import global_device_put
+    shard = NamedSharding(self.mesh, P(tuple(self.mesh.axis_names)))
+    repl = NamedSharding(self.mesh, P())
+    cache_ids, cache_feats = self._cache_tables()
+    return dict(
+        feat_ids=global_device_put(self.feat_ids, shard),
+        feat_starts=global_device_put(self._row_index.starts, shard),
+        feature_pb=global_device_put(self.feature_pb.astype(np.int32),
+                                     repl),
+        cache_ids=global_device_put(cache_ids, repl),
+        cache_starts=global_device_put(self._cache_index.starts, repl),
+        cache_feats=global_device_put(cache_feats, repl))
+
+  # device_arrays()' entries a lookup program takes, by placement
+  SHARD_KEYS = ('feat_ids', 'feat_starts', 'feats')
+  REPL_KEYS = ('feature_pb', 'cache_ids', 'cache_starts', 'cache_feats')
+
+  @staticmethod
+  def table_args(shard_view: dict, repl_view: dict, feats=None) -> tuple:
+    """The five table arguments of a :meth:`_shard_body` program out of
+    per-shard views of :meth:`device_arrays`' entries: rows travel with
+    the index that finds them, ``(starts, rows)``. ``feats`` replaces
+    the row payload (the slab path's ``(hot, slab_pos, slab_rows)``)."""
+    if feats is None:
+      feats = shard_view['feats']
+    return (shard_view['feat_ids'], (shard_view['feat_starts'], feats),
+            repl_view['feature_pb'], repl_view['cache_ids'],
+            (repl_view['cache_starts'], repl_view['cache_feats']))
 
   # ------------------------------------------------------------ stats
   def _stats_dev(self):
@@ -394,10 +506,13 @@ class DistFeature:
     cache-split -> miss-dedup -> bucketed-exchange -> merge computation
     and thread the [4] stats row through their own carry.
 
-    Returns ``body(feat_ids [n], feats [n, F] or [1, n, F], pb, cache_ids,
-    cache_feats, stats_row [4], ids [b], mask [b]) ->
-    (rows [b, F], new_stats_row [4])``. Must be traced on this store's
-    mesh (the exchange collectives run over every mesh axis).
+    Returns ``body(feat_ids [n], (feat_starts, feats [n, F] or
+    [1, n, F]), pb, cache_ids, (cache_starts, cache_feats), stats_row
+    [4], ids [b], mask [b]) -> (rows [b, F], new_stats_row [4])``: the
+    rows of each table arrive behind the ``starts`` of the index that
+    finds them (:meth:`table_args` packs the five table arguments from
+    :meth:`device_arrays`' entries). Must be traced on this store's mesh
+    (the exchange collectives run over every mesh axis).
 
     ``slab=True`` is the SLAB-BACKED lookup path (device
     oversubscription through the shard exchange — storage/dist_scan.py,
@@ -406,10 +521,11 @@ class DistFeature:
     ``[n, F]`` partition — a remote request resolves its position in
     this shard's sorted id table exactly as before, but the ROW comes
     from the HBM hot prefix (position < H) or the chunk's staged slab
-    (searchsorted over the staged position list, INT32_MAX pads never
-    match). Under an exact miss-exchange program every requested
-    position >= H is in the slab by construction, so the exchanged
-    bytes are identical to the all-HBM path."""
+    (searchsorted over the staged position list, which has no id space
+    to index; INT32_MAX pads never match). Under an exact miss-exchange
+    program every requested position >= H is in the slab by
+    construction, so the exchanged bytes are identical to the all-HBM
+    path."""
     import jax
     import jax.numpy as jnp
 
@@ -421,6 +537,8 @@ class DistFeature:
     dedup = self.dedup
     bucket_frac = self.bucket_frac
     hit_est = self._cache_frac
+    _, rshift, rdepth = self._row_index
+    _, cshift, cdepth = self._cache_index
     # collectives/specs over every mesh axis: works identically on the
     # flat ('g',) mesh and a 2-axis ('slice', 'chip') mesh
     ax = tuple(self.mesh.axis_names)
@@ -433,10 +551,9 @@ class DistFeature:
         the sorted owned-id table as usual, payload from the hot
         prefix or the staged slab (zeros where absent/padded — an
         impossible case for planned rows under an exact program)."""
-        hot, slab_pos, slab_rows = feats
-        pos = jnp.clip(jnp.searchsorted(feat_ids, flat), 0,
-                       feat_ids.shape[0] - 1)
-        found = feat_ids[pos] == flat
+        starts, (hot, slab_pos, slab_rows) = feats
+        found, pos = sorted_index.indexed_membership(
+            feat_ids, starts, flat, rshift, rdepth)
         hp = hot.shape[0]
         hot_rows = hot[jnp.clip(pos, 0, hp - 1)]
         sp = jnp.clip(jnp.searchsorted(slab_pos, pos.astype(jnp.int32)),
@@ -453,9 +570,9 @@ class DistFeature:
         is a physical copy of the whole table in front of every program
         that gathers from it — 4.7 GB at 9 M rows — where indexing
         through it is free."""
-        pos = jnp.clip(jnp.searchsorted(feat_ids, flat), 0,
-                       feat_ids.shape[0] - 1)
-        found = feat_ids[pos] == flat
+        starts, feats = feats
+        found, pos = sorted_index.indexed_membership(
+            feat_ids, starts, flat, rshift, rdepth)
         rows = feats[0, pos] if feats.ndim == 3 else feats[pos]
         return jnp.where(found[:, None], rows, 0)
 
@@ -541,11 +658,12 @@ class DistFeature:
     def body(feat_ids, feats, pb, cache_ids, cache_feats, stats, ids,
              mask):
       safe = jnp.maximum(ids, 0)
+      cache_starts, cache_feats = cache_feats
       with jax.named_scope(SCOPE_CACHE):
         if h > 0:
-          cpos = jnp.clip(jnp.searchsorted(cache_ids, safe), 0,
-                          cache_ids.shape[0] - 1)
-          is_hit = mask & (cache_ids[cpos] == safe)
+          in_cache, cpos = sorted_index.indexed_membership(
+              cache_ids, cache_starts, safe, cshift, cdepth)
+          is_hit = mask & in_cache
           out_hit = jnp.where(is_hit[:, None], cache_feats[cpos], 0)
           miss = mask & ~is_hit
         else:
@@ -586,25 +704,24 @@ class DistFeature:
     ax = tuple(self.mesh.axis_names)
     core = self._shard_body(b)
 
-    def body(feat_ids, feats, pb, cache_ids, cache_feats, stats, ids,
-             mask):
+    def body(shard, repl, stats, ids, mask):
       # per-shard views: feat_ids [1, n], feats [1, n, F], ids [1, b]
       # feats keeps its [1, n, F] axis (see lookup_local)
-      out, new_stats = core(feat_ids[0], feats, pb, cache_ids,
-                            cache_feats, stats[0], ids[0], mask[0])
+      views = {k: a if k == 'feats' else a[0] for k, a in shard.items()}
+      out, new_stats = core(*self.table_args(views, repl), stats[0],
+                            ids[0], mask[0])
       return out[None], new_stats[None]
 
     fn = shard_map(
         body, mesh=self.mesh,
-        in_specs=(P(ax), P(ax), P(), P(), P(), P(ax), P(ax), P(ax)),
+        in_specs=(P(ax), P(), P(ax), P(ax), P(ax)),
         out_specs=(P(ax), P(ax)))
     jfn = jax.jit(fn)
+    shard = {k: dev[k] for k in self.SHARD_KEYS}
+    repl = {k: dev[k] for k in self.REPL_KEYS}
 
     def run(ids, mask):
-      out, self._stats = jfn(dev['feat_ids'], dev['feats'],
-                             dev['feature_pb'], dev['cache_ids'],
-                             dev['cache_feats'], self._stats_dev(),
-                             ids, mask)
+      out, self._stats = jfn(shard, repl, self._stats_dev(), ids, mask)
       return out
 
     return run

@@ -184,14 +184,7 @@ class TieredDistFeature(DistFeature):
       import jax
       from jax.sharding import NamedSharding, PartitionSpec as P
 
-      from ..utils import global_device_put
       shard = NamedSharding(self.mesh, P(tuple(self.mesh.axis_names)))
-      repl = NamedSharding(self.mesh, P())
-      h = self.cache_rows
-      cache_ids = (self.cache_ids if h else
-                   np.full((1,), INT32_MAX, np.int32))
-      cache_feats = (self.cache_feats if h else
-                     np.zeros((1, self.feature_dim), self.storage_dtype))
       shape = (self.num_partitions, self.n_max, self.feature_dim)
 
       def part_block(p: int) -> np.ndarray:
@@ -209,12 +202,8 @@ class TieredDistFeature(DistFeature):
         return block[(slice(None),) + tuple(index[1:])]
 
       self._dev = dict(
-          feat_ids=global_device_put(self.feat_ids, shard),
           feats=jax.make_array_from_callback(shape, shard, cb),
-          feature_pb=global_device_put(self.feature_pb.astype(np.int32),
-                                       repl),
-          cache_ids=global_device_put(cache_ids, repl),
-          cache_feats=global_device_put(cache_feats, repl))
+          **self._routing_arrays())
     return self._dev
 
   def gather_positions(self, p: int, positions: np.ndarray) -> np.ndarray:
@@ -243,25 +232,14 @@ class TieredDistFeature(DistFeature):
             'chunk program clamps pad positions into the hot prefix) — '
             'pass hot_prefix_rows=... to TieredDistFeature')
       shard = NamedSharding(self.mesh, P(tuple(self.mesh.axis_names)))
-      repl = NamedSharding(self.mesh, P())
-      c = self.cache_rows
-      cache_ids = (self.cache_ids if c else
-                   np.full((1,), INT32_MAX, np.int32))
-      cache_feats = (self.cache_feats if c else
-                     np.zeros((1, self.feature_dim), self.storage_dtype))
       hot = np.zeros((self.num_partitions, h, self.feature_dim),
                      self.storage_dtype)
       for p in range(self.num_partitions):
         n_p = min(h, self._part_rows(p))
         if n_p:
           hot[p, :n_p] = self._tiers[p].gather(np.arange(n_p))
-      self._scan_dev = dict(
-          feat_ids=global_device_put(self.feat_ids, shard),
-          hot=global_device_put(hot, shard),
-          feature_pb=global_device_put(self.feature_pb.astype(np.int32),
-                                       repl),
-          cache_ids=global_device_put(cache_ids, repl),
-          cache_feats=global_device_put(cache_feats, repl))
+      self._scan_dev = dict(hot=global_device_put(hot, shard),
+                            **self._routing_arrays())
     return self._scan_dev
 
   # ---------------------------------------------- per-step demand paging
@@ -312,17 +290,17 @@ class TieredDistFeature(DistFeature):
     ax = tuple(self.mesh.axis_names)
     core = self._shard_body(b, slab=True)
 
-    def body(feat_ids, hot, slab_pos, slab_rows, pb, cache_ids,
-             cache_feats, stats, ids, mask):
+    def body(shard, repl, stats, ids, mask):
+      views = jax.tree.map(lambda a: a[0], shard)
       out, new_stats = core(
-          feat_ids[0], (hot[0], slab_pos[0], slab_rows[0]), pb,
-          cache_ids, cache_feats, stats[0], ids[0], mask[0])
+          *self.table_args(views, repl, (views['hot'], views['slab_pos'],
+                                         views['slab_rows'])),
+          stats[0], ids[0], mask[0])
       return out[None], new_stats[None]
 
     fn = shard_map(
         body, mesh=self.mesh,
-        in_specs=(P(ax), P(ax), P(ax), P(ax), P(), P(), P(), P(ax),
-                  P(ax), P(ax)),
+        in_specs=(P(ax), P(), P(ax), P(ax), P(ax)),
         out_specs=(P(ax), P(ax)))
     return jax.jit(fn)
 
@@ -379,9 +357,9 @@ class TieredDistFeature(DistFeature):
     if jfn is None:
       jfn = fns[cap] = self._build_slab_fn(b, cap)
     out, self._stats = jfn(
-        scan['feat_ids'], scan['hot'], slab_pos, slab_rows,
-        scan['feature_pb'], scan['cache_ids'], scan['cache_feats'],
-        self._stats_dev(), ids, mask)
+        dict(feat_ids=scan['feat_ids'], feat_starts=scan['feat_starts'],
+             hot=scan['hot'], slab_pos=slab_pos, slab_rows=slab_rows),
+        {k: scan[k] for k in self.REPL_KEYS}, self._stats_dev(), ids, mask)
     return out
 
   def tier_bytes(self) -> dict:

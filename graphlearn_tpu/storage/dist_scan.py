@@ -242,16 +242,17 @@ class TieredDistScanTrainer(DistScanTrainer):
     # loaders' contract)
     fdev = self._store.dist_scan_tables()
     ldev = self._label_store.device_arrays()
+    shard_keys = self._label_store.SHARD_KEYS
+    repl_keys = self._label_store.REPL_KEYS
+    table_args = self._label_store.table_args
     shard_tree = dict(
         g=gsh,
-        f={k: fdev[k] for k in ('feat_ids', 'hot')},
-        l={k: ldev[k] for k in ('feat_ids', 'feats')})
+        f={k: fdev[k] for k in ('feat_ids', 'feat_starts', 'hot')},
+        l={k: ldev[k] for k in shard_keys})
     repl_tree = dict(
         pb=d['node_pb'],
-        f={k: fdev[k] for k in ('feature_pb', 'cache_ids',
-                                'cache_feats')},
-        l={k: ldev[k] for k in ('feature_pb', 'cache_ids',
-                                'cache_feats')})
+        f={k: fdev[k] for k in repl_keys},
+        l={k: ldev[k] for k in repl_keys})
 
     def body(views, repl, stats_rows, seeds, smask, key, slab_pos,
              slab_rows):
@@ -261,15 +262,12 @@ class TieredDistScanTrainer(DistScanTrainer):
                            bucket_frac=bucket_frac, axes=ax,
                            axis_sizes=sizes)
       ids = res['node']
-      fv, frep = views['f'], repl['f']
-      x, srow = feat_body(fv['feat_ids'],
-                          (fv['hot'], slab_pos, slab_rows),
-                          frep['feature_pb'], frep['cache_ids'],
-                          frep['cache_feats'], stats_rows, ids, ids >= 0)
+      fv = views['f']
+      x, srow = feat_body(
+          *table_args(fv, repl['f'], (fv['hot'], slab_pos, slab_rows)),
+          stats_rows, ids, ids >= 0)
       lab_ids = ids[:label_cap] if label_cap is not None else ids
-      lv, lrep = views['l'], repl['l']
-      y, _ = lab_body(lv['feat_ids'], lv['feats'], lrep['feature_pb'],
-                      lrep['cache_ids'], lrep['cache_feats'],
+      y, _ = lab_body(*table_args(views['l'], repl['l']),
                       jnp.zeros((4,), jnp.int32), lab_ids, lab_ids >= 0)
       batch = dict(x=x,
                    edge_index=jnp.stack([res['row'], res['col']]),
@@ -313,18 +311,18 @@ class TieredDistScanTrainer(DistScanTrainer):
     # hot-prefix tables only, per ntype — no full [P, n_max, F] uploads
     fdevs = {t: self._feat[t].dist_scan_tables() for t in feat_types}
     ldev = self._label_store.device_arrays()
+    shard_keys = self._label_store.SHARD_KEYS
+    repl_keys = self._label_store.REPL_KEYS
+    table_args = self._label_store.table_args
     shard_tree = dict(
         g=gsh,
-        f={t: {k: fdevs[t][k] for k in ('feat_ids', 'hot')}
+        f={t: {k: fdevs[t][k] for k in ('feat_ids', 'feat_starts', 'hot')}
            for t in feat_types},
-        l={k: ldev[k] for k in ('feat_ids', 'feats')})
+        l={k: ldev[k] for k in shard_keys})
     repl_tree = dict(
         pb=dict(d['#pb']),
-        f={t: {k: fdevs[t][k] for k in ('feature_pb', 'cache_ids',
-                                        'cache_feats')}
-           for t in feat_types},
-        l={k: ldev[k] for k in ('feature_pb', 'cache_ids',
-                                'cache_feats')})
+        f={t: {k: fdevs[t][k] for k in repl_keys} for t in feat_types},
+        l={k: ldev[k] for k in repl_keys})
 
     def body(views, repl, stats_rows, seeds, smask, key, slab_pos,
              slab_rows):
@@ -333,16 +331,14 @@ class TieredDistScanTrainer(DistScanTrainer):
       x, new_rows = {}, {}
       for t in feat_types:
         ids = res['node'][t]
-        fv, frep = views['f'][t], repl['f'][t]
+        fv = views['f'][t]
         x[t], new_rows[t] = feat_bodies[t](
-            fv['feat_ids'], (fv['hot'], slab_pos[t], slab_rows[t]),
-            frep['feature_pb'], frep['cache_ids'], frep['cache_feats'],
+            *table_args(fv, repl['f'][t],
+                        (fv['hot'], slab_pos[t], slab_rows[t])),
             stats_rows[t], ids, ids >= 0)
       ids = res['node'][t_in]
       lab_ids = ids[:label_cap] if label_cap is not None else ids
-      lv, lrep = views['l'], repl['l']
-      y, _ = lab_body(lv['feat_ids'], lv['feats'], lrep['feature_pb'],
-                      lrep['cache_ids'], lrep['cache_feats'],
+      y, _ = lab_body(*table_args(views['l'], repl['l']),
                       jnp.zeros((4,), jnp.int32), lab_ids, lab_ids >= 0)
       ei = {et: jnp.stack([res['row'][et], res['col'][et]])
             for et in res['row']}

@@ -53,6 +53,9 @@ GAT_CELL = 'gat-products.scan-exact'
 GAT_CAPS = [6528, 33664, 84736]   # that cell's set-up line (PERF.md section 4)
 MESH_CELL = 'sage-papers.mesh-exact'
 MESH_CAPS = [8960, 62080, 192768]  # that cell's set-up line (PERF.md section 4)
+# halvings of a lookup in the cell's 1.85 M cached ids: the largest of its
+# 289 k buckets of 128 ids holds 16-31 (dist_feature.index_depth.cache)
+MESH_CACHE_DEPTH = 5
 CAPS = {
     'author__affiliated_to__institute': [128, 1920, 7040],
     'author__rev_written_by__paper': [128, 18304, 69120],
@@ -362,6 +365,7 @@ def lower_mesh_cache(cfg, mesh):
   from jax.sharding import NamedSharding, PartitionSpec as P
 
   from graphlearn_tpu.distributed import dist_feature
+  from graphlearn_tpu.ops import sorted_index
   from perfbench import datagen_mesh_node as datagen
   d = cfg['dataset']
   n, parts = d['num_nodes'], cfg['partitions']
@@ -371,10 +375,25 @@ def lower_mesh_cache(cfg, mesh):
   sds = jax.ShapeDtypeStruct
   compile_timed('cache.hot_ids', dist_feature._hot_ids_fn(h),
                 (sds((n,), jnp.int32, sharding=repl),))
+  # the two index builds, then the cache's fill through the rows' index
+  # (its depth is the table's: id % P ownership puts 2^shift / P ids in
+  # every bucket)
+  shift = sorted_index.index_shift(n_max, n)
+  depth = sorted_index.index_depth((1 << shift) // parts)
+  starts = (n >> shift) + 2
+  compile_timed('index.rows', dist_feature._index_shards_fn(mesh, n, shift),
+                (sds((parts, n_max), jnp.int32, sharding=shard),),
+                collectives=True)
+  cshift = sorted_index.index_shift(h, n)
+  compile_timed(
+      'index.cache',
+      jax.jit(lambda t: sorted_index.bucket_starts(t, n, cshift)),
+      (sds((h,), jnp.int32, sharding=repl),))
   compile_timed(
       'cache.gather_replicated',
-      dist_feature._gather_replicated_fn(mesh, jnp.float32),
+      dist_feature._gather_replicated_fn(mesh, jnp.float32, shift, depth),
       (sds((parts, n_max), jnp.int32, sharding=shard),
+       sds((parts, starts), jnp.int32, sharding=shard),
        sds((parts, n_max, d['feat_dim']), jnp.float32, sharding=shard),
        sds((h,), jnp.int32, sharding=repl)), collectives=True)
 
@@ -404,18 +423,26 @@ def lower_mesh_chunk(cfg, traffic, mesh):
     cell = mesh_node.Cell(small, traffic, lambda k, v: None)
   finally:
     mesh_node.estimate_dist_frontier_caps = calibrate
+  store = cell.dataset.node_features
+  # the small graph's id tables have the cell's shifts (the same N / rows)
+  # and, under id % P, its rows' depth; the cache's depth is the table's
+  store._cache_index = store._cache_index._replace(depth=MESH_CACHE_DEPTH)
   ex = mesh_scan.Executor(cell, traffic, 0)
   tr = ex.trainer
   real = cfg['dataset']
   n, parts = real['num_nodes'], cfg['partitions']
   g = mesh_generator_programs(cfg, cell.mesh)
-  store = cell.dataset.node_features
   # a table's leading (per-shard) axis: rows, rows + 1, edges; the
-  # replicated ones: nodes, cached rows
+  # replicated ones: nodes, cached rows; the two indexes' starts
+  starts = lambda idx, nodes: (nodes >> idx.shift) + 2
   swap = {store.n_max: g['n_max'], store.n_max + 1: g['n_max'] + 1,
           tr._shard_tree['g']['indices'].shape[1]: g['e_max'],
           cell.num_nodes: n, store.cache_rows: int(
-              n * cfg['feature_store']['split_ratio'])}
+              n * cfg['feature_store']['split_ratio']),
+          starts(store._row_index, cell.num_nodes): starts(
+              store._row_index, n),
+          starts(store._cache_index, cell.num_nodes): starts(
+              store._cache_index, n)}
   repl, shard = NamedSharding(mesh, P()), NamedSharding(mesh, P('g'))
   sds = jax.ShapeDtypeStruct
 

@@ -88,6 +88,31 @@ def test_device_built_shards_equal_host_built_bit_for_bit(world):
   assert np.array_equal(dev.node_features.cache_ids,
                         host.node_features.cache_ids)
   assert dev.node_features.feats is None and dev.graph.indices is None
+  # the indexes a device built are the host's (their starts were compared
+  # with the tables above); the label store shares the rows' index
+  for name in ('_row_index', '_cache_index'):
+    a, b = getattr(host.node_features, name), getattr(dev.node_features,
+                                                      name)
+    assert (a.shift, a.depth) == (b.shift, b.depth), name
+  assert dev.node_labels._row_index is dev.node_features._row_index
+  assert (dev.node_labels.device_arrays()['feat_starts']
+          is fb['feat_starts'])
+
+
+def test_device_built_store_answers_like_the_host_built(world):
+  host, dev = world['host'].node_features, world['dev'].node_features
+  rng = np.random.default_rng(2)
+  ids = rng.integers(0, N, (P, 20)).astype(np.int32)
+  ids[:, -2:] = -1
+  want = np.where((ids >= 0)[..., None], world['feat'][np.maximum(ids, 0)],
+                  0)
+  for store in (host, dev):
+    assert np.asarray(store.get(ids)).tobytes() == want.tobytes()
+    store.reset_stats()
+  lab = np.asarray(world['dev'].node_labels.get(ids))[..., 0]
+  assert np.array_equal(lab, np.where(ids >= 0,
+                                      world['label'][np.maximum(ids, 0)], 0))
+  world['dev'].node_labels.reset_stats()
 
 
 @pytest.mark.parametrize('hotness', ['host', 'none'])
