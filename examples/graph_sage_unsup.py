@@ -8,7 +8,16 @@ here (zero egress), so the graph is a synthetic community graph — link
 prediction on it is learnable exactly when the embeddings capture the
 communities.
 
-Run: python examples/graph_sage_unsup.py --epochs 2
+Training runs through ``glt.loader.ScanTrainer``: the epoch is a scanned
+program over the link loader — a step gathers its 512 seed edges by
+position in the epoch's keyed order, draws and tests its negatives, unions
+the seeds, expands and trains, all inside one chunk, and the negative
+sampler's ``link.negatives.*`` / ``link.seeds.unique`` counters come out
+once an epoch (docs/observability.md). ``--per-batch`` keeps the loop GLT
+users write: iterate the loader and step ``make_link_train_step`` batch by
+batch (two dispatches a batch).
+
+Run: python examples/graph_sage_unsup.py --epochs 2 [--per-batch]
 """
 import argparse
 import json
@@ -33,6 +42,10 @@ def main():
   ap.add_argument('--batch-size', type=int, default=512)
   ap.add_argument('--hidden', type=int, default=128)
   ap.add_argument('--lr', type=float, default=3e-3)
+  ap.add_argument('--chunk-size', type=int, default=16)
+  ap.add_argument('--per-batch', action='store_true',
+                  help='iterate the loader batch by batch instead of '
+                       'scanning the epoch')
   args = ap.parse_args()
 
   import jax
@@ -100,13 +113,22 @@ def main():
   train_step, eval_step = train_lib.make_link_train_step(model, tx)
 
   losses, accs, epoch_times = [], [], []
+  # a link job is asked for no num_classes: the trainer reads the step
+  # contract (seed pairs, negatives, the pair loss) off the loader's kind
+  trainer = None if args.per_batch else glt.loader.ScanTrainer(
+      loader, model, tx, chunk_size=args.chunk_size)
   for epoch in range(args.epochs):
     t0 = time.perf_counter()
-    for batch in loader:
-      state, loss, acc = train_step(state,
-                                    train_lib.link_batch_to_dict(batch))
-      losses.append(loss)
-      accs.append(acc)
+    if trainer is not None:
+      state, loss_e, acc_e = trainer.run_epoch(state)
+      losses.extend(loss_e)     # device arrays: fetched once, at the end
+      accs.extend(acc_e)
+    else:
+      for batch in loader:
+        state, loss, acc = train_step(state,
+                                      train_lib.link_batch_to_dict(batch))
+        losses.append(loss)
+        accs.append(acc)
     jax.block_until_ready(state)
     epoch_times.append(time.perf_counter() - t0)
 
@@ -115,6 +137,8 @@ def main():
   jax.block_until_ready(test_accs)
 
   print(json.dumps({
+      'trainer': 'per-batch loop' if trainer is None else 'ScanTrainer',
+      'negatives': glt.utils.trace.counters('link.'),
       'first_loss': round(float(losses[0]), 4),
       'final_loss': round(float(losses[-1]), 4),
       'final_train_link_acc': round(float(accs[-1]), 4),

@@ -15,6 +15,14 @@ from ..sampler import BaseSampler, EdgeSamplerInput, NegativeSampling
 from .node_loader import NodeLoader, SeedBatcher
 
 
+def _on_device(x) -> bool:
+  """A jax array, or a pair of them."""
+  import jax
+  if isinstance(x, (tuple, list)):
+    return len(x) == 2 and all(isinstance(a, jax.Array) for a in x)
+  return isinstance(x, jax.Array)
+
+
 class LinkLoader(NodeLoader):
   """Reference: loader/link_loader.py:35-229."""
 
@@ -29,7 +37,13 @@ class LinkLoader(NodeLoader):
     from ..typing import split_edge_type_seeds
     self.edge_type, edge_label_index = \
         split_edge_type_seeds(edge_label_index)
-    eli = np.asarray(edge_label_index)
+    # seed edges stay where the caller holds them: a device array (or a
+    # (rows, cols) pair of device arrays) is never fetched — a seed set
+    # of every edge of a large graph is built on the device and stays
+    # there; anything else becomes host numpy, as the reference's
+    eli = edge_label_index
+    if not _on_device(eli):
+      eli = np.asarray(eli)
     self.rows, self.cols = eli[0].reshape(-1), eli[1].reshape(-1)
     self.edge_label = (np.asarray(edge_label).reshape(-1)
                        if edge_label is not None else None)
@@ -45,6 +59,16 @@ class LinkLoader(NodeLoader):
     self._batcher = SeedBatcher(len(self.rows), batch_size, shuffle,
                                 drop_last, seed)
     del with_edge
+
+  def seed_pairs_device(self):
+    """``(rows, cols)`` as device arrays, uploaded once: what a scanned
+    epoch gathers a step's seed pairs from."""
+    import jax.numpy as jnp
+    if getattr(self, '_pairs_dev', None) is None:
+      as_dev = lambda a: a if _on_device(a) else jnp.asarray(
+          np.asarray(a, dtype=np.int32))
+      self._pairs_dev = (as_dev(self.rows), as_dev(self.cols))
+    return self._pairs_dev
 
   def __iter__(self):
     guarded, recompute = self._overflow_epoch_start()

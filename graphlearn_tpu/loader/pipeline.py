@@ -25,7 +25,9 @@ from typing import Optional
 
 from .. import ops
 from ..metrics.registry_names import (SCOPE_ALLREDUCE, SCOPE_FWD_BWD,
+                                      SCOPE_SAMPLE, SCOPE_SEEDS,
                                       SCOPE_TRAIN, SCOPE_UPDATE)
+from .link_loader import LinkLoader
 from .node_loader import NodeLoader
 
 _RECOMPUTE_MSG = (
@@ -60,15 +62,35 @@ def refuse_typed(loader, name: str):
         'typed graphs scan through loader.ScanTrainer')
 
 
+def refuse_link(loader, name: str):
+  """For the executors whose chunk programs keep the node job's step
+  contract (seeds, labels, cross-entropy): called first in their
+  ``__init__``."""
+  if isinstance(loader, LinkLoader):
+    raise ValueError(
+        f'{name} scans node-seeded jobs: its chunk program slices a '
+        '[steps, batch] matrix of seed NODES and trains on node labels. '
+        'An edge-seeded job (seed pairs, negatives drawn in the program, '
+        'the pair loss) scans through loader.ScanTrainer')
+
+
 class FusedEpochTrainer:
   """Shared plumbing for the fused epoch executors
   (scan_epoch.ScanTrainer and its subclasses): scope validation, the
   device feature/label tables, and the pure sample+collate body they
   trace into their programs.
 
-  Requirements: fused sampler, device-resident feature/label tables, no
-  edge features (the fused programs keep the reference fast path's
-  scope: supervised node classification). Where the batches come from
+  Requirements: fused sampler, device-resident feature tables, no edge
+  features. The STEP CONTRACT — what a step's seeds are, what the batch
+  source hands the chunk and which loss reads it — is read from the
+  loader's kind: a node loader gives seed nodes, node labels and the
+  masked cross-entropy step (``num_classes`` wide); a link loader
+  (``LinkNeighborLoader``) gives seed PAIRS by position in its
+  ``edge_label_index``, its ``NegativeSampling``, the sampler's link
+  body (``NeighborSampler._link_body``: negatives, seed union,
+  expansion, ``edge_label_index`` through ``seed_inverse``) and the pair
+  step (``train.make_link_train_step``'s loss), and is asked for no
+  ``num_classes``. Where a node job's batches come from
   is read from the loader's sampler: a homogeneous graph gives the
   fused multi-hop program + ``ops.collate_batch``; a typed graph with
   seeds of ONE node type gives the
@@ -80,10 +102,12 @@ class FusedEpochTrainer:
 
   _NAME = 'FusedEpochTrainer'
 
-  def __init__(self, loader: NodeLoader, model, tx, num_classes: int,
+  def __init__(self, loader: NodeLoader, model, tx,
+               num_classes: Optional[int] = None,
                seed_labels_only: Optional[bool] = None):
     sampler = loader.sampler
     typed = bool(getattr(sampler, 'is_hetero', False))
+    link = isinstance(loader, LinkLoader)
     if not sampler.fused:
       raise ValueError(f'{self._NAME} needs the fused sampler path')
     if sampler.with_edge:
@@ -100,7 +124,13 @@ class FusedEpochTrainer:
     if seed_labels_only is None:
       seed_labels_only = loader.seed_labels_only
     self._label_cap = self._batch_size if seed_labels_only else None
-    self._input_type = loader.input_type if typed else None
+    self._input_type = loader.input_type if typed and not link else None
+    from ..models import train as train_lib
+    if link:
+      self._init_link_source(loader, typed)
+      self._train_step, _ = train_lib.make_link_train_step(model, tx)
+      self._sample_collate = self._make_link_sample_collate_body()
+      return
     if typed:
       self._init_typed_source(loader)
     else:
@@ -111,8 +141,10 @@ class FusedEpochTrainer:
     self._labels = loader._label_table(self._input_type)
     if self._labels is None:
       raise ValueError(f'{self._NAME} needs node labels')
+    if num_classes is None:
+      raise ValueError(f'{self._NAME}: a node job trains on node labels '
+                       'by cross-entropy and needs num_classes')
 
-    from ..models import train as train_lib
     self._train_step, _ = train_lib.make_train_step(model, tx, num_classes)
     self._sample_collate = self._make_sample_collate_body()
 
@@ -160,6 +192,66 @@ class FusedEpochTrainer:
                        'tables (Feature on HBM)')
     self._feats, self._id2i = feats, id2i
 
+  def _init_link_source(self, loader, typed: bool):
+    """The edge-seeded batch source: ``batch_size`` seed pairs a step,
+    gathered on the device from the loader's ``edge_label_index`` by the
+    epoch order's positions, then the sampler's link body. What the
+    chunk does not run yet is refused here, each by its mechanism."""
+    neg = loader.neg_sampling
+    if typed:
+      from ..sampler.capacity import CapacityPlanError
+      raise CapacityPlanError(
+          self._NAME, 'a typed link batch seeds TWO node types (the '
+          "seed edge type's ends), and the chunk's typed source takes "
+          'seeds of one', 'iterate the typed LinkNeighborLoader per batch')
+    if neg is not None and not neg.is_binary():
+      raise ValueError(
+          f'{self._NAME}: the pair step reads edge_label_index / '
+          'edge_label; a triplet batch carries src / dst_pos / dst_neg '
+          'indices, for which models.train has no step — iterate the '
+          'loader per batch with your own margin loss')
+    if loader.edge_label is not None:
+      raise ValueError(
+          f'{self._NAME}: the chunk gathers a step\'s seed pairs by '
+          'position and labels them ones (then zeros for the negatives); '
+          'a caller\'s edge_label array is not gathered beside them — '
+          'iterate the loader per batch')
+    if len(loader.rows) % self._batch_size and not \
+        loader._batcher.drop_last:
+      raise ValueError(
+          f'{self._NAME}: a scanned link step has one static width, and '
+          'the per-batch loop draws num_negatives(tail) negatives for a '
+          'short last batch — pass drop_last=True, or a seed set that '
+          'batch_size divides')
+    self._neg_sampling = neg
+    self._link_body = self._sampler._link_body(self._batch_size, neg)
+    self._key_stride = 1
+    self._feats, self._id2i = self._resolve_feature_tables(loader)
+    # the table the chunk reads beside the features: a node job's node
+    # labels, a link job's seed pairs (rows, cols), device arrays
+    self._labels = loader.seed_pairs_device()
+
+  def _make_link_sample_collate_body(self):
+    """The link job's traced sample+collate body: ``pairs`` (the loader's
+    seed-edge arrays) and ``pos`` (the step's positions in them) where
+    the node body takes labels and seed ids. Returns ``(batch, overflow,
+    link_counts)``; the batch is ``train.link_batch_to_dict``'s."""
+    import jax
+    link_body = self._link_body
+
+    def _sample_collate(gargs, feats, id2i, pairs, pos, pmask, key):
+      del pmask   # every slot is a seed edge (no ragged tail: __init__)
+      with jax.named_scope(SCOPE_SAMPLE), jax.named_scope(SCOPE_SEEDS):
+        rows, cols = pairs[0][pos], pairs[1][pos]
+      res = link_body(gargs, rows, cols, key)
+      col = ops.collate_batch(res['node'], res['num_nodes'], res['row'],
+                              res['col'], feats, id2i, None, None, None)
+      batch = dict(x=col['x'], edge_index=col['edge_index'],
+                   edge_mask=res['edge_mask'], **res['link'])
+      return batch, res['overflow'], res['link_counts']
+
+    return _sample_collate
+
   def _step_keys(self, base_key, count):
     """What the traced body samples step ``count`` with: the sampler's
     own fold_in stream — one key on a homogeneous graph, the [S, 2]
@@ -206,6 +298,8 @@ class FusedEpochTrainer:
   def _sample_args(self):
     """The sampler's graph device arrays for the traced body, re-read
     each epoch (a padded-table reseed must reach the chunks)."""
+    if isinstance(self.loader, LinkLoader):
+      return self._sampler._link_args(self._neg_sampling)
     if self._input_type is not None:
       return self._sampler._typed_args()
     return self._sampler._fused_args()

@@ -60,7 +60,7 @@ from ..metrics import programs, spans
 from ..utils.strict import strict_guards
 from ..utils.trace import record_dispatch
 from .node_loader import NodeLoader
-from .pipeline import refuse_typed
+from .pipeline import refuse_link, refuse_typed
 from .scan_epoch import ScanTrainer
 
 
@@ -90,6 +90,7 @@ class RunTrainer(ScanTrainer):
                perm_seed: Optional[int] = None, config=None,
                track_eval: bool = True):
     refuse_typed(loader, self._NAME)   # typed run chunks: ROADMAP M5
+    refuse_link(loader, self._NAME)
     super().__init__(loader, model, tx, num_classes,
                      chunk_size=chunk_size,
                      seed_labels_only=seed_labels_only,

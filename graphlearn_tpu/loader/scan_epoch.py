@@ -69,6 +69,8 @@ import numpy as np
 from ..metrics import programs, spans
 from ..utils.strict import strict_guards
 from ..utils.trace import record_dispatch
+from ..metrics.registry_names import SCOPE_SAMPLE, SCOPE_SEEDS
+from .link_loader import LinkLoader
 from .node_loader import NodeLoader
 from .pipeline import (_RECOMPUTE_MSG, DistFusedEpochTrainer,
                        FusedEpochTrainer)
@@ -106,6 +108,52 @@ def _resolve_tuned_config(trainer_name: str, dataset, chunk_size,
   return 32 if chunk_size is None else int(chunk_size)
 
 
+#: rounds of the epoch order's Feistel network (an alternating unbalanced
+#: one: each round xors one half with a hash of the other)
+_ORDER_ROUNDS = 6
+
+
+def keyed_order(key, n: int, positions):
+  """Where a shuffled epoch over ``n`` seeds visits, at ``positions``
+  (int32, any shape, each in ``[0, n)``): a bijection on ``[0, n)`` drawn
+  from ``key`` and evaluated for just those positions — nothing of size
+  ``n`` is sorted or built, so a call of ``max_steps`` steps pays for
+  ``max_steps * batch`` evaluations whatever ``n`` is.
+
+  A Feistel network over the ``ceil(log2 n)`` bits of a position
+  (``_ORDER_ROUNDS`` rounds, round keys from ``key``) is a bijection on
+  ``[0, 2^bits)``; cycle-walking — apply it again while the value is not
+  under ``n`` — restricts it to one on ``[0, n)``. ``2^bits < 2n``, so a
+  walk takes under two applications on average."""
+  import jax
+  import jax.numpy as jnp
+  bits = max(2, int(n - 1).bit_length())
+  lo_bits = bits // 2
+  hi_bits = bits - lo_bits
+  lo_mask, hi_mask = (1 << lo_bits) - 1, (1 << hi_bits) - 1
+  round_keys = jax.random.bits(key, (_ORDER_ROUNDS,), jnp.uint32)
+
+  def mix(v, k):
+    h = (v + k) * jnp.uint32(0x9E3779B1)
+    h = (h ^ (h >> 15)) * jnp.uint32(0x85EBCA6B)
+    return h ^ (h >> 13)
+
+  def feistel(x):
+    hi, lo = x >> lo_bits, x & jnp.uint32(lo_mask)
+    for r in range(_ORDER_ROUNDS):
+      if r % 2 == 0:
+        hi = hi ^ (mix(lo, round_keys[r]) & jnp.uint32(hi_mask))
+      else:
+        lo = lo ^ (mix(hi, round_keys[r]) & jnp.uint32(lo_mask))
+    return (hi << lo_bits) | lo
+
+  x = feistel(positions.astype(jnp.uint32))
+  x = jax.lax.while_loop(
+      lambda x: jnp.any(x >= n),
+      lambda x: jnp.where(x >= n, feistel(x), x), x)
+  return x.astype(jnp.int32)
+
+
 def _recovery_config_for(trainer) -> dict:
   """The snapshot-fingerprint config (recovery/checkpoint.py): the
   flight grouping config PLUS every stream-determining knob it omits —
@@ -117,6 +165,11 @@ def _recovery_config_for(trainer) -> dict:
   import hashlib
   s = trainer._sampler
   cfg = trainer._flight_config()
+  seeds = getattr(trainer.loader, 'input_seeds', None)
+  if seeds is None:
+    # a link loader's seed edges may live on the device (and be every
+    # edge of the graph): the digest covers their number, not a fetch
+    seeds = [len(trainer.loader.rows)]
   cfg.update(
       strategy=getattr(s, 'strategy', None),
       dedup=getattr(s, 'dedup', None),
@@ -125,8 +178,7 @@ def _recovery_config_for(trainer) -> dict:
       frontier_caps=str(getattr(s, 'frontier_caps', None)),
       seeds_sha=hashlib.sha1(
           np.ascontiguousarray(
-              np.asarray(trainer.loader.input_seeds,
-                         np.int64)).tobytes()).hexdigest()[:16])
+              np.asarray(seeds, np.int64)).tobytes()).hexdigest()[:16])
   return cfg
 
 
@@ -139,7 +191,13 @@ class ScanTrainer(FusedEpochTrainer):
       typed graph with seeds of one node
       type: the chunk then traces the typed hop loop and the per-type
       collate (pipeline.FusedEpochTrainer), under the per-batch typed
-      loader's own keys, and everything else here is the same code.
+      loader's own keys, and everything else here is the same code. Or
+      a LinkNeighborLoader (homogeneous, binary or no negatives): the
+      chunk then traces the sampler's link body and the pair step, a
+      step's seeds are ``batch_size`` seed EDGES, ``num_classes`` is not
+      asked for, and a shuffled epoch's order is ``keyed_order`` — a
+      keyed bijection evaluated for the steps a call runs, where a node
+      job draws ``jax.random.permutation`` over all its seeds.
     chunk_size: K, the static number of steps per scanned dispatch. The
       tail chunk (steps % K) compiles once more at its own length; pick
       K to divide the epoch when compile count matters.
@@ -166,12 +224,17 @@ class ScanTrainer(FusedEpochTrainer):
   stage_hook = None
   ack_hook = None
 
-  def __init__(self, loader: NodeLoader, model, tx, num_classes: int,
+  def __init__(self, loader: NodeLoader, model, tx,
+               num_classes: Optional[int] = None,
                chunk_size: Optional[int] = None,
                seed_labels_only: Optional[bool] = None,
                perm_seed: Optional[int] = None, config=None):
     import jax
     super().__init__(loader, model, tx, num_classes, seed_labels_only)
+    # per chunk, the link body's [k, 4] counts (negatives tested,
+    # rejected, padded; rows of the seed union): a scan output, summed
+    # on the host once an epoch (_publish_link_counts empties it)
+    self._link_counts = []
     # config= takes a tune artifact (graphlearn_tpu.tune(),
     # docs/tuning.md): dataset-fingerprint-validated, supplies the
     # tuned chunk K when chunk_size is not given explicitly
@@ -214,7 +277,6 @@ class ScanTrainer(FusedEpochTrainer):
     import jax.numpy as jnp
     batch = self._batch_size
     shuffle = self._shuffle
-
     def epoch_seeds(seeds, key, steps):
       n = seeds.shape[0]
       order = (jax.random.permutation(key, n) if shuffle
@@ -234,40 +296,71 @@ class ScanTrainer(FusedEpochTrainer):
 
     return jax.jit(epoch_seeds, static_argnums=(2,))
 
+  def link_positions(self, order_key, start, k: int):
+    """``[k, batch]`` positions in the link loader's seed edges that
+    steps ``start .. start + k`` of an epoch train on: step ``g`` takes
+    ``g * batch + arange(batch)``, through the epoch's ``keyed_order``
+    under ``shuffle=True``. Traced into the chunk (``glt.sample/seeds``)
+    and callable on its own: a replay asks the trainer which seed edges
+    a step had."""
+    import jax
+    import jax.numpy as jnp
+    batch = self._batch_size
+    with jax.named_scope(SCOPE_SAMPLE), jax.named_scope(SCOPE_SEEDS):
+      pos = ((start + jnp.arange(k, dtype=jnp.int32))[:, None] * batch +
+             jnp.arange(batch, dtype=jnp.int32)[None, :])
+      if self._shuffle:
+        pos = keyed_order(order_key, len(self.loader.rows), pos)
+    return pos
+
   def _build_chunk_fn(self):
     """The scanned K-step program. Chunk position enters as a DEVICE
     scalar (dynamic_slice start), so every full chunk reuses one
     executable; only the tail length retraces. State and the overflow
-    carry are donated — HBM stays flat across chunk dispatches."""
+    carry are donated — HBM stays flat across chunk dispatches.
+
+    A node job slices its steps' seeds out of the epoch's seed matrix;
+    a link job has none — ``seed_mat`` is the epoch's order key, and the
+    chunk evaluates the order for its own ``k`` steps
+    (:meth:`link_positions`), so an epoch over every edge of a graph
+    costs a call the steps it runs."""
     import jax
+    import jax.numpy as jnp
     from jax import lax
     sample_collate = self._sample_collate
     train_step = self._train_step   # jit-of-jit: inlined into the scan
     step_keys, stride = self._step_keys, self._key_stride
+    link_positions = (self.link_positions
+                      if isinstance(self.loader, LinkLoader) else None)
 
     def scan_epoch_chunk(state, ovf, fargs, feats, id2i, labels,
                          seed_mat, mask_mat, base_key, count0, start, k):
-      seeds_k = lax.dynamic_slice_in_dim(seed_mat, start, k, axis=0)
-      masks_k = lax.dynamic_slice_in_dim(mask_mat, start, k, axis=0)
+      if link_positions is not None:
+        seeds_k = link_positions(seed_mat, start, k)
+        masks_k = jnp.ones(seeds_k.shape, bool)
+      else:
+        seeds_k = lax.dynamic_slice_in_dim(seed_mat, start, k, axis=0)
+        masks_k = lax.dynamic_slice_in_dim(mask_mat, start, k, axis=0)
       # the sampler's fold_in stream: global step g -> count0 + g (a
       # typed step draws `stride` counts, one per (hop, edge type))
       if stride == 1:
-        counts_k = count0 + start + lax.iota(seed_mat.dtype, k)
+        counts_k = count0 + start + lax.iota(seeds_k.dtype, k)
       else:
-        counts_k = count0 + (start + lax.iota(seed_mat.dtype, k)) * stride
+        counts_k = count0 + (start + lax.iota(seeds_k.dtype, k)) * stride
 
       def body(carry, xs):
         state, ovf = carry
         seeds, smask, count = xs
         key = step_keys(base_key, count)
-        batch, overflow = sample_collate(fargs, feats, id2i, labels,
-                                         seeds, smask, key)
+        # (batch, overflow) and, from a link source, its per-step counts
+        batch, overflow, *counts = sample_collate(fargs, feats, id2i,
+                                                  labels, seeds, smask, key)
         state, loss, acc = train_step(state, batch)
-        return (state, ovf | overflow), (loss, acc)
+        return (state, ovf | overflow), (loss, acc, *counts)
 
-      (state, ovf), (losses, accs) = lax.scan(
+      (state, ovf), outs = lax.scan(
           body, (state, ovf), (seeds_k, masks_k, counts_k))
-      return state, ovf, losses, accs
+      return (state, ovf, *outs)
 
     return jax.jit(scan_epoch_chunk, static_argnums=(11,),
                    donate_argnums=(0, 1))
@@ -369,6 +462,7 @@ class ScanTrainer(FusedEpochTrainer):
           state, steps, full_steps, start_step=start_step,
           resume_overflow=resume_overflow)
       completed = True
+      self._publish_link_counts()
       if guarded:
         # natural epoch end applies overflow_policy; a max_steps
         # break leaves the
@@ -403,7 +497,9 @@ class ScanTrainer(FusedEpochTrainer):
     """The epoch program proper: seed draw + scanned chunks. Split out
     so run_epoch owns only the guard/flight bracketing."""
     import jax
-    if self._seeds_dev is None:
+    link = isinstance(self.loader, LinkLoader)
+    self._link_counts = []   # a failed epoch's counts are not carried on
+    if self._seeds_dev is None and not link:
       self._seeds_dev = jax.device_put(
           np.asarray(self.loader.input_seeds, dtype=np.int32))
     # _epochs advances only on SUCCESS (below, with _call_count): a
@@ -433,10 +529,15 @@ class ScanTrainer(FusedEpochTrainer):
       # device-idle gap by what the host was doing in it. The dispatches
       # are async, so a span's dur is dispatch wall; the layers' device
       # time is read from their glt.* scopes (docs/observability.md)
-      record_dispatch('epoch_seeds')
-      with spans.span('epoch.seeds'):
-        seed_mat, mask_mat = self._seed_fn(self._seeds_dev, perm_key,
-                                           full_steps)
+      if link:
+        # no seed matrix: each chunk evaluates the epoch's order for its
+        # own steps (link_positions), from the order key alone
+        seed_mat, mask_mat = perm_key, None
+      else:
+        record_dispatch('epoch_seeds')
+        with spans.span('epoch.seeds'):
+          seed_mat, mask_mat = self._seed_fn(self._seeds_dev, perm_key,
+                                             full_steps)
       while start < steps:
         k = min(self.chunk_size, steps - start)
         if self.stage_hook is not None:
@@ -444,12 +545,13 @@ class ScanTrainer(FusedEpochTrainer):
             self.stage_hook(start // self.chunk_size, start, k)
         record_dispatch('scan_chunk')
         with spans.span('epoch.chunk', start=start, k=k):
-          state, ovf, loss_k, acc_k = self._chunk_fn(
+          state, ovf, loss_k, acc_k, *counts_k = self._chunk_fn(
               state, ovf, fargs, self._feats, self._id2i, self._labels,
               seed_mat, mask_mat, base_key, count0,
               jax.device_put(np.int32(start)), k)
         losses.append(loss_k)
         accs.append(acc_k)
+        self._link_counts.extend(counts_k)
         self._steps_dispatched = start + k
         if self.ack_hook is not None:
           # boundary carry for the recovery seam (recovery/checkpoint):
@@ -475,6 +577,24 @@ class ScanTrainer(FusedEpochTrainer):
     self._sampler._call_count += steps * self._key_stride
     self._epochs += 1
     return state, losses, accs, ovf
+
+  def _publish_link_counts(self):
+    """A link epoch's negative-sampler and seed-union counts into
+    ``link.negatives.{tested,rejected,padded}`` and ``link.seeds.unique``:
+    the chunks' ``[k, 4]`` scan outputs fetched once, after the epoch (no
+    fetch in the loop), as ``dist_exchange.rows.hop<h>`` is. A node epoch
+    has none."""
+    from ..utils import trace
+    counts, self._link_counts = self._link_counts, []
+    if not counts:
+      return
+    total = np.sum([np.asarray(c).sum(axis=0, dtype=np.int64)
+                    for c in counts], axis=0)
+    for name, n in zip(('link.negatives.tested', 'link.negatives.rejected',
+                        'link.negatives.padded', 'link.seeds.unique'),
+                       total.tolist()):
+      # graftlint: allow[metric-registry] the registered link.* family
+      trace.counter_inc(name, int(n))
 
   def _flight_config(self) -> dict:
     """Static epoch-program configuration, fingerprinted into flight

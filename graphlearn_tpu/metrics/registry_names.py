@@ -38,6 +38,12 @@ REGISTERED_METRICS = frozenset({
     # (the rows the miss-only row exchange asked for are
     # dist_feature.unique_misses)
     'dist_exchange.*',
+    # what a scanned link epoch's negative sampler and seed union did
+    # (loader/scan_epoch.py ScanTrainer over a link loader, published
+    # once per epoch from a per-step scan output): link.negatives.tested
+    # / .rejected (candidates that were edges) / .padded (slots no
+    # non-edge was left for), link.seeds.unique (rows of the seed union)
+    'link.*',
     # mp sampling workers (distributed/dist_sampling_producer.py)
     'producer.batches',
     'producer.sample_ms',
@@ -240,6 +246,15 @@ SCOPE_ALLREDUCE = 'allreduce'    # inside glt.train: the pmean (DDP)
 SCOPE_CACHE = 'cache'            # inside glt.collate: the hot-cache hit path
 SCOPE_EXCHANGE = 'exchange'      # inside glt.collate and glt.sample/hop<h>:
                                  # the all_to_all round trip and its routing
+# what an edge-seeded (link) job adds to a step, apart from the hops
+SCOPE_SEEDS = 'seeds'            # inside glt.sample: the epoch order's
+                                 # positions and the seed-pair gather
+SCOPE_NEGATIVE = 'negative'      # inside glt.sample: draw, membership test,
+                                 # compaction (ops.random_negative_sample)
+SCOPE_UNION = 'union'            # inside glt.sample: the seed dedup over the
+                                 # pairs' endpoints and its seed_inverse
+SCOPE_PAIRS = 'pairs'            # inside glt.train(/fwd_bwd): endpoint
+                                 # gather, scores, BCE and their backward
 
 
 def hop_scope(hop: int, part: str, etype=None) -> str:
@@ -271,6 +286,9 @@ REGISTERED_SCOPES = frozenset({
     'glt.sample/hop<h>/<etype>/draw',
     'glt.sample/hop<h>/<etype>/induce',
     'glt.sample/hop<h>/merge',
+    'glt.sample/seeds',
+    'glt.sample/negative',
+    'glt.sample/union',
     'glt.collate',
     'glt.collate/<ntype>',
     'glt.collate/cache',
@@ -279,4 +297,5 @@ REGISTERED_SCOPES = frozenset({
     'glt.train/fwd_bwd',
     'glt.train/update',
     'glt.train/allreduce',
+    'glt.train/pairs',
 })

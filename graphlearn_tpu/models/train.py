@@ -13,8 +13,8 @@ import jax
 import jax.numpy as jnp
 import optax
 
-from ..metrics.registry_names import (SCOPE_FWD_BWD, SCOPE_TRAIN,
-                                      SCOPE_UPDATE)
+from ..metrics.registry_names import (SCOPE_FWD_BWD, SCOPE_PAIRS,
+                                      SCOPE_TRAIN, SCOPE_UPDATE)
 
 
 class TrainState(NamedTuple):
@@ -192,17 +192,19 @@ def make_link_train_step(model, tx):
 
   def loss_fn(params, batch):
     h = forward(params, batch).astype(jnp.float32)
-    eli = batch['edge_label_index']
-    lab = batch['edge_label'].astype(jnp.float32)
-    valid = (eli[0] >= 0) & (eli[1] >= 0)
-    src = h[jnp.maximum(eli[0], 0)]
-    dst = h[jnp.maximum(eli[1], 0)]
-    score = (src * dst).sum(-1)
-    bce = optax.sigmoid_binary_cross_entropy(score, lab)
-    bce = jnp.where(valid, bce, 0.0)
-    loss = bce.sum() / jnp.maximum(valid.sum(), 1)
-    hit = ((score > 0) == (lab > 0.5)) & valid
-    acc = hit.sum() / jnp.maximum(valid.sum(), 1)
+    # what the link loss adds to a step: glt.train/…/pairs on a timeline
+    with jax.named_scope(SCOPE_PAIRS):
+      eli = batch['edge_label_index']
+      lab = batch['edge_label'].astype(jnp.float32)
+      valid = (eli[0] >= 0) & (eli[1] >= 0)
+      src = h[jnp.maximum(eli[0], 0)]
+      dst = h[jnp.maximum(eli[1], 0)]
+      score = (src * dst).sum(-1)
+      bce = optax.sigmoid_binary_cross_entropy(score, lab)
+      bce = jnp.where(valid, bce, 0.0)
+      loss = bce.sum() / jnp.maximum(valid.sum(), 1)
+      hit = ((score > 0) == (lab > 0.5)) & valid
+      acc = hit.sum() / jnp.maximum(valid.sum(), 1)
     return loss, acc
 
   train_step = _jit_train_step(loss_fn, tx)

@@ -9,7 +9,7 @@ from .induce_merge import (MergeInducerState, induce_next_merge,
 from .induce_tree import (TreeInducerState, induce_next_tree,
                           init_empty_tree, init_node_tree)
 from .negative import (random_negative_sample, random_negative_sample_local,
-                       sort_csr_segments)
+                       sort_csr_segments, sort_csr_segments_device)
 from .neighbor import (BLOCK, build_padded_adjacency,
                        build_padded_adjacency_device, build_row_cumsum,
                        choose_padded_window, edge_in_csr,

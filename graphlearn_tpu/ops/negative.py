@@ -29,11 +29,65 @@ def sort_csr_segments(indptr: np.ndarray, indices: np.ndarray):
   return indices[perm], perm
 
 
+#: edges a window of :func:`sort_csr_segments_device` holds (a power of
+#: two; a graph whose longest row is longer gets the next one above it)
+SEGMENT_SORT_WINDOW = 1 << 22
+
+
+def sort_csr_segments_device(indptr, indices, window: int = None):
+  """``sort_csr_segments``' sorted indices, made where the CSR lives: a
+  device array in, a NEW device array out, nothing of size E on the host.
+
+  The edge list is walked in windows of ``window`` edges that start and
+  end on row boundaries (``indptr`` is read on the host: N + 1 numbers).
+  One program, compiled once for the graph, sorts a window by (row,
+  neighbour) and writes it back in place: slots of the window outside
+  its rows keep their position (their key is their own index), so the
+  fixed-size write never disturbs a neighbouring window."""
+  ptr = np.asarray(indptr).astype(np.int64)
+  num_edges = int(ptr[-1])
+  out = jnp.array(indices, copy=True)
+  if num_edges == 0:
+    return out
+  width = window or SEGMENT_SORT_WINDOW
+  longest = int(np.diff(ptr).max())
+  while width < longest:
+    width *= 2
+  width = min(width, num_edges)
+  bounds = [0]
+  while bounds[-1] < num_edges:
+    # the last row boundary at most `width` edges on (a row is never cut)
+    stop = int(ptr[np.searchsorted(ptr, bounds[-1] + width, 'right') - 1])
+    bounds.append(stop)
+  ptr_dev = jnp.asarray(ptr.astype(np.int32))
+  for lo, hi in zip(bounds[:-1], bounds[1:]):
+    out = _sort_window(out, ptr_dev, np.int32(lo), np.int32(hi),
+                       width=width)
+  return out
+
+
+@functools.partial(jax.jit, static_argnames=('width',), donate_argnums=(0,))
+def _sort_window(indices, indptr, lo, hi, width: int):
+  start = jnp.minimum(lo, indices.shape[0] - width)
+  pos = start + jnp.arange(width, dtype=jnp.int32)
+  vals = jax.lax.dynamic_slice(indices, (start,), (width,))
+  inside = (pos >= lo) & (pos < hi)
+  rows = jnp.searchsorted(indptr, pos, side='right',
+                          method='sort').astype(jnp.int32) - 1
+  big = jnp.iinfo(jnp.int32).max
+  k1 = jnp.where(inside, rows, jnp.where(pos < lo, -1, big))
+  k2 = jnp.where(inside, vals, pos)
+  _, _, vals = jax.lax.sort((k1, k2, vals), num_keys=2)
+  return jax.lax.dynamic_update_slice(indices, vals, (start,))
+
+
 @functools.partial(jax.jit,
-                   static_argnames=('num_samples', 'trials', 'padding'))
+                   static_argnames=('num_samples', 'trials', 'padding',
+                                    'with_counts'))
 def random_negative_sample(indptr, sorted_indices, num_src, num_dst,
                            num_samples: int, key, trials: int = 5,
-                           padding: bool = False):
+                           padding: bool = False,
+                           with_counts: bool = False):
   """Sample (row, col) pairs absent from the CSR.
 
   Args:
@@ -48,7 +102,10 @@ def random_negative_sample(indptr, sorted_indices, num_src, num_dst,
       positive) pairs so the output is always full (reference ``padding``
       flag).
 
-  Returns (rows [num_samples], cols [num_samples], mask [num_samples]).
+  Returns (rows [num_samples], cols [num_samples], mask [num_samples]);
+  with ``with_counts`` also an int32 ``[3]``: candidates tested, those
+  rejected as edges, and output slots no non-edge was left for (which
+  ``padding`` fills with rejected candidates, in draw order).
   """
   total = num_samples * trials
   kr, kc = jax.random.split(key)
@@ -62,8 +119,12 @@ def random_negative_sample(indptr, sorted_indices, num_src, num_dst,
   out_rows = rows[take]
   out_cols = cols[take]
   out_mask = valid[take]
+  counts = jnp.stack([jnp.int32(total), is_edge.sum(dtype=jnp.int32),
+                      (~out_mask).sum(dtype=jnp.int32)])
   if padding:
     out_mask = jnp.ones_like(out_mask)
+  if with_counts:
+    return out_rows, out_cols, out_mask, counts
   return out_rows, out_cols, out_mask
 
 

@@ -7,7 +7,6 @@ the edge_dir='in' row/col flip (CSC stores (dst, src) pairs).
 """
 from typing import Optional
 
-import numpy as np
 
 from .. import ops
 from ..data import Graph
@@ -29,8 +28,8 @@ class RandomNegativeSampler:
     # in this package follows (docs/failure_model.md)
     self._key = jax.random.PRNGKey(0 if seed is None else seed)
     self._call_count = 0
-    self._sorted_indices, _ = ops.sort_csr_segments(
-        np.asarray(graph.indptr), np.asarray(graph.indices))
+    self._sorted_indices = ops.sort_csr_segments_device(
+        graph.topo.indptr, graph.indices)
 
   def sample(self, num_samples: int, trials: int = 5,
              padding: bool = False):

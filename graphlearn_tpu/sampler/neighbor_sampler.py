@@ -23,7 +23,8 @@ import numpy as np
 
 from .. import ops
 from ..data import Graph
-from ..metrics.registry_names import SCOPE_SAMPLE, hop_scope
+from ..metrics.registry_names import (SCOPE_NEGATIVE, SCOPE_SAMPLE,
+                                      SCOPE_UNION, hop_scope)
 from ..typing import EdgeType, NodeType, reverse_edge_type
 from .base import (BaseSampler, EdgeSamplerInput, HeteroSamplerOutput,
                    NeighborOutput, NodeSamplerInput, SamplerOutput)
@@ -331,7 +332,7 @@ def hetero_tree_blocks(seed_caps: Dict[NodeType, int], etypes,
 @functools.lru_cache(maxsize=None)
 def _fused_homo_fn(fanouts, caps, node_cap, with_edge, weighted, mode,
                    num_graph_nodes, padded=False, block_num_edges=0,
-                   fused_hop=False, fused_hop_window=512):
+                   fused_hop=False, fused_hop_window=512, seed_scope=None):
   """Jitted whole-multi-hop sample program, cached at MODULE level on its
   static signature: every sampler instance with the same config (e.g. the
   train and eval loaders of one run) shares one traced/compiled
@@ -340,8 +341,14 @@ def _fused_homo_fn(fanouts, caps, node_cap, with_edge, weighted, mode,
   All device arrays enter as ARGUMENTS, never closure constants — a
   captured array is baked into the executable as a constant (PERF.md
   rules).
+
+  ``seed_scope`` names the seed dedup's sub-scope of ``glt.sample`` where
+  a caller's seeds are a union worth telling from the hops (the link
+  body: ``union``); a node job's seeds stay unscoped, as they were.
   """
   init_fn, _, induce_fn = _inducer_for(mode, num_graph_nodes)
+  if seed_scope is not None:
+    init_fn = jax.named_scope(seed_scope)(init_fn)
 
   @jax.named_scope(SCOPE_SAMPLE)
   def fn(indptr, indices, eids, cum, tab, deg, eptab, seeds, seed_mask,
@@ -747,7 +754,7 @@ class NeighborSampler(BaseSampler):
       return _tree_node_cap(caps, list(fanouts))
     return sum(caps)
 
-  def _build_homo_fn(self, batch_cap: int, fanouts):
+  def _build_homo_fn(self, batch_cap: int, fanouts, seed_scope=None):
     """Resolve the shared jitted multi-hop program for this config."""
     g = self._get_graph()
     caps = self._homo_capacities(batch_cap, fanouts)
@@ -763,7 +770,7 @@ class NeighborSampler(BaseSampler):
         padded=self.padded_window is not None,
         block_num_edges=nblk_edges,
         fused_hop=self.use_fused_hop,
-        fused_hop_window=self.fused_hop_window)
+        fused_hop_window=self.fused_hop_window, seed_scope=seed_scope)
 
   def _padded_arrays(self):
     """Lazily built device-resident padded adjacency (homo).
@@ -868,14 +875,15 @@ class NeighborSampler(BaseSampler):
     return (ga['indptr'], ga['indices'], ga['eids'], cum, None,
             None if weighted else self._csr_meta(), None)
 
-  def _homo_fn(self, batch_cap: int, fanouts):
+  def _homo_fn(self, batch_cap: int, fanouts, seed_scope=None):
     sig = ('homo', batch_cap, tuple(fanouts), self.with_edge,
            self.with_weight, self.padded_window, self.strategy,
-           self.use_fused_hop, self.fused_hop_window)
+           self.use_fused_hop, self.fused_hop_window, seed_scope)
     if sig not in self._fns:
       from ..metrics import programs
       self._fns[sig] = programs.instrument(
-          self._build_homo_fn(batch_cap, tuple(fanouts)), 'sample')
+          self._build_homo_fn(batch_cap, tuple(fanouts), seed_scope),
+          'sample')
     return self._fns[sig]
 
   def _graph_arrays(self, etype=None):
@@ -1281,69 +1289,138 @@ class NeighborSampler(BaseSampler):
             'explicit key is homogeneous-only; hetero sampling uses the '
             "sampler's internal PRNG stream")
       return self._hetero_sample_from_edges(inputs, **kwargs)
-    # ONE key split across the negative draw and the node expansion —
-    # identical whether the key comes from the caller (overflow replay)
-    # or the sampler's own stream, so replayed batches match exactly
     if key is None:
       key = self._next_key()
-    kneg, knode = jax.random.split(key)
-    rows = np.asarray(inputs.row).reshape(-1)
-    cols = np.asarray(inputs.col).reshape(-1)
+    rows = jnp.asarray(inputs.row).reshape(-1)
+    cols = jnp.asarray(inputs.col).reshape(-1)
     b = rows.shape[0]
     neg = inputs.neg_sampling
-    g = self._get_graph()
-
-    neg_rows = neg_cols = None
-    if neg is not None:
-      num_neg = neg.num_negatives(b)
-      sorted_idx, _ = self._neg_sorted()
-      # num_neg is exact by contract (the label layout below indexes by
-      # it), so it cannot be pow2-clamped without changing the drawn
-      # negatives; batch shape is held constant by the producers'
-      # cyclic padding, and retrace_budget guards ragged ad-hoc callers
-      # graftlint: allow[retrace-hazard] num_samples is an exact contract; producer-side padding keeps b constant
-      nr, nc, nmask = ops.random_negative_sample(
-          g.indptr, sorted_idx, g.num_nodes, g.num_nodes, num_neg,
-          kneg, padding=True)
-      neg_rows, neg_cols = np.asarray(nr), np.asarray(nc)
-      if self.edge_dir == 'in':
-        # CSC stores (dst, src); emit user-facing (src, dst) pairs
-        # (reference: sampler/negative_sampler.py:21-57 row/col flip).
-        neg_rows, neg_cols = neg_cols, neg_rows
-      del nmask  # padding=True: all slots filled (non-strict mode)
-
-    if neg is None:
-      seeds = np.concatenate([rows, cols])
-    elif neg.is_binary():
-      seeds = np.concatenate([rows, cols, neg_rows, neg_cols])
-    else:  # triplet: negatives are dst candidates only
-      seeds = np.concatenate([rows, cols, neg_cols])
-
-    out = self.sample_from_nodes(NodeSamplerInput(seeds), key=knode)
-    inv = out.metadata['seed_inverse']  # local idx of each seed position
-    inv = jnp.asarray(inv)
-
-    if neg is None:
-      md = dict(edge_label_index=jnp.stack([inv[:b], inv[b:2 * b]]),
-                edge_label=jnp.asarray(inputs.label) if inputs.label is not
-                None else jnp.ones((b,), jnp.int32))
-    elif neg.is_binary():
-      num_neg = neg_rows.shape[0]
-      src = jnp.concatenate([inv[:b], inv[2 * b:2 * b + num_neg]])
-      dst = jnp.concatenate([inv[b:2 * b],
-                             inv[2 * b + num_neg:2 * b + 2 * num_neg]])
-      pos_label = (jnp.asarray(inputs.label) if inputs.label is not None
-                   else jnp.ones((b,), jnp.int32))
-      label = jnp.concatenate([pos_label, jnp.zeros((num_neg,),
-                                                    pos_label.dtype)])
-      md = dict(edge_label_index=jnp.stack([src, dst]), edge_label=label)
+    label = None if inputs.label is None else jnp.asarray(inputs.label)
+    if self.fused:
+      from ..utils.trace import record_dispatch
+      sig = ('link', b, neg and (neg.mode, neg.amount), label is not None)
+      if sig not in self._fns:
+        from ..metrics import programs
+        self._fns[sig] = programs.instrument(
+            jax.jit(self._link_body(b, neg)), 'link_sample')
+      record_dispatch('link_sample')
+      res = self._fns[sig](self._link_args(neg), rows, cols, key, label)
     else:
-      num_neg = neg_cols.shape[0]
-      md = dict(src_index=inv[:b], dst_pos_index=inv[b:2 * b],
-                dst_neg_index=inv[2 * b:2 * b + num_neg])
-    out.metadata.update(md)
-    out.batch_size = b
-    return out
+      # the per-op path: the same body, its jitted kernels dispatched
+      # one by one around the chained expansion
+      res = self._link_body(b, neg)(self._link_args(neg), rows, cols, key,
+                                    label)
+    md = res.pop('link')
+    return SamplerOutput(
+        node=res['node'], num_nodes=res['num_nodes'], row=res['row'],
+        col=res['col'], edge=res['edge'], edge_mask=res['edge_mask'],
+        batch=res['seeds'], batch_size=b,
+        num_sampled_nodes=res['num_sampled_nodes'],
+        num_sampled_edges=res['num_sampled_edges'],
+        input_type=None,
+        metadata=dict(md, seed_inverse=res['seed_inverse'],
+                      seed_mask=self._link_plan(b, neg)[3],
+                      overflow=res['overflow'],
+                      link_counts=res['link_counts']))
+
+  def _link_plan(self, b: int, neg):
+    """(negatives, seed width, padded seed capacity, validity mask) of a
+    link batch of ``b`` seed edges: both endpoints of every positive,
+    then the negatives' (binary: both ends; triplet: the dst candidate),
+    padded to a multiple of 8 as ``sample_from_nodes`` pads."""
+    from .calibrate import link_seed_width
+    num_neg = neg.num_negatives(b) if neg is not None else 0
+    width = link_seed_width(b, neg)
+    cap = _round_up(width)
+    return num_neg, width, cap, np.arange(cap) < width
+
+  def _link_args(self, neg):
+    """The device arrays the link body reads, passed and never captured:
+    the CSR's ``indptr`` and row-sorted ``indices`` for the membership
+    test (a job without negatives takes neither), and the expansion's
+    own arguments on the fused path."""
+    gargs = dict(fargs=self._fused_args() if self.fused else None)
+    if neg is not None:
+      gargs.update(indptr=self._graph_arrays()['indptr'],
+                   sorted=self._neg_sorted())
+    return gargs
+
+  def _link_body(self, b: int, neg):
+    """THE link-seed body, device arrays in and device arrays out:
+    ``(gargs, rows [b], cols [b], key, label) -> dict`` — ``num_neg``
+    negatives by ``ops.random_negative_sample``, the seed union
+    ``[rows, cols, neg_rows, neg_cols]`` (triplet: ``[rows, cols,
+    neg_cols]``), the node expansion, and under ``'link'`` the batch's
+    ``edge_label_index`` / ``edge_label`` (triplet: ``src_index``,
+    ``dst_pos_index``, ``dst_neg_index``) through ``seed_inverse``.
+    ``'link_counts'`` is int32 ``[4]``: candidates tested, rejected as
+    edges, slots filled by padding, rows of the seed union.
+
+    ONE key split across the negative draw and the node expansion —
+    identical whether the key comes from the caller (overflow replay),
+    the sampler's own stream or a scanned chunk's ``fold_in``, so every
+    caller of this body draws the same batch under the same key.
+    ``sample_from_edges`` dispatches it per batch; the scanned epoch
+    (loader.ScanTrainer over a LinkNeighborLoader) traces it into its
+    chunk, as the typed hop loop is."""
+    import jax.numpy as jnp
+    num_neg, width, cap, mask = self._link_plan(b, neg)
+    num_nodes = self._get_graph().num_nodes
+    binary = neg is not None and neg.is_binary()
+    flip = self.edge_dir == 'in'
+    fanouts = tuple(self.num_neighbors)
+    if self.fused:
+      homo = self._homo_fn(cap, fanouts, seed_scope=SCOPE_UNION)
+      expand = lambda fargs, seeds, smask, k: homo(*fargs, seeds, smask, k)
+    else:
+      expand = lambda _, seeds, smask, k: self._run_homo_chain(
+          cap, fanouts, seeds, smask, k)
+
+    def link_sample(gargs, rows, cols, key, label=None):
+      kneg, knode = jax.random.split(key)
+      parts = [rows, cols]
+      counts = jnp.zeros((3,), jnp.int32)
+      if neg is not None:
+        with jax.named_scope(SCOPE_SAMPLE), jax.named_scope(SCOPE_NEGATIVE):
+          # num_neg is exact by contract (the label layout below indexes
+          # by it); padding=True is the reference's non-strict mode
+          # graftlint: allow[retrace-hazard] num_samples is an exact contract; producer-side padding keeps b constant
+          nr, nc, _, counts = ops.random_negative_sample(
+              gargs['indptr'], gargs['sorted'], num_nodes, num_nodes,
+              num_neg, kneg, padding=True, with_counts=True)
+          if flip:
+            # CSC stores (dst, src); emit user-facing (src, dst) pairs
+            # (reference: sampler/negative_sampler.py:21-57 row/col flip)
+            nr, nc = nc, nr
+        parts += [nr, nc] if binary else [nc]
+      with jax.named_scope(SCOPE_SAMPLE), jax.named_scope(SCOPE_UNION):
+        seeds = jnp.concatenate(
+            [p.astype(jnp.int32) for p in parts] +
+            [jnp.zeros((cap - width,), jnp.int32)])
+      res = dict(expand(gargs['fargs'], seeds, jnp.asarray(mask), knode))
+      inv = res['seed_inverse']   # local idx of each seed position
+      if neg is None or binary:
+        pos_label = (label if label is not None
+                     else jnp.ones((b,), jnp.int32))
+      if neg is None:
+        md = dict(edge_label_index=jnp.stack([inv[:b], inv[b:2 * b]]),
+                  edge_label=pos_label)
+      elif binary:
+        src = jnp.concatenate([inv[:b], inv[2 * b:2 * b + num_neg]])
+        dst = jnp.concatenate([inv[b:2 * b],
+                               inv[2 * b + num_neg:2 * b + 2 * num_neg]])
+        md = dict(edge_label_index=jnp.stack([src, dst]),
+                  edge_label=jnp.concatenate(
+                      [pos_label, jnp.zeros((num_neg,), pos_label.dtype)]))
+      else:
+        md = dict(src_index=inv[:b], dst_pos_index=inv[b:2 * b],
+                  dst_neg_index=inv[2 * b:2 * b + num_neg])
+      res.update(seeds=seeds, link=md, link_counts=jnp.concatenate(
+          [counts, jnp.reshape(res['num_sampled_nodes'][0], (1,))
+           .astype(jnp.int32)]))
+      return res
+
+    return link_sample
 
   def _hetero_sample_from_edges(self, inputs: EdgeSamplerInput,
                                 num_dst_nodes: Optional[int] = None,
@@ -1370,7 +1447,7 @@ class NeighborSampler(BaseSampler):
     neg_rows = neg_cols = None
     if neg is not None:
       num_neg = neg.num_negatives(b)
-      sorted_idx, _ = self._neg_sorted(etype)
+      sorted_idx = self._neg_sorted(etype)
       # same contract as the homogeneous branch: num_neg is exact
       # graftlint: allow[retrace-hazard] num_samples is an exact contract; producer-side padding keeps b constant
       nr, nc, _ = ops.random_negative_sample(
@@ -1431,11 +1508,14 @@ class NeighborSampler(BaseSampler):
 
   @functools.lru_cache(maxsize=None)
   def _neg_sorted(self, etype=None):
-    """Per-(edge type) sorted CSR view for negative membership checks —
-    cached: the graph is static across batches, and the mp hetero link
-    hot loop would otherwise re-sort the whole CSR every batch."""
+    """Per-(edge type) row-sorted ``indices`` for negative membership
+    checks, a device array made on the device
+    (``ops.sort_csr_segments_device``: nothing of size E is sorted on the
+    host) — cached: the graph is static across batches, and the mp hetero
+    link hot loop would otherwise re-sort the whole CSR every batch."""
     g = self._get_graph(etype)
-    return ops.sort_csr_segments(np.asarray(g.indptr), np.asarray(g.indices))
+    return ops.sort_csr_segments_device(g.topo.indptr,
+                                        self._graph_arrays(etype)['indices'])
 
   def __hash__(self):
     return id(self)

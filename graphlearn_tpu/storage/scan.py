@@ -38,6 +38,7 @@ from typing import Optional
 import numpy as np
 
 from ..loader.node_loader import NodeLoader
+from ..loader.pipeline import refuse_link
 from ..loader.scan_epoch import ScanTrainer
 from ..metrics import spans
 from ..utils.strict import strict_guards
@@ -84,6 +85,7 @@ class TieredScanTrainer(ScanTrainer):
                seed_labels_only: Optional[bool] = None,
                perm_seed: Optional[int] = None, max_ahead: int = 2,
                stage_timeout_s: float = 30.0, config=None):
+    refuse_link(loader, self._NAME)
     store = loader.data.node_features
     if not isinstance(store, TieredFeature):
       raise ValueError(

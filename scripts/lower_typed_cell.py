@@ -5,7 +5,7 @@ section 2.3), no chip minute spent, no time or rate comes out of it:
 
     TPU_ACCELERATOR_TYPE=v5litepod-4 TPU_WORKER_HOSTNAMES=localhost \
     TPU_SKIP_MDS_QUERY=true JAX_PLATFORMS=cpu \
-    python scripts/lower_typed_cell.py [reference] [chunk] [gat] \
+    python scripts/lower_typed_cell.py [reference] [chunk] [gat] [link] \
         [mesh-generator] [mesh-cache] [mesh-chunk] [mesh-reference]
 
 ``reference``: ``perfbench/reference_hetero_node.py``'s step at the shapes of
@@ -14,7 +14,10 @@ section 2.3), no chip minute spent, no time or rate comes out of it:
 ``jit_scan_epoch_chunk`` over the typed loader with every table an argument
 at the cell's size (``peak`` under 15.75 GiB, the tables counted once; the
 compile itself refuses a program that does not fit). ``gat``: the same chunk
-program of ``gat-products.scan-exact``. Both chunk modes also print what a
+program of ``gat-products.scan-exact``; ``link``: the chunk program of
+``sage-products-unsup.link-scan-exact`` (the link body, the pair step),
+with the graph, the row-sorted copy of ``indices``, the rows and the two
+seed-edge arrays as arguments at the cell's size. The chunk modes also print what a
 start-up pays for the program before its first step: seconds to trace and to
 lower it here, the characters, lines and constants of its StableHLO text, the
 compiler's ``generated_code`` bytes and, where this compile-only client can
@@ -51,6 +54,8 @@ sys.path.insert(0, ROOT)
 CELL = 'rgat-igbh-small.typed-scan-exact'
 GAT_CELL = 'gat-products.scan-exact'
 GAT_CAPS = [6528, 33664, 84736]   # that cell's set-up line (PERF.md section 4)
+LINK_CELL = 'sage-products-unsup.link-scan-exact'
+LINK_CAPS = [32512, 113152, 122880]   # set before the cell's first chip run
 MESH_CELL = 'sage-papers.mesh-exact'
 MESH_CAPS = [8960, 62080, 192768]  # that cell's set-up line (PERF.md section 4)
 # halvings of a lookup in the cell's 1.85 M cached ids: the largest of its
@@ -322,6 +327,55 @@ def lower_gat_chunk(cfg, traffic, one_chip):
       int(traffic['chunk_size']), one_chip)
 
 
+def lower_link_chunk(cfg, traffic, one_chip):
+  """``sage-products-unsup.link-scan-exact``'s chunk program, by
+  ``lower_gat_chunk``'s method: traced over a graph 1/64 the size under
+  the cell's caps, lowered with every table at the cell's size. A link
+  chunk takes no seed matrix: its seeds are the epoch's order key."""
+  import copy
+
+  import jax
+  import jax.numpy as jnp
+
+  import graphlearn_tpu as glt
+  from perfbench.families import homo_link
+  real = cfg['dataset']
+  small = copy.deepcopy(cfg)
+  d = small['dataset']
+  d['num_nodes'] //= 64
+  d['num_directed_edges'] //= 64
+  calibrate = glt.sampler.estimate_frontier_caps
+  glt.sampler.estimate_frontier_caps = lambda *a, **kw: LINK_CAPS
+  try:
+    cell = homo_link.Cell(small, traffic, lambda k, v: None)
+  finally:
+    glt.sampler.estimate_frontier_caps = calibrate
+  print('lower_typed_cell: ' + json.dumps(cell.shapes()), flush=True)
+  model = cell.make_model(None)
+  state, tx, _ = cell.make_state(model, 0)
+  tr = glt.ScanTrainer(cell.make_loader(0), model, tx,
+                       chunk_size=int(traffic['chunk_size']))
+  # the order's bit width follows the seed set's size: trace it at the real
+  tr.loader.rows = tr.loader.cols = jax.ShapeDtypeStruct(
+      (real['num_directed_edges'],), jnp.int32)
+  lead = {d['num_nodes']: real['num_nodes'],
+          d['num_nodes'] + 1: real['num_nodes'] + 1,
+          d['num_directed_edges']: real['num_directed_edges']}
+  sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+  spec = lambda tree: jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+  table = lambda tree: jax.tree.map(
+      lambda a: sds((lead[a.shape[0]],) + a.shape[1:], a.dtype), tree)
+  chunk = getattr(tr._chunk_fn, '_glt_instrumented', tr._chunk_fn)
+  k = int(traffic['chunk_size'])
+  return compile_timed(
+      'jit_scan_epoch_chunk[sage-products-unsup]',
+      jax.jit(lambda *a: chunk(*a, k), donate_argnums=(0, 1)),
+      (spec(state), sds((), jnp.bool_),
+       *table((tr._sample_args(), tr._feats, tr._id2i, tr._labels)),
+       spec(tr._perm_key), None, spec(tr._sampler._key),
+       sds((), jnp.int32), sds((), jnp.int32)))
+
+
 # ------------------------------------------------- the mesh cell, per chip
 
 def mesh_2x2(parts):
@@ -536,6 +590,9 @@ def main(argv):
   if 'gat' in which:
     _, _, cfg, traffic, _ = run.load_cell(GAT_CELL, 'BENCHMARK.json')
     lower_gat_chunk(cfg, traffic, one_chip)
+  if 'link' in which:
+    _, _, cfg, traffic, _ = run.load_cell(LINK_CELL, 'BENCHMARK.json')
+    lower_link_chunk(cfg, traffic, one_chip)
 
 
 if __name__ == '__main__':

@@ -249,3 +249,31 @@ def test_dist_example_builds_its_shards_on_the_devices():
   assert np.isfinite(res['final_loss'])
   assert res['final_loss'] < res['first_loss']
   assert 'epoch_wall_s' in res
+
+
+@pytest.mark.parametrize('per_batch', [False, True],
+                         ids=['scanned', 'per-batch'])
+def test_unsup_example_trains_through_the_scanned_epoch(per_batch):
+  """examples/graph_sage_unsup.py end to end at a small size: by default
+  the epoch is ScanTrainer's scanned program over the link loader (its
+  negative-sampler counters published once an epoch), ``--per-batch`` keeps
+  the documented loop; both learn the communities' links."""
+  script = os.path.join(REPO, 'examples', 'graph_sage_unsup.py')
+  env = dict(os.environ, JAX_PLATFORMS='cpu')
+  out = subprocess.run(
+      [sys.executable, script, '--epochs', '2', '--num-nodes', '1500',
+       '--avg-deg', '8', '--batch-size', '64', '--hidden', '16',
+       '--chunk-size', '8'] + (['--per-batch'] if per_batch else []),
+      capture_output=True, text=True, timeout=280, cwd=REPO, env=env)
+  assert out.returncode == 0, out.stderr[-2000:]
+  res = json.loads(out.stdout.strip().splitlines()[-1])
+  assert np.isfinite(res['final_loss'])
+  assert res['final_loss'] < res['first_loss']
+  assert res['test_link_acc'] > 0.55, res        # chance = 0.5
+  if per_batch:
+    assert res['trainer'] == 'per-batch loop' and not res['negatives']
+  else:
+    assert res['trainer'] == 'ScanTrainer'
+    neg = res['negatives']
+    assert neg['link.negatives.tested'] > 0
+    assert neg['link.negatives.rejected'] < neg['link.negatives.tested']
