@@ -53,22 +53,28 @@ class DistDataset:
     ``[P, n_max, F]``. ``labels``: an ``[P, n_max]`` array in the order
     of ``feat_ids`` (kept on the devices as a one-column store that
     shares the feature store's id table, its index and the book), or a
-    host ``[N]`` array as the constructor takes. ``node_pb`` is the one
-    host array:
-    it routes the features too. ``hotness`` ranks the rows for the hot
-    cache (``split_ratio`` / ``cache_rows``)."""
+    host ``[N]`` array as the constructor takes. Where ``graph['row_ids']``
+    and ``features['feat_ids']`` are ONE array the graph shares the
+    store's two-level index over it; otherwise it builds its own.
+    ``node_pb`` is the one host array: it routes the features too.
+    ``hotness`` ranks the rows for the hot cache (``split_ratio`` /
+    ``cache_rows``)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from ..utils import global_device_put
     # one book on the devices for the graph and both stores, not a copy
     # each (N int32 replicated on every chip)
     pb_dev = global_device_put(np.asarray(node_pb).astype(np.int32),
                                NamedSharding(mesh, P()))
-    dg = DistGraph.from_device_shards(mesh, node_pb, edge_dir=edge_dir,
-                                      pb_dev=pb_dev, **graph)
     df = DistFeature.from_device_shards(
         mesh, node_pb, features['feat_ids'], features['feats'],
         split_ratio=split_ratio, cache_rows=cache_rows, hotness=hotness,
         wire_dtype=wire_dtype, bucket_frac=bucket_frac, pb_dev=pb_dev)
+    # every node a row owner: the graph's row table IS the store's id
+    # table (one array handed over for both), so is its index
+    shared = graph['row_ids'] is features['feat_ids']
+    dg = DistGraph.from_device_shards(
+        mesh, node_pb, edge_dir=edge_dir, pb_dev=pb_dev,
+        row_index=df._row_index if shared else None, **graph)
     if labels is not None and getattr(labels, 'ndim', 1) == 2:
       labels = DistFeature.from_device_shards(
           mesh, node_pb, features['feat_ids'], labels[..., None],

@@ -119,26 +119,6 @@ def _hot_ids_fn(h: int):
   return jax.jit(pick)
 
 
-def _index_shards_fn(mesh, id_space: int, shift: int):
-  """The program ``feat_ids [P, n] -> (starts [P, S], largest bucket)``:
-  every shard's two-level index built on the device its table lives on,
-  one ``shift`` for all (the shards run one lookup program), the largest
-  bucket of any shard replicated for the host to size ``depth`` from."""
-  import jax
-  from jax.sharding import PartitionSpec as P
-
-  from ..utils.compat import shard_map
-  ax = tuple(mesh.axis_names)
-
-  def body(fid):
-    starts, big = sorted_index.bucket_starts(fid[0], id_space, shift)
-    return starts[None], jax.lax.pmax(big, ax)
-
-  return jax.jit(shard_map(body, mesh=mesh, in_specs=P(ax),
-                           out_specs=(P(ax), P()),
-                           check_replication=False))
-
-
 def _gather_replicated_fn(mesh, dtype, shift: int, depth: int):
   """The program ``(feat_ids [P, n], feat_starts [P, S], feats
   [P, n, F], ids [m]) -> rows [m, F]`` replicated on every device of the
@@ -337,18 +317,10 @@ class DistFeature:
     n_total = int(self.feature_pb.shape[0])
     shared_rows = row_index is not None
 
-    def built(program, table, shift):
-      # one program over the table where it lives; its largest bucket
-      # is the one scalar set-up fetches
-      record_dispatch('dist_feature.build_index')
-      starts, big = program(table)
-      return sorted_index.SortedIndex(
-          starts, shift, sorted_index.index_depth(jax.device_get(big)))
-
     if not shared_rows:
-      shift = sorted_index.index_shift(self.n_max, n_total)
-      row_index = built(_index_shards_fn(mesh, n_total, shift), feat_ids,
-                        shift)
+      record_dispatch('dist_feature.build_index')
+      row_index = sorted_index.build_sorted_index_shards(mesh, feat_ids,
+                                                         n_total)
     h = self.cache_rows
     if h > 0:
       if hotness is None or not isinstance(hotness, jax.Array):
@@ -368,10 +340,14 @@ class DistFeature:
           np.zeros((1, self._fdim), self.storage_dtype), repl)
       self.cache_ids = None
     self.cache_feats = None                # on the devices only
+    # one program over the replicated cache ids; its largest bucket is
+    # the one scalar set-up fetches
     shift = sorted_index.index_shift(cache_ids.shape[0], n_total)
-    cache_index = built(
-        jax.jit(lambda t: sorted_index.bucket_starts(t, n_total, shift)),
-        cache_ids, shift)
+    record_dispatch('dist_feature.build_index')
+    starts, big = jax.jit(
+        lambda t: sorted_index.bucket_starts(t, n_total, shift))(cache_ids)
+    cache_index = sorted_index.SortedIndex(
+        starts, shift, sorted_index.index_depth(jax.device_get(big)))
     self._set_indexes(row_index, cache_index, shared_rows)
     self._dev = dict(
         feat_ids=feat_ids, feat_starts=row_index.starts, feats=feats,

@@ -12,14 +12,21 @@ set — shard p of the leading axis is partition p's local CSR:
   eids    [P, E]   global edge ids
   weights [P, E]   optional edge weights
 
-Row lookup inside a shard is a binary search on row_ids (ops.uniform_sample_local);
-cross-shard row access happens by routing seed ids with all_to_all, not by
+plus the two-level index over row_ids (ops/sorted_index.py):
+
+  row_starts [P, (N >> shift) + 2]   first position per bucket of 2^shift ids
+
+Row lookup inside a shard reads the two starts around the id and halves
+``depth`` times between them (ops.uniform_sample_local); ``shift`` and
+``depth`` follow from the table when the graph is built (``row_index``).
+Cross-shard row access happens by routing seed ids with all_to_all, not by
 pointer chasing — see DistNeighborSampler.
 """
 from typing import Dict, Optional
 
 import numpy as np
 
+from ..ops import sorted_index
 from ..typing import GraphPartitionData
 
 INT32_MAX = np.iinfo(np.int32).max
@@ -41,6 +48,18 @@ def build_local_csr(part: GraphPartitionData, by: str = 'src'):
   np.cumsum(counts, out=indptr[1:])
   return row_ids.astype(np.int32), indptr, other.astype(np.int32), \
       eids, weights
+
+
+def _publish_indexes(indexes, shared: bool = False):
+  """The gauges ``dist_graph.index_depth`` / ``dist_graph.index_bytes``:
+  the halvings a row lookup runs (the deepest index) and the bytes a chip
+  holds for the ``starts`` — none where the index is another owner's.
+  Set once when a graph is built, never per batch."""
+  from .. import metrics
+  metrics.set_gauge('dist_graph.index_depth',
+                    max((ix.depth for ix in indexes), default=0))
+  metrics.set_gauge('dist_graph.index_bytes', 0 if shared else sum(
+      4 * int(ix.starts.shape[-1]) for ix in indexes))
 
 
 class DistGraph:
@@ -84,11 +103,15 @@ class DistGraph:
       self.eids[i, :e] = eid
       if has_w:
         self.weights[i, :e] = w
+    # the sampling programs are traced with its shift and depth
+    self.row_index = sorted_index.build_sorted_index_host(self.row_ids,
+                                                          self.num_nodes)
+    _publish_indexes([self.row_index])
 
   @classmethod
   def from_device_shards(cls, mesh, node_pb, row_ids, indptr, indices,
                          eids=None, weights=None, edge_dir: str = 'out',
-                         pb_dev=None):
+                         pb_dev=None, row_index=None):
     """A DistGraph over shards that ALREADY live on their devices: the
     stacked ``[P, ...]`` arrays of the module docstring, each sharded on
     its leading axis over ``mesh`` (shard p on device p), as
@@ -104,7 +127,11 @@ class DistGraph:
     dataset keeps ONE book on the devices for its graph and stores).
     ``eids`` may be None when no consumer asks for edge ids
     (``with_edge=False`` samplers): a one-column placeholder stands in
-    for the program argument. The host-side tables of a host-built graph
+    for the program argument. The index of ``row_ids`` is built by one
+    program over the shards where they live; ``row_index`` is another
+    owner's index over the SAME array (a feature store whose ``feat_ids``
+    is this ``row_ids`` shares its ``_row_index``: no new bytes, no new
+    program). The host-side tables of a host-built graph
     (``sorted_local_indices``, ``row_cumsum_stacked``) have nothing to
     read here and raise."""
     import jax
@@ -132,8 +159,17 @@ class DistGraph:
     if eids is None:
       eids = jax.jit(lambda: jnp.full((p, 1), -1, jnp.int32),
                      out_shardings=shard)()
+    shared = row_index is not None
+    if not shared:
+      from ..utils.trace import record_dispatch
+      record_dispatch('dist_graph.build_index')
+      row_index = sorted_index.build_sorted_index_shards(
+          mesh, row_ids, self.num_nodes)
+    self.row_index = row_index
+    _publish_indexes([row_index], shared)
     self._dev = dict(
-        row_ids=row_ids, indptr=indptr, indices=indices, eids=eids,
+        row_ids=row_ids, row_starts=row_index.starts, indptr=indptr,
+        indices=indices, eids=eids,
         node_pb=pb_dev if pb_dev is not None else global_device_put(
             self.node_pb.astype(np.int32), NamedSharding(mesh, P())))
     if weights is not None:
@@ -223,6 +259,7 @@ class DistGraph:
     repl = NamedSharding(mesh, P())
     out = dict(
         row_ids=global_device_put(self.row_ids, shard),
+        row_starts=global_device_put(self.row_index.starts, shard),
         indptr=global_device_put(self.indptr, shard),
         indices=global_device_put(self.indices, shard),
         eids=global_device_put(self.eids, shard),
@@ -272,6 +309,9 @@ class DistHeteroGraph:
                     self.node_pb[et[0] if edge_dir == 'out' else et[2]],
                     edge_dir=edge_dir)
       self.sub[et] = g
+    # one index an edge type (id space: the row type's node count); the
+    # gauges read the deepest and the sum, not the last one built
+    _publish_indexes([g.row_index for g in self.sub.values()])
 
   @property
   def is_hetero(self) -> bool:

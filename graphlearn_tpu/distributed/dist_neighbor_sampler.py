@@ -16,7 +16,9 @@ Per hop, per shard:
   2. pack frontier into [P, C] buckets              (ops.route_slots/scatter)
   3. lax.all_to_all                                 (requests ride ICI)
   4. local fanout sample over the shard's CSR       (ops.uniform_sample_local
-                                                     or weighted_sample_local)
+                                                     or weighted_sample_local;
+                                                     rows found through the
+                                                     graph's index of row_ids)
   5. lax.all_to_all back                            (responses)
   6. unpermute into frontier order                  (ops.gather_from_buckets)
   7. dedup/relabel into the shard's batch           (ops.induce_next)
@@ -67,19 +69,28 @@ from .dist_graph import DistGraph, DistHeteroGraph
 from ..ops.route import exchange_capacity, round8 as _round8  # noqa: E402,F401
 
 
-def _local_sample(garr, flat, fm, k, key, weighted: bool):
-  """Shared shard-local fanout sample over this shard's stacked CSR."""
+# the per-shard graph arrays a sampling program takes (+ 'wcum' where the
+# draw is weighted); device_arrays() of a DistGraph has them all
+GRAPH_KEYS = ('row_ids', 'row_starts', 'indptr', 'indices', 'eids')
+
+
+def _local_sample(garr, flat, fm, k, key, weighted: bool, index):
+  """Shared shard-local fanout sample over this shard's stacked CSR.
+  ``index`` is the ``(shift, depth)`` ``garr['row_starts']`` was built
+  with (``DistGraph.row_index``)."""
+  shift, depth = index
   if weighted:
     return ops.weighted_sample_local(
         garr['row_ids'], garr['indptr'], garr['indices'], garr['wcum'],
-        flat, fm, k, key)
+        flat, fm, k, key, garr['row_starts'], shift, depth)
   return ops.uniform_sample_local(
-      garr['row_ids'], garr['indptr'], garr['indices'], flat, fm, k, key)
+      garr['row_ids'], garr['indptr'], garr['indices'], flat, fm, k, key,
+      garr['row_starts'], shift, depth)
 
 
 def _exchange_hop_hier(garr, pb, frontier, fmask, k, key, sizes,
                        with_edge: bool, weighted: bool, bucket_frac,
-                       axes):
+                       axes, index):
   """Hierarchical 2-stage exchange for a (slice, chip) mesh.
 
   Stage 1 transposes along 'chip' at FULL frontier width — intra-slice
@@ -123,7 +134,7 @@ def _exchange_hop_hier(garr, pb, frontier, fmask, k, key, sizes,
     req2 = jax.lax.all_to_all(send2, s_ax, 0, 0)     # [S, cap2] via DCN
     flat = req2.reshape(-1)
     nbrs, epos, m = _local_sample(garr, flat, flat >= 0, k, key,
-                                  weighted)
+                                  weighted, index)
     def back(vals, fill, dtype=None):
       r2 = jax.lax.all_to_all(vals.reshape(s_sz, cap2, k), s_ax, 0, 0)
       b2 = ops.gather_from_buckets(r2, mdest, slot2, ok2, fill=fill)
@@ -144,7 +155,7 @@ def _exchange_hop_hier(garr, pb, frontier, fmask, k, key, sizes,
     req = jax.lax.all_to_all(send, axes, 0, 0)
     flat = req.reshape(-1)
     nbrs, epos, m = _local_sample(garr, flat, flat >= 0, k, key,
-                                  weighted)
+                                  weighted, index)
     resp_n = jax.lax.all_to_all(nbrs.reshape(nparts, bf, k), axes, 0, 0)
     resp_m = jax.lax.all_to_all(m.reshape(nparts, bf, k), axes, 0, 0)
     back_n = ops.gather_from_buckets(resp_n, dest, slotp, okp)
@@ -173,15 +184,15 @@ def _exchange_hop_hier(garr, pb, frontier, fmask, k, key, sizes,
 def _exchange_hop(garr, pb, frontier, fmask, k, key, nparts: int,
                   with_edge: bool, weighted: bool = False,
                   bucket_frac=2.0, axes=('g',), axis_sizes=None,
-                  hop_scopes=None):
+                  hop_scopes=None, *, index):
   """One cross-shard hop, shared by the homo and hetero engines:
   route frontier ids by partition book -> all_to_all request ->
   local fanout sample over this shard's CSR -> all_to_all response ->
   unpermute into frontier order.
 
   Runs inside shard_map; all values are per-shard. ``garr`` holds the
-  shard's stacked local CSR (row_ids/indptr/indices/eids, plus wcum when
-  ``weighted``).
+  shard's stacked local CSR (``GRAPH_KEYS``, plus wcum when
+  ``weighted``); ``index`` the ``(shift, depth)`` of its ``row_starts``.
 
   Bucket capacity: with ``bucket_frac=None`` every bucket is sized to
   the full frontier width, so routing can NEVER overflow (loss-free by
@@ -213,7 +224,7 @@ def _exchange_hop(garr, pb, frontier, fmask, k, key, nparts: int,
     assert axis_sizes is not None and len(axis_sizes) == 2
     return _exchange_hop_hier(garr, pb, frontier, fmask, k, key,
                               axis_sizes, with_edge, weighted,
-                              bucket_frac, axes)
+                              bucket_frac, axes, index)
   bf = frontier.shape[0]
   safe = jnp.maximum(frontier, 0)
   with x_scope():
@@ -229,7 +240,8 @@ def _exchange_hop(garr, pb, frontier, fmask, k, key, nparts: int,
       flat = req.reshape(-1)
       fm = flat >= 0
     with draw_scope():
-      nbrs, epos, m = _local_sample(garr, flat, fm, k, key, weighted)
+      nbrs, epos, m = _local_sample(garr, flat, fm, k, key, weighted,
+                                    index)
       if with_edge:
         e = jnp.where(m, garr['eids'][jnp.where(m, epos, 0)], -1)
     with x_scope():
@@ -268,10 +280,13 @@ def _exchange_hop(garr, pb, frontier, fmask, k, key, nparts: int,
 def _homo_hop_loop(gdev, pb, seeds, smask, key, fanouts, caps,
                    node_cap: int, nparts: int, with_edge: bool,
                    weighted: bool, dedup: str = 'sort',
-                   bucket_frac=2.0, axes=('g',), axis_sizes=None):
+                   bucket_frac=2.0, axes=('g',), axis_sizes=None, *,
+                   index):
   """Multi-hop homo engine body (traced inside shard_map): dedup seeds,
   expand hop by hop via _exchange_hop + the chosen inducer. Returns the
-  per-shard result dict (no leading axis).
+  per-shard result dict (no leading axis). ``gdev`` is the shard's view
+  of ``DistNeighborSampler.graph_shards()``, ``index`` the sampler's
+  ``row_index_statics()``.
 
   ``res['exchange_rows']`` ([hops] int32) counts, per hop, the valid
   frontier ids this shard sent to ANOTHER shard for expansion.
@@ -318,7 +333,8 @@ def _homo_hop_loop(gdev, pb, seeds, smask, key, fanouts, caps,
       nbrs, m, e = _exchange_hop(gdev, pb, frontier, fmask, k,
                                  hop_keys[i], nparts, with_edge, weighted,
                                  bucket_frac=bucket_frac, axes=axes,
-                                 axis_sizes=axis_sizes, hop_scopes=scopes)
+                                 axis_sizes=axis_sizes, hop_scopes=scopes,
+                                 index=index)
     with jax.named_scope(x_scope):
       # frontier ids another shard expands: what the hop's all_to_all
       # carries off this chip (the rest rides its own bucket)
@@ -512,6 +528,20 @@ class DistNeighborSampler:
       return 'wcum' in self._dev[etype]
     return 'wcum' in self._dev
 
+  def graph_shards(self, etype=None) -> dict:
+    """The device arrays of the (edge type's) stacked CSR a sampling
+    program takes, each sharded on its leading axis: ``GRAPH_KEYS``,
+    plus ``wcum`` where the draw is weighted."""
+    d = self._dev if etype is None else self._dev[etype]
+    keys = GRAPH_KEYS + (('wcum',) if self._weighted_for(etype) else ())
+    return {k: d[k] for k in keys}
+
+  def row_index_statics(self, etype=None):
+    """``(shift, depth)`` of the (edge type's) index over ``row_ids``:
+    the statics a program that draws locally is traced with."""
+    g = self.graph if etype is None else self.graph.sub[etype]
+    return g.row_index.shift, g.row_index.depth
+
   def _sorted_loc_dev(self, etype=None):
     """Lazily uploaded [P, E] segment-sorted local indices (negative
     sampling membership table)."""
@@ -659,16 +689,14 @@ class DistNeighborSampler:
     bucket_frac = self.bucket_frac
     ax = self._axes
     sizes = self._axis_sizes
+    index = self.row_index_statics()
+    gsh = self.graph_shards()
 
-    def body(row_ids, indptr, indices, eids, wcum, pb, seeds, smask, keys):
-      gdev = dict(row_ids=row_ids[0], indptr=indptr[0],
-                  indices=indices[0], eids=eids[0])
-      if weighted:
-        gdev['wcum'] = wcum[0]
-      res = _homo_hop_loop(gdev, pb, seeds[0], smask[0], keys[0], fanouts,
-                           caps, node_cap, nparts, with_edge, weighted,
-                           dedup=dedup, bucket_frac=bucket_frac, axes=ax,
-                           axis_sizes=sizes)
+    def body(g, pb, seeds, smask, keys):
+      res = _homo_hop_loop(jax.tree.map(lambda a: a[0], g), pb, seeds[0], smask[0], keys[0],
+                           fanouts, caps, node_cap, nparts, with_edge,
+                           weighted, dedup=dedup, bucket_frac=bucket_frac,
+                           axes=ax, axis_sizes=sizes, index=index)
       return _lift(res)
 
     out_specs = dict(node=P(ax), num_nodes=P(ax), row=P(ax),
@@ -679,16 +707,13 @@ class DistNeighborSampler:
       out_specs['edge'] = P(ax)
     fn = shard_map(
         body, mesh=self.mesh,
-        in_specs=(P(ax), P(ax), P(ax), P(ax), P(ax), P(), P(ax),
-                  P(ax), P(ax)),
+        in_specs=(jax.tree.map(lambda _: P(ax), gsh), P(), P(ax), P(ax), P(ax)),
         out_specs=out_specs)
     jfn = jax.jit(fn)
-    d = self._dev
+    pb = self._dev['node_pb']
 
     def run(seeds, smask, keys):
-      return jfn(d['row_ids'], d['indptr'], d['indices'], d['eids'],
-                 d.get('wcum', d['eids']), d['node_pb'], seeds, smask,
-                 keys)
+      return jfn(gsh, pb, seeds, smask, keys)
 
     return run
 
@@ -723,13 +748,11 @@ class DistNeighborSampler:
     caps = self._capacities(width)
     node_cap = self._node_cap(caps)
     dedup = self.dedup
+    index = self.row_index_statics()
+    gsh = self.graph_shards()
 
-    def body(row_ids, indptr, indices, eids, wcum, sorted_loc, pb,
-             rows, cols, smask, keys):
-      gdev = dict(row_ids=row_ids[0], indptr=indptr[0],
-                  indices=indices[0], eids=eids[0])
-      if weighted:
-        gdev['wcum'] = wcum[0]
+    def body(g, sorted_loc, pb, rows, cols, smask, keys):
+      gdev = jax.tree.map(lambda a: a[0], g)
       rows_, cols_, sm, key = rows[0], cols[0], smask[0], keys[0]
       kneg, kloop = jax.random.split(key)
       if mode == 'none':
@@ -750,7 +773,7 @@ class DistNeighborSampler:
       res = _homo_hop_loop(gdev, pb, seeds, seed_mask, kloop, fanouts,
                            caps, node_cap, nparts, with_edge, weighted,
                            dedup=dedup, bucket_frac=bucket_frac,
-                           axes=ax, axis_sizes=sizes)
+                           axes=ax, axis_sizes=sizes, index=index)
       inv = res['seed_inverse']
       if mode == 'none':
         res['edge_label_index'] = jnp.stack([inv[:b], inv[b:2 * b]])
@@ -777,17 +800,15 @@ class DistNeighborSampler:
     out_specs = {k: P(ax) for k in out_keys}
     fn = shard_map(
         body, mesh=self.mesh,
-        in_specs=(P(ax),) * 6 + (P(),) + (P(ax),) * 4,
+        in_specs=(jax.tree.map(lambda _: P(ax), gsh), P(ax), P()) + (P(ax),) * 4,
         out_specs=out_specs)
     jfn = jax.jit(fn)
-    d = self._dev
+    pb = self._dev['node_pb']
 
     def run(rows, cols, smask, keys):
       sorted_loc = (self._sorted_loc_dev() if mode != 'none'
-                    else d['eids'])
-      return jfn(d['row_ids'], d['indptr'], d['indices'], d['eids'],
-                 d.get('wcum', d['eids']), sorted_loc, d['node_pb'],
-                 rows, cols, smask, keys)
+                    else gsh['eids'])
+      return jfn(gsh, sorted_loc, pb, rows, cols, smask, keys)
 
     return run
 
@@ -812,10 +833,11 @@ class DistNeighborSampler:
     node_cap = sum(caps)
     with_edge = self.with_edge
     weighted = self._weighted_for()
+    index = self.row_index_statics()
+    gsh = self.graph_shards()
 
-    def body(row_ids, indptr, indices, eids, pb, seeds, smask, keys):
-      gdev = dict(row_ids=row_ids[0], indptr=indptr[0],
-                  indices=indices[0], eids=eids[0])
+    def body(g, pb, seeds, smask, keys):
+      gdev = jax.tree.map(lambda a: a[0], g)
       seeds_, sm, key = seeds[0], smask[0], keys[0]
       node_buf, nvalid = seeds_, sm
       if fanouts:
@@ -830,7 +852,8 @@ class DistNeighborSampler:
                                      hop_keys[i], nparts, False, weighted,
                                      bucket_frac=self.bucket_frac,
                                      axes=ax,
-                                     axis_sizes=self._axis_sizes)
+                                     axis_sizes=self._axis_sizes,
+                                     index=index)
           state, out = ops.induce_next(state, fidx, nbrs, m)
           nxt = caps[i + 1]
           frontier = out['frontier'][:nxt]
@@ -869,14 +892,13 @@ class DistNeighborSampler:
     out_specs = {k: P(ax) for k in out_keys}
     fn = shard_map(
         body, mesh=self.mesh,
-        in_specs=(P(ax),) * 4 + (P(),) + (P(ax),) * 3,
+        in_specs=(jax.tree.map(lambda _: P(ax), gsh), P(), P(ax), P(ax), P(ax)),
         out_specs=out_specs)
     jfn = jax.jit(fn)
-    d = self._dev
+    pb = self._dev['node_pb']
 
     def run(seeds, smask, keys):
-      return jfn(d['row_ids'], d['indptr'], d['indices'], d['eids'],
-                 d['node_pb'], seeds, smask, keys)
+      return jfn(gsh, pb, seeds, smask, keys)
 
     return run
 
@@ -955,7 +977,8 @@ class DistNeighborSampler:
                                    self._weighted_for(et),
                                    bucket_frac=self.bucket_frac,
                                    axes=self._axes,
-                                   axis_sizes=self._axis_sizes)
+                                   axis_sizes=self._axis_sizes,
+                                   index=self.row_index_statics(et))
         ki += 1
         states[res_t], iout = induce(states[res_t], fidx, nbrs, m,
                                      offsets[res_t],
@@ -1050,9 +1073,8 @@ class DistNeighborSampler:
     args = []
     for et in etypes:
       ga = d[et]
-      args.extend([ga['row_ids'], ga['indptr'], ga['indices'],
-                   ga['eids'],
-                   ga.get('wcum', ga['eids'])])
+      args.extend([ga[k] for k in GRAPH_KEYS])
+      args.append(ga.get('wcum', ga['eids']))
     for nt in ntypes:
       args.append(d['#pb'][nt])
     return args
@@ -1061,14 +1083,13 @@ class DistNeighborSampler:
     etypes = list(self.graph.etypes)
     ntypes = list(self.graph.ntypes)
     i = 0
+    n = len(GRAPH_KEYS)
     garr = {}
     for et in etypes:
-      garr[et] = dict(row_ids=flat_args[i][0], indptr=flat_args[i + 1][0],
-                      indices=flat_args[i + 2][0],
-                      eids=flat_args[i + 3][0])
+      garr[et] = {k: flat_args[i + j][0] for j, k in enumerate(GRAPH_KEYS)}
       if self._weighted_for(et):
-        garr[et]['wcum'] = flat_args[i + 4][0]
-      i += 5
+        garr[et]['wcum'] = flat_args[i + n][0]
+      i += n + 1
     pbs = {}
     for nt in ntypes:
       pbs[nt] = flat_args[i]
@@ -1080,7 +1101,7 @@ class DistNeighborSampler:
     n_et = len(self.graph.etypes)
     n_nt = len(self.graph.ntypes)
     ax = tuple(self.mesh.axis_names)
-    return tuple([P(ax)] * (5 * n_et) + [P()] * n_nt +
+    return tuple([P(ax)] * ((len(GRAPH_KEYS) + 1) * n_et) + [P()] * n_nt +
                  [P(ax)] * n_tail)
 
   # ------------------------------------------------------- hetero build fn

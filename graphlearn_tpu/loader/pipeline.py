@@ -452,9 +452,8 @@ class DistFusedEpochTrainer:
     lab_body = self._label_store._shard_body(
         label_cap if label_cap is not None else node_cap)
     d = sampler._dev
-    gsh = {k: d[k] for k in ('row_ids', 'indptr', 'indices', 'eids')}
-    if weighted:
-      gsh['wcum'] = d['wcum']
+    gsh = sampler.graph_shards()
+    index = sampler.row_index_statics()
     fdev = self._feat.device_arrays()
     ldev = self._label_store.device_arrays()
     shard_keys = self._label_store.SHARD_KEYS
@@ -474,7 +473,7 @@ class DistFusedEpochTrainer:
                            fanouts, caps, node_cap, nparts, False,
                            weighted, dedup=dedup,
                            bucket_frac=bucket_frac, axes=ax,
-                           axis_sizes=sizes)
+                           axis_sizes=sizes, index=index)
       ids = res['node']
       x, srow = feat_body(*table_args(views['f'], repl['f']),
                           stats_rows, ids, ids >= 0)
@@ -511,13 +510,7 @@ class DistFusedEpochTrainer:
     lab_body = self._label_store._shard_body(
         label_cap if label_cap is not None else node_caps[t_in])
     d = sampler._dev
-    gsh = {}
-    for et in sampler.graph.etypes:
-      ga = d[et]
-      gsh[et] = {k: ga[k] for k in ('row_ids', 'indptr', 'indices',
-                                    'eids')}
-      if sampler._weighted_for(et):
-        gsh[et]['wcum'] = ga['wcum']
+    gsh = {et: sampler.graph_shards(et) for et in sampler.graph.etypes}
     fdevs = {t: self._feat[t].device_arrays() for t in feat_types}
     ldev = self._label_store.device_arrays()
     shard_keys = self._label_store.SHARD_KEYS

@@ -38,6 +38,10 @@ REGISTERED_METRICS = frozenset({
     # (the rows the miss-only row exchange asked for are
     # dist_feature.unique_misses)
     'dist_exchange.*',
+    # the two-level index over a mesh graph's row_ids, set once when a
+    # DistGraph builds or is handed it (distributed/dist_graph.py):
+    # dist_graph.index_depth, dist_graph.index_bytes
+    'dist_graph.*',
     # what a scanned link epoch's negative sampler and seed union did
     # (loader/scan_epoch.py ScanTrainer over a link loader, published
     # once per epoch from a per-step scan output): link.negatives.tested

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .sorted_index import indexed_membership
 from .unique import FILL
 
 
@@ -458,24 +459,33 @@ def uniform_sample_block(csr_meta, indices_blocks, num_edges: int, seeds,
   return nbrs, epos, mask
 
 
-@functools.partial(jax.jit, static_argnames=('k',))
+def _local_rows(row_ids, starts, seeds, seed_mask, shift: int, depth: int):
+  """Shard-local row lookup ``global id -> position in row_ids`` through
+  the table's two-level index (ops/sorted_index.py: one read of the
+  bucket starts, then ``depth`` halvings inside the bucket), and which
+  seeds this shard owns."""
+  found, pos = indexed_membership(row_ids, starts, seeds, shift, depth)
+  return found & seed_mask, pos
+
+
+@functools.partial(jax.jit, static_argnames=('k', 'shift', 'depth'))
 def uniform_sample_local(row_ids, indptr_loc, indices, seeds, seed_mask,
-                         k: int, key):
+                         k: int, key, starts, shift: int, depth: int):
   """Uniform fanout sampling over a *partition-local* CSR.
 
   The distributed graph stores only owned rows per shard: ``row_ids`` is the
   ascending (INT_MAX-padded) list of owned global ids and ``indptr_loc``
-  their local CSR offsets. Row lookup is a binary search instead of direct
-  indexing — the TPU replacement for the reference's partition-local Graph
-  rows (csrc/cpu/graph.cc + dist_neighbor_sampler.py:624). Seeds not owned
-  by this shard come back masked out.
+  their local CSR offsets. Row lookup goes through the graph's two-level
+  index over ``row_ids`` (``starts`` and its statics ``shift`` / ``depth``:
+  ``DistGraph.row_index``) instead of direct indexing — the TPU replacement
+  for the reference's partition-local Graph rows (csrc/cpu/graph.cc +
+  dist_neighbor_sampler.py:624). Seeds not owned by this shard come back
+  masked out.
 
   Same output contract as :func:`uniform_sample`.
   """
   b = seeds.shape[0]
-  pos = jnp.searchsorted(row_ids, seeds)
-  pos = jnp.clip(pos, 0, row_ids.shape[0] - 1)
-  found = (row_ids[pos] == seeds) & seed_mask
+  found, pos = _local_rows(row_ids, starts, seeds, seed_mask, shift, depth)
   start = indptr_loc[pos]
   deg = jnp.where(found, indptr_loc[pos + 1] - start, 0)
   u = jax.random.uniform(key, (b, k))
@@ -490,9 +500,10 @@ def uniform_sample_local(row_ids, indptr_loc, indices, seeds, seed_mask,
   return nbrs, jnp.where(mask, epos, 0), mask
 
 
-@functools.partial(jax.jit, static_argnames=('k',))
+@functools.partial(jax.jit, static_argnames=('k', 'shift', 'depth'))
 def weighted_sample_local(row_ids, indptr_loc, indices, row_cumsum, seeds,
-                          seed_mask, k: int, key):
+                          seed_mask, k: int, key, starts, shift: int,
+                          depth: int):
   """Edge-weight-biased fanout sampling over a *partition-local* CSR.
 
   Distributed counterpart of :func:`weighted_sample` (the reference's GPU
@@ -500,12 +511,11 @@ def weighted_sample_local(row_ids, indptr_loc, indices, row_cumsum, seeds,
   sampler/neighbor_sampler.py:86-91 — here the weighted path works in the
   sharded engine too). ``row_cumsum`` is the per-shard row-restarting
   cumulative weight array (:func:`build_row_cumsum` over the local CSR).
-  Same output contract as :func:`uniform_sample_local`.
+  Same output contract (and the same row lookup) as
+  :func:`uniform_sample_local`.
   """
   b = seeds.shape[0]
-  pos = jnp.searchsorted(row_ids, seeds)
-  pos = jnp.clip(pos, 0, row_ids.shape[0] - 1)
-  found = (row_ids[pos] == seeds) & seed_mask
+  found, pos = _local_rows(row_ids, starts, seeds, seed_mask, shift, depth)
   start = indptr_loc[pos]
   end = indptr_loc[pos + 1]
   deg = jnp.where(found, end - start, 0)

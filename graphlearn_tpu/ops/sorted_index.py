@@ -40,7 +40,7 @@ def index_shift(rows: int, id_space: int) -> int:
 def bucket_bounds(id_space: int, shift: int) -> np.ndarray:
   """The ``(N >> shift) + 2`` lower id bounds the starts are searched
   for; the last lies past every id of the space."""
-  return (np.arange((int(id_space) >> shift) + 2, dtype=np.int64)
+  return (np.arange((id_space >> shift) + 2, dtype=np.int64)
           << shift).astype(np.int32)
 
 
@@ -57,6 +57,36 @@ def bucket_starts(table, id_space: int, shift: int):
   starts = jnp.searchsorted(
       table, jnp.asarray(bucket_bounds(id_space, shift))).astype(jnp.int32)
   return starts, jnp.max(starts[1:] - starts[:-1])
+
+
+def index_shards_fn(mesh, id_space: int, shift: int):
+  """The program ``tables [P, n] -> (starts [P, S], largest bucket)``:
+  every shard's index built on the device its table lives on, one
+  ``shift`` for all (the shards run one lookup program), the largest
+  bucket of any shard replicated for the host to size ``depth`` from."""
+  import jax
+  from jax.sharding import PartitionSpec as P
+
+  from ..utils.compat import shard_map
+  ax = tuple(mesh.axis_names)
+
+  def body(table):
+    starts, big = bucket_starts(table[0], id_space, shift)
+    return starts[None], jax.lax.pmax(big, ax)
+
+  return jax.jit(shard_map(body, mesh=mesh, in_specs=P(ax),
+                           out_specs=(P(ax), P()),
+                           check_replication=False))
+
+
+def build_sorted_index_shards(mesh, tables, id_space: int) -> SortedIndex:
+  """The index of ``[P, n]`` tables sharded on their leading axis over
+  ``mesh``, built where they live: one program, and the largest bucket
+  the one scalar fetched."""
+  import jax
+  shift = index_shift(tables.shape[-1], id_space)
+  starts, big = index_shards_fn(mesh, id_space, shift)(tables)
+  return SortedIndex(starts, shift, index_depth(jax.device_get(big)))
 
 
 def build_sorted_index_host(table: np.ndarray, id_space: int) -> SortedIndex:

@@ -234,9 +234,8 @@ class TieredDistScanTrainer(DistScanTrainer):
     lab_body = self._label_store._shard_body(
         label_cap if label_cap is not None else node_cap)
     d = sampler._dev
-    gsh = {k: d[k] for k in ('row_ids', 'indptr', 'indices', 'eids')}
-    if weighted:
-      gsh['wcum'] = d['wcum']
+    gsh = sampler.graph_shards()
+    index = sampler.row_index_statics()
     # hot-prefix tables only — the full [P, n_max, F] partition table is
     # never uploaded on this path (device_arrays stays the per-step
     # loaders' contract)
@@ -260,7 +259,7 @@ class TieredDistScanTrainer(DistScanTrainer):
                            fanouts, caps, node_cap, nparts, False,
                            weighted, dedup=dedup,
                            bucket_frac=bucket_frac, axes=ax,
-                           axis_sizes=sizes)
+                           axis_sizes=sizes, index=index)
       ids = res['node']
       fv = views['f']
       x, srow = feat_body(
@@ -301,13 +300,7 @@ class TieredDistScanTrainer(DistScanTrainer):
     lab_body = self._label_store._shard_body(
         label_cap if label_cap is not None else node_caps[t_in])
     d = sampler._dev
-    gsh = {}
-    for et in sampler.graph.etypes:
-      ga = d[et]
-      gsh[et] = {k: ga[k] for k in ('row_ids', 'indptr', 'indices',
-                                    'eids')}
-      if sampler._weighted_for(et):
-        gsh[et]['wcum'] = ga['wcum']
+    gsh = {et: sampler.graph_shards(et) for et in sampler.graph.etypes}
     # hot-prefix tables only, per ntype — no full [P, n_max, F] uploads
     fdevs = {t: self._feat[t].dist_scan_tables() for t in feat_types}
     ldev = self._label_store.device_arrays()
@@ -377,6 +370,7 @@ class TieredDistScanTrainer(DistScanTrainer):
     weighted = sampler._weighted_for()
     bucket_frac = sampler.bucket_frac
     ax, sizes = self._axes, self._axis_sizes
+    index = sampler.row_index_statics()
     mesh = self.mesh
     gspec = jax.tree.map(lambda _: P(ax), self._shard_tree['g'])
 
@@ -413,7 +407,7 @@ class TieredDistScanTrainer(DistScanTrainer):
           res = _homo_hop_loop(gviews, pb_s, s, m, keys[my], fanouts,
                                caps, node_cap, nparts, False, weighted,
                                dedup=dedup, bucket_frac=bucket_frac,
-                               axes=ax, axis_sizes=sizes)
+                               axes=ax, axis_sizes=sizes, index=index)
           return carry, res['node']
 
         _, rows = lax.scan(step, 0, (seeds_my, mask_my, counts))

@@ -435,7 +435,7 @@ def lower_mesh_cache(cfg, mesh):
   shift = sorted_index.index_shift(n_max, n)
   depth = sorted_index.index_depth((1 << shift) // parts)
   starts = (n >> shift) + 2
-  compile_timed('index.rows', dist_feature._index_shards_fn(mesh, n, shift),
+  compile_timed('index.rows', sorted_index.index_shards_fn(mesh, n, shift),
                 (sds((parts, n_max), jnp.int32, sharding=shard),),
                 collectives=True)
   cshift = sorted_index.index_shift(h, n)
