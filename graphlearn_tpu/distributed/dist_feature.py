@@ -48,7 +48,10 @@ import numpy as np
 from .. import ops
 from ..ops import sorted_index
 from ..metrics.registry_names import (SCOPE_CACHE, SCOPE_COLLATE,
-                                      SCOPE_EXCHANGE)
+                                      SCOPE_DEDUP, SCOPE_EXCHANGE,
+                                      SCOPE_FANOUT, SCOPE_LOOKUP,
+                                      SCOPE_PACK, SCOPE_ROUTE, SCOPE_ROWS,
+                                      SCOPE_UNPACK, SCOPE_WIRE)
 from ..ops.route import exchange_capacity
 
 INT32_MAX = np.iinfo(np.int32).max
@@ -217,6 +220,9 @@ class DistFeature:
     # at exactly H/N; skewed-to-hot requests (the point of the cache)
     # hit more, so capacities sized on (1 - H/N) only gain slack
     self._cache_frac = self.cache_rows / n_total if n_total else 0.0
+    # the family this store's counters and gauges go under; a sampler's
+    # label store is told 'dist_label' (DistNeighborSampler._label_dist)
+    self.stats_prefix = 'dist_feature'
     self._dev = None
     self._stats = None
     self._fns = {}
@@ -520,6 +526,24 @@ class DistFeature:
     ax = tuple(self.mesh.axis_names)
     sizes = tuple(self.mesh.shape[a] for a in ax)
     hier = len(ax) == 2
+    # the miss buckets' capacity on the no-overflow path, and the request
+    # slots a shard's owners look up through it a step: every bucket of
+    # the flat exchange, the 'slice' stage's of the hierarchical one
+    # (sized on the mean VALID miss load, ~miss width over S, not the
+    # C*b slot count). Published once here, never per batch; the fill
+    # ratio is unique_misses over it (docs/observability.md)
+    if hier:
+      s_sz, c_sz = sizes
+      cap2 = (c_sz * b if bucket_frac is None or s_sz <= 1 else
+              min(c_sz * b,
+                  miss_capacity(b, s_sz, bucket_frac, hit_est)))
+      slots = s_sz * cap2
+    else:
+      cap_small = miss_capacity(b, nparts, bucket_frac, hit_est)
+      slots = nparts * cap_small
+    from .. import metrics
+    # graftlint: allow[metric-registry] the store's family (dist_feature.* / dist_label.*, both registered wildcards)
+    metrics.set_gauge(f'{self.stats_prefix}.exchange_slots', slots)
 
     if slab:
       def lookup_local(feat_ids, feats, flat):
@@ -528,16 +552,20 @@ class DistFeature:
         prefix or the staged slab (zeros where absent/padded — an
         impossible case for planned rows under an exact program)."""
         starts, (hot, slab_pos, slab_rows) = feats
-        found, pos = sorted_index.indexed_membership(
-            feat_ids, starts, flat, rshift, rdepth)
+        with jax.named_scope(SCOPE_LOOKUP):
+          found, pos = sorted_index.indexed_membership(
+              feat_ids, starts, flat, rshift, rdepth)
         hp = hot.shape[0]
-        hot_rows = hot[jnp.clip(pos, 0, hp - 1)]
-        sp = jnp.clip(jnp.searchsorted(slab_pos, pos.astype(jnp.int32)),
-                      0, slab_pos.shape[0] - 1)
-        in_slab = slab_pos[sp] == pos.astype(jnp.int32)
-        rows = jnp.where((pos < hp)[:, None], hot_rows,
-                         jnp.where(in_slab[:, None], slab_rows[sp], 0))
-        return jnp.where(found[:, None], rows, 0)
+        with jax.named_scope(SCOPE_ROWS):
+          hot_rows = hot[jnp.clip(pos, 0, hp - 1)]
+        with jax.named_scope(SCOPE_LOOKUP):
+          sp = jnp.clip(jnp.searchsorted(slab_pos, pos.astype(jnp.int32)),
+                        0, slab_pos.shape[0] - 1)
+          in_slab = slab_pos[sp] == pos.astype(jnp.int32)
+        with jax.named_scope(SCOPE_ROWS):
+          rows = jnp.where((pos < hp)[:, None], hot_rows,
+                           jnp.where(in_slab[:, None], slab_rows[sp], 0))
+          return jnp.where(found[:, None], rows, 0)
     else:
       def lookup_local(feat_ids, feats, flat):
         """Rows for a flat request vector over this shard's sorted owned
@@ -547,33 +575,41 @@ class DistFeature:
         that gathers from it — 4.7 GB at 9 M rows — where indexing
         through it is free."""
         starts, feats = feats
-        found, pos = sorted_index.indexed_membership(
-            feat_ids, starts, flat, rshift, rdepth)
-        rows = feats[0, pos] if feats.ndim == 3 else feats[pos]
-        return jnp.where(found[:, None], rows, 0)
+        with jax.named_scope(SCOPE_LOOKUP):
+          found, pos = sorted_index.indexed_membership(
+              feat_ids, starts, flat, rshift, rdepth)
+        with jax.named_scope(SCOPE_ROWS):
+          rows = feats[0, pos] if feats.ndim == 3 else feats[pos]
+          return jnp.where(found[:, None], rows, 0)
 
     def exchange_flat(feat_ids, feats, pb, req, rmask):
       """Fractional bucketed all_to_all with replicated full-width
       fallback (sampler _exchange_hop parity). Returns rows [b, F]
       (storage dtype) in request order + the overflow count."""
-      dest = jnp.where(rmask, pb[jnp.maximum(req, 0)], nparts)
-      slot, ok = ops.route_slots(dest, rmask, capacity=b)
+      with jax.named_scope(SCOPE_ROUTE):
+        dest = jnp.where(rmask, pb[jnp.maximum(req, 0)], nparts)
+        slot, ok = ops.route_slots(dest, rmask, capacity=b)
 
       def do(cap: int):
-        okc = ok & (slot < cap)
-        send = ops.scatter_to_buckets(req, dest, slot, okc, nparts, cap)
-        r = jax.lax.all_to_all(send, ax, 0, 0)          # [P, cap] reqs
+        with jax.named_scope(SCOPE_PACK):
+          okc = ok & (slot < cap)
+          send = ops.scatter_to_buckets(req, dest, slot, okc, nparts, cap)
+        with jax.named_scope(SCOPE_WIRE):
+          r = jax.lax.all_to_all(send, ax, 0, 0)        # [P, cap] reqs
         rows = lookup_local(feat_ids, feats, r.reshape(-1))
-        rows = rows.astype(wdtype).reshape(nparts, cap, fdim)
-        resp = jax.lax.all_to_all(rows, ax, 0, 0)       # [P, cap, F]
-        back = ops.gather_from_buckets(resp, dest, slot, okc, fill=0)
-        return back.astype(fdtype)
+        with jax.named_scope(SCOPE_ROWS):
+          rows = rows.astype(wdtype).reshape(nparts, cap, fdim)
+        with jax.named_scope(SCOPE_WIRE):
+          resp = jax.lax.all_to_all(rows, ax, 0, 0)     # [P, cap, F]
+        with jax.named_scope(SCOPE_UNPACK):
+          back = ops.gather_from_buckets(resp, dest, slot, okc, fill=0)
+          return back.astype(fdtype)
 
-      cap_small = miss_capacity(b, nparts, bucket_frac, hit_est)
       if cap_small >= b:
         return do(b), jnp.int32(0)
-      ovf = jnp.sum(rmask & (slot >= cap_small)).astype(jnp.int32)
-      total_ovf = jax.lax.psum(ovf, ax)
+      with jax.named_scope(SCOPE_ROUTE):
+        ovf = jnp.sum(rmask & (slot >= cap_small)).astype(jnp.int32)
+        total_ovf = jax.lax.psum(ovf, ax)
       rows = jax.lax.cond(total_ovf == 0, lambda _: do(cap_small),
                           lambda _: do(b), None)
       return rows, ovf
@@ -586,47 +622,61 @@ class DistFeature:
       sized on the mean VALID miss load (~miss width over S), not the
       C*b slot count."""
       s_ax, c_ax = ax
-      s_sz, c_sz = sizes
-      dest = jnp.where(rmask, pb[jnp.maximum(req, 0)], nparts)
-      c_dst = jnp.where(rmask, dest % c_sz, c_sz)
-      slot1, ok1 = ops.route_slots(c_dst, rmask, capacity=b)
-      send1 = ops.scatter_to_buckets(req, c_dst, slot1, ok1, c_sz, b)
-      req1 = jax.lax.all_to_all(send1, c_ax, 0, 0)      # [C, b] via ICI
-      mid = req1.reshape(-1)
-      mid_mask = mid >= 0
-      mdest = jnp.where(mid_mask, pb[jnp.maximum(mid, 0)] // c_sz, s_sz)
-      slot2, ok2f = ops.route_slots(mdest, mid_mask, capacity=c_sz * b)
-      cap2 = (c_sz * b if bucket_frac is None or s_sz <= 1 else
-              min(c_sz * b,
-                  miss_capacity(b, s_sz, bucket_frac, hit_est)))
+      with jax.named_scope(SCOPE_ROUTE):
+        dest = jnp.where(rmask, pb[jnp.maximum(req, 0)], nparts)
+        c_dst = jnp.where(rmask, dest % c_sz, c_sz)
+        slot1, ok1 = ops.route_slots(c_dst, rmask, capacity=b)
+      with jax.named_scope(SCOPE_PACK):
+        send1 = ops.scatter_to_buckets(req, c_dst, slot1, ok1, c_sz, b)
+      with jax.named_scope(SCOPE_WIRE):
+        req1 = jax.lax.all_to_all(send1, c_ax, 0, 0)    # [C, b] via ICI
+      with jax.named_scope(SCOPE_ROUTE):
+        mid = req1.reshape(-1)
+        mid_mask = mid >= 0
+        mdest = jnp.where(mid_mask, pb[jnp.maximum(mid, 0)] // c_sz, s_sz)
+        slot2, ok2f = ops.route_slots(mdest, mid_mask, capacity=c_sz * b)
 
       def hier_path(_):
-        ok2 = ok2f & (slot2 < cap2)
-        send2 = ops.scatter_to_buckets(mid, mdest, slot2, ok2, s_sz,
-                                       cap2)
-        req2 = jax.lax.all_to_all(send2, s_ax, 0, 0)    # [S, cap2] DCN
+        with jax.named_scope(SCOPE_PACK):
+          ok2 = ok2f & (slot2 < cap2)
+          send2 = ops.scatter_to_buckets(mid, mdest, slot2, ok2, s_sz,
+                                         cap2)
+        with jax.named_scope(SCOPE_WIRE):
+          req2 = jax.lax.all_to_all(send2, s_ax, 0, 0)  # [S, cap2] DCN
         rows = lookup_local(feat_ids, feats, req2.reshape(-1))
-        rows = rows.astype(wdtype).reshape(s_sz, cap2, fdim)
-        r2 = jax.lax.all_to_all(rows, s_ax, 0, 0)
-        b2 = ops.gather_from_buckets(r2, mdest, slot2, ok2, fill=0)
-        r1 = jax.lax.all_to_all(b2.reshape(c_sz, b, fdim), c_ax, 0, 0)
-        back = ops.gather_from_buckets(r1, c_dst, slot1, ok1, fill=0)
-        return back.astype(fdtype)
+        with jax.named_scope(SCOPE_ROWS):
+          rows = rows.astype(wdtype).reshape(s_sz, cap2, fdim)
+        with jax.named_scope(SCOPE_WIRE):
+          r2 = jax.lax.all_to_all(rows, s_ax, 0, 0)
+        with jax.named_scope(SCOPE_UNPACK):
+          b2 = ops.gather_from_buckets(r2, mdest, slot2, ok2, fill=0)
+        with jax.named_scope(SCOPE_WIRE):
+          r1 = jax.lax.all_to_all(b2.reshape(c_sz, b, fdim), c_ax, 0, 0)
+        with jax.named_scope(SCOPE_UNPACK):
+          back = ops.gather_from_buckets(r1, c_dst, slot1, ok1, fill=0)
+          return back.astype(fdtype)
 
       def flat_path(_):
-        slotp, okp = ops.route_slots(dest, rmask, capacity=b)
-        send = ops.scatter_to_buckets(req, dest, slotp, okp, nparts, b)
-        r = jax.lax.all_to_all(send, ax, 0, 0)
+        with jax.named_scope(SCOPE_ROUTE):
+          slotp, okp = ops.route_slots(dest, rmask, capacity=b)
+        with jax.named_scope(SCOPE_PACK):
+          send = ops.scatter_to_buckets(req, dest, slotp, okp, nparts, b)
+        with jax.named_scope(SCOPE_WIRE):
+          r = jax.lax.all_to_all(send, ax, 0, 0)
         rows = lookup_local(feat_ids, feats, r.reshape(-1))
-        rows = rows.astype(wdtype).reshape(nparts, b, fdim)
-        resp = jax.lax.all_to_all(rows, ax, 0, 0)
-        back = ops.gather_from_buckets(resp, dest, slotp, okp, fill=0)
-        return back.astype(fdtype)
+        with jax.named_scope(SCOPE_ROWS):
+          rows = rows.astype(wdtype).reshape(nparts, b, fdim)
+        with jax.named_scope(SCOPE_WIRE):
+          resp = jax.lax.all_to_all(rows, ax, 0, 0)
+        with jax.named_scope(SCOPE_UNPACK):
+          back = ops.gather_from_buckets(resp, dest, slotp, okp, fill=0)
+          return back.astype(fdtype)
 
       if cap2 >= c_sz * b:
         return hier_path(None), jnp.int32(0)
-      ovf = jnp.sum(mid_mask & (slot2 >= cap2)).astype(jnp.int32)
-      total_ovf = jax.lax.psum(ovf, ax)
+      with jax.named_scope(SCOPE_ROUTE):
+        ovf = jnp.sum(mid_mask & (slot2 >= cap2)).astype(jnp.int32)
+        total_ovf = jax.lax.psum(ovf, ax)
       rows = jax.lax.cond(total_ovf == 0, hier_path, flat_path, None)
       return rows, ovf
 
@@ -637,28 +687,32 @@ class DistFeature:
       cache_starts, cache_feats = cache_feats
       with jax.named_scope(SCOPE_CACHE):
         if h > 0:
-          in_cache, cpos = sorted_index.indexed_membership(
-              cache_ids, cache_starts, safe, cshift, cdepth)
-          is_hit = mask & in_cache
-          out_hit = jnp.where(is_hit[:, None], cache_feats[cpos], 0)
+          with jax.named_scope(SCOPE_LOOKUP):
+            in_cache, cpos = sorted_index.indexed_membership(
+                cache_ids, cache_starts, safe, cshift, cdepth)
+            is_hit = mask & in_cache
+          with jax.named_scope(SCOPE_ROWS):
+            out_hit = jnp.where(is_hit[:, None], cache_feats[cpos], 0)
           miss = mask & ~is_hit
         else:
           is_hit = jnp.zeros_like(mask)
           out_hit = jnp.zeros((b, fdim), fdtype)
           miss = mask
       with jax.named_scope(SCOPE_EXCHANGE):
-        if dedup:
-          # one request per unique missed id; `inverse` fans the
-          # response row back to every batch slot that asked for it
-          req, ucnt, inverse = ops.masked_unique(ids, miss, size=b)
-          rmask = req != ops.FILL
-        else:
-          req, rmask = ids, miss
-          inverse = jnp.where(miss, jnp.arange(b, dtype=jnp.int32), -1)
-          ucnt = jnp.sum(miss)
+        with jax.named_scope(SCOPE_DEDUP):
+          if dedup:
+            # one request per unique missed id; `inverse` fans the
+            # response row back to every batch slot that asked for it
+            req, ucnt, inverse = ops.masked_unique(ids, miss, size=b)
+            rmask = req != ops.FILL
+          else:
+            req, rmask = ids, miss
+            inverse = jnp.where(miss, jnp.arange(b, dtype=jnp.int32), -1)
+            ucnt = jnp.sum(miss)
         exchange = exchange_hier if hier else exchange_flat
         rows, ovf = exchange(feat_ids, feats, pb, req, rmask)
-        out_miss = rows[jnp.maximum(inverse, 0)]
+        with jax.named_scope(SCOPE_FANOUT):
+          out_miss = rows[jnp.maximum(inverse, 0)]
       out = jnp.where(is_hit[:, None], out_hit.astype(fdtype),
                       jnp.where(miss[:, None], out_miss, 0))
       batch_stats = jnp.stack([

@@ -1526,4 +1526,6 @@ class DistNeighborSampler:
                                      pb, mesh=self.mesh,
                                      dtype=lab.dtype))
       self._labels_cache[key] = hit
+    # its counters and its gauge go under dist_label.*, built or adopted
+    hit[1].stats_prefix = 'dist_label'
     return hit[1]

@@ -462,7 +462,8 @@ class ScanTrainer(FusedEpochTrainer):
           state, steps, full_steps, start_step=start_step,
           resume_overflow=resume_overflow)
       completed = True
-      self._publish_link_counts()
+      with spans.span('epoch.publish'):
+        self._publish_link_counts()
       if guarded:
         # natural epoch end applies overflow_policy; a max_steps
         # break leaves the
@@ -499,27 +500,31 @@ class ScanTrainer(FusedEpochTrainer):
     import jax
     link = isinstance(self.loader, LinkLoader)
     self._link_counts = []   # a failed epoch's counts are not carried on
-    if self._seeds_dev is None and not link:
-      self._seeds_dev = jax.device_put(
-          np.asarray(self.loader.input_seeds, dtype=np.int32))
-    # _epochs advances only on SUCCESS (below, with _call_count): a
-    # failed epoch's re-run must redraw the SAME permutation, matching
-    # the un-advanced sampler key stream
-    perm_key = jax.random.fold_in(self._perm_key, self._epochs)
+    # the call's prologue, before any device program: on a profiler
+    # timeline the idle gap in front of epoch.seeds is this span's
+    with spans.span('epoch.stage'):
+      if self._seeds_dev is None and not link:
+        self._seeds_dev = jax.device_put(
+            np.asarray(self.loader.input_seeds, dtype=np.int32))
+      # _epochs advances only on SUCCESS (below, with _call_count): a
+      # failed epoch's re-run must redraw the SAME permutation, matching
+      # the un-advanced sampler key stream
+      perm_key = jax.random.fold_in(self._perm_key, self._epochs)
 
-    # graph arrays re-fetched each epoch: the padded-table reseed in
-    # _begin_epoch must reach the chunks (lazy rebuild in _fused_args)
-    fargs = self._sample_args()
-    base_key = self._sampler._key
-    # chunk-position scalars enter as EXPLICIT device_puts: inside the
-    # strict_guards region (GLT_STRICT=1: transfer_guard('disallow') +
-    # checking_leaks) every implicit host->device transfer — a stray
-    # numpy arg, an eager op minting a constant — raises, so the epoch
-    # region provably contains nothing but all-device program dispatches
-    count0 = jax.device_put(np.int32(self._sampler._call_count + 1))
-    # a resume seeds the carry with the interrupted prefix's flag — a
-    # pre-crash overflow must still fire the epoch-end policy
-    ovf = jax.device_put(np.asarray(bool(resume_overflow)))
+      # graph arrays re-fetched each epoch: the padded-table reseed in
+      # _begin_epoch must reach the chunks (lazy rebuild in _fused_args)
+      fargs = self._sample_args()
+      base_key = self._sampler._key
+      # chunk-position scalars enter as EXPLICIT device_puts: inside the
+      # strict_guards region (GLT_STRICT=1: transfer_guard('disallow') +
+      # checking_leaks) every implicit host->device transfer — a stray
+      # numpy arg, an eager op minting a constant — raises, so the epoch
+      # region provably contains nothing but all-device program
+      # dispatches
+      count0 = jax.device_put(np.int32(self._sampler._call_count + 1))
+      # a resume seeds the carry with the interrupted prefix's flag — a
+      # pre-crash overflow must still fire the epoch-end policy
+      ovf = jax.device_put(np.asarray(bool(resume_overflow)))
     losses, accs = [], []
     start = start_step
     with strict_guards():
@@ -994,8 +999,9 @@ class DistScanTrainer(DistFusedEpochTrainer):
       # (the postmortem trail for exactly that failure) must still
       # close, so they sit in an inner finally
       try:
-        self.loader._publish_feature_stats()
-        self._publish_exchange_rows()
+        with spans.span('epoch.publish'):
+          self.loader._publish_feature_stats()
+          self._publish_exchange_rows()
       finally:
         spans.end(epoch_span,
                   steps=(steps if completed else
@@ -1020,36 +1026,40 @@ class DistScanTrainer(DistFusedEpochTrainer):
 
     from jax.sharding import NamedSharding, PartitionSpec
     repl = NamedSharding(self.mesh, PartitionSpec())
-    if self._seeds_dev is None:
-      # committed to the mesh (replicated) at upload: the seed program
-      # runs on the mesh, and an uncommitted single-device array would
-      # be broadcast IMPLICITLY at its first dispatch — a hidden
-      # device-to-device transfer GLT_STRICT's transfer guard rejects
-      self._seeds_dev = jax.device_put(
-          np.asarray(self.loader.input_seeds, dtype=np.int32), repl)
-    # _epochs advances only on SUCCESS (below, with _call_count): a
-    # failed epoch's re-run must redraw the SAME permutation or the
-    # chunk-granularity failover story (docs/failure_model.md) can't
-    # reproduce the completed chunks' seed matrix
-    perm_key = jax.device_put(
-        jax.random.fold_in(self._perm_key, self._epochs), repl)
+    # the call's prologue, before any device program: on a profiler
+    # timeline the idle gap in front of epoch.seeds is this span's
+    with spans.span('epoch.stage'):
+      if self._seeds_dev is None:
+        # committed to the mesh (replicated) at upload: the seed program
+        # runs on the mesh, and an uncommitted single-device array would
+        # be broadcast IMPLICITLY at its first dispatch — a hidden
+        # device-to-device transfer GLT_STRICT's transfer guard rejects
+        self._seeds_dev = jax.device_put(
+            np.asarray(self.loader.input_seeds, dtype=np.int32), repl)
+      # _epochs advances only on SUCCESS (below, with _call_count): a
+      # failed epoch's re-run must redraw the SAME permutation or the
+      # chunk-granularity failover story (docs/failure_model.md) can't
+      # reproduce the completed chunks' seed matrix
+      perm_key = jax.device_put(
+          jax.random.fold_in(self._perm_key, self._epochs), repl)
 
-    base_key = jax.device_put(self._sampler._key, repl)
-    stats = ({t: self._feat[t]._stats_dev() for t in self._feat_types}
-             if self.is_hetero else self._feat._stats_dev())
-    # commit the replicated carry leaves explicitly: a fresh (host /
-    # single-device) state and the chunk program's replicated outputs
-    # must present the SAME sharding signature, or every epoch's first
-    # chunk retraces (sharding is part of the jit cache key). The
-    # chunk-position scalars are explicit device_puts too: inside the
-    # strict_guards region (GLT_STRICT=1: transfer_guard('disallow') +
-    # checking_leaks) any implicit host->device transfer raises, so the
-    # epoch region provably dispatches only all-device program args
-    count0 = jax.device_put(np.int32(self._sampler._call_count + 1),
-                            repl)
-    params, opt_state, stepc, ovf = jax.device_put(
-        (state.params, state.opt_state, state.step,
-         np.asarray(bool(resume_overflow))), repl)
+      base_key = jax.device_put(self._sampler._key, repl)
+      stats = ({t: self._feat[t]._stats_dev() for t in self._feat_types}
+               if self.is_hetero else self._feat._stats_dev())
+      # commit the replicated carry leaves explicitly: a fresh (host /
+      # single-device) state and the chunk program's replicated outputs
+      # must present the SAME sharding signature, or every epoch's first
+      # chunk retraces (sharding is part of the jit cache key). The
+      # chunk-position scalars are explicit device_puts too: inside the
+      # strict_guards region (GLT_STRICT=1: transfer_guard('disallow') +
+      # checking_leaks) any implicit host->device transfer raises, so
+      # the epoch region provably dispatches only all-device program
+      # args
+      count0 = jax.device_put(np.int32(self._sampler._call_count + 1),
+                              repl)
+      params, opt_state, stepc, ovf = jax.device_put(
+          (state.params, state.opt_state, state.step,
+           np.asarray(bool(resume_overflow))), repl)
 
     def stats_back(tree):
       # hand the carried accumulators back to the stores AFTER EVERY

@@ -178,6 +178,12 @@ REGISTERED_SPANS = frozenset({
     'epoch.seeds',
     'epoch.concat',
     'epoch.hook',
+    # the two ends of a scan trainer's call, around no device program:
+    # the carry staging before epoch.seeds (device_puts of keys,
+    # counters and state; a link job's sampler arguments) and the
+    # counters' fetch-and-publish after the last chunk
+    'epoch.stage',
+    'epoch.publish',
     # per-batch loaders (loader/node_loader.py, distributed/
     # dist_loader.py): one span per delivered batch
     'loader.batch',
@@ -250,6 +256,23 @@ SCOPE_ALLREDUCE = 'allreduce'    # inside glt.train: the pmean (DDP)
 SCOPE_CACHE = 'cache'            # inside glt.collate: the hot-cache hit path
 SCOPE_EXCHANGE = 'exchange'      # inside glt.collate and glt.sample/hop<h>:
                                  # the all_to_all round trip and its routing
+# the parts of glt.collate/exchange (the miss-only row exchange), opened
+# at their call sites in DistFeature._shard_body; a fusion takes the name
+# of its root, so a part's time is what XLA rooted inside it
+SCOPE_DEDUP = 'dedup'            # ops.masked_unique over the missed ids
+SCOPE_ROUTE = 'route'            # the partition-book read, ops.route_slots,
+                                 # the overflow count and its psum
+SCOPE_PACK = 'pack'              # ops.scatter_to_buckets
+SCOPE_WIRE = 'wire'              # the request and the response all_to_all
+                                 # (the overflow count's psum is route's)
+SCOPE_LOOKUP = 'lookup'          # indexed_membership over a sorted id table
+                                 # (inside glt.collate/cache too)
+SCOPE_ROWS = 'rows'              # the row gather behind a lookup, its mask
+                                 # and the wire cast (inside glt.collate/cache
+                                 # and a shard-local draw too)
+SCOPE_UNPACK = 'unpack'          # ops.gather_from_buckets and the cast back
+SCOPE_FANOUT = 'fanout'          # rows[inverse]: a response row to every
+                                 # slot that asked for it
 # what an edge-seeded (link) job adds to a step, apart from the hops
 SCOPE_SEEDS = 'seeds'            # inside glt.sample: the epoch order's
                                  # positions and the seed-pair gather
@@ -285,6 +308,7 @@ def collate_scope(ntype: str) -> str:
 REGISTERED_SCOPES = frozenset({
     'glt.sample',
     'glt.sample/hop<h>/draw',
+    'glt.sample/hop<h>/draw/rows',
     'glt.sample/hop<h>/induce',
     'glt.sample/hop<h>/exchange',
     'glt.sample/hop<h>/<etype>/draw',
@@ -296,7 +320,17 @@ REGISTERED_SCOPES = frozenset({
     'glt.collate',
     'glt.collate/<ntype>',
     'glt.collate/cache',
+    'glt.collate/cache/lookup',
+    'glt.collate/cache/rows',
     'glt.collate/exchange',
+    'glt.collate/exchange/dedup',
+    'glt.collate/exchange/route',
+    'glt.collate/exchange/pack',
+    'glt.collate/exchange/wire',
+    'glt.collate/exchange/lookup',
+    'glt.collate/exchange/rows',
+    'glt.collate/exchange/unpack',
+    'glt.collate/exchange/fanout',
     'glt.train',
     'glt.train/fwd_bwd',
     'glt.train/update',

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..metrics.registry_names import SCOPE_ROWS
 from .sorted_index import indexed_membership
 from .unique import FILL
 
@@ -463,9 +464,11 @@ def _local_rows(row_ids, starts, seeds, seed_mask, shift: int, depth: int):
   """Shard-local row lookup ``global id -> position in row_ids`` through
   the table's two-level index (ops/sorted_index.py: one read of the
   bucket starts, then ``depth`` halvings inside the bucket), and which
-  seeds this shard owns."""
-  found, pos = indexed_membership(row_ids, starts, seeds, shift, depth)
-  return found & seed_mask, pos
+  seeds this shard owns: the part of a draw's scope that is not the draw
+  proper (``glt.sample/hop<h>/draw/.../rows``)."""
+  with jax.named_scope(SCOPE_ROWS):
+    found, pos = indexed_membership(row_ids, starts, seeds, shift, depth)
+    return found & seed_mask, pos
 
 
 @functools.partial(jax.jit, static_argnames=('k', 'shift', 'depth'))

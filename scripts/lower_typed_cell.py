@@ -157,6 +157,16 @@ def report(name, compiled, collectives=False):
     out['mesh_scopes'] = sorted(set(re.findall(
         r'glt\.(?:sample/hop\d+/exchange|collate/(?:cache|exchange)|'
         r'train/allreduce)', text)))
+    # the parts PR 39 names inside the exchange, the cache split and the
+    # shard-local draw, as the compiled text carries them (fusions named
+    # by their roots), control-flow and jit(...) components dropped
+    from perfbench import mesh_parts_reduce, scope_reduce
+    parts = {mesh_parts_reduce.part_of(scope_reduce.scope_path(
+        {'args': {'tf_op': op_name}}))
+             for op_name in set(re.findall(r'op_name="([^"]*)"', text))}
+    out['mesh_parts'] = sorted(
+        '/'.join(p) for p in parts - {None}
+        if p[1] != mesh_parts_reduce.UNSPLIT)
   # `temp` adds up the temporaries of inner loops that are never alive
   # together; `peak` is what the program needs at once, arguments included
   out['argument_plus_temp_gb'] = (out['argument'] + out['temp']) / 1e9
