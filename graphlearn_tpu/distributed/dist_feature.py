@@ -24,7 +24,15 @@ system — feature rows are ~F x wider than sampler id traffic, PERF.md
      sampler-exchange contract: loss-free on EVERY input,
      dist_neighbor_sampler._exchange_hop). On a 2-axis ('slice', 'chip')
      mesh the transposes go hierarchical: full-width along 'chip' (ICI),
-     fractional along 'slice' (DCN), retraced for the response.
+     fractional along 'slice' (DCN), retraced for the response. The
+     OWNERS' side is bounded by what the buckets hold, not by their
+     capacity: ``ops.route_slots`` ranks a request within its
+     destination, so a received bucket is a valid prefix followed by
+     pads, and :func:`bounded_lookup` runs the lookup in ``feat_ids`` and
+     the row gather tile by tile below the received block's last valid
+     column (a loss-free bucket is mostly pads: 8.5 % full in the mesh
+     benchmark cell). No capacity, wire byte or overflow rule changes;
+     the rows that leave a shard are the one-piece lookup's bit for bit.
   3. **Wire dtype**: ``wire_dtype=jnp.bfloat16`` ships response rows at
      half width and upcasts to the storage dtype after
      ``gather_from_buckets`` — independent of hit rate.
@@ -51,7 +59,8 @@ from ..metrics.registry_names import (SCOPE_CACHE, SCOPE_COLLATE,
                                       SCOPE_DEDUP, SCOPE_EXCHANGE,
                                       SCOPE_FANOUT, SCOPE_LOOKUP,
                                       SCOPE_PACK, SCOPE_ROUTE, SCOPE_ROWS,
-                                      SCOPE_UNPACK, SCOPE_WIRE)
+                                      SCOPE_TILE, SCOPE_UNPACK, SCOPE_WIRE)
+from ..ops.neighbor import draw_tile_rows, tiles_to_run
 from ..ops.route import exchange_capacity
 
 INT32_MAX = np.iinfo(np.int32).max
@@ -83,6 +92,63 @@ def feature_exchange_mb(request_width: int, nparts: int, feat_dim: int,
   volumes so byte regressions are visible without a trace."""
   cap = miss_capacity(request_width, nparts, bucket_frac, hit_rate)
   return nparts * cap * (id_bytes + feat_dim * wire_bytes) / 1e6
+
+
+def _part(name: str, tiled: bool = False):
+  """The scope of one part of the row exchange. In a tile of
+  :func:`bounded_lookup`'s loop the ``tile`` component stands BEHIND the
+  part's name (``lookup/tile``, ``rows/tile``): a reader files the work
+  under its part and counts the loop's executions by the component."""
+  import jax
+  return jax.named_scope(f'{name}/{SCOPE_TILE}' if tiled else name)
+
+
+def bounded_lookup(lookup, r, fdim: int, wdtype):
+  """The owners' side of the row exchange over a received request block
+  ``r [P, cap]`` (ids, any negative a pad): ``rows [P, cap, F]`` at the
+  wire dtype, zeros where ``lookup`` finds nothing, bounded by what the
+  buckets hold. ``lookup(flat [n], tiled) -> rows [n, F]`` is a
+  ``lookup_local`` bound to its tables.
+
+  A block wide enough to tile (``ops.neighbor.draw_tile_rows`` of its
+  ``cap``, the draw's one rule) is looked up in tiles of ``T`` columns,
+  ``ceil(last valid column / T)`` of them: only the tiles that begin
+  below its last valid column. ``ops.route_slots`` ranks a request
+  within its destination, so every received bucket is a valid prefix
+  followed by pads, and a bucket sized to be loss-free is mostly pads.
+  Any mask is exact, prefix or not: columns of tiles not run keep the
+  zeros a pad reads. The last tile is clamped to end at the cap, so it
+  may redo columns of the one before — to the same values. The trip
+  count is this shard's own: no collective may sit inside the loop. A
+  block narrower than the rule's threshold is looked up in one piece."""
+  import jax
+  import jax.numpy as jnp
+  nb, cap = r.shape
+  t = draw_tile_rows(cap)
+  if not t:
+    rows = lookup(r.reshape(-1), False)
+    with _part(SCOPE_ROWS):
+      return rows.astype(wdtype).reshape(nb, cap, fdim)
+  assert 0 < t <= cap, (t, cap)
+  with _part(SCOPE_LOOKUP):
+    tiles = tiles_to_run(r >= 0, t)
+
+  def body(i, out):
+    lo = jnp.minimum(i * t, cap - t)
+    with _part(SCOPE_LOOKUP, True):
+      flat = jax.lax.dynamic_slice(r, (0, lo), (nb, t)).reshape(-1)
+    rows = lookup(flat, True)
+    with _part(SCOPE_ROWS, True):
+      return jax.lax.dynamic_update_slice(
+          out, rows.astype(wdtype).reshape(nb, t, fdim), (0, lo, 0))
+
+  with _part(SCOPE_ROWS):
+    # inside shard_map the carry varies over the axes the block does
+    # (the v5e's compiler rebuilds this constant fill without its name:
+    # a timeline files it as unscoped, PERF.md section 5)
+    init = jax.lax.pcast(jnp.zeros((nb, cap, fdim), wdtype),
+                         tuple(jax.typeof(r).vma), to='varying')
+  return jax.lax.fori_loop(0, tiles, body, init)
 
 
 def _hot_ids_fn(h: int):
@@ -481,6 +547,53 @@ class DistFeature:
     return s
 
   # ---------------------------------------------------------- program
+  def _lookup_fn(self, slab: bool = False):
+    """``lookup_local(feat_ids [n], (feat_starts, feats), flat [m],
+    tiled=False) -> rows [m, F]`` of :meth:`_shard_body`: the rows of a
+    flat request vector over this shard's sorted owned ids, zeros where
+    absent or padded. ``tiled`` says the call is one tile of
+    :func:`bounded_lookup`'s loop (:func:`_part` names it so)."""
+    import jax.numpy as jnp
+    _, rshift, rdepth = self._row_index
+
+    if slab:
+      def lookup_local(feat_ids, feats, flat, tiled=False):
+        """Slab-backed rows for a flat request vector: position from
+        the sorted owned-id table as usual, payload from the hot
+        prefix or the staged slab (zeros where absent/padded — an
+        impossible case for planned rows under an exact program)."""
+        starts, (hot, slab_pos, slab_rows) = feats
+        with _part(SCOPE_LOOKUP, tiled):
+          found, pos = sorted_index.indexed_membership(
+              feat_ids, starts, flat, rshift, rdepth)
+        hp = hot.shape[0]
+        with _part(SCOPE_ROWS, tiled):
+          hot_rows = hot[jnp.clip(pos, 0, hp - 1)]
+        with _part(SCOPE_LOOKUP, tiled):
+          sp = jnp.clip(jnp.searchsorted(slab_pos, pos.astype(jnp.int32)),
+                        0, slab_pos.shape[0] - 1)
+          in_slab = slab_pos[sp] == pos.astype(jnp.int32)
+        with _part(SCOPE_ROWS, tiled):
+          rows = jnp.where((pos < hp)[:, None], hot_rows,
+                           jnp.where(in_slab[:, None], slab_rows[sp], 0))
+          return jnp.where(found[:, None], rows, 0)
+    else:
+      def lookup_local(feat_ids, feats, flat, tiled=False):
+        """Rows for a flat request vector over this shard's sorted owned
+        ids (zeros where absent/padded). ``feats`` may keep shard_map's
+        leading ``[1, n, F]`` axis: on a TPU dropping it (``feats[0]``)
+        is a physical copy of the whole table in front of every program
+        that gathers from it — 4.7 GB at 9 M rows — where indexing
+        through it is free."""
+        starts, feats = feats
+        with _part(SCOPE_LOOKUP, tiled):
+          found, pos = sorted_index.indexed_membership(
+              feat_ids, starts, flat, rshift, rdepth)
+        with _part(SCOPE_ROWS, tiled):
+          rows = feats[0, pos] if feats.ndim == 3 else feats[pos]
+          return jnp.where(found[:, None], rows, 0)
+    return lookup_local
+
   def _shard_body(self, b: int, slab: bool = False):
     """Per-shard lookup body over UNWRAPPED per-shard views — the core
     of the one-dispatch program, exposed so outer shard_map programs
@@ -519,7 +632,6 @@ class DistFeature:
     dedup = self.dedup
     bucket_frac = self.bucket_frac
     hit_est = self._cache_frac
-    _, rshift, rdepth = self._row_index
     _, cshift, cdepth = self._cache_index
     # collectives/specs over every mesh axis: works identically on the
     # flat ('g',) mesh and a 2-axis ('slice', 'chip') mesh
@@ -527,60 +639,37 @@ class DistFeature:
     sizes = tuple(self.mesh.shape[a] for a in ax)
     hier = len(ax) == 2
     # the miss buckets' capacity on the no-overflow path, and the request
-    # slots a shard's owners look up through it a step: every bucket of
-    # the flat exchange, the 'slice' stage's of the hierarchical one
-    # (sized on the mean VALID miss load, ~miss width over S, not the
-    # C*b slot count). Published once here, never per batch; the fill
-    # ratio is unique_misses over it (docs/observability.md)
+    # slots a shard packs, sends and returns through it a step: every
+    # bucket of the flat exchange, the 'slice' stage's of the hierarchical
+    # one (sized on the mean VALID miss load, ~miss width over S, not the
+    # C*b slot count). Its owners look the block up a tile at a time
+    # (bounded_lookup): the slots a tile holds, 0 where the block is too
+    # narrow to tile. Both published once here, never per batch; the fill
+    # ratio is unique_misses over the slots (docs/observability.md)
     if hier:
       s_sz, c_sz = sizes
       cap2 = (c_sz * b if bucket_frac is None or s_sz <= 1 else
               min(c_sz * b,
                   miss_capacity(b, s_sz, bucket_frac, hit_est)))
-      slots = s_sz * cap2
+      buckets, cap = s_sz, cap2
     else:
       cap_small = miss_capacity(b, nparts, bucket_frac, hit_est)
-      slots = nparts * cap_small
+      buckets, cap = nparts, cap_small
     from .. import metrics
     # graftlint: allow[metric-registry] the store's family (dist_feature.* / dist_label.*, both registered wildcards)
-    metrics.set_gauge(f'{self.stats_prefix}.exchange_slots', slots)
+    metrics.set_gauge(f'{self.stats_prefix}.exchange_slots', buckets * cap)
+    # graftlint: allow[metric-registry] the store's family, as above
+    metrics.set_gauge(f'{self.stats_prefix}.lookup_tile_slots',
+                      buckets * draw_tile_rows(cap))
 
-    if slab:
-      def lookup_local(feat_ids, feats, flat):
-        """Slab-backed rows for a flat request vector: position from
-        the sorted owned-id table as usual, payload from the hot
-        prefix or the staged slab (zeros where absent/padded — an
-        impossible case for planned rows under an exact program)."""
-        starts, (hot, slab_pos, slab_rows) = feats
-        with jax.named_scope(SCOPE_LOOKUP):
-          found, pos = sorted_index.indexed_membership(
-              feat_ids, starts, flat, rshift, rdepth)
-        hp = hot.shape[0]
-        with jax.named_scope(SCOPE_ROWS):
-          hot_rows = hot[jnp.clip(pos, 0, hp - 1)]
-        with jax.named_scope(SCOPE_LOOKUP):
-          sp = jnp.clip(jnp.searchsorted(slab_pos, pos.astype(jnp.int32)),
-                        0, slab_pos.shape[0] - 1)
-          in_slab = slab_pos[sp] == pos.astype(jnp.int32)
-        with jax.named_scope(SCOPE_ROWS):
-          rows = jnp.where((pos < hp)[:, None], hot_rows,
-                           jnp.where(in_slab[:, None], slab_rows[sp], 0))
-          return jnp.where(found[:, None], rows, 0)
-    else:
-      def lookup_local(feat_ids, feats, flat):
-        """Rows for a flat request vector over this shard's sorted owned
-        ids (zeros where absent/padded). ``feats`` may keep shard_map's
-        leading ``[1, n, F]`` axis: on a TPU dropping it (``feats[0]``)
-        is a physical copy of the whole table in front of every program
-        that gathers from it — 4.7 GB at 9 M rows — where indexing
-        through it is free."""
-        starts, feats = feats
-        with jax.named_scope(SCOPE_LOOKUP):
-          found, pos = sorted_index.indexed_membership(
-              feat_ids, starts, flat, rshift, rdepth)
-        with jax.named_scope(SCOPE_ROWS):
-          rows = feats[0, pos] if feats.ndim == 3 else feats[pos]
-          return jnp.where(found[:, None], rows, 0)
+    lookup_local = self._lookup_fn(slab)
+
+    def owner_rows(feat_ids, feats, r):
+      """The rows ``[P, cap, F]`` (wire dtype) this shard owns of a
+      received request block ``r [P, cap]``."""
+      return bounded_lookup(
+          lambda flat, tiled: lookup_local(feat_ids, feats, flat, tiled),
+          r, fdim, wdtype)
 
     def exchange_flat(feat_ids, feats, pb, req, rmask):
       """Fractional bucketed all_to_all with replicated full-width
@@ -596,9 +685,7 @@ class DistFeature:
           send = ops.scatter_to_buckets(req, dest, slot, okc, nparts, cap)
         with jax.named_scope(SCOPE_WIRE):
           r = jax.lax.all_to_all(send, ax, 0, 0)        # [P, cap] reqs
-        rows = lookup_local(feat_ids, feats, r.reshape(-1))
-        with jax.named_scope(SCOPE_ROWS):
-          rows = rows.astype(wdtype).reshape(nparts, cap, fdim)
+        rows = owner_rows(feat_ids, feats, r)
         with jax.named_scope(SCOPE_WIRE):
           resp = jax.lax.all_to_all(rows, ax, 0, 0)     # [P, cap, F]
         with jax.named_scope(SCOPE_UNPACK):
@@ -643,9 +730,7 @@ class DistFeature:
                                          cap2)
         with jax.named_scope(SCOPE_WIRE):
           req2 = jax.lax.all_to_all(send2, s_ax, 0, 0)  # [S, cap2] DCN
-        rows = lookup_local(feat_ids, feats, req2.reshape(-1))
-        with jax.named_scope(SCOPE_ROWS):
-          rows = rows.astype(wdtype).reshape(s_sz, cap2, fdim)
+        rows = owner_rows(feat_ids, feats, req2)
         with jax.named_scope(SCOPE_WIRE):
           r2 = jax.lax.all_to_all(rows, s_ax, 0, 0)
         with jax.named_scope(SCOPE_UNPACK):
@@ -663,9 +748,7 @@ class DistFeature:
           send = ops.scatter_to_buckets(req, dest, slotp, okp, nparts, b)
         with jax.named_scope(SCOPE_WIRE):
           r = jax.lax.all_to_all(send, ax, 0, 0)
-        rows = lookup_local(feat_ids, feats, r.reshape(-1))
-        with jax.named_scope(SCOPE_ROWS):
-          rows = rows.astype(wdtype).reshape(nparts, b, fdim)
+        rows = owner_rows(feat_ids, feats, r)
         with jax.named_scope(SCOPE_WIRE):
           resp = jax.lax.all_to_all(rows, ax, 0, 0)
         with jax.named_scope(SCOPE_UNPACK):

@@ -270,6 +270,13 @@ SCOPE_LOOKUP = 'lookup'          # indexed_membership over a sorted id table
 SCOPE_ROWS = 'rows'              # the row gather behind a lookup, its mask
                                  # and the wire cast (inside glt.collate/cache
                                  # and a shard-local draw too)
+SCOPE_TILE = 'tile'              # one tile of a loop that runs only below
+                                 # the last valid row: the draw's
+                                 # (ops.uniform_sample_tiled) and, behind
+                                 # lookup / rows, the owners' bounded lookup
+                                 # of a received block (dist_feature.
+                                 # bounded_lookup); a reader counts a
+                                 # loop's executions by the component
 SCOPE_UNPACK = 'unpack'          # ops.gather_from_buckets and the cast back
 SCOPE_FANOUT = 'fanout'          # rows[inverse]: a response row to every
                                  # slot that asked for it
@@ -328,7 +335,9 @@ REGISTERED_SCOPES = frozenset({
     'glt.collate/exchange/pack',
     'glt.collate/exchange/wire',
     'glt.collate/exchange/lookup',
+    'glt.collate/exchange/lookup/tile',
     'glt.collate/exchange/rows',
+    'glt.collate/exchange/rows/tile',
     'glt.collate/exchange/unpack',
     'glt.collate/exchange/fanout',
     'glt.train',

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..metrics.registry_names import SCOPE_ROWS
+from ..metrics.registry_names import SCOPE_ROWS, SCOPE_TILE
 from .sorted_index import indexed_membership
 from .unique import FILL
 
@@ -49,6 +49,18 @@ def draw_tile_rows(b: int) -> int:
     return 0
   per_tile = -(-b // DRAW_TILES)
   return -(-per_tile // _TILE_ALIGN) * _TILE_ALIGN
+
+
+def tiles_to_run(mask, tile: int):
+  """How many tiles of ``tile`` positions along the last axis of ``mask``
+  begin below its last valid one: ``ceil(last valid position / tile)``
+  as an int32 scalar, 0 for an empty mask. The trip count of every loop
+  tiled by :func:`draw_tile_rows` (the draw's; the mesh feature store's
+  bounded lookup of a received ``[P, cap]`` block)."""
+  n = mask.shape[-1]
+  last_valid = jnp.max(jnp.where(
+      mask, jnp.arange(1, n + 1, dtype=jnp.int32), 0))
+  return (last_valid + (tile - 1)) // tile
 
 
 def draw_offsets(start, deg, seed_mask, u, k: int):
@@ -95,15 +107,13 @@ def uniform_sample_tiled(indptr, indices, seeds, seed_mask, k: int, key,
   b = seeds.shape[0]
   tile_rows = draw_tile_rows(b) or b
   u = jax.random.uniform(key, (b, k))    # ONE stream over the whole cap
-  last_valid = jnp.max(jnp.where(
-      seed_mask, jnp.arange(1, b + 1, dtype=jnp.int32), 0))
-  tiles = (last_valid + (tile_rows - 1)) // tile_rows
+  tiles = tiles_to_run(seed_mask, tile_rows)
 
   def draw(seeds, seed_mask, u):
     return _draw_rows(indptr, indices, meta, seeds, seed_mask, u, k)
 
   def body(i, out):
-    with jax.named_scope('tile'):
+    with jax.named_scope(SCOPE_TILE):
       lo = jnp.minimum(i * tile_rows, b - tile_rows)
       part = draw(jax.lax.dynamic_slice(seeds, (lo,), (tile_rows,)),
                   jax.lax.dynamic_slice(seed_mask, (lo,), (tile_rows,)),

@@ -167,6 +167,13 @@ def report(name, compiled, collectives=False):
     out['mesh_parts'] = sorted(
         '/'.join(p) for p in parts - {None}
         if p[1] != mesh_parts_reduce.UNSPLIT)
+    # the owners' bounded lookup (PR 40): the tile loops the compiled text
+    # carries under glt.collate/exchange, by the cond branch that holds
+    # each, as the engagement counter's reader tells them apart
+    from perfbench.layer_metrics import row_exchange_tiles_per_step as rt
+    loops = {rt.tile_loop(scope_reduce.scope_path({'args': {'tf_op': n}}))
+             for n in set(re.findall(r'op_name="([^"]*)"', text))}
+    out['mesh_tile_loops'] = sorted('/'.join(l) for l in loops - {None})
   # `temp` adds up the temporaries of inner loops that are never alive
   # together; `peak` is what the program needs at once, arguments included
   out['argument_plus_temp_gb'] = (out['argument'] + out['temp']) / 1e9

@@ -154,6 +154,13 @@ def test_the_new_names_are_registered_and_documented():
       'glt.collate/cache/lookup', 'glt.collate/cache/rows',
       'glt.sample/hop<h>/draw/rows'}
   assert len(scopes) == 11 and scopes <= names.REGISTERED_SCOPES
+  # the owners' bounded lookup (ISSUE 40): one tile of a received block,
+  # the component BEHIND its part's name so a reader files it there
+  assert names.SCOPE_TILE == 'tile'
+  tiles = {f'glt.collate/exchange/{p}/{names.SCOPE_TILE}'
+           for p in (names.SCOPE_LOOKUP, names.SCOPE_ROWS)}
+  assert tiles <= names.REGISTERED_SCOPES
+  scopes |= tiles
   assert {'epoch.stage', 'epoch.publish'} <= names.REGISTERED_SPANS
   assert 'dist_feature.*' in names.REGISTERED_METRICS
   root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -162,7 +169,10 @@ def test_the_new_names_are_registered_and_documented():
   for name in sorted(scopes) + ['epoch.stage', 'epoch.publish',
                                 'glt.epoch.stage', 'glt.epoch.publish',
                                 'dist_feature.exchange_slots',
-                                'dist_label.exchange_slots']:
+                                'dist_label.exchange_slots',
+                                'dist_feature.lookup_tile_slots',
+                                'dist_label.lookup_tile_slots',
+                                'row_exchange_tiles_per_step']:
     assert f'`{name}`' in doc, name
 
 
