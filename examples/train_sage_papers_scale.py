@@ -11,10 +11,17 @@ prefix catches most lookups), and reports the measured hit rate alongside
 convergence.
 
 NOTE: every mixed (hot+cold) lookup reads ids on host — a device->host
-sync per batch — so the step walls printed here include that sync; they
-have not been measured on the chip (PERF.md). The design point being
-demonstrated is capability + hit-rate-proportional transfer, verified by
-tests/test_feature.py::test_unified_tensor_ships_only_cold_rows.
+sync per batch — so the step walls printed here include that sync; THIS
+per-batch path has not been measured on the chip. What the chip has
+measured is the same job through the scanned epoch, where the misses are
+planned and staged chunk by chunk with no sync in the loop
+(``storage.TieredScanTrainer``; benchmark cell
+``sage-papers-tiered.tiered-scan-exact``: a third of the papers100M shape,
+an 18.95 GB table with 15 % of its rows hot on one v5e chip, 7,487.5 seeds/s
+at a hit share of 85.25 % — the builder's chip runs of PR 41, PERF.md
+sections 4-5). The design point being
+demonstrated here is capability + hit-rate-proportional transfer, verified
+by tests/test_feature.py::test_unified_tensor_ships_only_cold_rows.
 
 Run: python examples/train_sage_papers_scale.py --steps 8
 """

@@ -80,13 +80,36 @@ class Topology:
     self.edge_weights = weights
     self._num_nodes = num_nodes
 
+  @classmethod
+  def from_csr(cls, indptr: np.ndarray, indices: np.ndarray,
+               num_nodes: Optional[int] = None, layout: Layout = 'CSR'):
+    """Adopt arrays that are ALREADY grouped (``indptr`` [N + 1],
+    ``indices`` [>= E], rows in ``layout`` order) as they are: no sort,
+    no copy where the dtypes fit, and NO edge ids or weights — the
+    constructor's COO round trip sorts E keys and mints an int64 id per
+    edge, which at half a billion edges is minutes and 8 GB for arrays
+    a node job never reads. ``indices`` may be longer than
+    ``indptr[-1]`` (a generator's padded tail): only the rows' ranges
+    are ever read."""
+    if layout not in ('CSR', 'CSC'):
+      raise ValueError(f'storage layout must be CSR or CSC, got {layout!r}')
+    self = cls.__new__(cls)
+    self.layout = layout
+    self.indptr = np.asarray(indptr).reshape(-1).astype(np.int64, copy=False)
+    self.indices = np.asarray(indices).reshape(-1).astype(np.int32,
+                                                          copy=False)
+    self.edge_ids = self.edge_weights = None
+    self._num_nodes = (int(self.indptr.shape[0]) - 1 if num_nodes is None
+                       else int(num_nodes))
+    return self
+
   @property
   def num_nodes(self) -> int:
     return self._num_nodes
 
   @property
   def num_edges(self) -> int:
-    return int(self.indices.shape[0])
+    return int(self.indptr[-1])
 
   @property
   def degrees(self) -> np.ndarray:

@@ -95,6 +95,16 @@ REGISTERED_METRICS = frozenset({
     'storage.hot_rows',
     'storage.warm_rows',
     'storage.disk_rows',
+    # what a tiered scanned call looked up and planned
+    # (storage/scan.py TieredScanTrainer, published once a call from
+    # the plan program's two sums and the plan the host holds): valid
+    # node slots and those the HBM hot prefix answered; rows staged
+    # (sorted unique misses a chunk) and the pow2 slab rows uploaded
+    # for them
+    'storage.lookups',
+    'storage.hot_hits',
+    'storage.planned_rows',
+    'storage.slab_cap_rows',
     # chunk-staged remote scan (distributed/remote_scan.py +
     # block_producer.py, docs/remote_scan.md): K-batch block exchange
     # between sampling servers and the scanned client
@@ -184,6 +194,14 @@ REGISTERED_SPANS = frozenset({
     # counters' fetch-and-publish after the last chunk
     'epoch.stage',
     'epoch.publish',
+    # the tiered scan trainer's host phases (storage/scan.py): the plan
+    # program's dispatch and the plan's hand-over to the stager, the
+    # wait for a chunk's slab (ChunkStager.take: the worker's fetch,
+    # dedup and gather where they are not done yet), and the slab's
+    # device_put
+    'epoch.plan',
+    'epoch.stage_wait',
+    'epoch.upload',
     # per-batch loaders (loader/node_loader.py, distributed/
     # dist_loader.py): one span per delivered batch
     'loader.batch',
@@ -289,6 +307,17 @@ SCOPE_UNION = 'union'            # inside glt.sample: the seed dedup over the
                                  # pairs' endpoints and its seed_inverse
 SCOPE_PAIRS = 'pairs'            # inside glt.train(/fwd_bwd): endpoint
                                  # gather, scores, BCE and their backward
+# the tiered scanned epoch (storage/scan.py)
+SCOPE_PLAN = 'glt.plan'          # the call's prologue: the id-only replay
+                                 # of the sampler over the steps the call
+                                 # runs — a layer of its own, so that it is
+                                 # not read as glt.sample of a trained step
+SCOPE_TIER = 'tier'              # inside glt.collate: tiered_gather, with
+SCOPE_HOT = 'hot'                # .../tier/hot: the HBM hot-prefix gather
+                                 # .../tier/lookup: the id2index remap and
+                                 # the slab membership search
+                                 # .../tier/rows: the slab row gather and
+                                 # the three-way select
 
 
 def hop_scope(hop: int, part: str, etype=None) -> str:
@@ -340,6 +369,11 @@ REGISTERED_SCOPES = frozenset({
     'glt.collate/exchange/rows/tile',
     'glt.collate/exchange/unpack',
     'glt.collate/exchange/fanout',
+    'glt.collate/tier',
+    'glt.collate/tier/hot',
+    'glt.collate/tier/lookup',
+    'glt.collate/tier/rows',
+    'glt.plan',
     'glt.train',
     'glt.train/fwd_bwd',
     'glt.train/update',

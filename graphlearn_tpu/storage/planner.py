@@ -26,6 +26,7 @@ The plan's unit is the STORAGE ROW (post-``id2index`` hotness remap),
 clamped exactly like the collate gather (pad slots -> node id 0), so
 "planned" and "gathered" can never disagree on padding.
 """
+import functools
 from dataclasses import dataclass, field
 from typing import List, Optional
 
@@ -40,21 +41,41 @@ class EpochPlan:
   chunk_size: int
   hot_rows: int
   warm_rows: int
-  # per chunk: sorted unique absolute storage rows >= hot_rows
-  chunk_rows: List[np.ndarray] = field(default_factory=list)
+  # per chunk: sorted unique absolute storage rows >= hot_rows — or,
+  # until someone asks (``rows``), a zero-argument callable that makes
+  # them (``TieredScanTrainer``'s plan: the chunk's rows are a device
+  # block the staging worker fetches and deduplicates). Indexed by the
+  # epoch's ABSOLUTE chunk number: a call that starts mid-epoch leaves
+  # the chunks before its first one empty.
+  chunk_rows: List = field(default_factory=list)
 
   @property
   def num_chunks(self) -> int:
     return len(self.chunk_rows)
 
+  def rows(self, c: int) -> np.ndarray:
+    """Chunk ``c``'s miss set, made on first use and kept."""
+    r = self.chunk_rows[c]
+    if callable(r):
+      r = self.chunk_rows[c] = r()
+    return r
+
+  def thunks(self) -> List:
+    """What a ``ChunkStager`` takes as its plan: per chunk a callable
+    that resolves the chunk's rows into this plan."""
+    return [functools.partial(self.rows, c)
+            for c in range(len(self.chunk_rows))]
+
   def slab_caps(self) -> List[int]:
     """The pow2 staging-shape set this plan compiles against."""
-    return [pow2_slab_cap(int(r.shape[0])) for r in self.chunk_rows]
+    return [pow2_slab_cap(int(self.rows(c).shape[0]))
+            for c in range(self.num_chunks)]
 
   def stats(self) -> dict:
-    rows = [int(r.shape[0]) for r in self.chunk_rows]
+    every = [self.rows(c) for c in range(self.num_chunks)]
+    rows = [int(r.shape[0]) for r in every]
     warm_edge = self.hot_rows + self.warm_rows
-    disk = [int(np.sum(r >= warm_edge)) for r in self.chunk_rows]
+    disk = [int(np.sum(r >= warm_edge)) for r in every]
     return dict(chunks=self.num_chunks, planned_rows=int(sum(rows)),
                 planned_disk_rows=int(sum(disk)),
                 max_chunk_rows=int(max(rows)) if rows else 0,
@@ -69,6 +90,16 @@ def rows_for_nodes(nodes: np.ndarray,
   return id2index[safe] if id2index is not None else safe
 
 
+def chunk_misses(block: np.ndarray, hot_rows: int) -> np.ndarray:
+  """Sorted unique storage rows ``>= hot_rows`` among one chunk's
+  ``[k, cap]`` block of rows (int64). The hot rows — most of a block
+  under a hotness order — are dropped BEFORE the sort: at 16 x 432 k ids
+  of which a seventh miss, 33 ms against 80 for ``np.unique`` of the
+  whole block."""
+  block = np.asarray(block).reshape(-1)
+  return np.unique(block[block >= hot_rows]).astype(np.int64)
+
+
 def plan_from_rows(rows_mat: np.ndarray, chunk_size: int, hot_rows: int,
                    warm_rows: int = 0) -> EpochPlan:
   """Per-chunk miss sets from a [steps, cap] storage-row matrix (the
@@ -80,9 +111,8 @@ def plan_from_rows(rows_mat: np.ndarray, chunk_size: int, hot_rows: int,
   plan = EpochPlan(chunk_size=int(chunk_size), hot_rows=int(hot_rows),
                    warm_rows=int(warm_rows))
   for start in range(0, steps, chunk_size):
-    block = rows_mat[start:start + chunk_size].reshape(-1)
-    uniq = np.unique(block)
-    plan.chunk_rows.append(uniq[uniq >= hot_rows].astype(np.int64))
+    plan.chunk_rows.append(
+        chunk_misses(rows_mat[start:start + chunk_size], hot_rows))
   return plan
 
 

@@ -4,22 +4,29 @@
 this trainer runs the SAME epoch-as-a-program over a
 ``storage.TieredFeature`` whose table spans HBM -> host RAM -> disk:
 
-* **Prologue plan, one dispatch.** The epoch-seeds program is extended
-  with an id-only replay of the sampler over every step (same
-  ``fold_in(base_key, count)`` keys the chunk programs will derive, so
-  the draws are bit-identical by the PR 1/4 replay contracts) and emits
-  the [steps, node_cap] STORAGE-ROW matrix alongside the seed matrix —
-  still ONE ``epoch_seeds`` dispatch, so the epoch budget stays
-  ``ceil(steps/K) + 2``. The row matrix is fetched once (the prologue's
-  one explicit ``jax.device_get``) and ``planner.plan_from_rows`` turns
-  it into per-chunk sorted miss sets.
+* **Prologue plan, one dispatch, for the steps the call runs.** The
+  epoch-seeds program is extended with an id-only replay of the sampler
+  (``glt.plan``) over steps ``[start_step, steps)`` of THIS call — not
+  over the epoch's ``full_steps``: a ``run_epoch(max_steps=m)`` call
+  replays, fetches and deduplicates O(m) steps — under the same
+  ``fold_in(base_key, count)`` keys the chunk programs will derive (so
+  the draws are bit-identical by the PR 1/4 replay contracts). It emits
+  one ``[k, node_cap]`` STORAGE-ROW block per chunk beside the seed
+  matrix, and the call's two sums (valid node slots, those the hot
+  prefix answers) — still ONE ``epoch_seeds`` dispatch, so the epoch
+  budget stays ``ceil(steps/K) + 2``. The dispatch thread fetches
+  nothing: each block is fetched (explicit ``jax.device_get``) and
+  turned into the chunk's sorted miss set (``planner.chunk_misses``: the
+  hot rows dropped, then a sort of the misses) by the STAGING WORKER, beside the
+  gather it feeds — 4 B x k x node_cap a chunk.
 * **Chunk-boundary staging.** While chunk ``c`` trains on device, the
   bounded staging worker (storage/staging.py) gathers chunk ``c+1``'s
   warm/disk rows into a pow2-padded host slab; at the boundary the
   dispatch thread device_puts the slab (explicit — the strict_guards
   region stays transfer-clean) and dispatches the chunk. Slabs are
   acked (freed) as soon as their chunk is dispatched.
-* **In-program tiered gather.** The chunk program's feature gather is
+* **In-program tiered gather.** The chunk program's feature gather
+  (``glt.collate/tier/{hot,lookup,rows}``) is
   hot-prefix ``take`` + slab ``searchsorted`` — every non-hot row a
   chunk touches is in its slab by construction (the plan is exact), so
   losses are BIT-IDENTICAL to the all-HBM ScanTrainer. Staging shapes
@@ -29,10 +36,13 @@ this trainer runs the SAME epoch-as-a-program over a
   (``storage.prefetch_miss``); the chaos suite completes the epoch
   bit-identically with a ``storage.stage`` fault armed.
 
-Sampling runs twice per epoch (once id-only in the plan, once in the
-chunks) — the price of an exact plan with zero extra dispatches. What
-it costs against the all-HBM epoch is not measured on the chip.
+Sampling runs twice per call (once id-only in the plan, once in the
+chunks) — the price of an exact plan with zero extra dispatches. On the
+chip (``sage-papers-tiered.tiered-scan-exact``, PERF.md section 5: the
+builder's runs of PR 41) the plan, the wait for the first slab and the
+uploads are read as ``tier_plan_ms`` and ``tier_host_gap_ms``.
 """
+import functools
 from typing import Optional
 
 import numpy as np
@@ -41,11 +51,21 @@ from ..loader.node_loader import NodeLoader
 from ..loader.pipeline import refuse_link
 from ..loader.scan_epoch import ScanTrainer
 from ..metrics import spans
+from ..metrics.registry_names import (SCOPE_COLLATE, SCOPE_HOT, SCOPE_LOOKUP,
+                                      SCOPE_PLAN, SCOPE_ROWS, SCOPE_TIER)
 from ..utils.strict import strict_guards
 from ..utils.trace import record_dispatch
 from . import planner
 from .staging import INT32_MAX, ChunkStager
 from .tiered import TieredFeature
+
+
+def _block_misses(block, hot_rows: int) -> np.ndarray:
+  """One chunk's sorted miss set from its ``[k, node_cap]`` device block
+  of storage rows: the block's one fetch, then ``planner.chunk_misses``.
+  Run by the staging worker (``ChunkStager._planned_rows``)."""
+  import jax
+  return planner.chunk_misses(jax.device_get(block), hot_rows)
 
 
 def tiered_gather(hot, slab_ids, slab, id2i, node):
@@ -54,17 +74,24 @@ def tiered_gather(hot, slab_ids, slab, id2i, node):
   ``ops.collate_batch``'s clamp exactly (pad slots -> node id 0), so a
   tiered batch is byte-identical to the all-HBM gather. Rows in neither
   (an impossible case under an exact plan) read as zeros rather than
-  garbage."""
+  garbage. Under ``glt.collate/tier``: ``hot`` (the prefix gather),
+  ``lookup`` (the id2index remap and the slab membership search) and
+  ``rows`` (the slab row gather and the select)."""
+  import jax
   import jax.numpy as jnp
-  safe = jnp.maximum(node, 0)
-  ridx = id2i[safe] if id2i is not None else safe
-  h = hot.shape[0]
-  hot_rows = hot[jnp.clip(ridx, 0, h - 1)]
-  pos = jnp.clip(jnp.searchsorted(slab_ids, ridx.astype(jnp.int32)), 0,
-                 slab_ids.shape[0] - 1)
-  in_slab = slab_ids[pos] == ridx.astype(jnp.int32)
-  return jnp.where((ridx < h)[:, None], hot_rows,
-                   jnp.where(in_slab[:, None], slab[pos], 0))
+  with jax.named_scope(SCOPE_COLLATE), jax.named_scope(SCOPE_TIER):
+    h = hot.shape[0]
+    with jax.named_scope(SCOPE_LOOKUP):
+      safe = jnp.maximum(node, 0)
+      ridx = (id2i[safe] if id2i is not None else safe).astype(jnp.int32)
+      pos = jnp.clip(jnp.searchsorted(slab_ids, ridx), 0,
+                     slab_ids.shape[0] - 1)
+      in_slab = slab_ids[pos] == ridx
+    with jax.named_scope(SCOPE_HOT):
+      hot_rows = hot[jnp.clip(ridx, 0, h - 1)]
+    with jax.named_scope(SCOPE_ROWS):
+      return jnp.where((ridx < h)[:, None], hot_rows,
+                       jnp.where(in_slab[:, None], slab[pos], 0))
 
 
 class TieredScanTrainer(ScanTrainer):
@@ -128,93 +155,110 @@ class TieredScanTrainer(ScanTrainer):
     return _sample_collate
 
   def _build_seed_fn(self):
-    """The prologue PLAN program: the base seed/permutation math plus
-    an id-only sampler replay over every step, emitting the epoch's
-    [steps, node_cap] storage-row matrix — one dispatch, fetched once.
-    """
+    """The prologue PLAN program: the base seed/permutation program
+    (the whole epoch's ``[full_steps, batch]`` seed matrix: what the
+    chunks slice is what the all-HBM trainer's chunks slice) and, under
+    ``glt.plan``, an id-only sampler replay over steps ``[start,
+    steps)`` — the steps this call runs — emitting per chunk its
+    ``[k, node_cap]`` block of storage rows, and ``[lookups, hot
+    hits]`` of the call: one dispatch. ``full_steps``, ``start`` and
+    ``steps`` are static: one executable per call shape."""
     import jax
     import jax.numpy as jnp
     from jax import lax
-    batch = self._batch_size
-    shuffle = self._shuffle
+    base_seeds = super()._build_seed_fn()
     sample_fn = self._sample_fn
     has_id2i = self._id2i is not None
+    hot_rows, chunk = self._store.hot_rows, self.chunk_size
 
-    def epoch_seeds(fargs, id2i, seeds, key, base_key, count0, steps):
-      n = seeds.shape[0]
-      order = (jax.random.permutation(key, n) if shuffle
-               else jnp.arange(n, dtype=jnp.int32))
-      total = steps * batch
-      if total <= n:
-        order = order[:total]
-        mask = jnp.ones((total,), bool)
-      else:
-        order = jnp.concatenate(
-            [order, jnp.zeros((total - n,), order.dtype)])
-        mask = jnp.arange(total) < n
-      seed_mat = jnp.where(mask, seeds[order], 0).reshape(steps, batch)
-      mask_mat = mask.reshape(steps, batch)
-      counts = count0 + lax.iota(jnp.int32, steps)
+    def epoch_seeds(fargs, id2i, seeds, key, base_key, count0,
+                    full_steps, start, steps):
+      seed_mat, mask_mat = base_seeds(seeds, key, full_steps)
+      with jax.named_scope(SCOPE_PLAN):
+        counts = count0 + start + lax.iota(jnp.int32, steps - start)
 
-      def step_rows(carry, xs):
-        seeds_s, mask_s, count = xs
-        k = jax.random.fold_in(base_key, count)
-        res = sample_fn(*fargs, seeds_s, mask_s, k)
-        safe = jnp.maximum(res['node'], 0)
-        ridx = id2i[safe] if has_id2i else safe
-        return carry, ridx.astype(jnp.int32)
+        def step_rows(carry, xs):
+          seeds_s, mask_s, count = xs
+          k = jax.random.fold_in(base_key, count)
+          node = sample_fn(*fargs, seeds_s, mask_s, k)['node']
+          safe = jnp.maximum(node, 0)
+          ridx = (id2i[safe] if has_id2i else safe).astype(jnp.int32)
+          valid = node >= 0
+          seen = jnp.stack([valid.sum(dtype=jnp.int32),
+                            (valid & (ridx < hot_rows)).sum(
+                                dtype=jnp.int32)])
+          return carry + seen, ridx
 
-      _, rows_mat = lax.scan(step_rows, 0, (seed_mat, mask_mat, counts))
-      return seed_mat, mask_mat, rows_mat
+        seen, rows_mat = lax.scan(
+            step_rows, jnp.zeros((2,), jnp.int32),
+            (seed_mat[start:steps], mask_mat[start:steps], counts))
+        blocks = tuple(rows_mat[a:a + chunk]
+                       for a in range(0, steps - start, chunk))
+      return seed_mat, mask_mat, blocks, seen
 
-    return jax.jit(epoch_seeds, static_argnums=(6,))
+    return jax.jit(epoch_seeds, static_argnums=(6, 7, 8))
 
   # ------------------------------------------------------------- epoch
 
   def _run_epoch_body(self, state, steps, full_steps, start_step=0,
                       resume_overflow=False):
-    """The tiered epoch program: fused plan prologue (one dispatch, one
-    explicit fetch) + staged chunk loop. Budget: 1 epoch_seeds +
-    ceil(steps/K) scan_chunk + 1 metrics_concat = ceil(steps/K) + 2 —
-    unchanged from the all-HBM trainer. A mid-epoch resume
-    (``start_step`` — recovery/checkpoint.py) re-runs the SAME plan
-    prologue (the permutation and sampler streams replay exactly) and
-    begins staging at the resume chunk; consumed chunks never stage
-    again."""
+    """The tiered epoch program: fused plan prologue (one dispatch; the
+    staging worker fetches each chunk's block) + staged chunk loop.
+    Budget: 1 epoch_seeds + ceil(steps/K) scan_chunk + 1 metrics_concat
+    = ceil(steps/K) + 2 — unchanged from the all-HBM trainer. The plan
+    covers steps ``[start_step, steps)``: what a call costs follows the
+    steps it runs, not the epoch's length. A mid-epoch resume
+    (``start_step`` — recovery/checkpoint.py) draws the SAME seed
+    matrix (the permutation and sampler streams replay exactly), plans
+    from the resume chunk on and begins staging there; consumed chunks
+    are neither planned nor staged again."""
     import jax
-    if self._seeds_dev is None:
-      self._seeds_dev = jax.device_put(
-          np.asarray(self.loader.input_seeds, dtype=np.int32))
-    perm_key = jax.random.fold_in(self._perm_key, self._epochs)
-    fargs = self._sampler._fused_args()
-    base_key = self._sampler._key
-    count0 = jax.device_put(np.int32(self._sampler._call_count + 1))
-    ovf = jax.device_put(np.asarray(bool(resume_overflow)))
+    with spans.span('epoch.stage'):
+      if self._seeds_dev is None:
+        self._seeds_dev = jax.device_put(
+            np.asarray(self.loader.input_seeds, dtype=np.int32))
+      perm_key = jax.random.fold_in(self._perm_key, self._epochs)
+      fargs = self._sampler._fused_args()
+      base_key = self._sampler._key
+      count0 = jax.device_put(np.int32(self._sampler._call_count + 1))
+      ovf = jax.device_put(np.asarray(bool(resume_overflow)))
     losses, accs = [], []
     start = start_step
     hot = self._feats
+    store = self._store
+    slab_rows = 0
     with strict_guards():
       record_dispatch('epoch_seeds')
-      seed_mat, mask_mat, rows_mat = self._seed_fn(
-          fargs, self._id2i, self._seeds_dev, perm_key, base_key,
-          count0, full_steps)
-      # the prologue's ONE fetch: the planned storage rows (explicit
-      # device_get — strict_guards rejects implicit transfers only)
-      rows_host = jax.device_get(rows_mat)[:steps]
-      plan = planner.plan_from_rows(rows_host, self.chunk_size,
-                                    self._store.hot_rows,
-                                    self._store.warm_rows)
-      self.last_plan = plan
-      self._stager.begin_epoch(plan.chunk_rows,
-                               start_chunk=start // self.chunk_size)
+      with spans.span('epoch.plan', steps=steps - start_step):
+        seed_mat, mask_mat, blocks, seen = self._seed_fn(
+            fargs, self._id2i, self._seeds_dev, perm_key, base_key,
+            count0, full_steps, start_step, steps)
+        # a chunk's miss set is made where it is needed, by whoever
+        # asks first — the staging worker: an explicit device_get of the
+        # chunk's block (strict_guards rejects implicit transfers only)
+        # and the dedup of its misses, off the dispatch thread
+        first = start_step // self.chunk_size
+        plan = planner.EpochPlan(
+            chunk_size=self.chunk_size, hot_rows=store.hot_rows,
+            warm_rows=store.warm_rows,
+            chunk_rows=[np.zeros((0,), np.int64)] * first + [
+                functools.partial(_block_misses, b, store.hot_rows)
+                for b in blocks])
+        del blocks
+        self.last_plan = plan
+        self._stager.begin_epoch(plan.thunks(), start_chunk=first)
       while start < steps:
         k = min(self.chunk_size, steps - start)
         c = start // self.chunk_size
         if self.stage_hook is not None:
-          self.stage_hook(c, start, k)
-        slab_ids_np, slab_np = self._stager.take(c)
-        slab_ids = jax.device_put(slab_ids_np)
-        slab = jax.device_put(slab_np)
+          with spans.span('epoch.hook', hook='stage', start=start):
+            self.stage_hook(c, start, k)
+        with spans.span('epoch.stage_wait', chunk=c):
+          slab_ids_np, slab_np = self._stager.take(c)
+        with spans.span('epoch.upload', chunk=c, bytes=slab_np.nbytes):
+          slab_ids = jax.device_put(slab_ids_np)
+          slab = jax.device_put(slab_np)
+        slab_rows += int(slab_np.shape[0])
         record_dispatch('scan_chunk')
         with spans.span('epoch.chunk', start=start, k=k):
           state, ovf, loss_k, acc_k = self._chunk_fn(
@@ -224,6 +268,7 @@ class TieredScanTrainer(ScanTrainer):
         # the device_put above copied the slab: free its ring slot and
         # let the worker pull the next chunk forward
         self._stager.ack(c)
+        del slab_ids_np, slab_np, slab_ids, slab
         losses.append(loss_k)
         accs.append(acc_k)
         self._steps_dispatched = start + k
@@ -234,16 +279,36 @@ class TieredScanTrainer(ScanTrainer):
                                    accs=accs, steps=steps,
                                    full_steps=full_steps,
                                    start_step=start_step)
-          self.ack_hook(c, start, k)
+          with spans.span('epoch.hook', hook='ack', start=start):
+            self.ack_hook(c, start, k)
         start += k
       if len(losses) > 1:
         record_dispatch('metrics_concat')
-        losses, accs = self._concat_fn(losses, accs)
+        with spans.span('epoch.concat'):
+          losses, accs = self._concat_fn(losses, accs)
       else:
         losses, accs = losses[0], accs[0]
+    with spans.span('epoch.publish'):
+      self._publish_tier_counts(plan, seen, slab_rows)
     self._sampler._call_count += steps
     self._epochs += 1
     return state, losses, accs, ovf
+
+  def _publish_tier_counts(self, plan, seen, slab_rows: int):
+    """``storage.lookups`` / ``.hot_hits`` (the plan program's two sums,
+    fetched here, once a call) and ``storage.planned_rows`` /
+    ``.slab_cap_rows`` (the plan the host already holds; the rows of the
+    slabs the loop uploaded): what the call looked up, what the hot
+    prefix answered, what was staged and what the padded slabs carried
+    for it."""
+    import jax
+
+    from .. import metrics
+    lookups, hits = (int(v) for v in jax.device_get(seen))
+    metrics.inc('storage.lookups', lookups)
+    metrics.inc('storage.hot_hits', hits)
+    metrics.inc('storage.planned_rows', plan.stats()['planned_rows'])
+    metrics.inc('storage.slab_cap_rows', slab_rows)
 
   def _flight_config(self) -> dict:
     cfg = super()._flight_config()

@@ -102,6 +102,13 @@ class ChunkStager:
     # graftlint: shared[_lock]
     self._next_submit = 0
     self.degraded = False   # a worker gather failed this epoch
+    # the largest slab capacity this stager has made, kept across
+    # epochs: a slab is never padded to less, so the chunk program's
+    # slab shape only grows and a steady job settles on ONE executable
+    # (a chunk whose miss count falls under a power of two that its
+    # neighbours pass would otherwise compile a second one, mid-run)
+    # graftlint: shared[_lock]
+    self._cap_floor = 1
     # perf_counter marks per chunk, kept for the whole epoch — the
     # chunk-boundary-overlap contract ("stage of c+1 completes before
     # chunk c's ack") is asserted from these
@@ -187,17 +194,18 @@ class ChunkStager:
         return
       with self._lock:
         slab = self._slabs.get(c)
-        rows_abs = self._plan[c] if c < len(self._plan) else None
-      if slab is None or rows_abs is None:
+        planned = c < len(self._plan)
+      if slab is None or not planned:
         continue   # epoch moved on under us
       try:
-        with spans.span('storage.stage', chunk=int(c),
-                        rows=int(rows_abs.shape[0])):
+        with spans.span('storage.stage', chunk=int(c)) as tok:
           t0 = time.perf_counter()
           # worker-only fault seam: armed faults fire HERE, never in
           # take()'s synchronous fallback — the degraded path must be
           # able to gather the same planned rows cleanly
           self._stage_fault()
+          rows_abs = self._planned_rows(c)
+          tok.attrs['rows'] = int(rows_abs.shape[0])
           ids, rows = self._gather(rows_abs)
           metrics.observe('storage.stage_ms',
                           (time.perf_counter() - t0) * 1e3)
@@ -225,9 +233,30 @@ class ChunkStager:
     ``storage.dist_stage``, storage/dist_scan.py)."""
     fault_point('storage.stage')
 
+  def _planned_rows(self, c: int) -> np.ndarray:
+    """Chunk ``c``'s sorted miss set. A plan entry may be a zero-argument
+    callable (``planner.EpochPlan.thunks``, which resolves a chunk once
+    and keeps it: the chunk's rows are still a device block, fetched and
+    deduplicated HERE, on whichever thread asks first — the worker,
+    beside the gather, so the dispatch thread never sorts while the
+    device waits)."""
+    with self._lock:
+      rows = self._plan[c]
+    return rows() if callable(rows) else rows
+
   def _gather(self, rows_abs: np.ndarray):
-    rows = self.store.stage_gather(rows_abs)
-    return pad_slab(rows_abs.astype(np.int32), rows)
+    """``pad_slab``'s slab, its rows gathered straight into the padded
+    buffer (one copy of a chunk's rows, and only the pad tail zeroed)."""
+    n = int(rows_abs.shape[0])
+    with self._lock:
+      cap = self._cap_floor = max(self._cap_floor, pow2_slab_cap(n))
+    ids = np.full((cap,), INT32_MAX, np.int32)
+    ids[:n] = rows_abs
+    f, dt = self.store.shape[1], self.store._np_dtype
+    slab = np.empty((cap, f), dt)
+    self.store.stage_gather(rows_abs, out=slab[:n])
+    slab[n:] = 0
+    return ids, slab
 
   def _ring_rows(self) -> int:
     with self._lock:
@@ -244,7 +273,6 @@ class ChunkStager:
     submits the next chunk so the pipeline stays ``max_ahead`` deep."""
     with self._lock:
       slab = self._slabs.get(c)
-      rows_abs = self._plan[c]
     ok = slab is not None and slab.ready.wait(self.timeout_s)
     self._submit_next()
     if ok and slab.error is None and slab.ids is not None:
@@ -253,6 +281,7 @@ class ChunkStager:
     # the SAME planned rows on the dispatch thread. Never a wrong
     # batch, only a slower one.
     self.degraded = True
+    rows_abs = self._planned_rows(c)
     metrics.inc('storage.prefetch_miss', int(rows_abs.shape[0]))
     return self._gather(rows_abs)
 

@@ -213,9 +213,16 @@ class TieredFeature(Feature):
         self._disk_base = 0
       self._n, self._f = n, int(arr.shape[1])
       self._np_dtype = arr.dtype
+    self._init_surface(id2index, dtype, device, promoted_rows)
+
+  def _init_surface(self, id2index, dtype, device, promoted_rows,
+                    hot_dev=None):
+    """The Feature surface over tiers already in place (no
+    super().__init__: the base stores the full array; the whole point
+    here is NOT holding one). ``hot_dev``: the hot prefix where it lives
+    only on the device (``from_tiers``)."""
+    self._hot_dev = hot_dev
     self.disk_rows = self._n - self.hot_rows - self.warm_rows
-    # Feature surface (no super().__init__: the base stores the full
-    # array; the whole point here is NOT holding one)
     self.split_ratio = self.hot_rows / self._n if self._n else 0.0
     self.cache_rows = self.hot_rows
     self.device_group_list = None
@@ -228,6 +235,36 @@ class TieredFeature(Feature):
     self._id2index_dev = None
     self._promoted = _PromotedCache(promoted_rows)
 
+  @classmethod
+  def from_tiers(cls, hot, warm: Optional[np.ndarray],
+                 id2index: Optional[np.ndarray] = None, device=None,
+                 promoted_rows: int = 65536):
+    """A store over tiers that are ALREADY where they belong: ``hot``
+    the [H, F] hot prefix as a DEVICE array (made or filled on the
+    chip), ``warm`` the [W, F] rest as ONE host array — adopted, not
+    copied, so a table that does not fit the chip costs the host one
+    table, where the array constructor holds the source, a copy of
+    each tier and the hot prefix a second time. No disk tier (hand a
+    ``DiskTier`` to the constructor for one). ``cpu_get`` and
+    ``share_ipc`` fetch hot rows from the device when asked."""
+    if hot is None or hot.shape[0] < 1:
+      raise ValueError('from_tiers takes the hot prefix as a device '
+                       'array of at least one row (scan_tables clamps '
+                       'pad slots into it)')
+    self = cls.__new__(cls)
+    self._disk, self._disk_base = None, 0
+    self._hot_np, self._warm_np = None, warm
+    self.hot_rows = int(hot.shape[0])
+    self.warm_rows = int(warm.shape[0]) if warm is not None else 0
+    self._n = self.hot_rows + self.warm_rows
+    self._f, self._np_dtype = int(hot.shape[1]), np.dtype(hot.dtype)
+    if warm is not None and (warm.shape[1] != self._f or
+                             warm.dtype != self._np_dtype):
+      raise ValueError(f'from_tiers: warm is {warm.shape[1:]} '
+                       f'{warm.dtype}, hot {hot.shape[1:]} {hot.dtype}')
+    self._init_surface(id2index, None, device, promoted_rows, hot_dev=hot)
+    return self
+
   # ------------------------------------------------------------ lifecycle
 
   def lazy_init(self):
@@ -236,7 +273,11 @@ class TieredFeature(Feature):
     ut = _TieredTensor(self._warm_np, self._disk, self._disk_base,
                        self._promoted, device=self.device,
                        dtype=self.dtype)
-    ut.init_from(self._hot_np, None)
+    if self._hot_dev is not None:
+      # from_tiers: the prefix is on the device already, nothing to put
+      ut._device_part, ut._device_rows = self._hot_dev, self.hot_rows
+    else:
+      ut.init_from(self._hot_np, None)
     # init_from only sees the hot block; stamp the tiered host span
     ut._host_rows_n = self.warm_rows + self.disk_rows
     self._unified = ut
@@ -267,7 +308,8 @@ class TieredFeature(Feature):
 
   def cpu_get(self, ids) -> np.ndarray:
     """Pure-host gather across all three tiers (hot rows come from the
-    host copy kept for IPC/rebuild, not from HBM)."""
+    host copy kept for IPC/rebuild; a ``from_tiers`` store keeps none
+    and fetches the asked-for hot rows from HBM)."""
     ids = np.asarray(ids).reshape(-1)
     if self._id2index is not None:
       rows = self._id2index[ids]
@@ -279,23 +321,46 @@ class TieredFeature(Feature):
     out = np.zeros((rows.shape[0], self._f), self._np_dtype)
     is_hot = rows < self.hot_rows
     if is_hot.any():
-      out[is_hot] = self._hot_np[rows[is_hot]]
+      out[is_hot] = self._hot_host(rows[is_hot])
     rest = ~is_hot
     if rest.any():
       self.lazy_init()
       out[rest] = self._unified._host_resolve(rows[rest] - self.hot_rows)
     return out
 
-  def stage_gather(self, abs_rows: np.ndarray) -> np.ndarray:
+  def _hot_host(self, rows: Optional[np.ndarray] = None) -> np.ndarray:
+    """Hot rows on the host: all of them (``rows`` None) or a gather.
+    A store whose prefix lives only on the device fetches them."""
+    if self._hot_dev is None:
+      return self._hot_np if rows is None else self._hot_np[rows]
+    import jax
+    if rows is None:
+      return np.asarray(jax.device_get(self._hot_dev))
+    idx = jax.device_put(np.asarray(rows, np.int32))
+    return np.asarray(jax.device_get(self._hot_dev[idx]))
+
+  def stage_gather(self, abs_rows: np.ndarray,
+                   out: Optional[np.ndarray] = None) -> np.ndarray:
     """Warm/disk rows for ABSOLUTE storage rows >= hot_rows, straight
     from the tiers (no promoted-cache consult, no miss accounting) —
-    the staging worker's read path (storage/staging.py)."""
+    the staging worker's read path (storage/staging.py). ``out``
+    ([len(abs_rows), F], the store's dtype) is written in place: the
+    stager hands in the head of its padded slab, so a chunk's rows are
+    copied once, not gathered and then padded."""
     abs_rows = np.asarray(abs_rows, np.int64).reshape(-1)
     if abs_rows.size and abs_rows.min() < self.hot_rows:
       raise IndexError('stage_gather serves the host tiers: rows must '
                        f'be >= hot_rows ({self.hot_rows})')
-    out = np.zeros((abs_rows.shape[0], self._f), self._np_dtype)
+    if out is None:
+      out = np.empty((abs_rows.shape[0], self._f), self._np_dtype)
     rel = abs_rows - self.hot_rows
+    if self.disk_rows == 0:
+      # every host row is warm: one gather straight into the slab
+      # (mode='clip' writes through ``out`` with no bounce buffer; the
+      # ids are in range by the check above and the plan's construction)
+      if rel.size:
+        np.take(self._warm_np, rel, axis=0, out=out, mode='clip')
+      return out
     is_warm = rel < self.warm_rows
     if is_warm.any():
       out[is_warm] = self._warm_np[rel[is_warm]]
@@ -337,8 +402,8 @@ class TieredFeature(Feature):
     blocks as host arrays (reference feature.py:240-257 — CUDA-IPC
     re-init collapses to host-array handoff on TPU)."""
     return ('tiered', self._disk.dir if self._disk is not None else None,
-            self._disk_base, self._hot_np, self._warm_np,
-            self._id2index, self.dtype)
+            self._disk_base, self._hot_host() if self.hot_rows else None,
+            self._warm_np, self._id2index, self.dtype)
 
   @classmethod
   def from_ipc_handle(cls, handle):
@@ -350,20 +415,11 @@ class TieredFeature(Feature):
     obj._hot_np, obj._warm_np = hot_np, warm_np
     obj.hot_rows = int(hot_np.shape[0]) if hot_np is not None else 0
     obj.warm_rows = int(warm_np.shape[0]) if warm_np is not None else 0
-    obj.disk_rows = (int(obj._disk.rows - disk_base)
-                     if obj._disk is not None else 0)
-    obj._n = obj.hot_rows + obj.warm_rows + obj.disk_rows
+    disk_rows = (int(obj._disk.rows - disk_base)
+                 if obj._disk is not None else 0)
+    obj._n = obj.hot_rows + obj.warm_rows + disk_rows
     ref = hot_np if hot_np is not None else warm_np
     obj._f = (int(ref.shape[1]) if ref is not None else obj._disk.dim)
     obj._np_dtype = (ref.dtype if ref is not None else obj._disk.dtype)
-    obj.split_ratio = obj.hot_rows / obj._n if obj._n else 0.0
-    obj.cache_rows = obj.hot_rows
-    obj.device_group_list = None
-    obj.device = None
-    obj.with_device = obj.hot_rows > 0
-    obj._id2index = id2index
-    obj.dtype = dtype
-    obj._unified = None
-    obj._id2index_dev = None
-    obj._promoted = _PromotedCache(65536)
+    obj._init_surface(id2index, dtype, None, 65536)
     return obj

@@ -254,7 +254,8 @@ def test_every_profiler_name_is_documented(name):
     assert name.split('/')[0] in (
         metrics.registry_names.SCOPE_SAMPLE,
         metrics.registry_names.SCOPE_COLLATE,
-        metrics.registry_names.SCOPE_TRAIN)
+        metrics.registry_names.SCOPE_TRAIN,
+        metrics.registry_names.SCOPE_PLAN)
 
 
 # --------------------------------------------------- epoch flight records
