@@ -293,8 +293,11 @@ SCOPE_TILE = 'tile'              # one tile of a loop that runs only below
                                  # (ops.uniform_sample_tiled) and, behind
                                  # lookup / rows, the owners' bounded lookup
                                  # of a received block (dist_feature.
-                                 # bounded_lookup); a reader counts a
-                                 # loop's executions by the component
+                                 # bounded_lookup) and, behind lookup, the
+                                 # tiered gather's search of its misses
+                                 # (storage/scan.py bounded_slab_search);
+                                 # a reader counts a loop's executions by
+                                 # the component
 SCOPE_UNPACK = 'unpack'          # ops.gather_from_buckets and the cast back
 SCOPE_FANOUT = 'fanout'          # rows[inverse]: a response row to every
                                  # slot that asked for it
@@ -315,7 +318,8 @@ SCOPE_PLAN = 'glt.plan'          # the call's prologue: the id-only replay
 SCOPE_TIER = 'tier'              # inside glt.collate: tiered_gather, with
 SCOPE_HOT = 'hot'                # .../tier/hot: the HBM hot-prefix gather
                                  # .../tier/lookup: the id2index remap and
-                                 # the slab membership search
+                                 # the slab membership search (.../tile:
+                                 # one tile of the compacted misses)
                                  # .../tier/rows: the slab row gather and
                                  # the three-way select
 
@@ -372,6 +376,7 @@ REGISTERED_SCOPES = frozenset({
     'glt.collate/tier',
     'glt.collate/tier/hot',
     'glt.collate/tier/lookup',
+    'glt.collate/tier/lookup/tile',
     'glt.collate/tier/rows',
     'glt.plan',
     'glt.train',

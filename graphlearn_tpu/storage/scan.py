@@ -29,7 +29,12 @@ this trainer runs the SAME epoch-as-a-program over a
   (``glt.collate/tier/{hot,lookup,rows}``) is
   hot-prefix ``take`` + slab ``searchsorted`` — every non-hot row a
   chunk touches is in its slab by construction (the plan is exact), so
-  losses are BIT-IDENTICAL to the all-HBM ScanTrainer. Staging shapes
+  losses are BIT-IDENTICAL to the all-HBM ScanTrainer. The search runs
+  over the slots that need it: a node buffer of 2,048 slots or more
+  compacts its valid non-hot slots and searches them tile by tile
+  (``glt.collate/tier/lookup/.../tile``, ``ceil(misses / tile)`` tiles by
+  the draw's rule ``ops.neighbor.draw_tile_rows``); hits and pads never
+  walk the search's rounds. Staging shapes
   are pow2-capped: one executable per (chunk length, slab cap) pair.
 * **Degradation, never corruption.** A failed/slow staging worker
   degrades to a synchronous gather of the same planned rows
@@ -52,7 +57,8 @@ from ..loader.pipeline import refuse_link
 from ..loader.scan_epoch import ScanTrainer
 from ..metrics import spans
 from ..metrics.registry_names import (SCOPE_COLLATE, SCOPE_HOT, SCOPE_LOOKUP,
-                                      SCOPE_PLAN, SCOPE_ROWS, SCOPE_TIER)
+                                      SCOPE_PLAN, SCOPE_ROWS, SCOPE_TIER,
+                                      SCOPE_TILE)
 from ..utils.strict import strict_guards
 from ..utils.trace import record_dispatch
 from . import planner
@@ -68,6 +74,43 @@ def _block_misses(block, hot_rows: int) -> np.ndarray:
   return planner.chunk_misses(jax.device_get(block), hot_rows)
 
 
+def bounded_slab_search(slab_ids, ridx, miss, tile: int):
+  """The slab membership search over the slots that need it: ``(code
+  [cap], tiles)`` where ``code`` is a ``miss`` slot's position in the
+  slab, -1 where the slab does not hold its row and on every other slot,
+  and ``tiles`` is the int32 number of tiles searched, ``ceil(n_miss /
+  tile)``.
+
+  The misses' storage rows are compacted to a prefix of a ``[cap]`` query
+  buffer (a rank by prefix sum and one ``mode='drop'`` scatter,
+  ``INT32_MAX`` behind them) and searched ``tile`` queries at a time by a
+  loop that runs only the tiles beginning below the last miss; the last
+  tile is clamped to end at the cap, so it may search queries of the one
+  before again, to the same answers. One gather by rank brings the
+  answers back to slot order."""
+  import jax
+  import jax.numpy as jnp
+  from ..ops.unique import searchsorted_membership
+  cap = ridx.shape[0]
+  assert 0 < tile <= cap, (tile, cap)
+  rank = jnp.cumsum(miss, dtype=jnp.int32) - 1
+  queries = jnp.full((cap,), INT32_MAX, jnp.int32).at[
+      jnp.where(miss, rank, cap)].set(ridx, mode='drop')
+  tiles = (rank[-1] + tile) // tile
+
+  def body(i, code):
+    with jax.named_scope(SCOPE_TILE):
+      lo = jnp.minimum(i * tile, cap - tile)
+      found, pos = searchsorted_membership(
+          slab_ids, jax.lax.dynamic_slice(queries, (lo,), (tile,)))
+      return jax.lax.dynamic_update_slice(
+          code, jnp.where(found, pos, -1), (lo,))
+
+  code = jax.lax.fori_loop(0, tiles, body,
+                           jnp.full((cap,), -1, jnp.int32))
+  return jnp.where(miss, code[jnp.maximum(rank, 0)], -1), tiles
+
+
 def tiered_gather(hot, slab_ids, slab, id2i, node):
   """Traced three-way feature gather: node-id buffer -> rows from the
   HBM hot prefix or the chunk's staged slab. Mirrors
@@ -76,17 +119,35 @@ def tiered_gather(hot, slab_ids, slab, id2i, node):
   (an impossible case under an exact plan) read as zeros rather than
   garbage. Under ``glt.collate/tier``: ``hot`` (the prefix gather),
   ``lookup`` (the id2index remap and the slab membership search) and
-  ``rows`` (the slab row gather and the select)."""
+  ``rows`` (the slab row gather and the select).
+
+  Only a valid slot the hot prefix does not answer can change what the
+  search decides, so a node buffer wide enough to tile
+  (``ops.neighbor.draw_tile_rows`` of its cap, the draw's one rule) is
+  searched through :func:`bounded_slab_search`, its tiles named
+  ``lookup/.../tile``; every pad is node 0's slot and takes ONE scalar
+  search's answer. A narrower buffer is searched in one piece."""
   import jax
   import jax.numpy as jnp
+  from ..ops.neighbor import draw_tile_rows
+  from ..ops.unique import searchsorted_membership
   with jax.named_scope(SCOPE_COLLATE), jax.named_scope(SCOPE_TIER):
     h = hot.shape[0]
     with jax.named_scope(SCOPE_LOOKUP):
-      safe = jnp.maximum(node, 0)
-      ridx = (id2i[safe] if id2i is not None else safe).astype(jnp.int32)
-      pos = jnp.clip(jnp.searchsorted(slab_ids, ridx), 0,
-                     slab_ids.shape[0] - 1)
-      in_slab = slab_ids[pos] == ridx
+      remap = lambda ids: (id2i[ids] if id2i is not None
+                           else ids).astype(jnp.int32)
+      ridx = remap(jnp.maximum(node, 0))
+      tile = draw_tile_rows(node.shape[0])
+      if not tile:
+        in_slab, pos = searchsorted_membership(slab_ids, ridx)
+      else:
+        valid = node >= 0
+        code, _ = bounded_slab_search(slab_ids, ridx,
+                                      valid & (ridx >= h), tile)
+        pad_found, pad_pos = searchsorted_membership(
+            slab_ids, remap(jnp.zeros((), node.dtype)))
+        code = jnp.where(valid, code, jnp.where(pad_found, pad_pos, -1))
+        pos, in_slab = jnp.maximum(code, 0), code >= 0
     with jax.named_scope(SCOPE_HOT):
       hot_rows = hot[jnp.clip(ridx, 0, h - 1)]
     with jax.named_scope(SCOPE_ROWS):
