@@ -8,7 +8,7 @@ store (dataset.py:160-174); hetero graphs/features are dicts keyed by
 EdgeType/NodeType. Tensors are numpy host-side; device placement happens in
 Graph/Feature lazily.
 """
-from typing import Dict, Optional, Union
+from typing import Dict, Optional, Tuple, Union
 
 import numpy as np
 
@@ -26,6 +26,9 @@ class Dataset:
     self.graph: Union[Graph, Dict[EdgeType, Graph], None] = graph
     self.node_features: Union[Feature, Dict[NodeType, Feature], None] = \
         node_features
+    # learned rows for node types that have no feature rows
+    # (init_node_tables): {ntype: (num_rows, width)}
+    self.node_tables: Optional[Dict[NodeType, Tuple[int, int]]] = None
     self.edge_features: Union[Feature, Dict[EdgeType, Feature], None] = \
         edge_features
     self.node_labels = node_labels
@@ -99,6 +102,29 @@ class Dataset:
     else:
       topo = self.graph.topo if isinstance(self.graph, Graph) else None
       self.node_features = build(node_feature_data, topo, id2idx)
+    return self
+
+  def init_node_tables(self, tables):
+    """Learned rows for node types that have no feature rows: ``{ntype:
+    (num_rows, width)}``, float32. The rows are not data but parameters
+    of the train state (``models/tables.py``): the model declares the
+    same tables (``models.RGNN(node_tables=dataset.node_tables)``) and
+    makes them from its init key, a typed batch carries those types'
+    node ids where it would carry rows, and the optimizer steps over
+    them with the weights. ``loader.ScanTrainer`` over a typed
+    ``NodeLoader`` trains them, and the per-batch typed loader emits the
+    ids; the other scanned trainers refuse such a loader
+    (``docs/capacity_plans.md``, "Learned node tables")."""
+    tables = {t: (int(r), int(w)) for t, (r, w) in dict(tables).items()}
+    stores = self.node_features if isinstance(self.node_features,
+                                              dict) else {}
+    both = sorted(set(tables) & set(stores))
+    if both:
+      raise ValueError(f'node types {both} have feature rows already; a '
+                       'learned table is for a type that has none')
+    if any(r < 1 or w < 1 for r, w in tables.values()):
+      raise ValueError(f'a learned table has rows and width >= 1: {tables}')
+    self.node_tables = tables
     return self
 
   def init_edge_features(self, edge_feature_data=None, split_ratio=1.0,

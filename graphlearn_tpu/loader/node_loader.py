@@ -338,9 +338,13 @@ class NodeLoader(OverflowGuardMixin):
       x = y = None
       if self.collect_features and self.data.node_features is not None:
         x = {}
+        tables = getattr(self.data, 'node_tables', None) or {}
         for t, buf in out.node.items():
           store = self.data.get_node_feature(t)
-          if store is not None:
+          if t in tables:
+            # a learned table: the model gathers its rows by these ids
+            x[t] = buf
+          elif store is not None:
             dt = store.device_table()
             if dt is not None:
               x[t] = ops.gather_rows(dt[0], dt[1], buf)

@@ -12,7 +12,8 @@ are built from:
   single-chip scanned trainers (``scan_epoch.ScanTrainer``,
   ``run_epoch.RunTrainer``, ``storage.TieredScanTrainer``): scope
   validation, the device feature/label tables and the pure
-  sample+collate body, for a homogeneous or a typed loader.
+  sample+collate body, for a homogeneous or a typed loader (a typed one
+  may give some node types learned tables in place of rows).
 * :class:`DistFusedEpochTrainer` — its mesh counterpart and the
   per-step trainer of the collocated mesh: the data-parallel train
   step and the traced sample+collate body that
@@ -60,6 +61,20 @@ def refuse_typed(loader, name: str):
     raise CapacityPlanError(
         name, 'its epoch loop keeps the homogeneous key stream',
         'typed graphs scan through loader.ScanTrainer')
+
+
+def refuse_tables(loader, name: str):
+  """For the executors that do not train learned node tables
+  (``Dataset.init_node_tables``): called first in their ``__init__``."""
+  if getattr(loader.data, 'node_tables', None):
+    raise ValueError(
+        f'{name}: the loader\'s dataset declares learned node tables '
+        f'({sorted(loader.data.node_tables)}). A table is a parameter of '
+        'the train state that the model gathers from the node ids the '
+        'collate emits, with its gradient scattered back into it and the '
+        'optimizer stepping over it; only loader.ScanTrainer over a typed '
+        'NodeLoader (seeds of one node type) builds that batch source '
+        '(docs/capacity_plans.md, "Learned node tables")')
 
 
 def refuse_link(loader, name: str):
@@ -125,6 +140,7 @@ class FusedEpochTrainer:
       seed_labels_only = loader.seed_labels_only
     self._label_cap = self._batch_size if seed_labels_only else None
     self._input_type = loader.input_type if typed and not link else None
+    self._id_types = ()    # node types whose rows are learned tables
     from ..models import train as train_lib
     if link:
       self._init_link_source(loader, typed)
@@ -164,8 +180,11 @@ class FusedEpochTrainer:
 
   def _init_typed_source(self, loader):
     """The typed batch source: seeds of ONE node type (``loader.
-    input_type``), the typed hop loop as one program, and per-type
-    device tables for every node type the plan gives rows to."""
+    input_type``), the typed hop loop as one program, and for every node
+    type the plan gives rows to either its device feature table or — a
+    type the dataset gives a learned table (``Dataset.init_node_tables``)
+    — its node ids, from which the model gathers the table's rows
+    (``models/tables.py``; the model must declare the same tables)."""
     from ..sampler.capacity import CapacityPlan
     t_in = self._input_type
     if t_in is None:
@@ -180,8 +199,18 @@ class FusedEpochTrainer:
     self._key_stride = plan.key_draws_per_batch
     stores = loader.data.node_features
     stores = stores if isinstance(stores, dict) else {}
-    feats, id2i = {}, {}
-    for t in plan.feat_types(available=stores):
+    tables = getattr(loader.data, 'node_tables', None) or {}
+    feats, id2i, id_types = {}, {}, []
+    for t in plan.feat_types():
+      if t in tables:
+        id_types.append(t)
+        continue
+      if t not in stores:
+        raise ValueError(
+            f'{self._NAME}: the sampling plan gives node type {t!r} rows, '
+            'and it has neither a feature store nor a learned table — '
+            'Dataset.init_node_features for its rows, or '
+            'Dataset.init_node_tables for rows the model learns')
       dt = stores[t].device_table()
       if dt is None:
         raise ValueError(f'{self._NAME} needs a device-resident feature '
@@ -190,7 +219,18 @@ class FusedEpochTrainer:
     if not feats:
       raise ValueError(f'{self._NAME} needs device-resident feature '
                        'tables (Feature on HBM)')
+    if id_types:
+      declared = dict(getattr(self.model, 'node_tables', None) or {})
+      want = {t: tuple(tables[t]) for t in id_types}
+      got = {t: tuple(declared[t]) for t in id_types if t in declared}
+      if got != want:
+        raise ValueError(
+            f'{self._NAME}: the dataset gives node types {sorted(want)} '
+            f'learned tables {want}, and the model declares {got}: build '
+            'the model with node_tables=dataset.node_tables, so that it '
+            'gathers the rows the collate leaves as node ids')
     self._feats, self._id2i = feats, id2i
+    self._id_types = tuple(id_types)
 
   def _init_link_source(self, loader, typed: bool):
     """The edge-seeded batch source: ``batch_size`` seed pairs a step,
@@ -198,6 +238,7 @@ class FusedEpochTrainer:
     epoch order's positions, then the sampler's link body. What the
     chunk does not run yet is refused here, each by its mechanism."""
     neg = loader.neg_sampling
+    refuse_tables(loader, f'{self._NAME} over a link loader')
     if typed:
       from ..sampler.capacity import CapacityPlanError
       raise CapacityPlanError(
@@ -269,9 +310,11 @@ class FusedEpochTrainer:
     [N, F] table fed straight to the fused collate gather. On a typed
     graph ``fargs`` is ``sampler._typed_args()``, ``feats`` / ``id2i``
     the per-type table dicts, ``labels`` the seed type's table and
-    ``key`` the step's [S, 2] per-touch keys."""
+    ``key`` the step's [S, 2] per-touch keys. A typed source with
+    learned tables also returns the step's valid table slots."""
+    import jax.numpy as jnp
     sample_fn, label_cap = self._sample_fn, self._label_cap
-    t_in = self._input_type
+    t_in, id_types = self._input_type, self._id_types
 
     def _sample_collate(fargs, feats, id2i, labels, seeds, smask, key):
       if t_in is None:
@@ -285,13 +328,19 @@ class FusedEpochTrainer:
         res = sample_fn(fargs, seeds, smask, key)
         x, edge_index, y = ops.collate_typed_batch(
             res['node'], res['row'], res['col'], feats, id2i, labels,
-            t_in, label_cap=label_cap)
+            t_in, label_cap=label_cap, id_types=id_types)
         num_seed_nodes = res['num_sampled_nodes'][t_in][0]
       batch = dict(x=x, edge_index=edge_index, edge_mask=res['edge_mask'],
                    y=y, num_seed_nodes=num_seed_nodes)
       # the calibrated-caps truncation flag rides OUTSIDE the batch dict
       # (train_step must not see it; the batch buffers are donated)
-      return batch, res['overflow']
+      if not id_types:
+        return batch, res['overflow']
+      # what the step read of the learned tables: its valid table slots
+      # (one per row under exact dedup), a per-step scan output
+      rows_read = sum((res['node'][t] >= 0).sum(dtype=jnp.int32)
+                      for t in id_types)
+      return batch, res['overflow'], rows_read[None]
 
     return _sample_collate
 

@@ -60,7 +60,7 @@ from ..metrics import programs, spans
 from ..utils.strict import strict_guards
 from ..utils.trace import record_dispatch
 from .node_loader import NodeLoader
-from .pipeline import refuse_link, refuse_typed
+from .pipeline import refuse_link, refuse_tables, refuse_typed
 from .scan_epoch import ScanTrainer
 
 
@@ -89,6 +89,7 @@ class RunTrainer(ScanTrainer):
                seed_labels_only: Optional[bool] = None,
                perm_seed: Optional[int] = None, config=None,
                track_eval: bool = True):
+    refuse_tables(loader, self._NAME)
     refuse_typed(loader, self._NAME)   # typed run chunks: ROADMAP M5
     refuse_link(loader, self._NAME)
     super().__init__(loader, model, tx, num_classes,

@@ -231,10 +231,17 @@ class ScanTrainer(FusedEpochTrainer):
                perm_seed: Optional[int] = None, config=None):
     import jax
     super().__init__(loader, model, tx, num_classes, seed_labels_only)
-    # per chunk, the link body's [k, 4] counts (negatives tested,
-    # rejected, padded; rows of the seed union): a scan output, summed
-    # on the host once an epoch (_publish_link_counts empties it)
-    self._link_counts = []
+    # per chunk, the batch source's [k, n] per-step counts — a link
+    # body's negatives tested, rejected, padded and rows of the seed
+    # union; a typed source's valid learned-table slots — a scan output,
+    # summed on the host once an epoch (_publish_step_counts empties it)
+    self._step_counts = []
+    if isinstance(loader, LinkLoader):
+      self._count_names = ('link.negatives.tested',
+                           'link.negatives.rejected',
+                           'link.negatives.padded', 'link.seeds.unique')
+    else:
+      self._count_names = ('table.rows_read',) if self._id_types else ()
     # config= takes a tune artifact (graphlearn_tpu.tune(),
     # docs/tuning.md): dataset-fingerprint-validated, supplies the
     # tuned chunk K when chunk_size is not given explicitly
@@ -463,7 +470,7 @@ class ScanTrainer(FusedEpochTrainer):
           resume_overflow=resume_overflow)
       completed = True
       with spans.span('epoch.publish'):
-        self._publish_link_counts()
+        self._publish_step_counts()
       if guarded:
         # natural epoch end applies overflow_policy; a max_steps
         # break leaves the
@@ -499,7 +506,7 @@ class ScanTrainer(FusedEpochTrainer):
     so run_epoch owns only the guard/flight bracketing."""
     import jax
     link = isinstance(self.loader, LinkLoader)
-    self._link_counts = []   # a failed epoch's counts are not carried on
+    self._step_counts = []   # a failed epoch's counts are not carried on
     # the call's prologue, before any device program: on a profiler
     # timeline the idle gap in front of epoch.seeds is this span's
     with spans.span('epoch.stage'):
@@ -556,7 +563,7 @@ class ScanTrainer(FusedEpochTrainer):
               jax.device_put(np.int32(start)), k)
         losses.append(loss_k)
         accs.append(acc_k)
-        self._link_counts.extend(counts_k)
+        self._step_counts.extend(counts_k)
         self._steps_dispatched = start + k
         if self.ack_hook is not None:
           # boundary carry for the recovery seam (recovery/checkpoint):
@@ -583,22 +590,21 @@ class ScanTrainer(FusedEpochTrainer):
     self._epochs += 1
     return state, losses, accs, ovf
 
-  def _publish_link_counts(self):
-    """A link epoch's negative-sampler and seed-union counts into
-    ``link.negatives.{tested,rejected,padded}`` and ``link.seeds.unique``:
-    the chunks' ``[k, 4]`` scan outputs fetched once, after the epoch (no
-    fetch in the loop), as ``dist_exchange.rows.hop<h>`` is. A node epoch
-    has none."""
+  def _publish_step_counts(self):
+    """The batch source's per-step counts into their counters — a link
+    epoch's ``link.negatives.{tested,rejected,padded}`` and
+    ``link.seeds.unique``, a typed epoch over learned tables'
+    ``table.rows_read``: the chunks' ``[k, n]`` scan outputs fetched
+    once, after the epoch (no fetch in the loop), as
+    ``dist_exchange.rows.hop<h>`` is. Any other epoch has none."""
     from ..utils import trace
-    counts, self._link_counts = self._link_counts, []
+    counts, self._step_counts = self._step_counts, []
     if not counts:
       return
     total = np.sum([np.asarray(c).sum(axis=0, dtype=np.int64)
                     for c in counts], axis=0)
-    for name, n in zip(('link.negatives.tested', 'link.negatives.rejected',
-                        'link.negatives.padded', 'link.seeds.unique'),
-                       total.tolist()):
-      # graftlint: allow[metric-registry] the registered link.* family
+    for name, n in zip(self._count_names, total.tolist()):
+      # graftlint: allow[metric-registry] link.* and table.rows_read names
       trace.counter_inc(name, int(n))
 
   def _flight_config(self) -> dict:

@@ -48,6 +48,10 @@ REGISTERED_METRICS = frozenset({
     # / .rejected (candidates that were edges) / .padded (slots no
     # non-edge was left for), link.seeds.unique (rows of the seed union)
     'link.*',
+    # what a scanned epoch over learned node tables read (loader/
+    # scan_epoch.py ScanTrainer, published once per epoch from a per-step
+    # scan output): valid table slots a step, summed over steps
+    'table.rows_read',
     # mp sampling workers (distributed/dist_sampling_producer.py)
     'producer.batches',
     'producer.sample_ms',
@@ -315,6 +319,11 @@ SCOPE_PLAN = 'glt.plan'          # the call's prologue: the id-only replay
                                  # of the sampler over the steps the call
                                  # runs — a layer of its own, so that it is
                                  # not read as glt.sample of a trained step
+SCOPE_TABLE = 'table'            # learned node tables (models/tables.py):
+SCOPE_GATHER = 'gather'          # glt.train/fwd_bwd/table/gather, the rows
+SCOPE_SCATTER = 'scatter'        # read; .../table/scatter, the scatter-add
+                                 # of their gradients; glt.train/update/
+                                 # table, the optimizer over the tables
 SCOPE_TIER = 'tier'              # inside glt.collate: tiered_gather, with
 SCOPE_HOT = 'hot'                # .../tier/hot: the HBM hot-prefix gather
                                  # .../tier/lookup: the id2index remap and
@@ -382,6 +391,9 @@ REGISTERED_SCOPES = frozenset({
     'glt.train',
     'glt.train/fwd_bwd',
     'glt.train/update',
+    'glt.train/update/table',
     'glt.train/allreduce',
     'glt.train/pairs',
+    'glt.train/fwd_bwd/table/gather',
+    'glt.train/fwd_bwd/table/scatter',
 })

@@ -15,6 +15,7 @@ from flax.linen.dtypes import promote_dtype
 
 from ..typing import EdgeType, NodeType
 from .conv import GATConv, GCNConv, SAGEConv
+from .tables import NODE_TABLES, NodeTables, table_shapes
 
 _CONVS = {'sage': SAGEConv, 'gcn': GCNConv, 'gat': GATConv}
 
@@ -1126,6 +1127,15 @@ class RGNN(nn.Module):
   # must come from hetero_tree_blocks(etype_caps=caps) with the SAME
   # caps as the loader's frontier_caps dict. Requires dedup='merge'.
   merge_dense: bool = False
+  # node_tables: {ntype: (rows, width)} — learned rows for the node types
+  # that have no feature rows (Dataset.init_node_tables; models/tables.py):
+  # the batch carries those types' node ids, and the model gathers their
+  # rows from params[NODE_TABLES][ntype] before anything else
+  node_tables: Any = None
+  # embed_inputs: the per-type input Dense (embed_<type>) a forward runs
+  # unless its call says otherwise; False when the first conv reads the
+  # rows as they come (R-GCN over 128-wide rows and tables)
+  embed_inputs: bool = True
 
   def __post_init__(self):
     # EdgeType-keyed dicts cannot live on Module fields (flax >= 0.10
@@ -1136,7 +1146,7 @@ class RGNN(nn.Module):
 
   @nn.compact
   def __call__(self, x_dict, edge_index_dict, edge_mask_dict,
-               train: bool = False, layers=None, embed: bool = True,
+               train: bool = False, layers=None, embed=None,
                head=None):
     hier = self.hop_node_offsets is not None
     hop_edge_offsets = thaw_etype_items(self.hop_edge_offsets)
@@ -1164,6 +1174,11 @@ class RGNN(nn.Module):
       check_hetero_offsets(x_dict, edge_index_dict,
                            self.hop_node_offsets, hop_edge_offsets,
                            self.num_layers)
+    if self.node_tables:
+      x_dict = NodeTables(table_shapes(self.node_tables),
+                          name=NODE_TABLES)(x_dict)
+    if embed is None:
+      embed = self.embed_inputs
     if embed:
       x_dict = {t: nn.Dense(self.hidden_dim, dtype=self.dtype,
                             name=f'embed_{t}')(x)

@@ -14,7 +14,9 @@ import jax.numpy as jnp
 import optax
 
 from ..metrics.registry_names import (SCOPE_FWD_BWD, SCOPE_PAIRS,
-                                      SCOPE_TRAIN, SCOPE_UPDATE)
+                                      SCOPE_TABLE, SCOPE_TRAIN,
+                                      SCOPE_UPDATE)
+from .tables import has_tables, is_table_path
 
 
 class TrainState(NamedTuple):
@@ -97,10 +99,32 @@ def make_loss_fn(model, num_classes: int):
   return loss_fn
 
 
+def optimizer_update(tx, grads, opt_state, params):
+  """``tx`` over the whole tree: ``(params, opt_state)`` after one update.
+  Where ``params`` hold learned node tables (``models/tables.py``) their
+  leaves — parameters, moments, the update's sweep over them — come from
+  a second call of the same update under ``table`` (``glt.train/update/
+  table`` on a timeline), the rest from the first: each call computes
+  every leaf, the compiler keeps only the leaves taken from it, and the
+  result is one update of one optimizer whatever ``tx`` couples across
+  leaves."""
+  updates, new_state = tx.update(grads, opt_state, params)
+  new_params = optax.apply_updates(params, updates)
+  if not has_tables(params):
+    return new_params, new_state
+  with jax.named_scope(SCOPE_TABLE):
+    t_updates, t_state = tx.update(grads, opt_state, params)
+    t_params = optax.apply_updates(params, t_updates)
+  pick = lambda rest, tables: jax.tree_util.tree_map_with_path(
+      lambda path, a, b: b if is_table_path(path) else a, rest, tables)
+  return pick(new_params, t_params), pick(new_state, t_state)
+
+
 def _jit_train_step(loss_fn, tx):
   """The jitted optimizer step over ``loss_fn`` (``jit_train_step`` on a
   profiler timeline), its device work under ``glt.train``: ``fwd_bwd``
-  (the value_and_grad) and ``update`` (the optimizer)."""
+  (the value_and_grad) and ``update`` (the optimizer,
+  :func:`optimizer_update`)."""
 
   @jax.jit
   @jax.named_scope(SCOPE_TRAIN)
@@ -109,8 +133,8 @@ def _jit_train_step(loss_fn, tx):
       (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(
           state.params, batch)
     with jax.named_scope(SCOPE_UPDATE):
-      updates, opt_state = tx.update(grads, state.opt_state, state.params)
-      params = optax.apply_updates(state.params, updates)
+      params, opt_state = optimizer_update(tx, grads, state.opt_state,
+                                           state.params)
     return TrainState(params, opt_state, state.step + 1), loss, acc
 
   return train_step

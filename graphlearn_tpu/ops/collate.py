@@ -86,7 +86,7 @@ def gather_rows(table, id2index, ids):
 
 
 def collate_typed_batch(node, row, col, feats, id2index, labels,
-                        input_type, label_cap=None):
+                        input_type, label_cap=None, id_types=()):
   """The typed counterpart of :func:`collate_batch`, as a traced body
   (the scanned chunk's; not jitted on its own): per node type the row
   gather from that type's device table under ``glt.collate/<ntype>``,
@@ -96,12 +96,16 @@ def collate_typed_batch(node, row, col, feats, id2index, labels,
 
   ``node`` / ``row`` / ``col``: the typed sampler's dicts; ``feats`` /
   ``id2index``: ``{ntype: table}`` for the types that carry rows
-  (``id2index[t]`` may be None); ``labels``: the seed type's table.
-  Returns ``(x, edge_index, y)``."""
+  (``id2index[t]`` may be None); ``labels``: the seed type's table;
+  ``id_types``: the node types whose rows are learned tables
+  (``models/tables.py``), for which ``x`` carries the node ids themselves
+  (pads -1) and the model gathers. Returns ``(x, edge_index, y)``."""
   x = {}
   for t, table in feats.items():
     with jax.named_scope(collate_scope(t)):
       x[t] = gather_rows(table, id2index[t], node[t])
+  for t in id_types:
+    x[t] = node[t]
   with jax.named_scope(SCOPE_COLLATE):
     ids = node[input_type]
     y = gather_rows(labels, None,

@@ -53,7 +53,7 @@ from typing import Optional
 import numpy as np
 
 from ..loader.node_loader import NodeLoader
-from ..loader.pipeline import refuse_link
+from ..loader.pipeline import refuse_link, refuse_tables
 from ..loader.scan_epoch import ScanTrainer
 from ..metrics import spans
 from ..metrics.registry_names import (SCOPE_COLLATE, SCOPE_HOT, SCOPE_LOOKUP,
@@ -173,6 +173,7 @@ class TieredScanTrainer(ScanTrainer):
                seed_labels_only: Optional[bool] = None,
                perm_seed: Optional[int] = None, max_ahead: int = 2,
                stage_timeout_s: float = 30.0, config=None):
+    refuse_tables(loader, self._NAME)
     refuse_link(loader, self._NAME)
     store = loader.data.node_features
     if not isinstance(store, TieredFeature):

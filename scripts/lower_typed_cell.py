@@ -6,7 +6,7 @@ section 2.3), no chip minute spent, no time or rate comes out of it:
     TPU_ACCELERATOR_TYPE=v5litepod-4 TPU_WORKER_HOSTNAMES=localhost \
     TPU_SKIP_MDS_QUERY=true JAX_PLATFORMS=cpu \
     python scripts/lower_typed_cell.py [reference] [chunk] [gat] [link] \
-        [mesh-generator] [mesh-cache] [mesh-chunk] [mesh-reference]
+        [table-chunk] [mesh-generator] [mesh-cache] [mesh-chunk] [mesh-reference]
 
 ``reference``: ``perfbench/reference_hetero_node.py``'s step at the shapes of
 ``rgat-igbh-small.typed-scan-exact`` (it runs on an emptied device: arguments
@@ -24,6 +24,11 @@ compiler's ``generated_code`` bytes and, where this compile-only client can
 serialise an executable, the bytes a warm start reads from the compile cache
 (PERF.md section 6, PR 34: the gate a change to the model passes before its
 first chip call).
+
+``table-chunk``: the chunk program of ``rgcn-mag.embed-scan-exact`` by
+``chunk``'s method, with the learned tables and their two Adam moments in
+the donated train state at the cell's size: its ``peak`` against the
+state's bytes says whether the compiler keeps a second copy of them.
 
 The ``mesh-*`` modes compile the programs of ``sage-papers.mesh-exact`` for
 the described 2x2, PER CHIP (add ``XLA_FLAGS=
@@ -56,6 +61,15 @@ GAT_CELL = 'gat-products.scan-exact'
 GAT_CAPS = [6528, 33664, 84736]   # that cell's set-up line (PERF.md section 4)
 LINK_CELL = 'sage-products-unsup.link-scan-exact'
 LINK_CAPS = [32512, 113152, 122880]   # set before the cell's first chip run
+TABLE_CELL = 'rgcn-mag.embed-scan-exact'
+TABLE_CAPS = {       # that cell's set-up line (PERF.md section 4)
+    'author__affiliated_with__institution': [128, 3328],
+    'author__writes__paper': [128, 33408],
+    'field_of_study__rev_has_topic__paper': [128, 142464],
+    'institution__rev_affiliated_with__author': [128, 128],
+    'paper__cites__paper': [15232, 129408],
+    'paper__has_topic__field_of_study': [9088, 37248],
+    'paper__rev_writes__author': [7808, 68992]}
 MESH_CELL = 'sage-papers.mesh-exact'
 MESH_CAPS = [8960, 62080, 192768]  # that cell's set-up line (PERF.md section 4)
 # halvings of a lookup in the cell's 1.85 M cached ids: the largest of its
@@ -300,6 +314,83 @@ def lower_chunk(cfg, traffic, one_chip):
                       int(traffic['chunk_size']), one_chip)
   print('lower_typed_cell: ' + json.dumps(dict(
       tables_bytes=tables, peak_gib=(out['peak'] or 0) / 2 ** 30,
+      chip_gib=15.75)), flush=True)
+  return out
+
+
+def lower_table_chunk(cfg, traffic, one_chip):
+  """``rgcn-mag.embed-scan-exact``'s chunk program, by ``lower_chunk``'s
+  method: traced over a graph 1/64 the size under the cell's caps, lowered
+  with the graph, the paper rows and labels as arguments at the cell's
+  size, and the train state — weights, the learned tables and both Adam
+  moments of each — at the cell's size and donated, as a run donates it."""
+  import copy
+
+  import jax
+  import jax.numpy as jnp
+
+  import graphlearn_tpu as glt
+  from graphlearn_tpu.models.tables import is_table_path
+  from perfbench.datagen_hetero_node import etype_of
+  from perfbench.families import hetero_table_node
+  real = cfg['dataset']
+  small = copy.deepcopy(cfg)
+  d = small['dataset']
+  d['node_types'] = {t: max(n // 64, 256) for t, n in d['node_types'].items()}
+  d['num_train'] = 8 * small['model']['batch_size']
+  for rel in d['relations'].values():
+    rel['edges'] //= 64
+  caps = {etype_of(k): v for k, v in TABLE_CAPS.items()}
+  calibrate = glt.sampler.estimate_hetero_frontier_caps
+  glt.sampler.estimate_hetero_frontier_caps = lambda *a, **kw: caps
+  try:
+    cell = hetero_table_node.Cell(small, traffic, lambda k, v: None)
+  finally:
+    glt.sampler.estimate_hetero_frontier_caps = calibrate
+  n_of = real['node_types']
+  # the learned tables at the cell's size: the model's parameters, the
+  # reference's initial ones and the dataset's declaration alike
+  cell.tables = {t: (n_of[t], int(real['table_dim'])) for t in cell.tables}
+  cell.model_desc['tables'] = {t: list(cell.tables[t])
+                               for t in cell.table_types}
+  cell.dataset.node_tables = dict(cell.tables)
+  model = cell.make_model(None)
+  state, tx, _ = cell.make_state(model, 0)
+  tr = glt.ScanTrainer(cell.make_loader(0), model, tx, cell.num_classes,
+                       chunk_size=int(traffic['chunk_size']))
+  sds = lambda shape, dt: jax.ShapeDtypeStruct(tuple(shape), dt,
+                                               sharding=one_chip)
+  state = jax.tree.map(lambda a: sds(a.shape, a.dtype), state)
+  e_of = {}
+  for name, rel in real['relations'].items():
+    e_of[etype_of(name)] = rel['edges'] * (2 if rel.get('undirected')
+                                           else 1)
+    if 'reverse' in rel:
+      e_of[etype_of(rel['reverse'])] = rel['edges']
+  fargs = {}
+  for et, ga in tr._sample_args().items():
+    n, e = n_of[et[0]], e_of[et]
+    lead = dict(indptr=n + 1, indices=e, meta=n)
+    fargs[et] = {k: sds((lead[k],) + a.shape[1:], a.dtype)
+                 for k, a in ga.items()}
+  feats = {t: sds((n_of[t],) + a.shape[1:], a.dtype)
+           for t, a in tr._feats.items()}
+  id2i = {t: None if a is None else sds((n_of[t],), a.dtype)
+          for t, a in tr._id2i.items()}
+  labels = sds((n_of[cell.input_type],), tr._labels.dtype)
+  steps = real['num_train'] // cell.batch
+  nbytes = lambda tree: sum(jnp.dtype(a.dtype).itemsize * math.prod(a.shape)
+                            for a in jax.tree.leaves(tree))
+  table_leaves = [a for p, a in jax.tree_util.tree_flatten_with_path(state)[0]
+                  if is_table_path(p)]
+  out = compile_chunk('jit_scan_epoch_chunk[tables]', tr, state,
+                      (fargs, feats, id2i, labels), steps, cell.batch,
+                      int(traffic['chunk_size']), one_chip)
+  print('lower_typed_cell: ' + json.dumps(dict(
+      graph_and_rows_bytes=nbytes((fargs, feats, id2i, labels)),
+      state_bytes=nbytes(state), tables_and_moments_bytes=nbytes(
+          table_leaves), peak_gib=(out['peak'] or 0) / 2 ** 30,
+      peak_less_arguments=(out['peak'] or 0) - out['argument'],
       chip_gib=15.75)), flush=True)
   return out
 
@@ -610,6 +701,9 @@ def main(argv):
   if 'link' in which:
     _, _, cfg, traffic, _ = run.load_cell(LINK_CELL, 'BENCHMARK.json')
     lower_link_chunk(cfg, traffic, one_chip)
+  if 'table-chunk' in which:
+    _, _, cfg, traffic, _ = run.load_cell(TABLE_CELL, 'BENCHMARK.json')
+    lower_table_chunk(cfg, traffic, one_chip)
 
 
 if __name__ == '__main__':
