@@ -18,7 +18,10 @@ Per hop, per shard:
   4. local fanout sample over the shard's CSR       (ops.uniform_sample_local
                                                      or weighted_sample_local;
                                                      rows found through the
-                                                     graph's index of row_ids)
+                                                     graph's index of row_ids;
+                                                     the received block drawn
+                                                     tile by tile below its
+                                                     last valid column)
   5. lax.all_to_all back                            (responses)
   6. unpermute into frontier order                  (ops.gather_from_buckets)
   7. dedup/relabel into the shard's batch           (ops.induce_next)
@@ -74,18 +77,21 @@ from ..ops.route import exchange_capacity, round8 as _round8  # noqa: E402,F401
 GRAPH_KEYS = ('row_ids', 'row_starts', 'indptr', 'indices', 'eids')
 
 
-def _local_sample(garr, flat, fm, k, key, weighted: bool, index):
-  """Shared shard-local fanout sample over this shard's stacked CSR.
-  ``index`` is the ``(shift, depth)`` ``garr['row_starts']`` was built
-  with (``DistGraph.row_index``)."""
+def _local_sample(garr, req, k, key, weighted: bool, index):
+  """Shared shard-local fanout sample over this shard's stacked CSR, of
+  the received request block ``req [buckets, cap]`` (pads negative),
+  handed over in its shape: the draw runs only below the block's last
+  valid column. Outputs ``[buckets·cap, k]``. ``index`` is the
+  ``(shift, depth)`` ``garr['row_starts']`` was built with
+  (``DistGraph.row_index``)."""
   shift, depth = index
   if weighted:
     return ops.weighted_sample_local(
         garr['row_ids'], garr['indptr'], garr['indices'], garr['wcum'],
-        flat, fm, k, key, garr['row_starts'], shift, depth)
+        req, req >= 0, k, key, garr['row_starts'], shift, depth)
   return ops.uniform_sample_local(
-      garr['row_ids'], garr['indptr'], garr['indices'], flat, fm, k, key,
-      garr['row_starts'], shift, depth)
+      garr['row_ids'], garr['indptr'], garr['indices'], req, req >= 0, k,
+      key, garr['row_starts'], shift, depth)
 
 
 def _exchange_hop_hier(garr, pb, frontier, fmask, k, key, sizes,
@@ -132,9 +138,7 @@ def _exchange_hop_hier(garr, pb, frontier, fmask, k, key, sizes,
     ok2 = ok2f & (slot2 < cap2)
     send2 = ops.scatter_to_buckets(mid, mdest, slot2, ok2, s_sz, cap2)
     req2 = jax.lax.all_to_all(send2, s_ax, 0, 0)     # [S, cap2] via DCN
-    flat = req2.reshape(-1)
-    nbrs, epos, m = _local_sample(garr, flat, flat >= 0, k, key,
-                                  weighted, index)
+    nbrs, epos, m = _local_sample(garr, req2, k, key, weighted, index)
     def back(vals, fill, dtype=None):
       r2 = jax.lax.all_to_all(vals.reshape(s_sz, cap2, k), s_ax, 0, 0)
       b2 = ops.gather_from_buckets(r2, mdest, slot2, ok2, fill=fill)
@@ -153,9 +157,7 @@ def _exchange_hop_hier(garr, pb, frontier, fmask, k, key, sizes,
     slotp, okp = ops.route_slots(dest, fmask, capacity=bf)
     send = ops.scatter_to_buckets(frontier, dest, slotp, okp, nparts, bf)
     req = jax.lax.all_to_all(send, axes, 0, 0)
-    flat = req.reshape(-1)
-    nbrs, epos, m = _local_sample(garr, flat, flat >= 0, k, key,
-                                  weighted, index)
+    nbrs, epos, m = _local_sample(garr, req, k, key, weighted, index)
     resp_n = jax.lax.all_to_all(nbrs.reshape(nparts, bf, k), axes, 0, 0)
     resp_m = jax.lax.all_to_all(m.reshape(nparts, bf, k), axes, 0, 0)
     back_n = ops.gather_from_buckets(resp_n, dest, slotp, okp)
@@ -237,11 +239,8 @@ def _exchange_hop(garr, pb, frontier, fmask, k, key, nparts: int,
       send = ops.scatter_to_buckets(frontier, dest, slot, okc, nparts,
                                     cap)
       req = jax.lax.all_to_all(send, axes, 0, 0)
-      flat = req.reshape(-1)
-      fm = flat >= 0
     with draw_scope():
-      nbrs, epos, m = _local_sample(garr, flat, fm, k, key, weighted,
-                                    index)
+      nbrs, epos, m = _local_sample(garr, req, k, key, weighted, index)
       if with_edge:
         e = jnp.where(m, garr['eids'][jnp.where(m, epos, 0)], -1)
     with x_scope():

@@ -293,8 +293,9 @@ SCOPE_ROWS = 'rows'              # the row gather behind a lookup, its mask
                                  # and the wire cast (inside glt.collate/cache
                                  # and a shard-local draw too)
 SCOPE_TILE = 'tile'              # one tile of a loop that runs only below
-                                 # the last valid row: the draw's
-                                 # (ops.uniform_sample_tiled) and, behind
+                                 # the last valid row: the draws'
+                                 # (ops.neighbor.draw_tiled: a frontier's,
+                                 # a received block's) and, behind
                                  # lookup / rows, the owners' bounded lookup
                                  # of a received block (dist_feature.
                                  # bounded_lookup) and, behind lookup, the

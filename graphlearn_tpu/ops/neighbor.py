@@ -55,8 +55,9 @@ def tiles_to_run(mask, tile: int):
   """How many tiles of ``tile`` positions along the last axis of ``mask``
   begin below its last valid one: ``ceil(last valid position / tile)``
   as an int32 scalar, 0 for an empty mask. The trip count of every loop
-  tiled by :func:`draw_tile_rows` (the draw's; the mesh feature store's
-  bounded lookup of a received ``[P, cap]`` block)."""
+  tiled by :func:`draw_tile_rows` (the draws', over a frontier or a
+  received ``[P, cap]`` block; the mesh feature store's bounded lookup of
+  such a block)."""
   n = mask.shape[-1]
   last_valid = jnp.max(jnp.where(
       mask, jnp.arange(1, n + 1, dtype=jnp.int32), 0))
@@ -91,42 +92,63 @@ def _draw_rows(indptr, indices, meta, seeds, seed_mask, u, k: int):
   return nbrs, safe_epos, mask
 
 
-def uniform_sample_tiled(indptr, indices, seeds, seed_mask, k: int, key,
-                         meta=None):
-  """:func:`uniform_sample` in tiles of :func:`draw_tile_rows` frontier
-  rows (one tile where that is 0), run only for the tiles that begin
-  below the last valid row of ``seed_mask`` (any mask: a prefix mask
-  skips its padding, a scattered one runs every tile). Rows of tiles not
-  run read what masked rows read: FILL, 0, False. The last tile is
-  clamped to end at the cap, so it may redraw rows of the one before —
-  to the same values.
+def draw_tiled(draw, seeds, seed_mask, u, tile: int):
+  """``draw(seeds [n], seed_mask [n], u [n, K]) -> (nbrs, epos, mask)``
+  (each ``[n, K]``) over ``seeds`` — a frontier ``[B]``, or a received
+  request block ``[P, cap]`` whose rows are drawn together — in tiles of
+  ``tile`` positions along the last axis, run only for the tiles that
+  begin below the last valid position of ``seed_mask`` (any mask: a
+  prefix skips its padding, a scattered one runs every tile). ``u`` is
+  the whole input's ``[seeds.size, K]`` uniforms in row-major order,
+  drawn once and sliced per tile, so every position draws what one piece
+  would. Positions of tiles not run read what masked ones read: FILL, 0,
+  False. The last tile is clamped to end at the last position, so it may
+  redraw positions of the one before — to the same values. The trip
+  count is this shard's own: no collective may sit inside ``draw``.
 
-  Returns ``(nbrs, epos, mask, tiles)``; ``tiles`` is the int32 number
-  of tiles run, ``ceil(last valid row / tile rows)``.
+  Returns ``(nbrs, epos, mask, tiles)``, the first three ``[seeds.size,
+  K]``; ``tiles`` is the int32 number of tiles run, ``ceil(last valid
+  position / tile)``.
   """
-  b = seeds.shape[0]
-  tile_rows = draw_tile_rows(b) or b
-  u = jax.random.uniform(key, (b, k))    # ONE stream over the whole cap
-  tiles = tiles_to_run(seed_mask, tile_rows)
-
-  def draw(seeds, seed_mask, u):
-    return _draw_rows(indptr, indices, meta, seeds, seed_mask, u, k)
+  n, k = seeds.shape[-1], u.shape[-1]
+  lead, ax = seeds.shape[:-1], seeds.ndim - 1
+  assert 0 < tile <= n, (tile, n)
+  u = u.reshape(lead + (n, k))
+  tiles = tiles_to_run(seed_mask, tile)
 
   def body(i, out):
     with jax.named_scope(SCOPE_TILE):
-      lo = jnp.minimum(i * tile_rows, b - tile_rows)
-      part = draw(jax.lax.dynamic_slice(seeds, (lo,), (tile_rows,)),
-                  jax.lax.dynamic_slice(seed_mask, (lo,), (tile_rows,)),
-                  jax.lax.dynamic_slice(u, (lo, 0), (tile_rows, k)))
-      return tuple(jax.lax.dynamic_update_slice(o, p, (lo, 0))
-                   for o, p in zip(out, part))
+      lo = jnp.minimum(i * tile, n - tile)
+      cut = lambda x: jax.lax.dynamic_slice_in_dim(x, lo, tile, ax)
+      part = draw(cut(seeds).reshape(-1), cut(seed_mask).reshape(-1),
+                  cut(u).reshape(-1, k))
+      return tuple(jax.lax.dynamic_update_slice_in_dim(
+          o, p.reshape(lead + (tile, k)), lo, ax) for o, p in zip(out, part))
 
-  nbrs, epos, mask = jax.eval_shape(draw, seeds, seed_mask, u)
-  init = (jnp.full(nbrs.shape, FILL, nbrs.dtype),
-          jnp.zeros(epos.shape, epos.dtype),
-          jnp.zeros(mask.shape, mask.dtype))
-  nbrs, epos, mask = jax.lax.fori_loop(0, tiles, body, init)
-  return nbrs, epos, mask, tiles
+  shapes = jax.eval_shape(draw, seeds.reshape(-1), seed_mask.reshape(-1),
+                          u.reshape(-1, k))
+  # inside shard_map the carry varies over the axes the block does
+  vma = tuple(jax.typeof(seeds).vma)
+  init = tuple(jax.lax.pcast(jnp.full(lead + (n, k), fill, s.dtype), vma,
+                             to='varying')
+               for s, fill in zip(shapes, (FILL, 0, False)))
+  out = jax.lax.fori_loop(0, tiles, body, init)
+  return tuple(o.reshape(-1, k) for o in out) + (tiles,)
+
+
+def uniform_sample_tiled(indptr, indices, seeds, seed_mask, k: int, key,
+                         meta=None):
+  """:func:`uniform_sample` in tiles of :func:`draw_tile_rows` frontier
+  rows (one tile where that is 0) by :func:`draw_tiled`.
+
+  Returns ``(nbrs, epos, mask, tiles)``; ``tiles`` is the int32 number of
+  tiles run, ``ceil(last valid row / tile rows)``.
+  """
+  b = seeds.shape[0]
+  u = jax.random.uniform(key, (b, k))    # ONE stream over the whole cap
+  return draw_tiled(
+      lambda s, m, u: _draw_rows(indptr, indices, meta, s, m, u, k),
+      seeds, seed_mask, u, draw_tile_rows(b) or b)
 
 
 @functools.partial(jax.jit, static_argnames=('k',))
@@ -481,6 +503,22 @@ def _local_rows(row_ids, starts, seeds, seed_mask, shift: int, depth: int):
     return found & seed_mask, pos
 
 
+def _draw_block(draw, seeds, seed_mask, k: int, key):
+  """A shard-local draw over a received request block ``seeds [P, cap]``
+  (a 1-D vector is a block of one row): in tiles of
+  :func:`draw_tile_rows` ``(cap)`` columns by :func:`draw_tiled`, only
+  below the block's last valid column, or in one piece where that is 0.
+  ``ops.route_slots`` ranks a request within its destination, so every
+  received bucket is a valid prefix followed by the pad -1, and a bucket
+  sized to be loss-free is mostly pads. The outputs are ``[P·cap, K]``,
+  one piece's element for element."""
+  u = jax.random.uniform(key, (seeds.size, k))   # ONE stream over the block
+  tile = draw_tile_rows(seeds.shape[-1])
+  if not tile:
+    return draw(seeds.reshape(-1), seed_mask.reshape(-1), u)
+  return draw_tiled(draw, seeds, seed_mask, u, tile)[:3]
+
+
 @functools.partial(jax.jit, static_argnames=('k', 'shift', 'depth'))
 def uniform_sample_local(row_ids, indptr_loc, indices, seeds, seed_mask,
                          k: int, key, starts, shift: int, depth: int):
@@ -495,22 +533,20 @@ def uniform_sample_local(row_ids, indptr_loc, indices, seeds, seed_mask,
   dist_neighbor_sampler.py:624). Seeds not owned by this shard come back
   masked out.
 
-  Same output contract as :func:`uniform_sample`.
+  ``seeds`` / ``seed_mask`` are the received request block ``[P, cap]``
+  (or a vector), drawn tile by tile below its last valid column
+  (:func:`_draw_block`). Same output contract as :func:`uniform_sample`,
+  over the ``P·cap`` slots in row-major order.
   """
-  b = seeds.shape[0]
-  found, pos = _local_rows(row_ids, starts, seeds, seed_mask, shift, depth)
-  start = indptr_loc[pos]
-  deg = jnp.where(found, indptr_loc[pos + 1] - start, 0)
-  u = jax.random.uniform(key, (b, k))
-  rand_off = jnp.floor(u * deg[:, None].astype(u.dtype)).astype(jnp.int32)
-  rand_off = jnp.minimum(rand_off, jnp.maximum(deg[:, None] - 1, 0))
-  seq_off = jnp.arange(k, dtype=jnp.int32)[None, :]
-  offsets = jnp.where(deg[:, None] > k, rand_off, seq_off)
-  mask = found[:, None] & (offsets < deg[:, None])
-  epos = start[:, None] + offsets
-  safe_epos = jnp.where(mask, epos, 0)
-  nbrs = jnp.where(mask, indices[safe_epos], FILL)
-  return nbrs, jnp.where(mask, epos, 0), mask
+  def draw(seeds, seed_mask, u):
+    found, pos = _local_rows(row_ids, starts, seeds, seed_mask, shift, depth)
+    start = indptr_loc[pos]
+    deg = jnp.where(found, indptr_loc[pos + 1] - start, 0)
+    epos, mask = draw_offsets(start, deg, found, u, k)
+    safe_epos = jnp.where(mask, epos, 0)
+    return jnp.where(mask, indices[safe_epos], FILL), safe_epos, mask
+
+  return _draw_block(draw, seeds, seed_mask, k, key)
 
 
 @functools.partial(jax.jit, static_argnames=('k', 'shift', 'depth'))
@@ -524,40 +560,42 @@ def weighted_sample_local(row_ids, indptr_loc, indices, row_cumsum, seeds,
   sampler/neighbor_sampler.py:86-91 — here the weighted path works in the
   sharded engine too). ``row_cumsum`` is the per-shard row-restarting
   cumulative weight array (:func:`build_row_cumsum` over the local CSR).
-  Same output contract (and the same row lookup) as
+  Same output contract, the same row lookup and the same tiles as
   :func:`uniform_sample_local`.
   """
-  b = seeds.shape[0]
-  found, pos = _local_rows(row_ids, starts, seeds, seed_mask, shift, depth)
-  start = indptr_loc[pos]
-  end = indptr_loc[pos + 1]
-  deg = jnp.where(found, end - start, 0)
-  end = start + deg
-  total = row_cumsum[jnp.maximum(end - 1, 0)]
-  total = jnp.where(deg > 0, total, 1.0)
-  u = jax.random.uniform(key, (b, k)) * total[:, None]
+  def draw(seeds, seed_mask, u):
+    b = seeds.shape[0]
+    found, pos = _local_rows(row_ids, starts, seeds, seed_mask, shift, depth)
+    start = indptr_loc[pos]
+    end = indptr_loc[pos + 1]
+    deg = jnp.where(found, end - start, 0)
+    end = start + deg
+    total = row_cumsum[jnp.maximum(end - 1, 0)]
+    total = jnp.where(deg > 0, total, 1.0)
+    u = u * total[:, None]
 
-  lo = jnp.broadcast_to(start[:, None], (b, k))
-  hi = jnp.broadcast_to(end[:, None], (b, k))
+    lo = jnp.broadcast_to(start[:, None], (b, k))
+    hi = jnp.broadcast_to(end[:, None], (b, k))
 
-  def body(_, carry):
-    lo, hi = carry
-    mid = (lo + hi) // 2
-    go_right = row_cumsum[jnp.clip(mid, 0, row_cumsum.shape[0] - 1)] < u
-    lo = jnp.where(go_right, mid + 1, lo)
-    hi = jnp.where(go_right, hi, mid)
-    return lo, hi
+    def body(_, carry):
+      lo, hi = carry
+      mid = (lo + hi) // 2
+      go_right = row_cumsum[jnp.clip(mid, 0, row_cumsum.shape[0] - 1)] < u
+      lo = jnp.where(go_right, mid + 1, lo)
+      hi = jnp.where(go_right, hi, mid)
+      return lo, hi
 
-  lo, hi = jax.lax.fori_loop(0, 32, body, (lo, hi))
-  wpos = jnp.minimum(lo, jnp.maximum(end[:, None] - 1, 0))
+    lo, hi = jax.lax.fori_loop(0, 32, body, (lo, hi))
+    wpos = jnp.minimum(lo, jnp.maximum(end[:, None] - 1, 0))
 
-  seq_off = jnp.arange(k, dtype=start.dtype)[None, :]
-  epos = jnp.where(deg[:, None] > k, wpos, start[:, None] + seq_off)
-  mask = found[:, None] & (
-      jnp.where(deg[:, None] > k, 0, seq_off) < deg[:, None])
-  safe_epos = jnp.where(mask, epos, 0)
-  nbrs = jnp.where(mask, indices[safe_epos], FILL)
-  return nbrs, jnp.where(mask, epos, 0), mask
+    seq_off = jnp.arange(k, dtype=start.dtype)[None, :]
+    epos = jnp.where(deg[:, None] > k, wpos, start[:, None] + seq_off)
+    mask = found[:, None] & (
+        jnp.where(deg[:, None] > k, 0, seq_off) < deg[:, None])
+    safe_epos = jnp.where(mask, epos, 0)
+    return jnp.where(mask, indices[safe_epos], FILL), safe_epos, mask
+
+  return _draw_block(draw, seeds, seed_mask, k, key)
 
 
 def edge_in_csr(indptr, indices, rows, cols):

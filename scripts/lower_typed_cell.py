@@ -188,6 +188,12 @@ def report(name, compiled, collectives=False):
     loops = {rt.tile_loop(scope_reduce.scope_path({'args': {'tf_op': n}}))
              for n in set(re.findall(r'op_name="([^"]*)"', text))}
     out['mesh_tile_loops'] = sorted('/'.join(l) for l in loops - {None})
+    # the shard-local draws' tile loops, by hop and cond branch, as
+    # local_draw_tiles_per_step tells them apart
+    from perfbench.layer_metrics import local_draw_tiles_per_step as dt
+    loops = {dt.tile_loop(scope_reduce.scope_path({'args': {'tf_op': n}}))
+             for n in set(re.findall(r'op_name="([^"]*)"', text))}
+    out['draw_tile_loops'] = sorted('/'.join(l) for l in loops - {None})
   # `temp` adds up the temporaries of inner loops that are never alive
   # together; `peak` is what the program needs at once, arguments included
   out['argument_plus_temp_gb'] = (out['argument'] + out['temp']) / 1e9

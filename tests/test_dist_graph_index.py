@@ -31,6 +31,8 @@ def uniform_sample_searched(row_ids, indptr_loc, indices, seeds, seed_mask,
                             k, key, *_index):
   import jax
   import jax.numpy as jnp
+  # a received block [P, cap] is drawn as its P·cap slots in order
+  seeds, seed_mask = seeds.reshape(-1), seed_mask.reshape(-1)
   found, pos = searched_rows(row_ids, seeds, seed_mask)
   start = indptr_loc[pos]
   deg = jnp.where(found, indptr_loc[pos + 1] - start, 0)
@@ -49,6 +51,7 @@ def weighted_sample_searched(row_ids, indptr_loc, indices, row_cumsum,
                              seeds, seed_mask, k, key, *_index):
   import jax
   import jax.numpy as jnp
+  seeds, seed_mask = seeds.reshape(-1), seed_mask.reshape(-1)
   b = seeds.shape[0]
   found, pos = searched_rows(row_ids, seeds, seed_mask)
   start = indptr_loc[pos]

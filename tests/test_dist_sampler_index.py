@@ -86,3 +86,46 @@ def test_sampler_through_the_index_is_the_searched_sampler(
       if 'edge_mask' in k:
         edges += int(x.sum())
   assert edges > 0
+
+
+@pytest.mark.parametrize('typed', [False, True], ids=['homo', 'typed'])
+@pytest.mark.parametrize('weighted', [False, True],
+                         ids=['uniform', 'weighted'])
+@pytest.mark.parametrize('mesh_kind', ['flat', 'slice_chip'])
+def test_sampler_drawing_tile_by_tile_is_the_one_piece_sampler(
+    mesh_kind, weighted, typed, monkeypatch):
+  """The shard-local draws take each received block in its shape and draw
+  it in tiles below its last valid column: with a tile rule that tiles
+  every block of the tiny graph, the batches are the ones the one-piece
+  draws give on the same keys, bit for bit."""
+  import jax
+  from graphlearn_tpu.ops import neighbor
+  mesh = _mesh(mesh_kind)
+  graph, seeds, fanouts = (_typed if typed else _homo)(weighted)
+  asked = []
+
+  def sample(rule):
+    def tile_rows(cap):
+      asked.append((cap, rule(cap)))
+      return rule(cap)
+    monkeypatch.setattr(neighbor, 'draw_tile_rows', tile_rows)
+    jax.clear_caches()           # the draws are traced again under the rule
+    sampler = glt.distributed.DistNeighborSampler(
+        graph, fanouts, mesh, with_edge=True, with_weight=weighted, seed=9)
+    return [_leaves(sampler.sample_from_nodes(seeds)) for _ in range(2)]
+
+  try:
+    tiled = sample(lambda cap: 4 if cap >= 8 else 0)
+    assert any(t for _, t in asked)          # the loop was traced
+    whole = sample(lambda cap: 0)
+  finally:
+    monkeypatch.undo()
+    jax.clear_caches()
+  edges = 0
+  for a, b in zip(tiled, whole):
+    assert [k for k, _ in a] == [k for k, _ in b]
+    for (k, x), (_, y) in zip(a, b):
+      assert x.dtype == y.dtype and x.tobytes() == y.tobytes(), k
+      if 'edge_mask' in k:
+        edges += int(x.sum())
+  assert edges > 0

@@ -146,6 +146,59 @@ def test_the_chunk_names_every_part_and_is_the_same_program_without_them():
                  for q in re.findall(r'"([^"]*)"', stubbed_located))
 
 
+def test_a_tiled_draw_keeps_its_row_lookup_under_rows(monkeypatch):
+  """With a tile rule that tiles the tiny chunk's received blocks, the
+  draws' ops sit in a ``tile`` body under ``glt.sample/hop<h>/draw`` and
+  the row lookup's still carry ``rows``: ``local_draw_ms`` and
+  ``local_draw_rows_ms`` read the same parts as over one piece, and
+  ``local_draw_tiles_per_step`` counts the body's ops and not those of the
+  halvings nested in it (the compiled program's ``op_name`` s, which the
+  profiler's timeline carries)."""
+  import jax
+  from graphlearn_tpu.ops import neighbor
+  from perfbench import mesh_parts_reduce as parts
+  from perfbench.layer_metrics import local_draw_tiles_per_step as tiles
+  monkeypatch.setattr(neighbor, 'draw_tile_rows', lambda cap: 1)
+  jax.clear_caches()
+  try:
+    trainer, state = _dist_trainer()
+    got = {}
+
+    def capture(c, k, *args):
+      got['k'], got['args'] = k, args
+      raise Stop
+
+    trainer._dispatch_chunk = capture
+    with pytest.raises(Stop):
+      trainer.run_epoch(state)
+    text = trainer._chunk_fn_for(got['k']).lower(
+        trainer._shard_tree, trainer._repl_tree, *got['args']).compile(
+        ).as_text()
+  finally:
+    monkeypatch.undo()
+    jax.clear_caches()
+  paths = set()
+  for name in re.findall(r'op_name="([^"]*)"', text):
+    comps = name.split('/')
+    at = [i for i, c in enumerate(comps) if c.startswith('glt.')]
+    if at and comps[at[0]] == 'glt.sample':
+      paths.add(tuple(comps[at[0]:]))
+  in_tile = [p for p in paths if names.SCOPE_TILE in p]
+  assert in_tile
+  for p in in_tile:
+    group, part = parts.part_of(p)
+    assert re.fullmatch(r'glt\.sample/hop\d/draw', group), p
+    assert part == (names.SCOPE_ROWS if names.SCOPE_ROWS in p
+                    else parts.UNSPLIT), p
+  rows = [p for p in in_tile if names.SCOPE_ROWS in p]
+  assert rows and all(p.index(names.SCOPE_ROWS) > p.index(names.SCOPE_TILE)
+                      for p in rows)
+  counted = [p for p in in_tile if tiles.tile_loop(p)]
+  nested = [p for p in rows if 'while' in p[p.index(names.SCOPE_TILE):]]
+  assert counted and nested and not any(tiles.tile_loop(p) for p in nested)
+  assert {tiles.tile_loop(p)[0] for p in counted} >= {'glt.sample/hop1/draw'}
+
+
 # ------------------------- (ii) the tables agree (tests/test_metrics.py's)
 
 def test_the_new_names_are_registered_and_documented():
