@@ -14,8 +14,10 @@ system — feature rows are ~F x wider than sampler id traffic, PERF.md
      §UnifiedTensor; reference data/feature.py split_ratio + hotness
      reorder): the globally hottest ``cache_rows`` rows live replicated on
      every shard next to its owned partition. Requested ids are split
-     hit/miss INSIDE the program by a lookup in the sorted cached id
-     set; hits gather locally and never touch the interconnect.
+     hit/miss INSIDE the program by two reads of a rank directory over
+     the cached ids (ops/rank_directory.py: a word of membership bits
+     and the rank below it); hits gather locally and never touch the
+     interconnect.
   2. **Miss-only bucketed exchange**: only cache misses — deduped within
      the batch (one request per unique id, response scattered back to all
      its slots) — enter the all_to_all, packed into per-destination
@@ -37,12 +39,12 @@ system — feature rows are ~F x wider than sampler id traffic, PERF.md
      half width and upcasts to the storage dtype after
      ``gather_from_buckets`` — independent of hit rate.
 
-Every position in a sorted id table — the owners' lookup of the miss
-buckets in ``feat_ids``, the cache split in ``cache_ids``, set-up's fill
-of the cache — is found through the table's two-level index
-(ops/sorted_index.py): built once when the store is, on the host for
-host tables and on the devices for device shards, and handed to the
-programs beside the rows it leads to.
+Every position in the sorted ``feat_ids`` — the owners' lookup of the
+miss buckets, set-up's fill of the cache — is found through the table's
+two-level index (ops/sorted_index.py). Both the index and the cache's
+directory are built once when the store is, on the host for host tables
+and on the devices for device shards, and handed to the programs beside
+the rows they lead to.
 
 On-device hit/miss/overflow counters ride the same program (a [P, 4]
 accumulator threaded through every ``get``), so hit rates are observable
@@ -54,7 +56,7 @@ from typing import Optional
 import numpy as np
 
 from .. import ops
-from ..ops import sorted_index
+from ..ops import rank_directory, sorted_index
 from ..metrics.registry_names import (SCOPE_CACHE, SCOPE_COLLATE,
                                       SCOPE_DEDUP, SCOPE_EXCHANGE,
                                       SCOPE_FANOUT, SCOPE_LOOKUP,
@@ -258,17 +260,17 @@ class DistFeature:
     self._init_lookup(split_ratio, cache_rows, wire_dtype, bucket_frac,
                       dedup)
     h = self.cache_rows
+    n_total = int(self.feature_pb.shape[0])
     if h > 0:
       self.cache_ids = self._hot_ids(hotness, h)
       self.cache_feats = self.cpu_get(self.cache_ids)
+      self._cache_dir = rank_directory.build_directory_host(self.cache_ids,
+                                                            n_total)
     else:
       self.cache_ids = None
       self.cache_feats = None
-    n_total = int(self.feature_pb.shape[0])
     self._set_indexes(
-        sorted_index.build_sorted_index_host(self.feat_ids, n_total),
-        sorted_index.build_sorted_index_host(self._cache_tables()[0],
-                                             n_total))
+        sorted_index.build_sorted_index_host(self.feat_ids, n_total))
 
   def _init_lookup(self, split_ratio, cache_rows, wire_dtype, bucket_frac,
                    dedup):
@@ -294,29 +296,31 @@ class DistFeature:
     self._fns = {}
 
   def _cache_tables(self):
-    """The host cache ``(ids [H], rows [H, F])``; a store with no cache
-    feeds its programs a one-entry pad table that matches no id."""
+    """The host cache ``((words [W], rank [W]), rows [H, F])``: its rank
+    directory and its rows. A store with no cache feeds its programs a
+    one-entry pad directory and row, which no lookup reads."""
     if self.cache_rows:
-      return self.cache_ids, self.cache_feats
-    return (np.full((1,), INT32_MAX, np.int32),
+      return self._cache_dir, self.cache_feats
+    return ((np.zeros((1,), np.uint32), np.zeros((1,), np.int32)),
             np.zeros((1, self.feature_dim), self.storage_dtype))
 
-  def _set_indexes(self, row_index, cache_index, shared_rows=False):
-    """Keep the two-level indexes of ``feat_ids`` and ``cache_ids``
+  def _set_indexes(self, row_index, shared_rows=False):
+    """Keep the two-level index of ``feat_ids``
     (``ops.sorted_index.SortedIndex``: the lookup programs are traced
-    with their ``shift`` and ``depth``) and publish what THIS store
-    built over a real table — the halvings a lookup runs and the bytes
-    a chip holds for them. Set once here, never per batch."""
+    with its ``shift`` and ``depth``) and publish what THIS store built
+    — the halvings a row lookup runs, and the bytes a chip holds for
+    that index and for the cache's rank directory
+    (``ops.rank_directory``: 4 bytes an entry). Set once here, never per
+    batch."""
     from .. import metrics
-    self._row_index, self._cache_index = row_index, cache_index
+    self._row_index = row_index
     nbytes = 0
     if not shared_rows:
       metrics.set_gauge('dist_feature.index_depth.rows', row_index.depth)
       nbytes += 4 * int(row_index.starts.shape[-1])
     if self.cache_rows:
-      metrics.set_gauge('dist_feature.index_depth.cache',
-                        cache_index.depth)
-      nbytes += 4 * int(cache_index.starts.shape[-1])
+      nbytes += 4 * sum(rank_directory.directory_sizes(
+          self.feature_pb.shape[0]))
     if nbytes:
       metrics.set_gauge('dist_feature.index_bytes', nbytes)
 
@@ -359,8 +363,8 @@ class DistFeature:
     but the owner add zero). ``feature_pb`` stays a host array (the
     routing book; ``pb_dev`` is its replicated placement where the
     caller already made it); :meth:`cpu_get` fetches from the devices.
-    The two-level indexes of ``feat_ids`` and of the cached ids are
-    built on the devices too; ``row_index`` is another store's
+    The two-level index of ``feat_ids`` and the cache's rank directory
+    are built on the devices too; ``row_index`` is another store's
     ``_row_index`` over the SAME ``feat_ids`` (a label store shares the
     feature store's, as it shares the book)."""
     import jax
@@ -404,29 +408,22 @@ class DistFeature:
       cache_feats = _gather_replicated_fn(
           mesh, feats.dtype, row_index.shift, row_index.depth)(
               feat_ids, row_index.starts, feats, cache_ids)
+      # one program over the replicated cache ids
+      record_dispatch('dist_feature.build_index')
+      words, rank = jax.jit(
+          lambda t: rank_directory.build_directory(t, n_total))(cache_ids)
       self.cache_ids = np.asarray(cache_ids)
     else:
-      cache_ids = global_device_put(np.full((1,), INT32_MAX, np.int32),
-                                    repl)
-      cache_feats = global_device_put(
-          np.zeros((1, self._fdim), self.storage_dtype), repl)
+      (words, rank), cache_feats = jax.tree.map(
+          lambda a: global_device_put(a, repl), self._cache_tables())
       self.cache_ids = None
     self.cache_feats = None                # on the devices only
-    # one program over the replicated cache ids; its largest bucket is
-    # the one scalar set-up fetches
-    shift = sorted_index.index_shift(cache_ids.shape[0], n_total)
-    record_dispatch('dist_feature.build_index')
-    starts, big = jax.jit(
-        lambda t: sorted_index.bucket_starts(t, n_total, shift))(cache_ids)
-    cache_index = sorted_index.SortedIndex(
-        starts, shift, sorted_index.index_depth(jax.device_get(big)))
-    self._set_indexes(row_index, cache_index, shared_rows)
+    self._set_indexes(row_index, shared_rows)
     self._dev = dict(
         feat_ids=feat_ids, feat_starts=row_index.starts, feats=feats,
         feature_pb=pb_dev if pb_dev is not None else global_device_put(
             self.feature_pb.astype(np.int32), repl),
-        cache_ids=cache_ids, cache_starts=cache_index.starts,
-        cache_feats=cache_feats)
+        cache_words=words, cache_rank=rank, cache_feats=cache_feats)
     return self
 
   def _init_storage(self, feat_parts, dtype):
@@ -465,37 +462,39 @@ class DistFeature:
 
   def _routing_arrays(self) -> dict:
     """A host-built store's routing structures placed on the mesh: the
-    sorted id tables and their indexes, the book, the hot cache —
-    everything a lookup program takes but the row payload."""
+    sorted id tables and their index, the book, the hot cache and its
+    directory — everything a lookup program takes but the row payload."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from ..utils import global_device_put
     shard = NamedSharding(self.mesh, P(tuple(self.mesh.axis_names)))
     repl = NamedSharding(self.mesh, P())
-    cache_ids, cache_feats = self._cache_tables()
+    (words, rank), cache_feats = self._cache_tables()
     return dict(
         feat_ids=global_device_put(self.feat_ids, shard),
         feat_starts=global_device_put(self._row_index.starts, shard),
         feature_pb=global_device_put(self.feature_pb.astype(np.int32),
                                      repl),
-        cache_ids=global_device_put(cache_ids, repl),
-        cache_starts=global_device_put(self._cache_index.starts, repl),
+        cache_words=global_device_put(words, repl),
+        cache_rank=global_device_put(rank, repl),
         cache_feats=global_device_put(cache_feats, repl))
 
   # device_arrays()' entries a lookup program takes, by placement
   SHARD_KEYS = ('feat_ids', 'feat_starts', 'feats')
-  REPL_KEYS = ('feature_pb', 'cache_ids', 'cache_starts', 'cache_feats')
+  REPL_KEYS = ('feature_pb', 'cache_words', 'cache_rank', 'cache_feats')
 
   @staticmethod
   def table_args(shard_view: dict, repl_view: dict, feats=None) -> tuple:
     """The five table arguments of a :meth:`_shard_body` program out of
     per-shard views of :meth:`device_arrays`' entries: rows travel with
-    the index that finds them, ``(starts, rows)``. ``feats`` replaces
-    the row payload (the slab path's ``(hot, slab_pos, slab_rows)``)."""
+    what finds their position, ``(starts, rows)`` behind the sorted
+    ``feat_ids`` and ``(rank, rows)`` behind the cache's membership
+    words. ``feats`` replaces the row payload (the slab path's ``(hot,
+    slab_pos, slab_rows)``)."""
     if feats is None:
       feats = shard_view['feats']
     return (shard_view['feat_ids'], (shard_view['feat_starts'], feats),
-            repl_view['feature_pb'], repl_view['cache_ids'],
-            (repl_view['cache_starts'], repl_view['cache_feats']))
+            repl_view['feature_pb'], repl_view['cache_words'],
+            (repl_view['cache_rank'], repl_view['cache_feats']))
 
   # ------------------------------------------------------------ stats
   def _stats_dev(self):
@@ -602,10 +601,10 @@ class DistFeature:
     and thread the [4] stats row through their own carry.
 
     Returns ``body(feat_ids [n], (feat_starts, feats [n, F] or
-    [1, n, F]), pb, cache_ids, (cache_starts, cache_feats), stats_row
+    [1, n, F]), pb, cache_words, (cache_rank, cache_feats), stats_row
     [4], ids [b], mask [b]) -> (rows [b, F], new_stats_row [4])``: the
-    rows of each table arrive behind the ``starts`` of the index that
-    finds them (:meth:`table_args` packs the five table arguments from
+    rows of each table arrive behind what finds their position
+    (:meth:`table_args` packs the five table arguments from
     :meth:`device_arrays`' entries). Must be traced on this store's mesh
     (the exchange collectives run over every mesh axis).
 
@@ -632,7 +631,6 @@ class DistFeature:
     dedup = self.dedup
     bucket_frac = self.bucket_frac
     hit_est = self._cache_frac
-    _, cshift, cdepth = self._cache_index
     # collectives/specs over every mesh axis: works identically on the
     # flat ('g',) mesh and a 2-axis ('slice', 'chip') mesh
     ax = tuple(self.mesh.axis_names)
@@ -764,15 +762,15 @@ class DistFeature:
       return rows, ovf
 
     @jax.named_scope(SCOPE_COLLATE)
-    def body(feat_ids, feats, pb, cache_ids, cache_feats, stats, ids,
+    def body(feat_ids, feats, pb, cache_words, cache_feats, stats, ids,
              mask):
       safe = jnp.maximum(ids, 0)
-      cache_starts, cache_feats = cache_feats
+      cache_rank, cache_feats = cache_feats
       with jax.named_scope(SCOPE_CACHE):
         if h > 0:
           with jax.named_scope(SCOPE_LOOKUP):
-            in_cache, cpos = sorted_index.indexed_membership(
-                cache_ids, cache_starts, safe, cshift, cdepth)
+            in_cache, cpos = rank_directory.directory_membership(
+                cache_words, cache_rank, safe, h)
             is_hit = mask & in_cache
           with jax.named_scope(SCOPE_ROWS):
             out_hit = jnp.where(is_hit[:, None], cache_feats[cpos], 0)

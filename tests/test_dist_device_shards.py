@@ -88,12 +88,12 @@ def test_device_built_shards_equal_host_built_bit_for_bit(world):
   assert np.array_equal(dev.node_features.cache_ids,
                         host.node_features.cache_ids)
   assert dev.node_features.feats is None and dev.graph.indices is None
-  # the indexes a device built are the host's (their starts were compared
-  # with the tables above); the label store shares the rows' index
-  for name in ('_row_index', '_cache_index'):
-    a, b = getattr(host.node_features, name), getattr(dev.node_features,
-                                                      name)
-    assert (a.shift, a.depth) == (b.shift, b.depth), name
+  # the index and the cache's directory a device built are the host's
+  # (their tables were compared above); the label store shares the rows'
+  # index
+  a, b = host.node_features._row_index, dev.node_features._row_index
+  assert (a.shift, a.depth) == (b.shift, b.depth)
+  assert {'cache_words', 'cache_rank'} <= set(fa)
   assert dev.node_labels._row_index is dev.node_features._row_index
   assert (dev.node_labels.device_arrays()['feat_starts']
           is fb['feat_starts'])
@@ -127,7 +127,7 @@ def test_cache_selection_by_host_vector_or_none(world, hotness):
   got = glt.distributed.DistFeature.from_device_shards(
       mesh, world['node_pb'], fa['feat_ids'], fa['feats'], cache_rows=17,
       hotness=hot).device_arrays()
-  for k in ('cache_ids', 'cache_feats'):
+  for k in ('cache_words', 'cache_rank', 'cache_feats'):
     assert np.asarray(want[k]).tobytes() == np.asarray(got[k]).tobytes(), k
 
 

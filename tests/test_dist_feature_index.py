@@ -1,6 +1,7 @@
 """The mesh feature store finds every position through its two-level
-indexes (ISSUE 36): same rows as the host's exact gather under any book,
-no whole-table ``searchsorted`` left in the lookup program, and the index
+index over ``feat_ids`` and the hot cache's rank directory: same rows as
+the host's exact gather under any book, no whole-table ``searchsorted``
+and no halving over the cached ids left in the lookup program, and what
 a store built is on record.
 """
 import re
@@ -89,6 +90,13 @@ def test_slab_get_is_cpu_get_bit_for_bit(book, cache_rows, tmp_path):
                                    feat[np.maximum(ids, 0)], 0).tobytes()
 
 
+def _while_tables(text):
+  """Lengths of the 1-D int32 tables every ``while`` of a lowered
+  program's text carries: a halving loop carries the table it bisects."""
+  return {int(n) for head in re.findall(r'stablehlo\.while\(.*', text)
+          for n in re.findall(r'tensor<(\d+)xi32>', head.split(') : ')[-1])}
+
+
 def _searches(text):
   """Operand lengths ``(table, queries)`` of every ``searchsorted`` call
   in a lowered program's text."""
@@ -108,10 +116,22 @@ def test_no_whole_table_search_left_in_the_lookup_program(tmp_path):
   probe = jax.jit(searchsorted_membership).lower(
       np.arange(9, dtype=np.int32), ids[0]).as_text()
   assert _searches(probe) == [(9, b)]
+  # and a halving loop over a table of 23 ids where there is one
+  from graphlearn_tpu.ops import sorted_index
+  table = np.arange(0, N, 9, dtype=np.int32)[:23]
+  idx = sorted_index.build_sorted_index_host(table, N)
+  probe = jax.jit(lambda t, s, q: sorted_index.indexed_membership(
+      t, s, q, idx.shift, idx.depth)).lower(table, idx.starts,
+                                            ids[0]).as_text()
+  assert idx.depth >= 1 and 23 in _while_tables(probe)
   df = DistFeature(P, parts, pb, _mesh('flat'), cache_rows=23, hotness=hot)
   text = jax.jit(df._build_fn(b)).lower(ids, ids >= 0).as_text()
   df.reset_stats()                   # the lowering left a tracer there
   assert 'all_to_all' in text and 'searchsorted' not in text
+  # the cache split reads its directory: no loop over the 23 cached ids
+  # (the rows' halvings still carry each shard's feat_ids)
+  assert df.n_max in _while_tables(text)
+  assert df.cache_rows not in _while_tables(text)
   # the slab path keeps ONE search: over the staged position list, which
   # has no id space to index; none over feat_ids or cache_ids
   tdf = TieredDistFeature(P, parts, pb, _mesh('flat'),
@@ -132,34 +152,34 @@ def test_no_whole_table_search_left_in_the_lookup_program(tmp_path):
 
 
 def test_a_built_store_publishes_its_index():
-  """``dist_feature.index_depth.{rows,cache}`` and ``.index_bytes``: set
-  when the store builds its indexes, read without touching a device."""
+  """``dist_feature.index_depth.rows`` and ``.index_bytes``: set when the
+  store builds its index and its cache's directory, read without touching
+  a device."""
   from graphlearn_tpu import metrics
   from graphlearn_tpu.ops import sorted_index
-  names = ('dist_feature.index_depth.rows', 'dist_feature.index_depth.cache',
-           'dist_feature.index_bytes')
+  names = ('dist_feature.index_depth.rows', 'dist_feature.index_bytes')
   for name in names:
     metrics.set_gauge(name, -1)
   pb, _, parts, hot = _world('random')
   df = DistFeature(P, parts, pb, _mesh('flat'), cache_rows=23, hotness=hot)
-  rows, cache = df._row_index, df._cache_index
+  rows = df._row_index
+  (words, rank), cache_rows = df._cache_tables()
   assert rows.shift == sorted_index.index_shift(df.n_max, N)
-  assert cache.shift == sorted_index.index_shift(23, N)
   assert rows.starts.shape == (P, (N >> rows.shift) + 2)
-  assert cache.starts.shape == ((N >> cache.shift) + 2,)
+  assert words.shape == (N // 16 + 1,) and rank.shape == (1,)
+  assert cache_rows.shape == (23, F)
   assert metrics.gauge(names[0]).value == rows.depth >= 1
-  assert metrics.gauge(names[1]).value == cache.depth >= 1
-  assert metrics.gauge(names[2]).value == 4 * (
-      rows.starts.shape[1] + cache.starts.shape[0])
+  assert metrics.gauge(names[1]).value == 4 * (
+      rows.starts.shape[1] + words.shape[0] + rank.shape[0])
   # a store with no cache publishes its rows' index alone
   for name in names:
     metrics.set_gauge(name, -1)
   bare = DistFeature(P, parts, pb, _mesh('flat'))
   assert metrics.gauge(names[0]).value == bare._row_index.depth
-  assert metrics.gauge(names[1]).value == -1
-  assert metrics.gauge(names[2]).value == 4 * bare._row_index.starts.shape[1]
-  # and its pad table resolves to a single bucket, same code
-  assert bare._cache_index.starts.shape == (2,)
+  assert metrics.gauge(names[1]).value == 4 * bare._row_index.starts.shape[1]
+  # and its pad directory is one entry, a branch no lookup traces
+  (words, rank), _ = bare._cache_tables()
+  assert words.shape == rank.shape == (1,)
   assert (np.asarray(bare.get(_requests())).tobytes()
           == _want(bare, _requests()).tobytes())
 
@@ -171,6 +191,5 @@ def test_the_index_names_are_registered_and_documented():
   root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
   with open(os.path.join(root, 'docs', 'observability.md')) as f:
     doc = f.read()
-  for name in ('dist_feature.index_depth.rows',
-               'dist_feature.index_depth.cache', 'dist_feature.index_bytes'):
+  for name in ('dist_feature.index_depth.rows', 'dist_feature.index_bytes'):
     assert f'`{name}`' in doc, name
