@@ -50,16 +50,6 @@ PRODUCTS = dict(
 LOSS_ATOL = 1e-4
 PARAM_ATOL = 1e-3
 
-#: what each Pallas kernel does on a TPU v5e when asked for (PERF.md
-#: "Bring-up"). 'refused' = Mosaic rejects it; the smoke asserts the
-#: refusal so the routing flag can never quietly fall back to XLA.
-KERNEL_VERDICTS = {
-    'gather_rows_hbm': 'lowers',
-    'gather_rows_hbm2': 'lowers',
-    'sample_hop_fused': 'lowers',
-    'sample_level_fused': 'refused',
-}
-
 
 def _load_products_example():
   spec = importlib.util.spec_from_file_location(
@@ -295,26 +285,9 @@ def run_smoke(shape, require_platform, trace_dir):
   }
 
 
-def _refusal(fn):
-  """First line of the compiler's message when ``fn`` is refused; fails
-  if it lowers (then its KERNEL_VERDICTS entry must flip to 'lowers', so
-  the smoke checks parity from then on)."""
-  import jax
-  try:
-    jax.block_until_ready(fn())
-  except Exception as e:
-    # Mosaic's verdict arrives as MosaicError (lowering) or as XLA's
-    # JaxRuntimeError (fast-memory allocation); anything else is a bug
-    if type(e).__name__ not in ('MosaicError', 'JaxRuntimeError'):
-      raise
-    return f'{type(e).__name__}: ' + ' '.join(str(e).split())[:300]
-  raise AssertionError('kernel marked refused now lowers — update '
-                       'KERNEL_VERDICTS')
-
-
 def _kernel_phase(shape, ds, ops):
-  """One lower-or-refused verdict per Pallas kernel, at one realistic
-  shape each; a kernel that lowers must match its XLA twin bit for bit."""
+  """One verdict per Pallas kernel, at one realistic shape each: it
+  lowers and matches its XLA twin bit for bit."""
   import jax
   import jax.numpy as jnp
   import numpy as np
@@ -322,14 +295,11 @@ def _kernel_phase(shape, ds, ops):
   out = {}
 
   def verdict(name, run, parity):
-    if KERNEL_VERDICTS[name] == 'refused':
-      out[name] = {'verdict': 'refused', 'message': _refusal(run)}
-    else:
-      t0 = time.perf_counter()
-      got = jax.block_until_ready(run())
-      assert parity(got), f'{name}: kernel output != XLA reference'
-      out[name] = {'verdict': 'lowers', 'bit_parity': True,
-                   'compile_and_run_s': round(time.perf_counter() - t0, 1)}
+    t0 = time.perf_counter()
+    got = jax.block_until_ready(run())
+    assert parity(got), f'{name}: kernel output != XLA reference'
+    out[name] = {'verdict': 'lowers', 'bit_parity': True,
+                 'compile_and_run_s': round(time.perf_counter() - t0, 1)}
     print(f'chip_smoke: kernel {name}: {out[name]}', flush=True)
 
   n = shape['gather_rows']
@@ -338,7 +308,6 @@ def _kernel_phase(shape, ds, ops):
                     .astype(np.int32))
   want = jnp.take(table, ids, axis=0)
   same = lambda got: bool(jnp.array_equal(got, want))
-  verdict('gather_rows_hbm', lambda: ops.gather_rows_hbm(table, ids), same)
   verdict('gather_rows_hbm2', lambda: ops.gather_rows_hbm2(table, ids),
           same)
   del table, want
@@ -358,23 +327,6 @@ def _kernel_phase(shape, ds, ops):
                                        meta=meta, window=window),
           lambda got: all(bool(jnp.array_equal(a, g))
                           for a, g in zip(hop_ref, got)))
-
-  st0, uniq, umask, _ = ops.init_node_merge(seeds, mask, capacity=b + b * k)
-  fidx = jnp.arange(b, dtype=jnp.int32)
-  nbrs, _, m = ops.uniform_sample(ip, ind, uniq, umask, k, key, meta=meta)
-  st_ref, out_ref = ops.induce_next_merge(st0, fidx, nbrs, m, prefix_cap=b,
-                                          max_new=b * k, update_view=False)
-
-  def level_same(got):
-    st, lvl = got[0], got[1]
-    return bool(jnp.array_equal(st.nodes, st_ref.nodes)) and all(
-        bool(jnp.array_equal(out_ref[f], lvl[f])) for f in out_ref)
-
-  verdict('sample_level_fused',
-          lambda: ops.sample_level_fused(
-              ip, ind, blocks, uniq, umask, k, key, st0, fidx, meta=meta,
-              prefix_cap=b, max_new=b * k, final=True, window=window),
-          level_same)
   return out
 
 

@@ -62,9 +62,7 @@ class Config:
       'loader/run_epoch.py',
       'distributed/dist_feature.py', 'distributed/dist_neighbor_sampler.py',
       'distributed/remote_scan.py', 'distributed/block_producer.py',
-      # tune/ drives candidate A/B epochs through the scanned trainers:
-      # its probe loops sit on the same guarded hot path they score
-      'ops/', 'serving/', 'storage/', 'recovery/', 'tune/')
+      'ops/', 'serving/', 'storage/', 'recovery/')
   # rule dispatch-instrumentation: modules whose jit entrypoints must
   # record dispatches (the dispatch-budget tests' instrumented surface)
   dispatch_modules: Tuple[str, ...] = (
@@ -76,9 +74,9 @@ class Config:
       # Pallas kernel modules (ISSUE 13): their host-level routing
       # wrappers dispatch module-jitted impls and must stay budgeted
       'ops/gather_pallas.py', 'ops/sample_fused.py',
-      # round 15: the run program's jit entrypoints and the tuner's
-      # candidate A/B epochs carry the same dispatch-budget contract
-      'loader/run_epoch.py', 'tune/')
+      # the run program's jit entrypoints carry the same dispatch-budget
+      # contract
+      'loader/run_epoch.py')
   # cross-module jit factories the per-module dataflow can't see: calls
   # to these names yield jitted callables (models/train.py builders)
   known_jit_factories: Tuple[str, ...] = ('make_train_step',)

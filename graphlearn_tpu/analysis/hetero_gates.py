@@ -1,7 +1,7 @@
 """Rule hetero-gate: hetero capability refusals go through CapacityPlan.
 
-The typed fast paths (hetero block streams, per-ntype exchange slabs,
-typed tune artifacts) closed the era of `if x.is_hetero: raise
+The typed fast paths (hetero block streams, per-ntype exchange slabs)
+closed the era of `if x.is_hetero: raise
 ValueError('homogeneous-only')` scattered through the marquee paths.
 A capability gap on a typed dataset must now either
 

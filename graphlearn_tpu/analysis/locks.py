@@ -2,7 +2,7 @@
 is acyclic.
 
 The host-thread population (ChunkStager, the checkpoint writer,
-RotationScheduler, RetuneScheduler, the serving dispatcher, admission
+RotationScheduler, the serving dispatcher, admission
 control) shares state through a handful of known fields, each guarded
 by one lock. PR 8's compile-watermark race and PR 15's rotate_now
 force-flag were both the same bug: a field the comments SAID was

@@ -83,29 +83,6 @@ class Feature:
     self.dtype = dtype
     self._unified = None
     self._id2index_dev = None
-    self._kernel_routing = None
-
-  def set_kernel_routing(self, use_pallas_v2: bool = False,
-                         block_rows: int = 256, run_span: int = 8):
-    """Route the all-hot gather through the run-segmented DMA kernel
-    (ops.gather_rows_hbm2) with the given grid point — the tuned-
-    artifact application path (tune/artifact.py apply_kernel_routing).
-    Safe before or after lazy_init; off-TPU the UnifiedTensor flag is
-    inert (its _pallas_ok gate)."""
-    self._kernel_routing = dict(use_pallas_v2=bool(use_pallas_v2),
-                                pallas_v2_block_rows=int(block_rows),
-                                pallas_v2_run_span=int(run_span))
-    if self._unified is not None:
-      for k, v in self._kernel_routing.items():
-        setattr(self._unified, k, v)
-
-  def _stamp_kernel_routing(self):
-    # getattr: subclasses built via __new__ (IPC rehydration) and
-    # TieredFeature (no super().__init__) may lack the slot
-    routing = getattr(self, '_kernel_routing', None)
-    if routing is not None and self._unified is not None:
-      for k, v in routing.items():
-        setattr(self._unified, k, v)
 
   def lazy_init(self):
     if self._unified is not None:
@@ -144,7 +121,6 @@ class Feature:
     ut.init_from(hot_block,
                  self.feature_array[hot:] if hot < n else None)
     self._unified = ut
-    self._stamp_kernel_routing()
     if self._id2index is not None:
       import jax
       self._id2index_dev = jax.device_put(self._id2index, self.device)

@@ -142,13 +142,8 @@ class UnifiedTensor:
     import jax.numpy as jnp
     if self._host_rows_n == 0:
       if self._pallas_ok():
-        if self.use_pallas_v2:
-          from ..ops import gather_rows_hbm2
-          return gather_rows_hbm2(self._device_part, jnp.asarray(ids),
-                                  block_rows=self.pallas_v2_block_rows,
-                                  run_span=self.pallas_v2_run_span)
-        from ..ops import gather_rows_hbm
-        return gather_rows_hbm(self._device_part, jnp.asarray(ids))
+        from ..ops import gather_rows_hbm2
+        return gather_rows_hbm2(self._device_part, jnp.asarray(ids))
       return jnp.take(self._device_part, jnp.asarray(ids), axis=0)
     ids_np = np.asarray(ids)
     if self._device_part is None:
@@ -182,22 +177,15 @@ class UnifiedTensor:
     record_dispatch('unified_tensor.cold_scatter')
     return scatter_fn(out, jnp.asarray(pos), cold_rows)
 
-  use_pallas = False   # opt-in: device traces show XLA's take is faster
-  # for the all-hot row gather on v5e (1.20 vs 1.41 ms/call, PERF.md);
-  # the kernel remains available for rigs where the balance differs
   use_pallas_v2 = False   # opt-in: the run-segmented multi-row DMA
-  # gather (ops.gather_rows_hbm2) — the same evidence-gated contract:
-  # not measured on the chip (ROADMAP.md S5): auto-route only once a
-  # cell shows a win. When both flags are set, v2 wins.
-  pallas_v2_block_rows = 256   # autotune grid knobs (tune/tuner.py)
-  pallas_v2_run_span = 8
+  # gather (ops.gather_rows_hbm2), not yet timed on the chip (ROADMAP.md
+  # S6): auto-route only once a cell shows a win
 
   def _pallas_ok(self) -> bool:
-    """All-hot gathers use a Pallas row-DMA kernel when opted in (either
-    generation's flag). Off-TPU the flag is inert; on TPU a table the
-    kernel cannot serve raises (ops.table_kernel_ok)."""
-    if not (self.use_pallas or self.use_pallas_v2) or \
-        self._device_part is None:
+    """All-hot gathers use the Pallas row-DMA kernel when opted in.
+    Off-TPU the flag is inert; on TPU a table the kernel cannot serve
+    raises (ops.table_kernel_ok)."""
+    if not self.use_pallas_v2 or self._device_part is None:
       return False
     from ..ops.gather_pallas import table_kernel_ok
     return table_kernel_ok('UnifiedTensor', self._device_part)

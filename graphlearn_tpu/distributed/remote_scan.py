@@ -266,19 +266,6 @@ class RemoteBlockStager:
     self._submit_next()
 
 
-def _norm_fans(f):
-  """Canonical comparison form of a fanout spec: per-etype dict
-  fanouts (hetero plans) normalize to sorted string keys — tuned
-  artifacts round-trip etype keys through JSON as strings
-  (docs/capacity_plans.md)."""
-  if isinstance(f, dict):
-    from ..typing import as_str
-    return {as_str(tuple(et)) if isinstance(et, (tuple, list))
-            else str(et): [int(k) for k in v]
-            for et, v in sorted(f.items(), key=lambda kv: str(kv[0]))}
-  return [int(k) for k in f]
-
-
 def _group_frame(frame: dict, prefix: str, et_keyed: bool = False):
   """Host-side regroup of a typed block frame's dotted keys
   (``x.paper`` / ``row.paper__cites__paper`` — docs/capacity_plans.md)
@@ -288,53 +275,6 @@ def _group_frame(frame: dict, prefix: str, et_keyed: bool = False):
   return {(to_edge_type(kk[len(p):]) if et_keyed else kk[len(p):]):
           np.asarray(v) for kk, v in frame.items()
           if kk.startswith(p)}
-
-
-def _resolve_remote_config(name: str, config, fanouts,
-                           batch_size: int) -> dict:
-  """Validate a tune-artifact ``config=`` against the remote scenario
-  and return its tuned block knobs (empty when no config). Topology
-  must be 'remote' or a generic 'local' artifact (chunk K + kernels
-  transfer; block knobs are remote-only), and the artifact's
-  fanouts/batch_size must match the stream this trainer creates — a
-  block-stream assignment tuned at different frame shapes is a
-  different program population (docs/tuning.md 'Topology
-  candidates')."""
-  if config is None:
-    return {}
-  art_topo = getattr(config, 'topology', 'local') or 'local'
-  if art_topo not in ('local', 'remote'):
-    raise ValueError(
-        f'{name}: tune artifact was tuned for topology {art_topo!r}, '
-        "but this trainer runs the 'remote' scenario — per-topology "
-        'knobs do not transfer; re-run graphlearn_tpu.tune('
-        "topology='remote') (docs/tuning.md)")
-  choices = getattr(config, 'choices', None) or {}
-  tuned_fans = choices.get('fanouts')
-  if tuned_fans is not None and \
-      _norm_fans(tuned_fans) != _norm_fans(fanouts):
-    raise ValueError(
-        f'{name}: tune artifact pins fanouts {tuned_fans} but '
-        f'this trainer streams at {_norm_fans(fanouts)} — the '
-        'block frames were sized for a different sampling shape '
-        '(docs/tuning.md)')
-  tuned_bs = choices.get('batch_size')
-  if tuned_bs is not None and int(tuned_bs) != int(batch_size):
-    raise ValueError(
-        f'{name}: tune artifact pins batch_size={int(tuned_bs)} but '
-        f'this trainer streams at batch_size={int(batch_size)} '
-        '(docs/tuning.md)')
-  if getattr(config, 'dataset', None) is not None:
-    import warnings
-    warnings.warn(
-        f'{name}: the remote client holds no dataset to recompute '
-        'the artifact fingerprint against — tuned config accepted on '
-        'the tune-side validation only', RuntimeWarning, stacklevel=3)
-  if art_topo == 'remote' and hasattr(config, 'topology_kwargs'):
-    kw = config.topology_kwargs()
-    return {k: kw[k] for k in ('block_ahead', 'block_wire_dtype')
-            if k in kw}
-  return {}
 
 
 class RemoteScanTrainer:
@@ -365,12 +305,6 @@ class RemoteScanTrainer:
       ``block_ahead`` / ``block_timeout``.
     seed: sampling seed; folded per server exactly like the per-batch
       remote loaders (``seed * 7919 + i``).
-    config: a tune artifact (``graphlearn_tpu.tune(topology='remote')``,
-      docs/tuning.md): supplies the tuned chunk K when ``chunk_size``
-      is not given and the tuned ``block_ahead``/``block_wire_dtype``
-      (overriding the worker_options defaults — the artifact is the
-      signed assignment); refuses a mismatched topology, fanouts, or
-      batch size.
   """
 
   _NAME = 'RemoteScanTrainer'
@@ -385,28 +319,15 @@ class RemoteScanTrainer:
 
   def __init__(self, num_neighbors, input_nodes, model, tx,
                num_classes: int, batch_size: int = 64,
-               chunk_size: Optional[int] = None, shuffle: bool = False,
+               chunk_size: int = 32, shuffle: bool = False,
                drop_last: bool = False, collect_features: bool = True,
-               worker_options=None, seed: Optional[int] = None,
-               config=None):
+               worker_options=None, seed: Optional[int] = None):
     import jax
 
     from ..models import train as train_lib
     from ..sampler import SamplingConfig, SamplingType
     from . import dist_client
     from .resilience import Heartbeat
-    # config= takes a tune artifact (graphlearn_tpu.tune(topology=
-    # 'remote'), docs/tuning.md): topology-checked, structurally
-    # validated against the fanouts/batch this trainer streams at, and
-    # the source of the tuned chunk K + block knobs below. The client
-    # holds no dataset, so the dataset fingerprint cannot be
-    # recomputed here — it was validated on the tune side
-    tuned_block = _resolve_remote_config(
-        self._NAME, config, _norm_num_neighbors(num_neighbors),
-        batch_size)
-    if chunk_size is None:
-      chunk_size = int(config.trainer_kwargs()['chunk_size']) \
-          if config is not None else 32
     if chunk_size < 1:
       raise ValueError(f'chunk_size must be >= 1, got {chunk_size}')
     input_type, input_nodes = _split_input_type(input_nodes)
@@ -441,13 +362,6 @@ class RemoteScanTrainer:
     self._max_ahead = getattr(opts, 'block_ahead', 2) if opts else 2
     self._fetch_timeout = getattr(opts, 'block_timeout', 30.0) \
         if opts else 30.0
-    # the artifact's tuned block knobs are the signed, evidence-backed
-    # assignment: a non-None tuned value overrides the worker_options
-    # default (hand-pick by passing options WITHOUT config=)
-    if 'block_wire_dtype' in tuned_block:
-      self._wire_dtype = tuned_block['block_wire_dtype']
-    if 'block_ahead' in tuned_block:
-      self._max_ahead = int(tuned_block['block_ahead'])
     self._failover_enabled = (opts.failover if opts else True)
     self._tenant = getattr(opts, 'tenant', None) if opts else None
     self._tenant_priority = getattr(opts, 'tenant_priority', None) \

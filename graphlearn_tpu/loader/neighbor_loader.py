@@ -24,7 +24,7 @@ class NeighborLoader(NodeLoader):
                padded_window: Optional[int] = None,
                seed_labels_only: bool = False,
                frontier_caps=None, overflow_policy: str = 'raise',
-               use_fused_hop=False, fused_hop_window: int = 512):
+               use_fused_hop=False):
     # frontier_caps='auto': calibrate in-loader against THIS loader's
     # seed pool and batch size (sampler.calibrate), so no caller ever
     # hand-computes calibration widths
@@ -50,7 +50,7 @@ class NeighborLoader(NodeLoader):
         with_weight=with_weight, strategy=strategy, edge_dir=data.edge_dir,
         seed=seed, node_budget=node_budget, dedup=dedup,
         padded_window=padded_window, frontier_caps=frontier_caps,
-        use_fused_hop=use_fused_hop, fused_hop_window=fused_hop_window)
+        use_fused_hop=use_fused_hop)
     super().__init__(data, sampler, input_nodes, batch_size, shuffle,
                      drop_last, with_edge, collect_features, to_device,
                      seed, seed_labels_only=seed_labels_only,

@@ -84,10 +84,10 @@ class RunTrainer(ScanTrainer):
   _NAME = 'RunTrainer'
 
   def __init__(self, loader: NodeLoader, model, tx, num_classes: int,
-               chunk_size: Optional[int] = None, epochs: int = 1,
+               chunk_size: int = 32, epochs: int = 1,
                patience: Optional[int] = None, min_delta: float = 0.0,
                seed_labels_only: Optional[bool] = None,
-               perm_seed: Optional[int] = None, config=None,
+               perm_seed: Optional[int] = None,
                track_eval: bool = True):
     refuse_tables(loader, self._NAME)
     refuse_typed(loader, self._NAME)   # typed run chunks: ROADMAP M5
@@ -95,7 +95,7 @@ class RunTrainer(ScanTrainer):
     super().__init__(loader, model, tx, num_classes,
                      chunk_size=chunk_size,
                      seed_labels_only=seed_labels_only,
-                     perm_seed=perm_seed, config=config)
+                     perm_seed=perm_seed)
     if epochs < 1:
       raise ValueError(f'epochs must be >= 1, got {epochs}')
     if patience is not None and patience < 1:

@@ -76,38 +76,6 @@ from .pipeline import (_RECOMPUTE_MSG, DistFusedEpochTrainer,
                        FusedEpochTrainer)
 
 
-def _resolve_tuned_config(trainer_name: str, dataset, chunk_size,
-                          config, topology: str = 'local') -> int:
-  """Resolve the chunk size from an explicit value or a tune-artifact
-  ``config=`` (graphlearn_tpu/tune/, docs/tuning.md). An artifact is
-  validated against the loader's dataset BY FINGERPRINT — a tuned
-  config on a drifted graph refuses loudly, the recovery-snapshot
-  refusal contract — and against the trainer's TOPOLOGY: a non-local
-  artifact only fits the scenario it was tuned for (a remote
-  block-stream assignment says nothing about a tiered exchange), while
-  a local artifact's knobs (chunk K, kernel routing) stay generically
-  acceptable everywhere. Duck-typed (validate_dataset +
-  trainer_kwargs) so the loader package never imports tune/."""
-  if config is not None:
-    art_topo = getattr(config, 'topology', 'local') or 'local'
-    if art_topo not in ('local', topology):
-      raise ValueError(
-          f'{trainer_name}: tune artifact was tuned for topology '
-          f'{art_topo!r}, but this trainer runs the {topology!r} '
-          'scenario — per-topology knobs do not transfer; re-run '
-          f'graphlearn_tpu.tune(topology={topology!r}) '
-          '(docs/tuning.md "Topology candidates")')
-    config.validate_dataset(dataset, where=trainer_name)
-    if chunk_size is None:
-      chunk_size = config.trainer_kwargs()['chunk_size']
-    if hasattr(config, 'apply_kernel_routing'):
-      # kernel selection is an artifact choice, not an env var: stamp
-      # the tuned gather-kernel routing onto the dataset's feature
-      # store (tune/artifact.py; v1 artifacts carry kernels-off)
-      config.apply_kernel_routing(dataset)
-  return 32 if chunk_size is None else int(chunk_size)
-
-
 #: rounds of the epoch order's Feistel network (an alternating unbalanced
 #: one: each round xors one half with a hash of the other)
 _ORDER_ROUNDS = 6
@@ -207,9 +175,6 @@ class ScanTrainer(FusedEpochTrainer):
   """
 
   _NAME = 'ScanTrainer'
-  #: which tune() scenario this trainer runs — the config= topology
-  #: compatibility check (_resolve_tuned_config; docs/tuning.md)
-  _TOPOLOGY = 'local'
 
   # chunk-boundary staging hooks (storage/ subsystem, docs/storage.md;
   # recovery/ checkpointing, docs/recovery.md): ``stage_hook(
@@ -226,9 +191,9 @@ class ScanTrainer(FusedEpochTrainer):
 
   def __init__(self, loader: NodeLoader, model, tx,
                num_classes: Optional[int] = None,
-               chunk_size: Optional[int] = None,
+               chunk_size: int = 32,
                seed_labels_only: Optional[bool] = None,
-               perm_seed: Optional[int] = None, config=None):
+               perm_seed: Optional[int] = None):
     import jax
     super().__init__(loader, model, tx, num_classes, seed_labels_only)
     # per chunk, the batch source's [k, n] per-step counts — a link
@@ -242,12 +207,6 @@ class ScanTrainer(FusedEpochTrainer):
                            'link.negatives.padded', 'link.seeds.unique')
     else:
       self._count_names = ('table.rows_read',) if self._id_types else ()
-    # config= takes a tune artifact (graphlearn_tpu.tune(),
-    # docs/tuning.md): dataset-fingerprint-validated, supplies the
-    # tuned chunk K when chunk_size is not given explicitly
-    chunk_size = _resolve_tuned_config(self._NAME, loader.data,
-                                       chunk_size, config,
-                                       topology=self._TOPOLOGY)
     if chunk_size < 1:
       raise ValueError(f'chunk_size must be >= 1, got {chunk_size}')
     self.chunk_size = int(chunk_size)
@@ -725,7 +684,6 @@ class DistScanTrainer(DistFusedEpochTrainer):
   """
 
   _NAME = 'DistScanTrainer'
-  _TOPOLOGY = 'dist'
 
   # chunk-boundary staging hooks — same contract as ScanTrainer's:
   # host-side callables around each chunk dispatch, the attachment
@@ -735,21 +693,15 @@ class DistScanTrainer(DistFusedEpochTrainer):
   ack_hook = None
 
   def __init__(self, loader, model, tx, num_classes: int,
-               chunk_size: Optional[int] = None,
+               chunk_size: int = 32,
                seed_labels_only: Optional[bool] = None,
-               perm_seed: Optional[int] = None, config=None):
+               perm_seed: Optional[int] = None):
     import jax
     super().__init__(loader, model, tx, num_classes, seed_labels_only)
     # per chunk, the [P, k, hops] counts of frontier ids each shard sent
     # to other shards: a scan output, summed on the host once an epoch
     # (_publish_exchange_rows empties it)
     self._sent = []
-    # config= takes a tune artifact (docs/tuning.md): topology-checked
-    # ('dist' or a generic local artifact) and validated against the
-    # DistGraph's stacked-partition fingerprint (tune/artifact.py)
-    chunk_size = _resolve_tuned_config(self._NAME, loader.data,
-                                       chunk_size, config,
-                                       topology=self._TOPOLOGY)
     if chunk_size < 1:
       raise ValueError(f'chunk_size must be >= 1, got {chunk_size}')
     self.chunk_size = int(chunk_size)

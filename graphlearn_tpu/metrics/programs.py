@@ -24,8 +24,7 @@ program population a first-class observable:
   lowered+compiled once through the AOT path to capture XLA
   ``cost_analysis()`` / ``memory_analysis()`` attribution (flops, bytes
   accessed, peak HBM estimate, donation efficacy) — the per-program
-  cost signal ROADMAP items 4/5 (Pallas floor attack, one-call
-  autotune) take as input. Off by default: the AOT compile is a second
+  cost signal. Off by default: the AOT compile is a second
   host-side compilation of the same program (never a dispatch).
 * :func:`retrace_budget` turns the test-only "one executable" asserts
   into a production guard rail: exceeding the budget raises under
@@ -175,7 +174,7 @@ class ProgramRegistry:
 
   Fed by :func:`instrument` wrappers at the package's dispatch sites;
   read by ``retrace_budget``, the flight recorder (per-epoch deltas of
-  :meth:`flight_snapshot`) and the tuner (:meth:`aggregate`)."""
+  :meth:`flight_snapshot`) and :meth:`aggregate`'s process totals."""
 
   def __init__(self):
     self._lock = threading.Lock()

@@ -129,7 +129,6 @@ REGISTERED_METRICS = frozenset({
     'ops.gather_runs',
     'ops.gather_fallbacks',
     'ops.fused_hop_calls',
-    'ops.fused_level_calls',
     'ops.gather_ms',
     'checkpoint.saves',
     'checkpoint.bytes',
@@ -142,18 +141,6 @@ REGISTERED_METRICS = frozenset({
     'recovery.resumes',
     'recovery.resume_chunks',
     'recovery.rollbacks',
-    # one-call autotuner (graphlearn_tpu/tune/, docs/tuning.md):
-    # observatory-scored candidate A/Bs behind the config artifact
-    'tune.candidates',
-    'tune.rejected',
-    'tune.probe_ms',
-    'tune.artifacts',
-    # continuous retuning (tune/retune.py, docs/tuning.md 'Continuous
-    # retuning'): drift-trigger fires, successful shadow-retune
-    # publishes, and the shadow replica's tune wall
-    'tune.retunes',
-    'tune.drift_triggers',
-    'tune.shadow_wall_ms',
     # run-as-a-program (loader/run_epoch.py): whole-run scans with
     # in-carry eval + early stop — host-side schedule counters only
     # (the stop point itself is device state, read from the report)
@@ -241,13 +228,6 @@ REGISTERED_SPANS = frozenset({
     # rolled-back chunk index in its attrs (docs/recovery.md)
     'checkpoint.save',
     'recovery.resume',
-    # one-call autotuner (tune/tuner.py): one span per tune() run, one
-    # per candidate A/B (compile + steady epochs inside)
-    'tune.run',
-    'tune.candidate',
-    # continuous retuning (tune/retune.py): one span per shadow
-    # retune attempt, carrying the firing drift trigger in its attrs
-    'tune.retune',
     # run-as-a-program (loader/run_epoch.py): one span wrapping the
     # whole multi-epoch run; the inherited epoch.run/epoch.chunk spans
     # parent under it

@@ -1,7 +1,7 @@
 from .collate import (collate_batch, collate_typed_batch, gather_rows,
                       stack2, stack2_batched, valid_mask)
-from .gather_pallas import (decode_gather_plan, gather_rows_hbm,
-                            gather_rows_hbm2, plan_gather_runs)
+from .gather_pallas import (decode_gather_plan, gather_rows_hbm2,
+                            plan_gather_runs)
 from .induce import InducerState, induce_next, init_empty, init_node
 from .induce_map import (MapInducerState, induce_next_map, init_node_map)
 from .induce_merge import (MergeInducerState, induce_next_merge,
@@ -19,8 +19,7 @@ from .neighbor import (BLOCK, build_padded_adjacency,
                        weighted_sample_local)
 from .route import (exchange_capacity, gather_from_buckets, round8,
                     route_slots, scatter_to_buckets)
-from .sample_fused import (LEVEL_MAX_CANDIDATES, build_indices128,
-                           sample_hop_fused, sample_level_fused)
+from .sample_fused import build_indices128, sample_hop_fused
 from .stitch import stitch_rows
 from .subgraph import (node_subgraph, node_subgraph_bucketed,
                        node_subgraph_local)

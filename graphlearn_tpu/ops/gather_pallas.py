@@ -1,4 +1,4 @@
-"""Pallas TPU kernels: random row gather from an HBM-resident table.
+"""Pallas TPU kernel: random row gather from an HBM-resident table.
 
 TPU-native replacement for the reference's UnifiedTensor gather kernel
 (/root/reference/graphlearn_torch/csrc/cuda/unified_tensor.cu:48-81, a
@@ -6,35 +6,24 @@ warp-per-row UVA gather). The feature lookup is the biggest per-batch byte
 mover in GNN training (PERF.md: ~40x the sampler's budget), and XLA lowers
 `jnp.take` over a large HBM table through generic dynamic-gather machinery.
 
-Two generations live here:
-
-v1 (``gather_rows_hbm``): one async row DMA per output row, many in
-flight at once — grid step i owns output rows [i*G, (i+1)*G); the row
-ids arrive via scalar prefetch (known before the body runs), the body
-starts G concurrent HBM->VMEM row copies straight into the output block,
-then waits. Measured on v5e-1: LOSES to XLA's take (1.41 vs 1.20 ms on
-the 131k x [1M, 128] probe) — every row is its own DMA transaction, the
-exact bound XLA's gather already sits at.
-
-v2 (``gather_rows_hbm2``): multi-row DMA over contiguous id-RUNS. The
+``gather_rows_hbm2`` issues multi-row DMAs over contiguous id-RUNS. The
 repo's design rule (ops/induce_merge.py, PERF.md): sorts beat random
-access on TPU, so v2 sorts the ids on device (one key+payload lax.sort),
+access on TPU, so it sorts the ids on device (one key+payload lax.sort),
 segments the sorted ids into maximal runs of STRICTLY CONSECUTIVE table
 rows (split at ``run_span`` and at grid-block boundaries), and issues
 ONE async copy per full run instead of per row — contiguous source AND
 destination, so a sorted or locality-heavy id vector collapses from B
 transactions to ~B/run_span. Slots not covered by a full-span run keep
-the v1 single-row copy (random ids degrade to exactly v1 + the sort).
-The unsort back to caller order is one more payload sort + a [B, F]
-row permutation; callers whose ids are ALREADY sorted-unique (the
-tiered-storage staging planner, searchsorted slab gathers) pass
-``presorted=True`` and skip both. The autotune grid (block_rows,
-run_span) is tune/tuner.py's; not measured on the chip (ROADMAP.md
-S5), so routing stays evidence-gated behind ``UnifiedTensor.use_pallas_v2`` exactly like v1's ``use_pallas``.
+a single-row copy. The unsort back to caller order is one more payload
+sort + a [B, F] row permutation; callers whose ids are ALREADY
+sorted-unique (the tiered-storage staging planner, searchsorted slab
+gathers) pass ``presorted=True`` and skip both. Not yet timed on the
+chip (ROADMAP.md S6), so routing stays opt-in behind
+``UnifiedTensor.use_pallas_v2`` / ``EmbeddingStore.use_pallas_v2``.
 
-Calling one of these ops IS asking for the kernel. Off-TPU they fall back
+Calling this op IS asking for the kernel. Off-TPU it falls back
 to the bit-identical `jnp.take` (same clamped-id contract) so CPU tests
-and examples run; tests exercise the kernels via interpret=True on small
+and examples run; tests exercise the kernel via interpret=True on small
 shapes. On TPU there is no fallback: a table Mosaic cannot serve (width
 not 128-lane aligned) raises with the reason instead of quietly running
 XLA's gather under the kernel's name.
@@ -55,7 +44,7 @@ def _require_lane_aligned(owner: str, table):
         f'{owner}: a Pallas gather was asked for, but the table width '
         f'{table.shape[1]} is not a multiple of 128 lanes — Mosaic cannot '
         'lower the HBM row DMA. Pad the width, or gather with jnp.take '
-        '(clear the use_pallas* flag); there is no XLA fallback on TPU')
+        '(clear the use_pallas_v2 flag); there is no XLA fallback on TPU')
 
 
 def _kernel_route(name: str, table, ids, interpret: bool, force: bool):
@@ -79,7 +68,7 @@ def _kernel_route(name: str, table, ids, interpret: bool, force: bool):
 
 def table_kernel_ok(owner: str, table) -> bool:
   """Whether a store that was ASKED to route its gathers through these
-  kernels (``use_pallas*``) can: False off-TPU (CPU runs keep the XLA
+  kernel (``use_pallas_v2``) can: False off-TPU (CPU runs keep the XLA
   gather), True on TPU — and a raise on TPU when the table cannot be
   served, so a set flag never means a silent XLA gather there."""
   if jax.default_backend() != 'tpu':
@@ -90,82 +79,8 @@ def table_kernel_ok(owner: str, table) -> bool:
         f'{owner}: a Pallas gather was asked for, but the table is '
         f'sharded over {len(table.sharding.device_set)} devices — the '
         'row-DMA kernels serve a single-device table. Clear the '
-        'use_pallas* flag for sharded stores')
+        'use_pallas_v2 flag for sharded stores')
   return True
-
-
-def _gather_kernel(ids_ref, table_ref, out_ref, sems):
-  from jax.experimental import pallas as pl
-  from jax.experimental.pallas import tpu as pltpu
-  i = pl.program_id(0)
-  g = out_ref.shape[0]
-
-  def dma(slot):
-    rid = ids_ref[i * g + slot]
-    return pltpu.make_async_copy(table_ref.at[rid], out_ref.at[slot],
-                                 sems.at[slot])
-
-  def issue(slot, _):
-    dma(slot).start()
-    return _
-
-  jax.lax.fori_loop(0, g, issue, None, unroll=True)
-
-  def drain(slot, _):
-    dma(slot).wait()
-    return _
-
-  jax.lax.fori_loop(0, g, drain, None, unroll=True)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=('block_rows', 'interpret', 'force'))
-def gather_rows_hbm(table, ids, block_rows: int = 128,
-                    interpret: bool = False, force: bool = False):
-  """Gather ``table[ids]`` via per-row async DMAs.
-
-  Args:
-    table: [N, F] device array (HBM-resident; never copied wholesale).
-    ids: [B] int32 row indices (clamped to [0, N)).
-    block_rows: rows per grid step == concurrent DMAs in flight.
-      Device-trace truth on v5e-1 (1M x 128 f32 table, 131k random ids):
-      best config 1.41 ms/call at 128/256 vs XLA take's 1.20 ms — XLA's
-      gather wins on this chip, so callers opt in explicitly
-      (UnifiedTensor.use_pallas) — rounds 2-20, PERF.md section 6.
-    interpret: run the Pallas interpreter (CPU tests).
-    force: run the kernel even off-TPU (AOT lowering checks); default
-      falls back to jnp.take when the backend isn't TPU.
-
-  Returns [B, F] gathered rows.
-  """
-  if not _kernel_route('gather_rows_hbm', table, ids, interpret, force):
-    return jnp.take(table, jnp.clip(ids, 0, table.shape[0] - 1), axis=0)
-  from jax.experimental import pallas as pl
-  from jax.experimental.pallas import tpu as pltpu
-
-  b = ids.shape[0]
-  g = min(block_rows, b)
-  pad = (-b) % g
-  ids = jnp.clip(ids, 0, table.shape[0] - 1).astype(jnp.int32)
-  if pad:
-    ids = jnp.concatenate([ids, jnp.zeros((pad,), jnp.int32)])
-  grid = (b + pad) // g
-
-  out = pl.pallas_call(
-      _gather_kernel,
-      grid_spec=pltpu.PrefetchScalarGridSpec(
-          num_scalar_prefetch=1,
-          grid=(grid,),
-          in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-          out_specs=pl.BlockSpec((g, table.shape[1]),
-                                 lambda i, ids_ref: (i, 0)),
-          scratch_shapes=[pltpu.SemaphoreType.DMA((g,))],
-      ),
-      out_shape=jax.ShapeDtypeStruct((b + pad, table.shape[1]),
-                                     table.dtype),
-      interpret=interpret,
-  )(ids, table)
-  return out[:b] if pad else out
 
 
 # ------------------------------------------------------------------ v2
@@ -175,7 +90,7 @@ def gather_rows_hbm(table, ids, block_rows: int = 128,
 # the int32 CSR contract elsewhere in the stack). NOTE: kind 2 occupies
 # the int32 SIGN bit, so decoding must mask after the shift —
 # ``(plan >> 30) & 3`` — or an arithmetic right shift turns it into -2.
-_KIND_SINGLE = 0   # one row DMA for this slot (v1 behaviour)
+_KIND_SINGLE = 0   # one row DMA for this slot
 _KIND_RUN = 1      # this slot starts a full ``run_span``-row DMA
 _KIND_COVERED = 2  # covered by a preceding run start: no DMA
 _ROW_MASK = (1 << 30) - 1
@@ -280,8 +195,9 @@ def _gather2_kernel_factory(span):
 @functools.partial(jax.jit,
                    static_argnames=('block_rows', 'run_span', 'presorted',
                                     'interpret'))
-def _gather_rows_hbm2_impl(table, ids, block_rows: int, run_span: int,
-                           presorted: bool, interpret: bool):
+def _gather_rows_hbm2_impl(table, ids, block_rows: int = 256,
+                           run_span: int = 8, presorted: bool = False,
+                           interpret: bool = False):
   from jax.experimental import pallas as pl
   from jax.experimental.pallas import tpu as pltpu
 
@@ -334,17 +250,17 @@ def gather_rows_hbm2(table, ids, block_rows: int = 256, run_span: int = 8,
 
   Args:
     table: [N, F] device array (HBM-resident; F must be 128-lane aligned
-      for the kernel path — on TPU a misaligned width raises, like v1).
+      for the kernel path — on TPU a misaligned width raises).
     ids: [B] int32 row indices (clamped to [0, N)).
-    block_rows: output rows per grid step (autotune axis 1).
-    run_span: rows per multi-row DMA (autotune axis 2; 1 degenerates to
-      the v1 per-row kernel plus the sort).
+    block_rows: output rows per grid step.
+    run_span: rows per multi-row DMA (1 degenerates to one DMA per row
+      plus the sort).
     presorted: ids are already ascending — skips the sort AND the unsort
       row permutation (the tiered staging planner's slab gathers and
       any searchsorted-driven caller qualify).
     interpret: run the Pallas interpreter (CPU tests).
     force: run the kernel even off-TPU; off-TPU it still falls back
-      (with a warning) on misaligned widths, like v1.
+      (with a warning) on misaligned widths.
 
   Returns [B, F] gathered rows.
   """

@@ -4,10 +4,9 @@
 Every marquee fast path in this repo is a closed-shape contract — the
 scanned trainers compile one executable per chunk length, the block
 producers ship frames whose arrays stack, the tiered exchange stages
-slabs whose capacities are known at plan time, and tune() fingerprints
-the dataset + choices that produced those shapes. Until this module,
+slabs whose capacities are known at plan time. Until this module,
 that contract lived implicitly in a chain of homogeneous helpers
-(``capacity_plan`` -> wire frames -> slab caps -> tune fingerprint),
+(``capacity_plan`` -> wire frames -> slab caps),
 and every hetero workload fell off it into dispatch-per-batch paths.
 
 ``CapacityPlan`` reifies the chain once: per node type and edge type,
@@ -21,9 +20,7 @@ ad hoc) by
 * ``distributed.block_producer`` (typed multi-ntype block frames for
   ``RemoteScanTrainer``),
 * the exchange planner + ``storage.dist_scan`` stagers (per-ntype
-  exchange slabs for ``TieredDistScanTrainer``),
-* ``tune()`` / ``tune(topology=...)`` (typed dataset fingerprints and
-  per-etype fanout candidates).
+  exchange slabs for ``TieredDistScanTrainer``).
 
 A consumer that cannot build a plan raises :class:`CapacityPlanError`
 naming the missing input and this doc anchor — the graftlint
@@ -168,57 +165,6 @@ class CapacityPlan:
       keys += [f'batch.{self.input_type}', f'y.{self.input_type}']
     return keys
 
-  def block_mb_per_chunk(self, k: int, feat_dims: Dict[str, int],
-                         edge_id_bytes: int = 4) -> float:
-    """Analytic wire size of one K-batch block frame under this plan —
-    the typed generalization of ``block_mb_per_chunk`` the topology
-    tuner screens candidates with."""
-    feat_bytes = 2 if self.wire_dtype in ('bf16', 'bfloat16') else 4
-    total = 0
-    for t, cap in self.node_caps.items():
-      f = feat_dims.get(t, 0)
-      total += cap * f * feat_bytes      # x rows
-      total += cap * edge_id_bytes      # node ids
-    for _oet, ecap in self.edge_caps.items():
-      total += ecap * 3 * edge_id_bytes  # row + col + mask
-    b = self.batch_cap
-    total += b * 2 * edge_id_bytes       # batch ids + labels
-    return k * total / 1e6
-
-  def slab_caps_upper(self, hot_prefix_rows: Dict[str, int],
-                      chunk_size: int) -> Dict[str, int]:
-    """Per-ntype upper bound on a chunk's staged-slab capacity: at most
-    ``chunk_size * node_caps[t]`` distinct rows can miss the hot prefix
-    in one chunk (the planner pads the actual miss count to pow2 and
-    never exceeds this)."""
-    out = {}
-    for t in self.feat_types():
-      h = int(hot_prefix_rows.get(t, 0))
-      cap = chunk_size * int(self.node_caps[t])
-      out[t] = 0 if h <= 0 else cap
-    return out
-
-  # --------------------------------------------------------- tune view
-
-  def fingerprint_payload(self) -> dict:
-    """Canonical JSON-able view for tune artifacts: the shapes a tuned
-    choice set was measured under. Etype keys are stringified so the
-    payload round-trips through JSON unchanged."""
-    return {
-        'ntypes': list(self.ntypes),
-        'etypes': [_et_str(et) for et in self.etypes],
-        'edge_dir': self.edge_dir,
-        'input_type': self.input_type,
-        'seed_caps': {t: int(v) for t, v in sorted(self.seed_caps.items())},
-        'node_caps': {t: int(v) for t, v in sorted(self.node_caps.items())},
-        'hop_caps': [
-            {_et_str(et): [int(x) for x in caps]
-             for et, caps in sorted(per_et.items())}
-            for per_et in self.hop_caps],
-        'key_draws_per_batch': int(self.key_draws_per_batch),
-        'wire_dtype': self.wire_dtype,
-    }
-
   # ------------------------------------------------------- constructors
 
   @classmethod
@@ -274,7 +220,7 @@ class CapacityPlan:
                    wire_dtype=None) -> 'CapacityPlan':
     """Plan for one sampler + seed batch — hetero when the sampler is,
     else the degenerate homo plan. The one constructor consumers call
-    (block producers, tiered stagers, tune probes)."""
+    (block producers, tiered stagers)."""
     if getattr(sampler, 'is_hetero', False):
       if input_type is None:
         raise CapacityPlanError(

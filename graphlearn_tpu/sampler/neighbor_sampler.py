@@ -332,7 +332,7 @@ def hetero_tree_blocks(seed_caps: Dict[NodeType, int], etypes,
 @functools.lru_cache(maxsize=None)
 def _fused_homo_fn(fanouts, caps, node_cap, with_edge, weighted, mode,
                    num_graph_nodes, padded=False, block_num_edges=0,
-                   fused_hop=False, fused_hop_window=512, seed_scope=None):
+                   fused_hop=False, seed_scope=None):
   """Jitted whole-multi-hop sample program, cached at MODULE level on its
   static signature: every sampler instance with the same config (e.g. the
   train and eval loaders of one run) shares one traced/compiled
@@ -373,57 +373,43 @@ def _fused_homo_fn(fanouts, caps, node_cap, with_edge, weighted, mode,
       # smaller sorts under calibrated plans, and keeps the contiguous
       # node append statically safe
       node_offs, _ = merge_layout_from_caps(caps, fanouts)
-    # fused LEVEL routing: under the merge engine the whole level —
-    # sample + gather + exact dedup — runs as ONE kernel pass
-    # (ops.sample_level_fused, the dedup map resolved in-kernel); tree
-    # mode keeps the hop kernel + its positional inducer (the tree
-    # layout needs no cross-hop dedup, so there is no map to fuse)
-    fused_level = fused_hop and mode == 'merge'
     for i, k in enumerate(fanouts):
-      if fused_level:
-        state, out, epos, m = ops.sample_level_fused(
-            indptr, indices, tab, frontier, fmask, k, keys[i], state,
-            fidx, meta=deg, prefix_cap=node_offs[i], max_new=caps[i + 1],
-            final=(i + 1 == len(fanouts)), window=fused_hop_window,
-            interpret=(fused_hop == 'interpret'))
-      else:
-        with jax.named_scope(hop_scope(i, 'draw')):
-          if padded:
-            nbrs, epos, m = ops.uniform_sample_padded(
-                tab, deg, frontier, fmask, k, keys[i], epos_table=eptab)
-          elif block_num_edges:
-            # deg is the metadata row gather; tab = (csr_meta,
-            # indices_blocks)
-            nbrs, epos, m = ops.uniform_sample_block(
-                deg, tab, block_num_edges, frontier, fmask, k, keys[i])
-          elif weighted:
-            nbrs, epos, m = ops.weighted_sample(
-                indptr, indices, cum, frontier, fmask, k, keys[i])
-          elif fused_hop:
-            # fused sample+gather Pallas hop (ops/sample_fused.py): same
-            # fold_in stream as uniform_sample bit for bit — tab carries
-            # the [E/128, 128] aligned indices view, deg the csr_meta
-            # row table. Off-TPU the op routes its own XLA fallback, so
-            # the flag is safe to leave on in CPU tests ('interpret'
-            # forces the kernel through the Pallas interpreter for
-            # parity coverage).
-            nbrs, epos, m = ops.sample_hop_fused(
-                indptr, indices, tab, frontier, fmask, k, keys[i],
-                meta=deg, window=fused_hop_window,
-                interpret=(fused_hop == 'interpret'))
-          else:
-            # deg slot carries the [N, 2] csr_meta row table for plain
-            # uniform sampling (see _fused_args / ops.uniform_sample)
-            nbrs, epos, m = ops.uniform_sample(
-                indptr, indices, frontier, fmask, k, keys[i], meta=deg)
-        # the frontier feeds the next hop at caps[i+1] width; when
-        # nothing truncates it (no node_budget clamp) the map inducer can
-        # emit it positionally and skip two S-element compaction scatters
-        compact = (i + 1 < len(caps)) and caps[i + 1] < caps[i] * k
-        with jax.named_scope(hop_scope(i, 'induce')):
-          state, out = induce_fn(state, fidx, nbrs, m, node_offs[i],
-                                 compact, final=(i + 1 == len(fanouts)),
-                                 max_new=caps[i + 1])
+      with jax.named_scope(hop_scope(i, 'draw')):
+        if padded:
+          nbrs, epos, m = ops.uniform_sample_padded(
+              tab, deg, frontier, fmask, k, keys[i], epos_table=eptab)
+        elif block_num_edges:
+          # deg is the metadata row gather; tab = (csr_meta,
+          # indices_blocks)
+          nbrs, epos, m = ops.uniform_sample_block(
+              deg, tab, block_num_edges, frontier, fmask, k, keys[i])
+        elif weighted:
+          nbrs, epos, m = ops.weighted_sample(
+              indptr, indices, cum, frontier, fmask, k, keys[i])
+        elif fused_hop:
+          # fused sample+gather Pallas hop (ops/sample_fused.py): same
+          # fold_in stream as uniform_sample bit for bit — tab carries
+          # the [E/128, 128] aligned indices view, deg the csr_meta
+          # row table. Off-TPU the op routes its own XLA fallback, so
+          # the flag is safe to leave on in CPU tests ('interpret'
+          # forces the kernel through the Pallas interpreter for
+          # parity coverage). Every dedup engine induces its output.
+          nbrs, epos, m = ops.sample_hop_fused(
+              indptr, indices, tab, frontier, fmask, k, keys[i],
+              meta=deg, interpret=(fused_hop == 'interpret'))
+        else:
+          # deg slot carries the [N, 2] csr_meta row table for plain
+          # uniform sampling (see _fused_args / ops.uniform_sample)
+          nbrs, epos, m = ops.uniform_sample(
+              indptr, indices, frontier, fmask, k, keys[i], meta=deg)
+      # the frontier feeds the next hop at caps[i+1] width; when
+      # nothing truncates it (no node_budget clamp) the map inducer can
+      # emit it positionally and skip two S-element compaction scatters
+      compact = (i + 1 < len(caps)) and caps[i + 1] < caps[i] * k
+      with jax.named_scope(hop_scope(i, 'induce')):
+        state, out = induce_fn(state, fidx, nbrs, m, node_offs[i],
+                               compact, final=(i + 1 == len(fanouts)),
+                               max_new=caps[i + 1])
       # message direction: neighbor -> seed
       rows.append(out['cols'])
       cols.append(out['rows'])
@@ -492,8 +478,7 @@ class NeighborSampler(BaseSampler):
                node_budget: Optional[int] = None, fused: bool = True,
                dedup: str = 'auto',
                padded_window: Optional[int] = None,
-               frontier_caps=None, use_fused_hop=False,
-               fused_hop_window: int = 512):
+               frontier_caps=None, use_fused_hop=False):
     import jax
     self.graph = graph
     self.num_neighbors = num_neighbors
@@ -593,12 +578,11 @@ class NeighborSampler(BaseSampler):
     # sample+gather Pallas kernel (ops.sample_hop_fused — one staged
     # segment DMA per seed instead of k element gathers). MEASURED-WIN
     # flag, default False (the repo's evidence-gated routing pattern,
-    # like UnifiedTensor.use_pallas): the XLA path is bit-identical —
+    # like UnifiedTensor.use_pallas_v2): the XLA path is bit-identical —
     # same counter-addressed fold_in stream — so flipping it never
     # changes samples. 'interpret' runs the kernel through the Pallas
-    # interpreter (CPU parity tests). fused_hop_window is the staged
-    # segment span per seed (multiple of 128; deg > window seeds take
-    # the per-sample row-DMA path inside the kernel).
+    # interpreter (CPU parity tests). Not yet timed on the chip
+    # (ROADMAP.md S4(4)).
     if use_fused_hop:
       if isinstance(graph, dict):
         raise ValueError('use_fused_hop is homogeneous-only (the typed '
@@ -615,11 +599,7 @@ class NeighborSampler(BaseSampler):
       if not fused:
         raise ValueError('use_fused_hop requires the fused '
                          'multi-hop program (fused=True)')
-      if fused_hop_window % 128 != 0 or fused_hop_window <= 0:
-        raise ValueError('fused_hop_window must be a positive multiple '
-                         'of 128 (aligned row DMAs)')
     self.use_fused_hop = use_fused_hop
-    self.fused_hop_window = fused_hop_window
     self._padded_seed = 0 if seed is None else seed
     self._key = jax.random.PRNGKey(0 if seed is None else seed)
     self._call_count = 0    # host-side PRNG stream position
@@ -769,8 +749,7 @@ class NeighborSampler(BaseSampler):
         mode, g.num_nodes if mode == 'map_table' else 0,
         padded=self.padded_window is not None,
         block_num_edges=nblk_edges,
-        fused_hop=self.use_fused_hop,
-        fused_hop_window=self.fused_hop_window, seed_scope=seed_scope)
+        fused_hop=self.use_fused_hop, seed_scope=seed_scope)
 
   def _padded_arrays(self):
     """Lazily built device-resident padded adjacency (homo).
@@ -821,12 +800,12 @@ class NeighborSampler(BaseSampler):
     128-lane cousin of _block_arrays' [E/16, 16] view). min_rows keeps
     the kernel's staged window slice in bounds on tiny graphs."""
     g = self._get_graph(etype)
-    key = ('indices128', id(g), self.fused_hop_window)
+    key = ('indices128', id(g))
     if key not in self._garrs:
-      from ..ops.sample_fused import LANES
+      from ..ops.sample_fused import LANES, WINDOW
       ga = self._graph_arrays(etype)
       self._garrs[key] = ops.build_indices128(
-          ga['indices'], min_rows=self.fused_hop_window // LANES + 1)
+          ga['indices'], min_rows=WINDOW // LANES + 1)
     return self._garrs[key]
 
   def _csr_meta(self, etype=None):
@@ -878,7 +857,7 @@ class NeighborSampler(BaseSampler):
   def _homo_fn(self, batch_cap: int, fanouts, seed_scope=None):
     sig = ('homo', batch_cap, tuple(fanouts), self.with_edge,
            self.with_weight, self.padded_window, self.strategy,
-           self.use_fused_hop, self.fused_hop_window, seed_scope)
+           self.use_fused_hop, seed_scope)
     if sig not in self._fns:
       from ..metrics import programs
       self._fns[sig] = programs.instrument(
@@ -998,15 +977,9 @@ class NeighborSampler(BaseSampler):
       fn = self._homo_fn(cap, fanouts)
       if self.use_fused_hop:
         # kernel-path observability: batches whose hop program routed
-        # through the fused Pallas kernel (len(fanouts) hops per call).
-        # Under the merge engine the whole LEVEL fuses (sample + gather
-        # + in-kernel dedup, ops.sample_level_fused); other engines fuse
-        # the sample+gather hop only.
+        # through the fused Pallas kernel (len(fanouts) hops per call)
         from .. import metrics
-        if self._dedup_mode() == 'merge':
-          metrics.inc('ops.fused_level_calls')
-        else:
-          metrics.inc('ops.fused_hop_calls')
+        metrics.inc('ops.fused_hop_calls')
       record_dispatch('sample')
       res = fn(*self._fused_args(), jnp.asarray(padded), jnp.asarray(mask),
                key)

@@ -75,33 +75,11 @@ class ServingEngine:
     refresh_fn: ``ids -> [n, F] rows`` final-layer recompute
       (``EmbeddingMaterializer.refresh_rows``) for stale nodes;
       requires a store with ``update_rows`` (the single-replica store).
-    config: a tune artifact (``graphlearn_tpu.tune()``,
-      docs/tuning.md): supplies the calibrated bucket ladder when
-      ``buckets`` is not given explicitly, and refuses a store whose
-      node count drifted from the tuned dataset's.
   """
 
   def __init__(self, store, buckets: Optional[Sequence[int]] = None,
                max_wait_ms: float = 2.0,
-               refresh_fn: Optional[Callable] = None, config=None):
-    if config is not None:
-      tuned_n = (config.dataset or {}).get('num_nodes')
-      store_n = getattr(store, 'num_nodes', None)
-      if tuned_n is not None and store_n is not None and \
-          int(tuned_n) != int(store_n):
-        raise ValueError(
-            f'ServingEngine config= artifact was tuned for '
-            f'{tuned_n} nodes but the store serves {store_n} — '
-            'dataset drifted; re-run graphlearn_tpu.tune() '
-            f'(artifact fingerprint {config.fingerprint}, '
-            'docs/tuning.md)')
-      if buckets is None:
-        buckets = config.serving_kwargs()['buckets']
-      if hasattr(config, 'apply_kernel_routing'):
-        # the tuned gather-kernel choice reaches the engine's store
-        # (EmbeddingStore.set_kernel_routing); stores without the
-        # surface (dist/tiered) simply don't accept it
-        config.apply_kernel_routing(store)
+               refresh_fn: Optional[Callable] = None):
     if buckets is None:
       buckets = DEFAULT_BUCKETS
     buckets = tuple(sorted(int(b) for b in set(buckets)))

@@ -55,14 +55,11 @@ class EmbeddingStore:
 
   granularity = 1
 
-  #: tuned kernel routing (tune/artifact.py apply_kernel_routing):
   #: route the bucket gather through the run-segmented DMA kernel
-  #: (ops.gather_rows_hbm2) at the tuned grid point — the same gate as
-  #: UnifiedTensor: inert off-TPU; on TPU a width the kernel cannot
+  #: (ops.gather_rows_hbm2; set before the first lookup) — the same gate
+  #: as UnifiedTensor: inert off-TPU; on TPU a width the kernel cannot
   #: serve raises
   use_pallas_v2 = False
-  pallas_v2_block_rows = 256
-  pallas_v2_run_span = 8
 
   def __init__(self, embeddings, num_nodes: Optional[int] = None):
     import jax
@@ -75,18 +72,6 @@ class EmbeddingStore:
     # one-executable-per-bucket without per-cap bookkeeping here
     self._gather = None
     self._scatter = None
-    self._kernel_routed = False
-
-  def set_kernel_routing(self, use_pallas_v2: bool = False,
-                         block_rows: int = 256, run_span: int = 8):
-    """Apply a tuned-artifact kernel choice to the lookup gather.
-    Rebuilds the gather program on the next lookup; the bucket set and
-    semantics are unchanged (the kernel is bit-identical to the XLA
-    gather — ops/gather_pallas.py)."""
-    self.use_pallas_v2 = bool(use_pallas_v2)
-    self.pallas_v2_block_rows = int(block_rows)
-    self.pallas_v2_run_span = int(run_span)
-    self._gather = None
 
   @property
   def feature_dim(self) -> int:
@@ -98,15 +83,12 @@ class EmbeddingStore:
       import jax.numpy as jnp
       from ..ops.gather_pallas import (_gather_rows_hbm2_impl,
                                        table_kernel_ok)
-      self._kernel_routed = self.use_pallas_v2 and table_kernel_ok(
-          'EmbeddingStore', self._emb)
-      if self._kernel_routed:
-        br, rs = self.pallas_v2_block_rows, self.pallas_v2_run_span
+      if self.use_pallas_v2 and table_kernel_ok('EmbeddingStore',
+                                                self._emb):
 
         def gather(emb, ids, mask):
           rows = _gather_rows_hbm2_impl(
-              emb, jnp.maximum(ids, 0).astype(jnp.int32), br, rs,
-              False, False)
+              emb, jnp.maximum(ids, 0).astype(jnp.int32))
           return jnp.where(mask[:, None], rows, 0)
       else:
 
@@ -122,10 +104,12 @@ class EmbeddingStore:
   def lookup(self, ids, mask):
     """[cap] padded ids (-1 pads, mask False) -> [cap, F] device rows.
     One dispatch; the capacity's program persists across requests."""
+    import jax
     import jax.numpy as jnp
     ids = jnp.asarray(ids)
     fn = self._gather_fn()
-    if self._kernel_routed:
+    if self.use_pallas_v2 and jax.default_backend() == 'tpu':
+      # the program above routed the kernel (it raised otherwise)
       from .. import metrics
       metrics.inc('ops.gather_runs')
     record_dispatch('serve_lookup')

@@ -114,13 +114,12 @@ class TieredDistScanTrainer(DistScanTrainer):
   """
 
   _NAME = 'TieredDistScanTrainer'
-  _TOPOLOGY = 'tiered_dist'
 
   def __init__(self, loader, model, tx, num_classes: int,
-               chunk_size: Optional[int] = None,
+               chunk_size: int = 32,
                seed_labels_only: Optional[bool] = None,
                perm_seed: Optional[int] = None, max_ahead: int = 2,
-               stage_timeout_s: float = 30.0, config=None):
+               stage_timeout_s: float = 30.0):
     sampler = getattr(loader, 'sampler', None)
     store = getattr(sampler, 'dist_feature', None)
     # homo or hetero, ONE store contract: every feature store the chunk
@@ -170,28 +169,9 @@ class TieredDistScanTrainer(DistScanTrainer):
           hint='give every ntype its own spill_dir (e.g. '
                'os.path.join(root, ntype)) so each store keeps its own '
                'sorted-row tables')
-    if config is not None:
-      # config= takes a tune artifact (docs/tuning.md 'Topology
-      # candidates'). hot_prefix_rows is a STORE-construction knob —
-      # the trainer cannot apply it after the fact, so a tuned value
-      # that disagrees with the store it is handed is a loud error,
-      # not a silent acceptance of untuned capacity
-      tuned_hot = (config.choices or {}).get('hot_prefix_rows') \
-          if hasattr(config, 'choices') else None
-      for t, s in stores.items():
-        want = (tuned_hot.get(t) if isinstance(tuned_hot, dict)
-                else tuned_hot)
-        if want is not None and int(want) != int(s.hot_prefix_rows):
-          raise ValueError(
-              f'{self._NAME}: tune artifact pins hot_prefix_rows='
-              f'{int(want)} but the TieredDistFeature store'
-              f'{"" if t is None else f" for ntype {t!r}"} was '
-              f'built with hot_prefix_rows={int(s.hot_prefix_rows)} '
-              '— rebuild the store with the tuned value (the knob is '
-              'storage layout, not a trainer kwarg; docs/tuning.md)')
     self._store = store
     super().__init__(loader, model, tx, num_classes, chunk_size,
-                     seed_labels_only, perm_seed, config=config)
+                     seed_labels_only, perm_seed)
     if self.is_hetero:
       # one staging pipeline per sampled feature-bearing ntype — the
       # CapacityPlan's node_caps pick the set; each ntype's slab caps

@@ -169,10 +169,10 @@ class TieredScanTrainer(ScanTrainer):
   _NAME = 'TieredScanTrainer'
 
   def __init__(self, loader: NodeLoader, model, tx, num_classes: int,
-               chunk_size: Optional[int] = None,
+               chunk_size: int = 32,
                seed_labels_only: Optional[bool] = None,
                perm_seed: Optional[int] = None, max_ahead: int = 2,
-               stage_timeout_s: float = 30.0, config=None):
+               stage_timeout_s: float = 30.0):
     refuse_tables(loader, self._NAME)
     refuse_link(loader, self._NAME)
     store = loader.data.node_features
@@ -182,10 +182,8 @@ class TieredScanTrainer(ScanTrainer):
           f'{type(store).__name__}; use loader.ScanTrainer for all-HBM '
           'Feature tables')
     self._store = store
-    # config= takes a tune artifact (docs/tuning.md): fingerprint-
-    # validated in ScanTrainer.__init__, supplies the tuned chunk K
     super().__init__(loader, model, tx, num_classes, chunk_size,
-                     seed_labels_only, perm_seed, config=config)
+                     seed_labels_only, perm_seed)
     self._stager = ChunkStager(store, max_ahead=max_ahead,
                                timeout_s=stage_timeout_s)
     self.last_plan = None   # EpochPlan of the most recent epoch

@@ -281,7 +281,6 @@ class TieredFeature(Feature):
     # init_from only sees the hot block; stamp the tiered host span
     ut._host_rows_n = self.warm_rows + self.disk_rows
     self._unified = ut
-    self._stamp_kernel_routing()
     if self._id2index is not None:
       import jax
       self._id2index_dev = jax.device_put(self._id2index, self.device)

@@ -72,7 +72,6 @@ REGISTERED_SITES = frozenset({
     'tenant.admit',
     'tenant.throttle',
     'tenant.reap',
-    'tune.shadow_retune',
 })
 
 

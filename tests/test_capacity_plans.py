@@ -6,9 +6,8 @@ The contracts under test, in order:
 * **The plan artifact** — the homo degenerate plan (one ntype, one
   implicit etype, stride 1) and the typed hetero plan agree with the
   engine kernels they wrap: hop/node/edge caps, per-(hop, etype) PRNG
-  draw counts, the closed frame key set, and a JSON-stable
-  fingerprint payload. ``CapacityPlanError`` names the consumer, the
-  missing input, and the doc anchor.
+  draw counts and the closed frame key set. ``CapacityPlanError``
+  names the consumer, the missing input, and the doc anchor.
 * **Link ack provenance** — LINK block frames carry the seed edge
   endpoints (``#META.edge_batch``) with the true pre-pad count, read
   back through ``sampler.ack_edge_ids``; node frames return None.
@@ -21,11 +20,6 @@ The contracts under test, in order:
   stores sharing one spill_dir are refused at construction (their
   part_NNN spill files would silently overwrite); crash + resume is
   bit-identical.
-* **Typed tune artifacts** — tune() on a hetero dataset emits a
-  fingerprinted v3 artifact with per-etype fanout candidates in
-  evidence; the artifact round-trips through ``config=`` on
-  RemoteScanTrainer / DistScanTrainer / TieredDistScanTrainer, and a
-  drifted or mis-shaped consumer is refused loudly.
 """
 import tempfile
 
@@ -116,16 +110,6 @@ class TestCapacityPlanUnit:
         'x.item' in keys
     assert any(k.startswith('row.') for k in keys)
     assert 'batch.user' in keys and 'y.user' in keys
-
-  def test_fingerprint_payload_json_stable(self):
-    import json
-    plan = CapacityPlan.hetero([UB, BU], FANOUTS, {'user': BS}, 'out',
-                               input_type='user')
-    payload = plan.fingerprint_payload()
-    assert json.loads(json.dumps(payload)) == payload
-    # etype keys are stringified (JSON round-trip safe)
-    assert all(isinstance(k, str) for per in payload['hop_caps']
-               for k in per)
 
   def test_from_sampler_requires_input_type(self):
     ds = make_hetero_dataset()
@@ -580,97 +564,3 @@ def test_hetero_tiered_crash_resume_bit_identical(tmp_path):
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
   assert fresh._epochs == 1
   fresh.close()
-
-
-# --------------------------------------------------- typed tune artifacts
-
-
-def test_hetero_tune_v3_artifact_and_config_acceptance(tmp_path):
-  """tune() on a hetero dataset emits a fingerprinted v3 artifact with
-  per-etype fanout candidates in evidence; the artifact round-trips
-  through ``config=`` on RemoteScanTrainer (structural validation —
-  the client holds no dataset), and typed artifacts signed with the
-  dist fingerprint are accepted by DistScanTrainer and
-  TieredDistScanTrainer, with drifted shapes refused loudly."""
-  from graphlearn_tpu.tune import TuneArtifact
-  from graphlearn_tpu.tune.artifact import dataset_fingerprint
-  ds = make_hetero_dataset()
-  seeds = np.arange(NU)
-  path = str(tmp_path / 'hetero_tune.json')
-  art = glt.tune(ds, dict(fanouts=FANOUTS, input_nodes=('user', seeds),
-                          batch_size=BS),
-                 probe_steps=2, out_path=path)
-  assert art.version == 3
-  assert art.dataset is not None and art.dataset.get('hetero') is True
-  # per-etype fanout candidates were fielded (typed_base + trims)
-  cand_names = {r.get('name') for r in art.evidence
-                if r.get('kind') == 'candidate'}
-  assert 'typed_base' in cand_names
-  assert any(n.startswith('trim_') for n in cand_names)
-  # choices carry JSON-safe stringified etype keys
-  assert isinstance(art.choices['fanouts'], dict)
-  assert set(art.choices['fanouts']) == \
-      {'user__buys__item', 'item__rev_buys__user'}
-
-  loaded = TuneArtifact.load(path)
-  assert loaded.fingerprint == art.fingerprint
-  # a fresh identical dataset validates; a drifted one is refused
-  loaded.validate_dataset(make_hetero_dataset(), where='test')
-  drifted = make_hetero_dataset()
-  drifted.init_node_features(
-      {'user': np.zeros((NU, 7), np.float32),
-       'item': np.zeros((NI, 7), np.float32)})
-  with pytest.raises(ValueError, match='fingerprint mismatch'):
-    loaded.validate_dataset(drifted, where='test')
-
-  # remote acceptor: the trainer streams at the artifact's tuned
-  # per-etype fanouts (string keys round-trip back to etype tuples),
-  # takes the tuned chunk K, and refuses mismatched fanout shapes
-  tuned_fans = {glt.typing.to_edge_type(k): v
-                for k, v in loaded.choices['fanouts'].items()}
-  pairs = [_start_block_server(ds)]
-  try:
-    _init_client(pairs)
-    model, tx, _, _ = _rgnn_model_state(ds, seeds)
-    trainer = glt.distributed.RemoteScanTrainer(
-        tuned_fans, ('user', seeds), model, tx, CLASSES, batch_size=BS,
-        seed=0, config=loaded,
-        worker_options=glt.distributed.RemoteDistSamplingWorkerOptions(
-            server_rank=0))
-    assert trainer.chunk_size == \
-        int(loaded.trainer_kwargs()['chunk_size'])
-    trainer.shutdown()
-    with pytest.raises(ValueError, match='fanouts'):
-      glt.distributed.RemoteScanTrainer(
-          {UB: [3, 3], BU: [3, 3]}, ('user', seeds), model, tx,
-          CLASSES, batch_size=BS, seed=0, config=loaded,
-          worker_options=glt.distributed.RemoteDistSamplingWorkerOptions(
-              server_rank=0))
-  finally:
-    _teardown(pairs)
-
-  # dist + tiered acceptors: v3 artifacts signed with the MATCHING
-  # dist dataset's typed fingerprint round-trip through config=
-  dist_loader = make_tiered_loader(False)
-  dist_fp = dataset_fingerprint(dist_loader.data)
-  assert dist_fp is not None and dist_fp.get('hetero') is True
-  assert set(dist_fp['num_nodes']) == {'u', 'v'}
-  dist_art = TuneArtifact(dict(chunk_k=2, batch_size=2),
-                          dataset=dist_fp)
-  dist_path = str(tmp_path / 'dist.json')
-  dist_art.save(dist_path)
-  dist_art = TuneArtifact.load(dist_path)
-  model, tx = _tiered_model_tx()
-  tr = glt.loader.DistScanTrainer(dist_loader, model, tx, 3,
-                                  config=dist_art)
-  assert tr.chunk_size == 2
-  tmp = tempfile.mkdtemp(prefix='glt_hetero_tiered_cfg_')
-  tr2 = TieredDistScanTrainer(make_tiered_loader(True, spill_dir=tmp),
-                              model, tx, 3, config=dist_art)
-  assert tr2.chunk_size == 2
-  tr2.close()
-  # the LOCAL hetero artifact must NOT validate against the dist
-  # dataset — different typed fingerprints, refused loudly
-  with pytest.raises(ValueError, match='fingerprint mismatch'):
-    glt.loader.DistScanTrainer(make_tiered_loader(False), model, tx, 3,
-                               config=loaded)

@@ -200,8 +200,6 @@ def test_dist_feature_wire_dtype():
       np.asarray(ref16.get(ids)).astype(np.float32))
 
 
-@pytest.mark.slow  # tier-1 budget (PR 18): hetero variant — the homo
-# cache bit-exact matrix and the stats/loader-epoch test stay tier-1
 def test_dist_feature_hetero_cached_loader_end_to_end():
   """Hetero: per-type cached stores through DistNeighborLoader produce
   byte-identical batch features vs uncached stores."""

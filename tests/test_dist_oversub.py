@@ -296,7 +296,6 @@ def test_per_step_demand_paged_get_bit_identical():
     over.device_arrays()
 
 
-@pytest.mark.slow  # tier-1 budget: shuffle=False is the equivalence rep
 def test_tiered_dist_scan_shuffle_bit_identical():
   """shuffle=True: the plan program's in-shard_map permutation draw is
   bit-identical to the base seed program's plain-jit draw, so the

@@ -173,7 +173,6 @@ def test_dist_scan_matches_per_step_homo_8dev():
                   chunk=2)
 
 
-@pytest.mark.slow  # tier-1 budget: homo_8dev stays the equivalence rep
 def test_dist_scan_matches_per_step_hetero():
   """Typed engine equivalence on a 2-partition mesh: the scanned chunk
   inlines _hetero_engine + per-ntype cached feature lookups (one stats
@@ -299,7 +298,6 @@ def test_dist_scan_rejects_remote_and_recompute():
                                chunk_size=0)
 
 
-@pytest.mark.slow  # tier-1 budget: compiles its own capped programs
 def test_dist_scan_overflow_guard():
   """Calibrated-caps overflow rides the scan carry psum-replicated:
   'raise' fires at epoch end with zero in-epoch syncs; a max_steps

@@ -181,12 +181,7 @@ def test_route_overflow_counter():
   assert int(nov) == 0 and bool(ok.all())
 
 
-# tier-1 wall budget (conftest canary): the full-width posture (None)
-# cannot overflow by construction — the interesting legs are the
-# fractional default (2.0) and the forced-fallback fraction (0.25),
-# which stay as the family's tier-1 representatives
-@pytest.mark.parametrize('bucket_frac', [
-    pytest.param(None, marks=pytest.mark.slow), 2.0, 0.25])
+@pytest.mark.parametrize('bucket_frac', [None, 2.0, 0.25])
 def test_dist_sampler_bucket_frac_loss_free(bucket_frac):
   """Sub-frontier exchange buckets (capacity = frac * frontier / P with
   the replicated full-width fallback) keep the loss-free contract at
@@ -216,14 +211,7 @@ def test_dist_sampler_bucket_frac_loss_free(bucket_frac):
       assert v in ((u + 1) % N, (u + 2) % N), (bucket_frac, u, v)
 
 
-@pytest.mark.parametrize('bucket_frac', [
-    # both variants now slow (tier-1 wall-budget canary):
-    # test_dist_hier_exchange_skewed_fallback_s4 stays as the tier-1
-    # hier-exchange rep (fractional DCN stage + replicated fallback at
-    # slice=4), and the slow hier scanned-epoch equivalence covers the
-    # 2-axis program end to end
-    pytest.param(2.0, marks=pytest.mark.slow),
-    pytest.param(0.25, marks=pytest.mark.slow)])
+@pytest.mark.parametrize('bucket_frac', [2.0, 0.25])
 def test_dist_sampler_two_axis_mesh(bucket_frac):
   """The same sampling program runs on a 2-axis (slice, chip) mesh —
   the multi-slice layout: the hierarchical 2-stage exchange transposes
@@ -392,7 +380,6 @@ def test_dist_link_sampler_binary():
     np.testing.assert_array_equal(label[p], [1, 1, 0, 0])
 
 
-@pytest.mark.slow  # tier-1 budget: multi-seed scan; full suite runs it
 def test_dist_link_negatives_strict():
   """neg_strict=True on a dense graph: every mask-VALID negative pair is
   guaranteed a non-edge (the shard-local check is complete because each
@@ -984,9 +971,6 @@ def test_dist_frontier_caps_sufficient_no_overflow():
       assert v in ((u + 1) % N, (u + 2) % N)
 
 
-@pytest.mark.slow   # tier-1 wall budget: the overflow FLAG stays
-# tier-1-covered by test_dist_link_frontier_caps_overflow and the local
-# loader policy tests; this is the full dist policy matrix
 def test_dist_frontier_caps_overflow_flag_and_policies():
   """Too-small caps: the replicated on-device flag trips; the loader's
   default policy raises at epoch end; 'recompute' replays offenders at
@@ -1120,8 +1104,6 @@ def worst_caps_from_plan(hop_caps):
   return worst
 
 
-@pytest.mark.slow  # tier-1 budget (PR 19): dist variant — the local
-# hetero calibrated-caps structure test stays the tier-1 rep
 def test_dist_hetero_calibrated_caps():
   """Dict-form calibrated caps on the DISTRIBUTED typed engine
   (round-5 parity with the local hetero clamps): caps at the plan's own

@@ -463,9 +463,6 @@ def test_overflow_guard_edges():
   assert loader._ovf_accum is None
 
 
-@pytest.mark.slow  # tier-1 budget (PR 18): loader-layer hetero-caps
-# policies — the sampler-layer structure/overflow test and the dist
-# hetero-caps test stay tier-1 as the family reps
 def test_hetero_loader_calibrated_caps_policies():
   """Hetero NeighborLoader under dict-form calibrated caps: quiet epoch
   with calibrated caps under the default raise policy; tiny caps raise

@@ -413,7 +413,6 @@ def test_flight_record_dist_loader_feature_bitmatch(monkeypatch,
   assert rec['dispatch'] is None               # no region was active
 
 
-@pytest.mark.slow  # tier-1 wall budget (PR 8): the LOCAL ScanTrainer
 def test_flight_record_dist_scan_trainer(monkeypatch, tmp_path):
   # flight bit-match stays tier-1, and the dist feature-stats parity is
   # carried by test_dist_scan_epoch's equivalence protocol

@@ -200,7 +200,6 @@ def test_merge_dense_matches_segment():
                                  rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.slow  # tier-1 budget: SAGE merge-dense variant stays tier-1
 def test_merge_dense_gat_matches_segment():
   """MergeGATConv's per-target k-run softmax == segment-softmax GATConv
   on merge batches (seed logits identical), incl. calibrated caps."""
@@ -573,8 +572,6 @@ def test_hierarchical_hgt_matches_full(dedup):
                              rtol=5e-5, atol=5e-5)
 
 
-@pytest.mark.slow  # tier-1 budget (PR 16): zero-degree variant of
-# test_merge_dense_matches_segment, which stays tier-1
 def test_merge_dense_zero_degree_leading_seed():
   """Dense block writes must stay aligned when the FIRST run of a hop
   block has every edge masked (a zero-out-degree seed): its target
@@ -619,7 +616,6 @@ def test_merge_dense_zero_degree_leading_seed():
                                rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.slow  # tier-1 budget: hgt_tree_dense variant stays tier-1
 def test_tree_dense_hetero_matches_segment():
   """TreeHeteroConv's typed dense k-run aggregation == HeteroConv over
   per-etype segment convs on hetero tree batches (seed logits), for

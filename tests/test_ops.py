@@ -529,42 +529,26 @@ def test_node_subgraph_bucketed_celebrity():
 
 # ---------------------------------------------------------------- pallas
 
-def test_gather_rows_hbm_interpret():
-  """Pallas row-gather kernel vs numpy, via the interpreter (no TPU in
-  the test env); exercises non-128-aligned F, duplicate ids, and padding
-  of B to the block size."""
-  rng = np.random.default_rng(0)
-  table = rng.random((97, 100), np.float32)
-  tdev = jnp.asarray(table)
-  ids = np.array([0, 96, 7, 7, 45, 3, 8, 12, 1, 0, 33], np.int32)
-  out = ops.gather_rows_hbm(tdev, jnp.asarray(ids), block_rows=4,
-                            interpret=True)
-  np.testing.assert_allclose(np.asarray(out), table[ids])
-  # fallback path off-TPU without interpret
-  out = ops.gather_rows_hbm(tdev, jnp.asarray(ids))
-  np.testing.assert_allclose(np.asarray(out), table[ids])
-  # out-of-range ids clamp instead of faulting
-  out = ops.gather_rows_hbm(tdev, jnp.asarray(np.array([200, -5], np.int32)),
-                            block_rows=2, interpret=True)
-  np.testing.assert_allclose(np.asarray(out), table[[96, 0]])
-
-
 def test_gather_rows_hbm_force_misaligned_falls_back():
-  """Regression (ISSUE 13): force=True on a misaligned table width used
-  to reach Mosaic and fail to lower — force must yield to the 128-lane
-  alignment guard (with a warning) and return the bit-identical XLA
-  fallback instead. interpret=True keeps honoring force (the Pallas
-  interpreter has no lane constraint; the v1 test above relies on it)."""
+  """Regression: force=True on a misaligned table width used to reach
+  Mosaic and fail to lower — force must yield to the 128-lane alignment
+  guard (with a warning) and return the bit-identical XLA fallback
+  instead. interpret=True keeps honoring force (the Pallas interpreter
+  has no lane constraint)."""
   import warnings
   rng = np.random.default_rng(2)
   table = rng.random((64, 100), np.float32)     # 100 % 128 != 0
   ids = np.array([3, 0, 63, 17], np.int32)
-  for fn in (ops.gather_rows_hbm, ops.gather_rows_hbm2):
-    with warnings.catch_warnings(record=True) as wlog:
-      warnings.simplefilter('always')
-      out = fn(jnp.asarray(table), jnp.asarray(ids), force=True)
-    assert any('128-lane' in str(w.message) for w in wlog), fn
-    np.testing.assert_array_equal(np.asarray(out), table[ids])
+  with warnings.catch_warnings(record=True) as wlog:
+    warnings.simplefilter('always')
+    out = ops.gather_rows_hbm2(jnp.asarray(table), jnp.asarray(ids),
+                               force=True)
+  assert any('128-lane' in str(w.message) for w in wlog)
+  np.testing.assert_array_equal(np.asarray(out), table[ids])
+  # the interpreter serves the same misaligned width through the kernel
+  out = ops.gather_rows_hbm2(jnp.asarray(table), jnp.asarray(ids),
+                             block_rows=2, run_span=2, interpret=True)
+  np.testing.assert_array_equal(np.asarray(out), table[ids])
 
 
 def test_plan_gather_runs_covers_every_slot_exactly_once():
@@ -642,7 +626,7 @@ def test_gather_rows_hbm2_interpret_parity():
                                    block_rows=16, run_span=4,
                                    presorted=True, interpret=True)
         np.testing.assert_array_equal(np.asarray(out), ref_np[ids])
-  # clamping matches take's contract (same as v1)
+  # clamping matches take's contract
   t = jnp.asarray(tables['f32'])
   out = ops.gather_rows_hbm2(t, jnp.asarray(np.array([900, -3], np.int32)),
                              block_rows=4, run_span=2, interpret=True)
@@ -691,8 +675,42 @@ def test_sample_hop_fused_interpret_parity():
               np.asarray(a), np.asarray(b)), (window, k, what)
 
 
-@pytest.mark.slow  # tier-1 budget (PR 18): counter-stream variant of
-# test_sample_hop_fused_interpret_parity, which stays tier-1
+@pytest.mark.parametrize('fanouts,tight', [
+    ([5], False), ([10, 5], False), ([15, 10, 5], False),
+    ([15, 10, 5], True)])
+def test_fused_hop_default_dedup_matches_xla_sampler(fanouts, tight):
+  """use_fused_hop='interpret' under the default dedup (the merge
+  engine) draws each hop through the sample_hop_fused kernel and
+  induces it with the merge inducer: nodes, edges, masks and the
+  overflow flag equal the plain sampler's under calibrated caps, and
+  under caps cut to a quarter, where the flag trips."""
+  import graphlearn_tpu as glt
+  from graphlearn_tpu.sampler import NodeSamplerInput
+  rng = np.random.default_rng(7)
+  n, e, b = 300, 2400, 16
+  rows, cols = rng.integers(0, n, e), rng.integers(0, n, e)
+  g = glt.data.Graph(glt.data.Topology(np.stack([rows, cols]),
+                                       num_nodes=n), 'CPU')
+  caps = glt.sampler.estimate_frontier_caps(g, fanouts, b, num_probes=3)
+  if tight:
+    caps = [max(8, c // 4) for c in caps]
+  s_ref = glt.sampler.NeighborSampler(g, fanouts, seed=3, with_edge=True,
+                                      frontier_caps=caps)
+  s_fh = glt.sampler.NeighborSampler(g, fanouts, seed=3, with_edge=True,
+                                     frontier_caps=caps,
+                                     use_fused_hop='interpret')
+  assert s_fh._dedup_mode() == 'merge'
+  seeds = rng.integers(0, n, b)
+  a = s_ref.sample_from_nodes(NodeSamplerInput(seeds), batch_cap=b)
+  c = s_fh.sample_from_nodes(NodeSamplerInput(seeds), batch_cap=b)
+  for field in ('node', 'row', 'col', 'edge', 'edge_mask'):
+    np.testing.assert_array_equal(np.asarray(getattr(a, field)),
+                                  np.asarray(getattr(c, field)), field)
+  assert int(a.num_nodes) == int(c.num_nodes)
+  overflow = bool(np.asarray(a.metadata['overflow']))
+  assert overflow == bool(np.asarray(c.metadata['overflow'])) == tight
+
+
 def test_sample_hop_fused_stream_matches_sampler_counters():
   """Same fold_in counters -> identical edges: a NeighborSampler with
   use_fused_hop='interpret' (kernel exercised through the Pallas
@@ -712,8 +730,7 @@ def test_sample_hop_fused_stream_matches_sampler_counters():
                                         with_edge=True)
     s_fh = glt.sampler.NeighborSampler(g, [4, 3], seed=11, dedup=dedup,
                                        with_edge=True,
-                                       use_fused_hop='interpret',
-                                       fused_hop_window=128)
+                                       use_fused_hop='interpret')
     for _ in range(3):
       seeds = rng.integers(0, n, 16)
       a = s_ref.sample_from_nodes(NodeSamplerInput(seeds), batch_cap=16)

@@ -65,8 +65,7 @@ def _model_state(ds, tx=None):
   return model, tx, state
 
 
-@pytest.mark.parametrize('shuffle', [
-    False, pytest.param(True, marks=pytest.mark.slow)])  # tier-1 budget
+@pytest.mark.parametrize('shuffle', [False, True])
 def test_run_trainer_bit_identical_and_budget(shuffle):
   """E=3 epochs in ceil(E*steps/K)+2 dispatches, losses/params
   bit-identical to three sequential ScanTrainer epochs — ragged tail

@@ -210,9 +210,6 @@ def test_scan_trainer_dispatch_count():
   assert dc_loop.counts['sample'] == steps
 
 
-@pytest.mark.slow  # tier-1 budget (PR 18): kernel-routed variant of
-# test_scan_trainer_dispatch_count (budget rep stays tier-1); the fused
-# hop's kernel parity rides test_ops interpret-parity
 def test_scan_dispatch_budget_with_fused_hop_kernel_routed():
   """ISSUE 13 acceptance: routing the fused sample+gather Pallas hop
   into the scanned epoch (use_fused_hop='interpret' exercises the real

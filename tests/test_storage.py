@@ -268,7 +268,6 @@ def test_tiered_scan_bit_parity_and_budget(tmp_path, hbm_run):
   tr_b.close()
 
 
-@pytest.mark.slow
 def test_tiered_scan_shuffle_parity(tmp_path):
   """shuffle=True: both trainers draw the SAME on-device permutation
   (same perm seed), so the tiered epoch stays bit-identical. (The
@@ -289,9 +288,6 @@ def test_tiered_scan_shuffle_parity(tmp_path):
   tr_b.close()
 
 
-@pytest.mark.slow  # tier-1 budget (PR 19): the staged-plan contract rides
-# test_tiered_scan_bit_parity_and_budget in tier-1; this host-replay
-# diagnostic runs in the full suite (both shuffle modes)
 @pytest.mark.parametrize('shuffle', [False, True])
 def test_plan_matches_host_replay(tmp_path, shuffle):
   """Prologue plan correctness: the fused device plan (sampler replay
@@ -322,8 +318,6 @@ def test_plan_matches_host_replay(tmp_path, shuffle):
   tr.close()
 
 
-@pytest.mark.slow  # tier-1 budget (PR 19): overlap-timing variant —
-# staging correctness rides the tiered bit-parity tier-1 rep
 def test_chunk_boundary_overlap(tmp_path):
   """Stage of chunk c+1 completes BEFORE chunk c's ack when the device
   is slow: wrap the chunk dispatch in a deterministic blocking stub
@@ -358,8 +352,6 @@ def test_chunk_boundary_overlap(tmp_path):
   tr.close()
 
 
-@pytest.mark.slow  # tier-1 budget (PR 19): program-set closure also
-# asserted by the compile-count checks in the tune/dist_oversub reps
 def test_pow2_staging_shape_closure(tmp_path):
   """One executable per (chunk length, slab cap) shape: epoch 2 of a
   shuffle=False run presents the identical pow2 shape set, so the
@@ -416,8 +408,6 @@ def test_degraded_sync_fallback_chaos(tmp_path, hbm_run):
   tr.close()
 
 
-@pytest.mark.slow  # tier-1 budget (PR 19): seam unit variant — the
-# recovery crash-resume reps exercise the stage/ack seams tier-1
 def test_scan_trainer_stage_ack_hooks(tmp_path):
   """The generic chunk-boundary hooks on the base ScanTrainer (the
   seam DistScanTrainer shares): stage_hook fires before each chunk
@@ -441,8 +431,6 @@ def test_scan_trainer_stage_ack_hooks(tmp_path):
 # -------------------------------------------------- observability + flight
 
 
-@pytest.mark.slow  # tier-1 budget (PR 19): observability variant — the
-# tiered bit-parity rep and test_metrics flight bitmatch stay tier-1
 def test_storage_flight_and_metrics(tmp_path, monkeypatch):
   """The tiered epoch's flight record carries the per-epoch staging
   deltas in its 'storage' field, and the staging metrics land in the
